@@ -1,4 +1,4 @@
-"""On-TPU autotune sweep (VERDICT r3 #8): block sizes for flash fwd+bwd
+"""On-TPU autotune sweep: block sizes for flash fwd+bwd
 and decode_mha at the llama bench/serving shapes, persisted to the
 IN-REPO cache (.autotune_cache.json) so `bench.py` picks tuned blocks on
 first run. Commit the file after a successful sweep.
@@ -7,9 +7,10 @@ Run: python experiments/exp_autotune_sweep.py        (TPU; ~3-5 min)
 
 Each tune target runs in its OWN subprocess with a wall-clock budget
 (EXP_TRIAL_SECS, default 900) and saves its winner into the repo cache
-INCREMENTALLY (AutoTuneCache.load merges) — the 2026-07-31 session hung
-in the first trial's remote compile and produced nothing; with per-trial
-isolation a wedged compile costs one entry, not the sweep.
+INCREMENTALLY (AutoTuneCache.load merges): with per-trial isolation a
+hung compile costs one entry, not the sweep. Trials run one at a time
+and this parent never touches JAX, so one process at a time wants the
+chip.
 """
 import json
 import os
@@ -39,26 +40,15 @@ def tune_one(spec: dict):
     if os.environ.get("EXP_FORCE_CPU"):
         jax.config.update("jax_platforms", "cpu")
 
-    cache = os.path.join(REPO, ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from paddle_tpu.device.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     from paddle_tpu.ops import autotune
 
-    # FRESH table, then merge ONLY the repo file: a per-user cache
-    # (CPU/interpret entries from prior tune() auto-saves) must never
-    # leak into the committed real-hardware file; merging the repo file
-    # first makes each trial's save incremental instead of clobbering
-    repo_cache = os.path.join(REPO, ".autotune_cache.json")
-    autotune._GLOBAL = autotune.AutoTuneCache()
-    autotune._loaded[0] = True
-    try:
-        autotune._GLOBAL.load(repo_cache)
-    except (OSError, ValueError) as e:  # corrupt file loses one merge,
-        print(json.dumps({"warning":     # not the whole sweep
-                          f"repo cache unreadable ({e}); starting fresh"}),
-              flush=True)
-    autotune.set_cache_path(repo_cache)
+    # the in-repo file is the only table autotune reads; naming it as the
+    # write path makes each trial's save an incremental merge into it
+    autotune.set_cache_path(os.path.join(REPO, ".autotune_cache.json"))
     if jax.default_backend() != "tpu":
         print(json.dumps({"warning": "not on TPU — sweep would record "
                           "meaningless CPU timings; refusing to persist"}))

@@ -27,10 +27,9 @@ def run(name):
     import jax
     import jax.numpy as jnp
 
-    cache = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from paddle_tpu.device.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     from paddle_tpu.models import LlamaForCausalLM, llama_config
     from paddle_tpu.models.llama_functional import (build_train_step,

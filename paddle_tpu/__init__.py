@@ -9,36 +9,6 @@ from __future__ import annotations
 
 import importlib
 
-# jax compat: shard_map graduated out of jax.experimental after 0.4.x
-# (renaming check_rep → check_vma on the way), and the codebase imports
-# the graduated name (`from jax import shard_map`) with the graduated
-# kwargs. Alias a translating wrapper on older jax so every internal
-# module and user script sees one spelling.
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    try:
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        def _shard_map_compat(*args, **kwargs):
-            if "check_vma" in kwargs:
-                kwargs["check_rep"] = kwargs.pop("check_vma")
-            if "axis_names" in kwargs:
-                # graduated API: axis_names = the axes shard_map manages;
-                # experimental spelling: auto = the complement
-                names = frozenset(kwargs.pop("axis_names"))
-                mesh = kwargs.get("mesh",
-                                  args[1] if len(args) > 1 else None)
-                if mesh is not None:
-                    auto = frozenset(mesh.axis_names) - names
-                    if auto:
-                        kwargs["auto"] = auto
-            return _shard_map(*args, **kwargs)
-
-        _jax.shard_map = _shard_map_compat
-    except Exception:  # pragma: no cover - very old jax: leave unpatched
-        pass
-
 # dtypes
 from .core.dtype import (
     bfloat16,

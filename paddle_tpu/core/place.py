@@ -59,8 +59,9 @@ def _devices_of_kind(kind: str):
             return tuple(jax.devices("cpu"))
         except RuntimeError:
             return tuple()
-    accel = [d for d in jax.devices() if d.platform != "cpu"]
-    return tuple(accel) if accel else tuple(jax.devices())
+    # no accelerator -> empty, and jax_device() raises: a TPUPlace never
+    # hands back CPU devices under another name
+    return tuple(d for d in jax.devices() if d.platform != "cpu")
 
 
 _current_device = [None]

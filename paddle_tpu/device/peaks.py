@@ -6,20 +6,20 @@ achieved FLOP/s and bytes/s; THIS module supplies the denominator —
 the peak the hardware could do — so MFU and the roofline verdict
 (memory-bound vs compute-bound) mean the same thing across backends:
 
-- **TPU**: a static per-generation table keyed by substring match on
-  ``device_kind`` (bf16 dense peaks + HBM bandwidth). The v4/v5 compute
-  numbers intentionally match the ones ``bench.py`` has used for every
-  recorded ``BENCH_r*.json`` MFU, so ledger MFU and the training-bench
-  MFU stay comparable across rounds.
-- **CPU** (the tier-1/test backend): no meaningful datasheet number
-  exists, so the peak is CALIBRATED once per process — a small timed
-  matmul for FLOP/s, a timed device-array copy for bytes/s — and
-  cached. Calibrated MFU is only comparable within one host, which is
-  exactly what a CPU A/B needs (and why the record carries
-  ``source: "calibrated"``).
+- **TPU**: a static table keyed by the EXACT ``device_kind`` string the
+  chip reports (bf16 dense peak + HBM bandwidth, with the source of the
+  numbers). A TPU that is not listed raises — a wrong or invented
+  denominator is worse than none.
+- **Anything else** (the tier-1/test backend is the CPU): no meaningful
+  datasheet number exists, so the peak is CALIBRATED once per process —
+  a small timed matmul for FLOP/s, a timed device-array copy for
+  bytes/s — and cached. Calibrated MFU is only comparable within one
+  host, which is exactly what a CPU A/B needs (and why the record
+  carries ``source: "calibrated"``).
 - Environment overrides ``PADDLE_TPU_PEAK_FLOPS`` /
   ``PADDLE_TPU_PEAK_BYTES`` win over both (``source: "env"``) — the
-  escape hatch for unlisted hardware or a deliberately pinned baseline.
+  way to run on unlisted hardware or against a deliberately pinned
+  baseline; an unlisted TPU needs both.
 
 ``machine_balance`` (peak FLOPs / peak bytes, FLOP-per-byte) is the
 roofline ridge: a program whose arithmetic intensity sits below it is
@@ -34,27 +34,20 @@ from typing import Any, Dict, Optional
 
 __all__ = ["peaks", "peak_flops", "machine_balance", "TPU_PEAKS"]
 
-# (device_kind substring, bf16 dense FLOP/s, HBM bytes/s) — first match
-# wins, so more specific generations sort before catch-alls ("v5e"
-# before "v5"; device_kind examples: "TPU v4", "TPU v5e", "TPU v5p",
-# "TPU v6e"/"TPU Trillium").
-TPU_PEAKS = (
-    ("v6e", 918e12, 1640e9),
-    ("trillium", 918e12, 1640e9),
-    ("v5e", 394e12, 819e9),
-    ("lite", 394e12, 819e9),
-    ("v5", 459e12, 2765e9),
-    ("v4", 275e12, 1228e9),
-    ("v3", 123e12, 900e9),
-    ("v2", 45e12, 700e9),
-)
+# device_kind (exact) -> (bf16 dense FLOP/s, HBM bytes/s).
+# "TPU v5 lite" is what a v5e chip reports. Source: Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16 (394 is the int8 figure),
+# 16 GB HBM at 819 GB/s.
+TPU_PEAKS = {
+    "TPU v5 lite": (197e12, 819e9),
+}
 
 _lock = threading.Lock()
 _cache: Optional[Dict[str, Any]] = None
 
 
 def _calibrate_cpu() -> Dict[str, float]:
-    """One-shot CPU peak probe: best-of-3 timed f32 matmul (2·n³ FLOPs)
+    """One-shot peak probe for a backend with no datasheet (the CPU): best-of-3 timed f32 matmul (2·n³ FLOPs)
     and device-array copy (2·nbytes moved). ~100 ms once per process;
     runs at ledger enable / first profile read, never on a dispatch."""
     import jax
@@ -87,46 +80,39 @@ def peaks(refresh: bool = False) -> Dict[str, Any]:
         {"device_kind", "platform", "peak_flops", "peak_bytes_per_s",
          "machine_balance", "source": "table" | "calibrated" | "env"}
 
-    Never raises: with no usable backend it falls back to a nominal
-    1 TFLOP/s (``source: "fallback"``) so a profile read cannot take
-    serving down."""
+    Raises ``KeyError`` on a TPU whose ``device_kind`` is not in
+    ``TPU_PEAKS`` (unless the environment overrides supply both
+    numbers); backend errors propagate."""
     global _cache
     with _lock:
         if _cache is not None and not refresh:
             return _cache
-    kind, platform = "unknown", "unknown"
-    flops = byts = None
-    source = "fallback"
-    try:
-        import jax
+    import jax
 
-        dev = jax.devices()[0]
-        platform = dev.platform
-        kind = getattr(dev, "device_kind", platform) or platform
-        low = kind.lower()
-        for sub, f, b in TPU_PEAKS:
-            if sub in low:
-                flops, byts, source = f, b, "table"
-                break
-        if flops is None:
-            cal = _calibrate_cpu()
-            flops = cal["peak_flops"]
-            byts = cal["peak_bytes_per_s"]
-            source = "calibrated"
-    except Exception:
-        pass
+    dev = jax.devices()[0]
+    platform = dev.platform
+    kind = dev.device_kind
     env_f = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
     env_b = os.environ.get("PADDLE_TPU_PEAK_BYTES")
+    if env_f and env_b:
+        flops = byts = None
+    elif platform == "tpu":
+        if kind not in TPU_PEAKS:
+            raise KeyError(
+                f"no peak FLOP/s and bytes/s listed for device_kind "
+                f"{kind!r} (listed: {sorted(TPU_PEAKS)}); add it to "
+                f"paddle_tpu/device/peaks.py with its source, or set "
+                f"PADDLE_TPU_PEAK_FLOPS and PADDLE_TPU_PEAK_BYTES")
+        flops, byts = TPU_PEAKS[kind]
+        source = "table"
+    else:
+        cal = _calibrate_cpu()
+        flops, byts = cal["peak_flops"], cal["peak_bytes_per_s"]
+        source = "calibrated"
     if env_f or env_b:
         source = "env"
-        if env_f:
-            flops = float(env_f)
-        if env_b:
-            byts = float(env_b)
-    if not flops or flops <= 0:
-        flops = 1e12
-    if not byts or byts <= 0:
-        byts = 1e11
+        flops = float(env_f) if env_f else flops
+        byts = float(env_b) if env_b else byts
     rec = {"device_kind": kind, "platform": platform,
            "peak_flops": flops, "peak_bytes_per_s": byts,
            "machine_balance": flops / byts, "source": source}

@@ -4,10 +4,13 @@ Reference analog: the auto-tuner handing each candidate to the distributed
 launcher and reading metrics back from logs
 (python/paddle/distributed/auto_tuner/utils.py: gen_new_args /
 read_metric_log). TPU-native: the subprocess bootstraps a virtual CPU mesh
-of ``num_devices`` when the host doesn't expose that many real chips
-(exactly like ``__graft_entry__.dryrun_multichip``), so the full dp×mp×pp×
-sharding search space is explorable on a single host; on a real pod slice
-the same code path uses the real devices.
+of ``num_devices`` (exactly like ``__graft_entry__.dryrun_multichip``), so
+the full dp×mp×pp×sharding search space is explorable on a single host.
+
+One process per chip: trials run one at a time and this parent never
+touches JAX, so with ``use_real_devices`` the one live trial is the only
+process that wants the chips. A tuner embedded in a program that already
+holds them must leave ``use_real_devices`` off (CPU trials).
 """
 from __future__ import annotations
 

@@ -152,14 +152,6 @@ def _k_all_reduce(x, axis, extra):
     return _preduce(x, extra[0], axis)
 
 
-def _axis_size(axis):
-    """lax.axis_size is missing on jax 0.4.x; psum of 1 is the portable
-    spelling of a named-axis size inside a collective context."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
-
-
 def _k_all_gather_stack(x, axis, extra):
     return lax.all_gather(x, axis, axis=0)  # [world, ...]
 
@@ -173,14 +165,14 @@ def _k_reduce_scatter(x, axis, extra):
     if op == ReduceOp.SUM:
         return lax.psum_scatter(x, axis, scatter_dimension=0, tiled=True)
     full = _preduce(x, op, axis)
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     i = lax.axis_index(axis)
     chunk = x.shape[0] // n
     return lax.dynamic_slice_in_dim(full, i * chunk, chunk, 0)
 
 
 def _k_all_to_all(x, axis, extra):
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     xs = x.reshape((n, x.shape[0] // n) + x.shape[1:])
     return lax.all_to_all(xs, axis, split_axis=0, concat_axis=0,
                           tiled=False).reshape(x.shape)
@@ -203,7 +195,7 @@ def _k_scatter(x, axis, extra):
     # x: each rank holds the FULL [world*chunk, ...] on src; take own chunk
     src = extra[0]
     full = lax.all_gather(x, axis, axis=0)[src]
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     i = lax.axis_index(axis)
     chunk = full.shape[0] // n
     return lax.dynamic_slice_in_dim(full, i * chunk, chunk, 0)

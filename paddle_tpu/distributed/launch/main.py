@@ -6,9 +6,12 @@ Usage:  python -m paddle_tpu.distributed.launch [--nnodes N] [--node_rank R]
             [--nproc_per_node P] [--master HOST:PORT] [--log_dir DIR]
             [--elastic_level L] [--max_restarts K] training_script [args...]
 
-TPU-native notes: a TPU host normally runs ONE process owning all local
-chips (nproc_per_node=1 default); the reference's per-GPU process model is
-still supported for CPU simulation (each proc limited via JAX flags). The
+TPU-native notes: a TPU host runs ONE process owning all local chips
+(nproc_per_node=1 default). nproc_per_node > 1 — the reference's per-GPU
+process model — is CPU-only for now: a chip belongs to one process at a
+time, the children inherit this environment (``--devices`` hands every
+child the SAME list), so on a TPU host each would claim every chip. This
+parent never touches JAX itself. The
 rank-0 TCP store (native C++ TCPStore) plays the HTTPMaster role; each
 child gets the reference env contract (PADDLE_TRAINER_ID,
 PADDLE_TRAINER_ENDPOINTS, MASTER_ADDR/PORT, PADDLE_NNODES).

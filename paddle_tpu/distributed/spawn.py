@@ -1,6 +1,10 @@
 """paddle.distributed.spawn parity (reference: distributed/spawn.py) —
-multiprocess helper for CPU-simulation of multi-process training. On TPU
-proper, one process owns all chips; spawn exists for the reference's
+multiprocess helper for CPU-simulation of multi-process training.
+
+CPU-only for now: a chip belongs to one process at a time, and the
+workers inherit the parent's whole environment, so on a TPU host every
+worker (and a parent that has touched JAX) would claim every chip. On
+TPU proper, one process owns all chips; spawn exists for the reference's
 process-per-worker tests."""
 from __future__ import annotations
 

@@ -143,12 +143,15 @@ def apply_rotary_emb(x, cos, sin):
     already broadcast to x's rank (the ragged-decode path passes per-ROW
     angles ``[B, 1, 1, d2]``).
 
-    On TPU the shared-table form routes to the Pallas fused_rope kernel:
+    On TPU the shared-table form routes to the Pallas fused_rope kernel
+    (unless x is laid out over a GSPMD mesh, which cannot partition it):
     the half-split of the 128-lane head_dim is VMEM-local there, where the
-    jnp slice+concat forms cost two HBM relayouts (measured ~20x slower at
-    llama shapes). The per-row form stays in jnp (one token per row)."""
+    jnp slice+concat forms cost two HBM relayouts. The per-row form stays
+    in jnp (one token per row)."""
+    from ..ops.pallas import _kernel_routable
+
     shared = cos.ndim == 2
-    if shared and jax.default_backend() == "tpu" and x.shape[-1] % 2 == 0:
+    if shared and x.shape[-1] % 2 == 0 and _kernel_routable(x):
         from ..ops.pallas_kernels import fused_rope
 
         return fused_rope(x, cos, sin)

@@ -26,13 +26,6 @@ __all__ = [
 ]
 
 
-def _use_pallas() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
 def _sdpa_ref(q, k, v, mask=None, causal=False, dropout_p=0.0, scale=None,
               dropout_key=None):
     """[B, S, H, D] reference composition; f32 softmax accumulation.
@@ -81,10 +74,12 @@ def flash_attention(query, key, value, dropout: float = 0.0, causal: bool = Fals
     """
     p = dropout if training else 0.0
     from ...ops.flash_attention_kernel import supports
+    from ...ops.pallas import _kernel_routable
     from ...ops.pallas import flash_attention as pallas_flash
 
     sq, sk = query.shape[1], key.shape[1]
-    use_kernel = supports(sq, sk) and (_use_pallas() or p > 0.0)
+    use_kernel = supports(sq, sk) and (_kernel_routable(unwrap(query))
+                                       or p > 0.0)
     if use_kernel:
         if p > 0.0:
             seed = jax.random.randint(default_generator.next_key(), (1,),

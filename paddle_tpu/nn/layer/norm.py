@@ -168,13 +168,14 @@ class RMSNorm(Layer):
         from ...core.autograd import apply_op
         from ...framework.flags import get_flags
 
-        from ...ops.pallas import _on_tpu
+        from ...ops._helpers import unwrap
+        from ...ops.pallas import _kernel_routable
 
-        # pallas only on real TPU here: off-TPU the model path must stay
-        # plain XLA so multi-device (GSPMD) dryruns don't trace interpret-
-        # mode pallas_call inside pjit. The kernel itself is still covered
-        # off-TPU through the incubate functional surface (interpret mode).
-        if (_on_tpu()
+        # pallas only on a real TPU and off a GSPMD mesh (Mosaic kernels
+        # cannot be partitioned automatically): elsewhere the model path
+        # stays plain XLA. The kernel itself is still covered off-TPU
+        # through the incubate functional surface (interpret mode).
+        if (_kernel_routable(unwrap(x))
                 and get_flags("FLAGS_use_pallas_kernels")["FLAGS_use_pallas_kernels"]):
             from ...ops import pallas_kernels as pk
 

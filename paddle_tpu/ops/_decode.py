@@ -11,17 +11,9 @@ from __future__ import annotations
 
 import math
 
-import jax
 import jax.numpy as jnp
 
 __all__ = ["gqa_decode_attention"]
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def gqa_decode_attention(q, k_cache, v_cache, seq_lens, tp=None):
@@ -43,10 +35,12 @@ def gqa_decode_attention(q, k_cache, v_cache, seq_lens, tp=None):
         return shard_map(
             lambda q_, k_, v_, l_: gqa_decode_attention(q_, k_, v_, l_),
             mesh=mesh, in_specs=(head, kv, kv, P()), out_specs=head,
-            check_rep=False)(q, k_cache, v_cache, seq_lens)
+            check_vma=False)(q, k_cache, v_cache, seq_lens)
     b, hq, d = q.shape
     s_max, hkv = k_cache.shape[1], k_cache.shape[2]
-    if hq == hkv and _on_tpu():
+    from .pallas import _kernel_routable
+
+    if hq == hkv and _kernel_routable(q):
         from .pallas_kernels import decode_mha
 
         return decode_mha(q, k_cache, v_cache, seq_lens)

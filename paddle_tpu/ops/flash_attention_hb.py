@@ -51,17 +51,16 @@ def supports_hb(q_shape, k_shape, dropout_p: float,
     b, sq, h, d = q_shape
     hkv, sk = k_shape[2], k_shape[1]
     it = _interpret() if interpret is None else interpret
-    # 2026-07-31 on-chip finding (experiments/tpu_session.log): Mosaic on
-    # the v5e toolchain rejected the H-batched 3D tpu.matmul the original
-    # kernel was built around ("Bad lhs type", remote_compile 500) at
-    # every block size tried.  The kernel has since been restructured to
-    # statically-unrolled per-head 2D dots (whose slice/store forms are
-    # themselves unverified on hardware — see _per_head), so device
-    # routing stays off until PADDLE_TPU_HB_ON_DEVICE=1 — set by the
-    # session script's on-chip test step (tpu_session.sh step 1; note
-    # exp_flash_hb calls the kernel DIRECTLY and never consults this
-    # gate) — verifies it; flip the default only after a measured win.
-    # Per-head (6.0 ms fwd+bwd at bench shapes) remains the device path.
+    # 2026-07-31 on-chip finding: Mosaic on the v5e toolchain rejected
+    # the H-batched 3D tpu.matmul the original kernel was built around
+    # ("Bad lhs type") at every block size tried.  The kernel has since
+    # been restructured to statically-unrolled per-head 2D dots (whose
+    # slice/store forms are themselves unverified on hardware — see
+    # _per_head), so device routing stays off until
+    # PADDLE_TPU_HB_ON_DEVICE=1 (note exp_flash_hb calls the kernel
+    # DIRECTLY and never consults this gate) verifies it; flip the
+    # default only after a measured win. Per-head remains the device
+    # path (ROADMAP D4).
     if not it and os.environ.get("PADDLE_TPU_HB_ON_DEVICE", "") != "1":
         return False
     # this kernel does bf16 D-contracting dots WITHOUT the _sublane_plan

@@ -41,15 +41,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu memory spaces; interpret mode needs pl only
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import tpu as pltpu
 
-    _VMEM = pltpu.VMEM
-    _SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-    _SMEM_SPEC = None
+_VMEM = pltpu.VMEM
+_SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 __all__ = ["flash_attention_bhsd", "supports"]
 

@@ -27,9 +27,6 @@ QUANTIZED pools (``kv_dtype="int8"`` serving): pass the per-(page,
 kv_head) absmax scale arrays and the kernel dequantizes AFTER the page
 DMA (``paddle_tpu.quantization.kv`` conventions) — decode's HBM read
 is half the bytes, which is the whole lever on bandwidth-bound decode.
-A jax build without ``jax.experimental.pallas.tpu`` (the grid spec
-needs it even in interpret mode) falls back to a pure-jnp dense-gather
-reference with the same math — CPU-compat, not a performance path.
 
 Relationship to ``ops/pallas.py::paged_attention``: that function wraps
 the STOCK ``jax.experimental.pallas.ops.tpu.paged_attention`` kernel
@@ -47,11 +44,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ..quantization.kv import KV_QMAX as _KV_QMAX
 
@@ -95,8 +88,8 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
             # fused dequant (quantization.kv conventions): the scale
             # block is this page's [Hkv] absmax row, selected by the
             # same prefetched-table index map that aimed the K/V DMA
-            k = k * (ks_ref[0] * (1.0 / _KV_QMAX))[None, :, None]
-            v = v * (vs_ref[0] * (1.0 / _KV_QMAX))[None, :, None]
+            k = k * (ks_ref[0, 0] * (1.0 / _KV_QMAX))[None, :, None]
+            v = v * (vs_ref[0, 0] * (1.0 / _KV_QMAX))[None, :, None]
         g = q.shape[0] // k.shape[1]
         if g > 1:                                   # GQA: share KV heads
             k = jnp.repeat(k, g, axis=1)            # VMEM-local repeat
@@ -125,15 +118,12 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 def _paged_decode_ref(q, k_pool, v_pool, page_table, seq_lens,
                       k_scale=None, v_scale=None):
-    """Pure-jnp reference/fallback: gather each row's pages dense and
-    run a masked softmax. Used when this jax build lacks
-    ``jax.experimental.pallas.tpu`` (the grid spec below needs it even
-    in interpret mode) — numerically equivalent to the kernel (same
-    f32 math, plain instead of online softmax), NOT byte-identical,
-    and it materializes [B, max_pages*page_size] KV so it is a
-    CPU-compat path, not a performance one. Quantized pools dequant
-    here with the same ``quantization.kv`` conventions the fused
-    kernel uses."""
+    """Pure-jnp reference the tests hold the kernel to: gather each
+    row's pages dense and run a masked softmax — numerically equivalent
+    to the kernel (same f32 math, plain instead of online softmax), NOT
+    byte-identical, and it materializes [B, max_pages*page_size] KV.
+    Quantized pools dequant here with the same ``quantization.kv``
+    conventions the fused kernel uses. No serving path calls it."""
     b, h, d = q.shape
     hkv = k_pool.shape[2]
     ps = k_pool.shape[1]
@@ -207,13 +197,7 @@ def paged_decode_mha(q, k_pool, v_pool, page_table, seq_lens,
         return shard_map(
             lambda *a: paged_decode_mha(*a, interpret=interpret),
             mesh=mesh, in_specs=tuple(in_specs), out_specs=head,
-            check_rep=False)(*operands)
-    if pltpu is None:
-        # the scalar-prefetch grid spec needs jax.experimental.pallas
-        # .tpu even in interpret mode — fall back to the dense-gather
-        # reference (same math, no paging win) instead of failing
-        return _paged_decode_ref(q, k_pool, v_pool, page_table,
-                                 seq_lens, k_scale, v_scale)
+            check_vma=False)(*operands)
     b, h, d = q.shape
     hkv = k_pool.shape[2]
     if h % hkv:
@@ -229,7 +213,7 @@ def paged_decode_mha(q, k_pool, v_pool, page_table, seq_lens,
         return (jnp.maximum(pt[bi, pi], 0), 0, 0, 0)
 
     def _page_scale(bi, pi, pt, _lens):
-        return (jnp.maximum(pt[bi, pi], 0), 0)
+        return (jnp.maximum(pt[bi, pi], 0), 0, 0)
 
     quant = k_scale is not None
     in_specs = [
@@ -239,9 +223,13 @@ def paged_decode_mha(q, k_pool, v_pool, page_table, seq_lens,
     ]
     operands = [q, k_pool, v_pool]
     if quant:
-        in_specs += [pl.BlockSpec((1, hkv), _page_scale),
-                     pl.BlockSpec((1, hkv), _page_scale)]
-        operands += [k_scale, v_scale]
+        # one page's [Hkv] scale row as a (1, 1, Hkv) block of a
+        # [num_pages, 1, Hkv] view: a (1, Hkv) block of the 2-D array is
+        # refused by the TPU lowering (second-to-last block dim must be
+        # a multiple of 8 or the whole axis)
+        in_specs += [pl.BlockSpec((1, 1, hkv), _page_scale),
+                     pl.BlockSpec((1, 1, hkv), _page_scale)]
+        operands += [k_scale[:, None, :], v_scale[:, None, :]]
 
     def kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest):
         if quant:
@@ -270,4 +258,7 @@ def paged_decode_mha(q, k_pool, v_pool, page_table, seq_lens,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=grid_spec,
         interpret=it,
+        # a stable name: compiled text and profiler traces find the
+        # kernel by it (the body is a closure called ``kernel``)
+        name="paged_decode",
     )(page_table, seq_lens, q, *operands[1:])

@@ -24,10 +24,25 @@ __all__ = ["flash_attention", "paged_attention", "grouped_matmul",
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
+    return jax.default_backend() == "tpu"
+
+
+def _kernel_routable(x) -> bool:
+    """Whether a Pallas kernel may take the (traced) array ``x`` here: on
+    a TPU, and ``x`` not laid out over a multi-device mesh that GSPMD
+    partitions. Mosaic kernels cannot be partitioned automatically —
+    lowering refuses them ("wrap the call in a shard_map") — so under
+    such a mesh the XLA composition runs instead, which GSPMD does
+    partition; inside a ``shard_map`` (every mesh axis Manual, as the
+    ``tp=`` wraps below make it) the kernel sees a local block and is
+    fine. The mesh is read off the value's type, where jit puts it for
+    arguments committed to a ``NamedSharding``."""
+    if not _on_tpu():
         return False
+    mesh = jax.typeof(x).sharding.mesh
+    return (mesh.empty or mesh.size == 1
+            or all(t == jax.sharding.AxisType.Manual
+                   for t in mesh.axis_types))
 
 
 def _chunked_attention(q, k, v, causal: bool, sm_scale: float,
@@ -122,7 +137,7 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale: float = None,
                 q_, k_, v_, causal=causal, sm_scale=sm_scale,
                 dropout_p=dropout_p, seed=seed),
             mesh=mesh, in_specs=(hs, hs, hs), out_specs=hs,
-            check_rep=False)(q, k, v)
+            check_vma=False)(q, k, v)
     d = q.shape[-1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
 
@@ -148,7 +163,7 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale: float = None,
     # off-TPU the kernel runs in interpret mode (~17x slower than the XLA
     # fallback) — only worth it when in-kernel dropout semantics are needed
     use_kernel = supports(qt.shape[2], kt.shape[2]) and (
-        _on_tpu() or dropout_p > 0.0)
+        _kernel_routable(q) or dropout_p > 0.0)
     if use_kernel:
         out = flash_attention_bhsd(qt, kt, vt, causal=causal, sm_scale=scale,
                                    dropout_p=dropout_p, seed=seed)
@@ -198,7 +213,7 @@ def prefix_chunk_attention(q, k_cache, v_cache, pos, sm_scale: float = None,
             lambda q_, k_, v_, p_: prefix_chunk_attention(
                 q_, k_, v_, p_, sm_scale=sm_scale),
             mesh=mesh, in_specs=(hs, hs, hs, P()), out_specs=hs,
-            check_rep=False)(q, k_cache, v_cache, pos)
+            check_vma=False)(q, k_cache, v_cache, pos)
     d = q.shape[-1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     qt = jnp.swapaxes(q, 1, 2)          # [B, H, S, D]

@@ -47,6 +47,47 @@ def _row_block(n_rows: int, target: int = 256) -> int:
     return max(b, 1)
 
 
+# What the pipelined (double-buffered) operand blocks of one grid step may
+# take of the 16 MiB of scoped VMEM Mosaic grants a kernel on v5e; the
+# rest is left to the kernel body's fp32 temporaries. Compiles for the
+# described chip (tests/test_chip_compile.py) hold the sizes below to it.
+_VMEM_BLOCK_BUDGET = 12 << 20
+
+
+def _vmem_rows(kernel: str, n_rows: int, bytes_per_row: int, target: int,
+               interpret: bool) -> int:
+    """Rows per block for a kernel that streams ``n_rows`` rows of
+    ``bytes_per_row`` (all pipeline buffers of one row counted): the
+    largest divisor of ``n_rows`` that is <= ``target``, fits
+    ``_VMEM_BLOCK_BUDGET`` and, when compiled for the chip, is a sublane
+    multiple (8) or the whole axis. Raises where no such block exists —
+    the chip's compiler would refuse the kernel anyway, less legibly."""
+    cap = min(target, max(_VMEM_BLOCK_BUDGET // bytes_per_row, 1))
+    for b in range(min(n_rows, cap), 0, -1):
+        if n_rows % b == 0 and (interpret or b % 8 == 0 or b == n_rows):
+            return b
+    raise ValueError(
+        f"{kernel}: no row block for {n_rows} rows of {bytes_per_row} "
+        f"bytes — needs a divisor <= {cap} that is a multiple of 8")
+
+
+def _lane_block(kernel: str, n: int, target: int, interpret: bool) -> int:
+    """Tile width along a lane (last) or contraction dim: the largest
+    128-multiple divisor of ``n`` that is <= ``target`` (5504 = 43 x 128
+    gives 128, 11008 gives 256). Interpret mode takes any divisor. On
+    the chip a width with no such divisor raises: Mosaic refuses
+    sub-lane-multiple bf16 matmul tiles, and nothing here pads or
+    upcasts behind the caller's back."""
+    if interpret:
+        return _row_block(n, target)
+    for b in range(target - target % 128, 0, -128):
+        if n % b == 0:
+            return b
+    raise ValueError(
+        f"{kernel}: dim {n} has no 128-multiple tile <= {target}; pad "
+        "the operand to a lane multiple before calling the kernel")
+
+
 # ---------------------------------------------------------------------------
 # RMSNorm (Llama hot path)
 # ---------------------------------------------------------------------------
@@ -63,7 +104,11 @@ def _rms_fwd_impl(x, weight, eps):
     shape = x.shape
     h = shape[-1]
     x2 = x.reshape(-1, h)
-    rb = _row_block(x2.shape[0])
+    interpret = _interpret()
+    # x block in + out, each double-buffered: 256 rows at 4096 bf16, 128
+    # at 4096 fp32 (256 ran out of VMEM)
+    rb = _vmem_rows("rms_norm", x2.shape[0],
+                    4 * h * jnp.dtype(x.dtype).itemsize, 256, interpret)
     out = pl.pallas_call(
         functools.partial(_rms_kernel, eps=eps),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
@@ -71,7 +116,7 @@ def _rms_fwd_impl(x, weight, eps):
         in_specs=[pl.BlockSpec((rb, h), lambda i: (i, 0)),
                   pl.BlockSpec((h,), lambda i: (0,))],
         out_specs=pl.BlockSpec((rb, h), lambda i: (i, 0)),
-        interpret=_interpret(),
+        interpret=interpret,
     )(x2, weight)
     return out.reshape(shape)
 
@@ -136,8 +181,12 @@ def _ln_fwd_impl(x, residual, bias, gamma, beta, eps):
     h = shape[-1]
     x2 = x.reshape(-1, h)
     n = x2.shape[0]
-    rb = _row_block(n)
     has_resid = residual is not None
+    interpret = _interpret()
+    # x (+ residual) in and out, each double-buffered
+    rb = _vmem_rows("fused_layer_norm", n,
+                    (6 if has_resid else 4) * h
+                    * jnp.dtype(x.dtype).itemsize, 256, interpret)
     has_bias = bias is not None
     r2 = residual.reshape(-1, h) if has_resid else jnp.zeros((1, h), x.dtype)
     b = bias if has_bias else jnp.zeros((h,), x.dtype)
@@ -155,7 +204,7 @@ def _ln_fwd_impl(x, residual, bias, gamma, beta, eps):
             pl.BlockSpec((h,), lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((rb, h), lambda i: (i, 0)),
-        interpret=_interpret(),
+        interpret=interpret,
     )(x2, r2, b, gamma, beta)
     return out.reshape(shape)
 
@@ -228,8 +277,8 @@ def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref):
     c = cos_ref[...].astype(jnp.float32)[..., None, :]  # [1, bs, 1, D]
     s = sin_ref[...].astype(jnp.float32)[..., None, :]
     d2 = x.shape[-1] // 2
-    xr = pltpu.roll(x, d2, 3) if pltpu is not None and not _interpret() \
-        else jnp.roll(x, d2, axis=-1)
+    xr = jnp.roll(x, d2, axis=-1) if _interpret() \
+        else pltpu.roll(x, d2, 3)
     o_ref[...] = (x * c + xr * s).astype(o_ref.dtype)
 
 
@@ -241,7 +290,12 @@ def _rope_impl(x, cos, sin):
     sin_f = jnp.concatenate([-sin, sin], axis=-1)
     cos_b = jnp.broadcast_to(cos_f[None], (b_, s_, d_))
     sin_b = jnp.broadcast_to(sin_f[None], (b_, s_, d_))
-    sb = _row_block(s_, 512)
+    # x block in + out, each double-buffered, sets the row block: 512
+    # rows at 16 heads x 128 bf16, 256 at 32 heads (512 ran out of VMEM)
+    interpret = _interpret()
+    sb = _vmem_rows("fused_rope", s_,
+                    4 * h_ * d_ * jnp.dtype(x.dtype).itemsize, 512,
+                    interpret)
     out = pl.pallas_call(
         _rope_kernel,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
@@ -252,7 +306,7 @@ def _rope_impl(x, cos, sin):
             pl.BlockSpec((1, sb, d_), lambda i, j: (i, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, sb, h_, d_), lambda i, j: (i, j, 0, 0)),
-        interpret=_interpret(),
+        interpret=interpret,
     )(x, cos_b, sin_b)
     return out
 
@@ -364,7 +418,12 @@ def _decode_mha_jit(q, k_cache, v_cache, seq_lens, block_s):
     b_, h_, d_ = q.shape
     s_max = k_cache.shape[1]
     scale = 1.0 / math.sqrt(d_)
-    bs = _row_block(s_max, block_s)
+    # K and V blocks, each double-buffered, bound the S-block: block_s
+    # rows at 16 heads x 128 bf16, 256 at 32 heads (512 ran out of VMEM)
+    interpret = _interpret()
+    bs = _vmem_rows("decode_mha", s_max,
+                    4 * h_ * d_ * jnp.dtype(k_cache.dtype).itemsize,
+                    block_s, interpret)
     return pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, block_s=bs),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -381,7 +440,7 @@ def _decode_mha_jit(q, k_cache, v_cache, seq_lens, block_s):
             pltpu.VMEM((1, h_), jnp.float32),
             pltpu.VMEM((1, h_), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret,
     )(seq_lens, q, k_cache, v_cache)
 
 
@@ -419,22 +478,18 @@ def fused_linear_param_grad_add(x, dy, dweight):
     """dweight(fp32) += xᵀ @ dy — the reference's main-grad accumulation
     kernel (fused_linear_param_grad_add_kernel.cu): bf16 activations/grad,
     fp32 accumulator, single fused pass, aliased in-place output. Tiled
-    over (K, N, T) so 7B-scale weights (e.g. 4096x11008) accumulate through
-    a bounded VMEM working set."""
+    over (K, N, T) in 128-multiple tiles so 7B-scale weights (e.g.
+    4096x11008) accumulate through a bounded VMEM working set; on the
+    chip K, N and T must each be a multiple of 128 (``_lane_block``)."""
     x2 = x.reshape(-1, x.shape[-1])
     dy2 = dy.reshape(-1, dy.shape[-1])
     kdim, ndim = dweight.shape
     tdim = x2.shape[0]
-    bk = _row_block(kdim, 512)
-    bn = _row_block(ndim, 512)
-    bt = _row_block(tdim, 512)
-    if not _interpret() and (bt % 128 or bk % 128 or bn % 128) \
-            and (x2.dtype != jnp.float32 or dy2.dtype != jnp.float32):
-        # Mosaic rejects bf16 matmuls at sub-lane-multiple tile dims
-        # ("Bad lhs type"); fp32 compiles — real training shapes are
-        # 128-multiples and keep the bf16 MXU path
-        x2 = x2.astype(jnp.float32)
-        dy2 = dy2.astype(jnp.float32)
+    interpret = _interpret()
+    name = "fused_linear_param_grad_add"
+    bk = _lane_block(name, kdim, 512, interpret)
+    bn = _lane_block(name, ndim, 512, interpret)
+    bt = _lane_block(name, tdim, 512, interpret)
     dw32 = dweight.astype(jnp.float32)
     return pl.pallas_call(
         _grad_add_kernel,
@@ -448,5 +503,5 @@ def fused_linear_param_grad_add(x, dy, dweight):
         out_specs=pl.BlockSpec((bk, bn), lambda i, j, t: (i, j)),
         scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
         input_output_aliases={2: 0},
-        interpret=_interpret(),
+        interpret=interpret,
     )(x2, dy2, dw32)

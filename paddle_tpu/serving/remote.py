@@ -1073,16 +1073,22 @@ def spawn_replica(extra_args: Optional[List[str]] = None, *,
                   env: Optional[dict] = None):
     """Start ``python -m paddle_tpu.serving.remote`` and wait for its
     ready marker. Returns ``(proc, base_url)``. The child inherits our
-    environment (JAX_PLATFORMS included) and binds an ephemeral port —
-    parallel test runs never collide."""
+    environment (JAX_PLATFORMS included) and our stderr — a replica
+    that dies, on the device or before it, says why — and binds an
+    ephemeral port, so parallel test runs never collide.
+
+    CPU-only for now where the parent itself uses JAX: a chip belongs
+    to one process, and the child inherits the whole environment, so on
+    a TPU host it would claim every chip the parent already holds. A
+    parent that stays off JAX can hand each child its own chip through
+    ``env`` (``TPU_VISIBLE_CHIPS``)."""
     cmd = [sys.executable, "-m", "paddle_tpu.serving.remote",
            "--port", "0"] + list(extra_args or [])
     child_env = dict(os.environ)
     if env:
         child_env.update(env)
     proc = subprocess.Popen(
-        cmd, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-        env=child_env, text=True)
+        cmd, stdout=subprocess.PIPE, env=child_env, text=True)
     end = time.monotonic() + startup_timeout_s
     port = None
     while time.monotonic() < end:
@@ -1348,8 +1354,12 @@ def main(argv: Optional[List[str]] = None) -> int:
     p.add_argument("--slo-tpot", type=float, default=None)
     ns = p.parse_args(argv)
 
+    from ..device.compile_cache import use_compile_cache
     from .http import serve_http
 
+    # before the first compile: replicas of one checkout (and a respawn
+    # of this one) share what they compile instead of each starting cold
+    use_compile_cache()
     srv = _build_server(ns)
     srv.wait_ready()
     httpd = serve_http(srv, addr=ns.host, port=ns.port)

@@ -11,9 +11,8 @@ from paddle_tpu.ops import autotune
 
 
 @pytest.fixture(autouse=True)
-def _isolated_cache(tmp_path, monkeypatch):
-    # keep tests away from the user's persistent cache file
-    monkeypatch.setenv(autotune._CACHE_ENV, str(tmp_path / "cache.json"))
+def _isolated_cache():
+    # a fresh process-wide table; nothing is persisted without a path
     old = autotune._GLOBAL
     autotune._GLOBAL = autotune.AutoTuneCache()
     autotune._loaded[0] = True

@@ -1,0 +1,118 @@
+"""The chip's compiler, asked before the chip: AOT compiles for a described
+``TPU v5 lite`` (no device attached) of the Pallas kernels the trainer and
+the paged server reach, at real widths. Interpret mode cannot see what the
+compiler refuses — a block that breaks the (8, 128) tiling, a kernel that
+overruns VMEM — and each of the repairs below was such a refusal.
+
+Kernels pick interpret mode from ``jax.devices()[0]``, which is the CPU
+here, so the tests steer the three ``_interpret()`` helpers. The persistent
+compile cache is off around the compiles: an entry written for a described
+device cannot be read back without one, and the next compile would warn.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops import flash_attention_kernel as fk
+from paddle_tpu.ops import paged_attention as pa
+from paddle_tpu.ops import pallas_kernels as pk
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here: nothing to ask
+        pytest.skip(f"the v5e topology cannot be described: {e}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _compiled_not_interpreted(monkeypatch):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    for mod in (fk, pa, pk):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+    cache = jax.config.jax_enable_compilation_cache
+    precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    # the conftest's "highest" is for CPU parity tests; the chip runs the
+    # default, and Mosaic refuses a bf16 dot at fp32 precision
+    jax.config.update("jax_default_matmul_precision", None)
+    yield
+    jax.config.update("jax_default_matmul_precision", precision)
+    jax.config.update("jax_enable_compilation_cache", cache)
+    compilation_cache.reset_cache()
+
+
+def _compile(chip, fn, *shapes):
+    """Compile ``fn`` for the chip; returns the kernels in the program."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return text.count("tpu_custom_call")
+
+
+def test_flash_fwd_bwd(chip):
+    def loss(q, k, v):
+        return fk.flash_attention_bhsd(q, k, v, causal=True).astype(
+            F32).sum()
+
+    qkv = ((4, 16, 2048, 128), BF16)
+    assert _compile(chip, jax.grad(loss, argnums=(0, 1, 2)),
+                    qkv, qkv, qkv) == 3   # fwd, bwd dq, bwd dk/dv
+
+
+@pytest.mark.parametrize("hkv", [32, 8])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_decode(chip, hkv, kv_dtype):
+    b, h, d, pages, ps, maxp = 8, 32, 128, 512, 16, 64
+    pool = ((pages, ps, hkv, d), I8 if kv_dtype == "int8" else BF16)
+    shapes = [((b, h, d), BF16), pool, pool, ((b, maxp), I32), ((b,), I32)]
+    if kv_dtype == "int8":   # per-(page, kv_head) scales
+        shapes += [((pages, hkv), F32)] * 2
+    assert _compile(chip, pa.paged_decode_mha, *shapes) == 1
+
+
+@pytest.mark.parametrize("heads", [16, 32])
+def test_fused_rope(chip, heads):
+    assert _compile(chip, pk.fused_rope, ((4, 2048, heads, 128), BF16),
+                    ((2048, 64), BF16), ((2048, 64), BF16)) == 1
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_rms_norm(chip, dtype):
+    assert _compile(chip, pk.rms_norm, ((4, 2048, 4096), dtype),
+                    ((4096,), dtype)) == 1
+
+
+def test_decode_mha(chip):
+    kv = ((8, 2048, 32, 128), BF16)
+    assert _compile(chip, pk.decode_mha, ((8, 32, 128), BF16), kv, kv,
+                    ((8,), I32)) == 1
+
+
+def test_fused_linear_param_grad_add(chip):
+    # the 1b3 FFN: 5504 = 43 x 128 has no power-of-two tile
+    assert _compile(chip, pk.fused_linear_param_grad_add,
+                    ((8192, 2048), BF16), ((8192, 5504), BF16),
+                    ((2048, 5504), F32)) == 1
+
+
+def test_unrepairable_width_raises_by_name(chip):
+    """A width with no 128-multiple tile is refused by name on the chip —
+    no upcast, no padding behind the caller's back."""
+    with pytest.raises(ValueError, match="fused_linear_param_grad_add.*128"):
+        _compile(chip, pk.fused_linear_param_grad_add, ((256, 100), BF16),
+                 ((256, 256), BF16), ((100, 256), F32))

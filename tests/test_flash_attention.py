@@ -146,7 +146,7 @@ def test_bf16_roundtrip():
 def test_device_scale_parity(shape, dtype, causal):
     """Parity at shapes real-TPU tiling accepts (seq/blocks 128-multiples)
     in BOTH head-dim regimes and dtypes — the on-chip analog of
-    test_forward_parity, exercised by experiments/tpu_session.sh."""
+    test_forward_parity."""
     b, hq, hkv, sq, sk, d = shape
     tol = 3e-2 if dtype == jnp.bfloat16 else 2e-4
     q = rand(b, hq, sq, d, dtype=dtype, seed=31)

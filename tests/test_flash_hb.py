@@ -13,11 +13,10 @@ from paddle_tpu.ops.flash_attention_hb import (flash_attention_bshd_hb,
                                                supports_hb)
 
 # The hb kernel's ORIGINAL batched-3D-dot form was Mosaic-rejected on-chip
-# ("Bad lhs type", experiments/tpu_session.log 2026-07-31); it has been
-# restructured to per-head 2D dots but that form is unverified on hardware,
-# so supports_hb refuses device routing (and this module skips on device)
-# unless the PADDLE_TPU_HB_ON_DEVICE=1 escape hatch opts in — the session
-# script's on-chip test step sets it.
+# ("Bad lhs type", 2026-07-31); it has been restructured to per-head 2D
+# dots but that form is unverified on hardware, so supports_hb refuses
+# device routing (and this module skips on device) unless the
+# PADDLE_TPU_HB_ON_DEVICE=1 escape hatch opts in.
 import os
 
 from paddle_tpu.ops.flash_attention_kernel import _interpret

@@ -13,8 +13,7 @@ silently. This tool makes the comparison mechanical and CI-able:
 
 Exit status: 0 when nothing regressed (identical records compare
 clean by construction), 1 on any regression past threshold, 2 on
-usage/load errors — so ``experiments/tpu_session.sh`` and CI can gate
-on it directly.
+usage/load errors — so a script or CI can gate on it directly.
 
 **Direction-aware**: a +20% on ``tokens_per_sec`` is an improvement;
 a +20% on ``tpot_p50`` is a regression. Direction is classified from

@@ -450,7 +450,12 @@ def _build_fleet_router(args):
     Only the knobs the replica entrypoint exposes are forwarded (main
     validates the rest are at defaults). With --kill-replica-at T, the
     timer SIGKILLs replica 0's process; the supervisor respawns it.
-    Returns (router, vocab, kill_fn)."""
+    Returns (router, vocab, kill_fn).
+
+    CPU-only for now: the children inherit this process's whole
+    environment, so on a TPU host each of the N would claim every chip
+    (a chip belongs to one process at a time). A fleet on chips needs a
+    per-child chip assignment this tool does not make yet."""
     from paddle_tpu.models import llama_config
     from paddle_tpu.serving import Router
     from paddle_tpu.serving.remote import RemoteReplicaSpec
@@ -2101,4 +2106,9 @@ def _run_arm(args, arm: str, spec_on: bool, trace_on: bool, prompts,
 
 
 if __name__ == "__main__":
+    # entry-point only (tests call main() in-process): runs of this tool
+    # and the replica children it spawns share one persistent compile cache
+    from paddle_tpu.device.compile_cache import use_compile_cache
+
+    use_compile_cache()
     sys.exit(main())
