@@ -860,6 +860,7 @@ class ContinuousBatchingEngine:
         self._slot_req = {}            # slot -> request id
         self._tokens = {}              # request id -> [generated ids]
         self._budget = {}              # request id -> remaining tokens
+        self._plen = {}                # request id -> prompt length
         self._cfg = {}                 # request id -> GenerationConfig
         self._finished = {}            # request id -> np.ndarray
         self._next_req = 0
@@ -1168,9 +1169,6 @@ class ContinuousBatchingEngine:
             rid = self._next_req
             self._next_req += 1
             last_logits = self._admit_cache(slot, ids, plen, cfg)
-            first, tok_done = self._sample_first(rid, last_logits, cfg)
-            self._install_state(slot, plen, first, tok_done, cfg,
-                                aidx=aidx, ids=ids)
         except BaseException:
             # a failed admission must not leak capacity: the popped
             # slot (and, paged, any page reservation _admit_cache made;
@@ -1178,8 +1176,30 @@ class ContinuousBatchingEngine:
             # the error propagates
             self._abort_admit(slot)
             raise
-        self._init_spec(rid, ids, first, cfg)
-        return self._register(slot, rid, first, tok_done, cfg, t0)
+        return self._first_token(slot, rid, ids, plen, last_logits, cfg,
+                                 aidx, t0)
+
+    def _first_token(self, slot: int, rid: int, ids, plen: int,
+                     last_logits, cfg, aidx: int, t0: float) -> int:
+        """The tail every admission shares (one-shot, warm, chunked):
+        sample the first token from the prompt's last logits, install
+        the slot's state, register the request. The device calls sit
+        inside the abort guard; the bookkeeping after them does not
+        (no device call left to fail). Traced as ``engine.first_token``:
+        ``_register`` reads the token (``int(first)``), which is where
+        the host waits for the prefill it dispatched earlier."""
+        with trace.span("engine.first_token"):
+            try:
+                first, tok_done = self._sample_first(rid, last_logits,
+                                                     cfg)
+                self._install_state(slot, plen, first, tok_done, cfg,
+                                    aidx=aidx, ids=ids)
+            except BaseException:
+                self._abort_admit(slot)
+                raise
+            self._init_spec(rid, ids, first, cfg)
+            self._plen[rid] = plen
+            return self._register(slot, rid, first, tok_done, cfg, t0)
 
     def _acquire_adapter(self, cfg) -> int:
         """Resolve the request's adapter name to its bank index and
@@ -1335,24 +1355,29 @@ class ContinuousBatchingEngine:
         width = self._prefill_width(plen)
         self._count_prefill(width if self.prefill_buckets is not None
                             else "exact")
+        sp = trace.NULL_SPAN
         if trace.enabled():
             # the bucket CHOICE is the observable that explains a
-            # prefill's latency class (compiled-program width)
-            trace.event("engine.prefill", engine=self._monitor_engine,
-                        plen=plen, bucket=width)
-        return self._prefill(self.params, _pad_ids(ids, width), mini,
-                             jnp.int32(plen - 1), self._bank(),
-                             jnp.int32(aidx))
+            # prefill's latency class (compiled-program width); the
+            # span is the DISPATCH of the program, not its device time
+            sp = trace.span("engine.prefill", engine=self._monitor_engine,
+                            plen=plen, bucket=width, cached=0)
+        with sp:
+            return self._prefill(self.params, _pad_ids(ids, width), mini,
+                                 jnp.int32(plen - 1), self._bank(),
+                                 jnp.int32(aidx))
 
     def _admit_cache(self, slot: int, ids, plen: int, cfg):
         """Cache-layout hook: prefill the prompt and install its KV into
         slot's cache; returns the prompt's last-position logits. The
         dense base scatters a max_len mini cache; the paged subclass
         reserves pages and scatters a bucket-sized one."""
-        mini = self._mini_cache(self.max_len)
+        with trace.span("engine.mini_cache"):
+            mini = self._mini_cache(self.max_len)
         last_logits, mini = self._run_prefill(
             ids, plen, mini, aidx=self._aidx_stash.get(slot, 0))
-        self._install_mini(slot, mini, plen)
+        with trace.span("engine.install"):
+            self._install_mini(slot, mini, plen)
         return last_logits
 
     def _reserve_admit(self, slot: int, plen: int, cfg) -> None:
@@ -1380,6 +1405,7 @@ class ContinuousBatchingEngine:
         # bookkeeping, no device read happens here)
         self._finished[rid] = np.asarray(self._tokens.pop(rid), np.int32)
         del self._budget[rid]
+        self._plen.pop(rid, None)
         self._cfg.pop(rid, None)
         self._spec.pop(rid, None)
         aidx = self._rid_aidx.pop(rid, 0)
@@ -1464,6 +1490,7 @@ class ContinuousBatchingEngine:
         self._slot_req.clear()
         self._tokens.clear()
         self._budget.clear()
+        self._plen.clear()
         self._cfg.clear()
         self._spec.clear()
         self._finished.clear()
@@ -1569,8 +1596,10 @@ class ContinuousBatchingEngine:
         slab the dense engine always uses). The paged prefix-cache
         override maps cached prefix pages first and starts chunking
         past them."""
-        self._reserve_admit(slot, plen, cfg)
-        return self._mini_cache(self.max_len), 0
+        with trace.span("engine.reserve"):
+            self._reserve_admit(slot, plen, cfg)
+        with trace.span("engine.mini_cache"):
+            return self._mini_cache(self.max_len), 0
 
     def admit_chunk(self, adm: _ChunkedAdmission) -> bool:
         """Run ONE fixed-shape prefill chunk of an admission started
@@ -1588,9 +1617,18 @@ class ContinuousBatchingEngine:
             last = adm.off + r >= adm.plen
             if r < C:       # only the FINAL chunk may be partial
                 chunk = _pad_ids(chunk, C)
-            adm.last_logits, adm.mini = self._prefill_chunk(
-                self.params, chunk, adm.mini, jnp.int32(adm.off),
-                jnp.int32(r - 1), self._bank(), jnp.int32(aidx))
+            sp = trace.NULL_SPAN
+            if trace.enabled():
+                # ``cached``: the prompt tokens already in the mini,
+                # so plen - cached is what THIS program computes
+                sp = trace.span("engine.prefill",
+                                engine=self._monitor_engine,
+                                plen=adm.off + r, bucket=C,
+                                cached=adm.off)
+            with sp:
+                adm.last_logits, adm.mini = self._prefill_chunk(
+                    self.params, chunk, adm.mini, jnp.int32(adm.off),
+                    jnp.int32(r - 1), self._bank(), jnp.int32(aidx))
             adm.off += C
             adm.chunks_done += 1
             if monitor.enabled():
@@ -1601,20 +1639,15 @@ class ContinuousBatchingEngine:
                     engine=self._monitor_engine).inc()
             if not last:
                 return False
-            self._install_mini(adm.slot, adm.mini, adm.plen)
-            first, tok_done = self._sample_first(adm.rid,
-                                                 adm.last_logits,
-                                                 adm.cfg)
-            self._install_state(adm.slot, adm.plen, first, tok_done,
-                                adm.cfg, aidx=aidx, ids=adm.ids)
+            with trace.span("engine.install"):
+                self._install_mini(adm.slot, adm.mini, adm.plen)
         except BaseException:
             adm.closed = True
             self._abort_admit(adm.slot)
             raise
-        adm.closed = True
-        self._init_spec(adm.rid, adm.ids, first, adm.cfg)
-        self._register(adm.slot, adm.rid, first, tok_done, adm.cfg,
-                       adm.t0)
+        adm.closed = True       # _first_token reclaims on ITS failures
+        self._first_token(adm.slot, adm.rid, adm.ids, adm.plen,
+                          adm.last_logits, adm.cfg, aidx, adm.t0)
         return True
 
     def abort_admit(self, adm: _ChunkedAdmission) -> None:
@@ -2068,8 +2101,7 @@ class ContinuousBatchingEngine:
         return self._segment_cache[key_]
 
     # lint: hot-path
-    def _decode_segment_spec_device(self, n_steps: int,
-                                    cfg=None):
+    def _decode_segment_spec_device(self, n_steps: int, cfg, sp):
         """Device-resident speculative decode segment: ONE dispatch of
         the fused :meth:`_spec_segment_device_fn` program, then ONE
         readback for collection — no per-verify-step host round-trip
@@ -2158,12 +2190,8 @@ class ContinuousBatchingEngine:
                 c.labels(engine=self._monitor_engine,
                          outcome="accepted").inc(accepted)
         if trace.enabled():
-            trace.record(
-                "engine.spec_segment",
-                dur_ns=int((time.perf_counter() - t0) * 1e9),
-                engine=self._monitor_engine, mode="device",
-                steps=n_steps, forwards=forwards, proposed=proposed,
-                accepted=accepted, emitted=total, host_syncs=0)
+            sp.set(mode="device", forwards=forwards, proposed=proposed,
+                   accepted=accepted, emitted=total, host_syncs=0)
         return len(self._slot_req)
 
     @staticmethod
@@ -2204,8 +2232,7 @@ class ContinuousBatchingEngine:
         return t
 
     # lint: hot-path
-    def _decode_segment_spec(self, n_steps: int,
-                             cfg: Optional[GenerationConfig] = None):
+    def _decode_segment_spec(self, n_steps: int, cfg, sp):
         """Speculative decode segment: ``n_steps`` verify steps of the
         ONE compiled ``_spec_step_fn`` program, with the host loop in
         between — propose fresh drafts from each slot's proposer,
@@ -2340,13 +2367,9 @@ class ContinuousBatchingEngine:
             # per-segment speculative accounting: acceptance explains
             # why a segment's emitted count beat (or matched) its
             # verify-forward count
-            trace.record(
-                "engine.spec_segment",
-                dur_ns=int((time.perf_counter() - t0) * 1e9),
-                engine=self._monitor_engine, mode="host",
-                steps=n_steps, forwards=forwards, proposed=proposed,
-                accepted=accepted, emitted=total,
-                host_syncs=forwards)
+            sp.set(mode="host", forwards=forwards, proposed=proposed,
+                   accepted=accepted, emitted=total,
+                   host_syncs=forwards)
         return len(self._slot_req)
 
     # lint: hot-path
@@ -2365,16 +2388,32 @@ class ContinuousBatchingEngine:
         driver — omitted, the base stream is seeded from 0)."""
         if not self._slot_req:
             return 0
+        run, phase = self._decode_segment_plain, "engine.segment"
         if self._spec:
             # at least one live slot is speculating: the whole batch
             # rides ONE widened verify program (plain/sampled rows at
             # 1 token/step). Device mode fuses all n_steps into one
             # compiled segment; host mode drives the per-step loop
             # its host proposers need.
-            if self.spec_mode == "device":
-                return self._decode_segment_spec_device(n_steps, cfg)
-            return self._decode_segment_spec(n_steps, cfg)
-        n_live = len(self._slot_req)
+            run = (self._decode_segment_spec_device
+                   if self.spec_mode == "device"
+                   else self._decode_segment_spec)
+            phase = "engine.spec_segment"
+        sp = trace.NULL_SPAN
+        if trace.enabled():
+            # the counters a reader needs to reckon the KV the segment
+            # must read, from host bookkeeping alone (no device pull):
+            # live rows, and their prompt + generated tokens now
+            sp = trace.span(
+                phase, engine=self._monitor_engine, steps=n_steps,
+                rows=len(self._slot_req),
+                ctx_tokens=sum(self._plen[rid] + len(self._tokens[rid])
+                               for rid in self._slot_req.values()))
+        with sp:
+            return run(n_steps, cfg, sp)
+
+    # lint: hot-path
+    def _decode_segment_plain(self, n_steps: int, cfg, sp):
         t0 = time.perf_counter()
         # every segment must draw fresh sampling noise even when no
         # request was admitted in between — fold in a segment counter
@@ -2416,11 +2455,7 @@ class ContinuousBatchingEngine:
                 engine=self._monitor_engine).set(
                 emitted / dt if dt > 0 else 0.0)
         if trace.enabled():
-            trace.record(
-                "engine.segment",
-                dur_ns=int((time.perf_counter() - t0) * 1e9),
-                engine=self._monitor_engine, steps=n_steps,
-                active=n_live, emitted=emitted)
+            sp.set(emitted=emitted)
         return len(self._slot_req)
 
     @staticmethod
@@ -3069,11 +3104,14 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         # point; the bucket keys the compiled program count to
         # O(len(buckets))), then scatter the prompt's KV rows into
         # freshly reserved pages
-        mini = self._mini_cache(self._prefill_width(plen))
+        with trace.span("engine.mini_cache"):
+            mini = self._mini_cache(self._prefill_width(plen))
         last_logits, mini = self._run_prefill(
             ids, plen, mini, aidx=self._aidx_stash.get(slot, 0))
-        self._reserve_admit(slot, plen, cfg)
-        self._install_mini(slot, mini, plen)
+        with trace.span("engine.reserve"):
+            self._reserve_admit(slot, plen, cfg)
+        with trace.span("engine.install"):
+            self._install_mini(slot, mini, plen)
         return last_logits
 
     def _degrade_partial_hit(self, slot: int, plen: int, cfg, pids,
@@ -3120,20 +3158,27 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         # not the raw coverage — the clamp above shrinks it
         self._prefix_stash[slot]["saved"] = c_cmp
         tail = plen - c_cmp
-        mini = self._mini_cache(self.max_len)
-        mini = self._gather_mini(mini, pids)
+        with trace.span("engine.mini_cache"):
+            mini = self._mini_cache(self.max_len)
+            mini = self._gather_mini(mini, pids)
         self._count_prefill("warm")
+        sp = trace.NULL_SPAN
         if trace.enabled():
-            trace.event("engine.prefill", engine=self._monitor_engine,
-                        plen=plen, bucket="warm", cached=c_cmp)
+            # bucket: the tail program's width; plen - cached is what
+            # it computes of the prompt
+            sp = trace.span("engine.prefill", engine=self._monitor_engine,
+                            plen=plen, bucket=wt, cached=c_cmp)
         tail_ids = _pad_ids(ids[:, c_cmp:], wt)
-        last_logits, mini = self._prefill_chunk(
-            self.params, tail_ids, mini, jnp.int32(c_cmp),
-            jnp.int32(tail - 1), self._bank(),
-            jnp.int32(self._aidx_stash.get(slot, 0)))
-        self.alloc.map_shared(slot, pids)
-        self._reserve_admit(slot, plen, cfg)
-        self._install_mini(slot, mini, plen)
+        with sp:
+            last_logits, mini = self._prefill_chunk(
+                self.params, tail_ids, mini, jnp.int32(c_cmp),
+                jnp.int32(tail - 1), self._bank(),
+                jnp.int32(self._aidx_stash.get(slot, 0)))
+        with trace.span("engine.reserve"):
+            self.alloc.map_shared(slot, pids)
+            self._reserve_admit(slot, plen, cfg)
+        with trace.span("engine.install"):
+            self._install_mini(slot, mini, plen)
         return last_logits
 
     def _gather_mini(self, mini, pids):
@@ -3317,21 +3362,23 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self._prefix_stash[slot] = {"ids": ids, "c_map": c_map,
                                     "hashes": hashes, "saved": start,
                                     "salt": salt}
-        self.alloc.map_shared(slot, pids)
-        self._reserve_admit(slot, plen, cfg)
-        # copy-on-write the partial shared page EAGERLY, while the
-        # claim is atomic with the reservation — install runs gaps
-        # later, and the spare page must not be stolen by growth or
-        # another admission in between
-        p0 = c_map if c_map < plen else plen
-        if p0 % self.page_size and self.alloc.needs_cow(slot, p0):
-            self._cow_page(slot, p0 // self.page_size)
-        mini = self._mini_cache(self.max_len)
-        if pids:
-            # full cached coverage gathered (fixed-shape program);
-            # rows the chunks recompute from `start` just overwrite
-            # their gathered copies with bitwise-identical values
-            mini = self._gather_mini(mini, pids)
+        with trace.span("engine.reserve"):
+            self.alloc.map_shared(slot, pids)
+            self._reserve_admit(slot, plen, cfg)
+            # copy-on-write the partial shared page EAGERLY, while the
+            # claim is atomic with the reservation — install runs gaps
+            # later, and the spare page must not be stolen by growth or
+            # another admission in between
+            p0 = c_map if c_map < plen else plen
+            if p0 % self.page_size and self.alloc.needs_cow(slot, p0):
+                self._cow_page(slot, p0 // self.page_size)
+        with trace.span("engine.mini_cache"):
+            mini = self._mini_cache(self.max_len)
+            if pids:
+                # full cached coverage gathered (fixed-shape program);
+                # rows the chunks recompute from `start` just overwrite
+                # their gathered copies with bitwise-identical values
+                mini = self._gather_mini(mini, pids)
         return mini, start
 
     def _warmup_prefix(self) -> dict:

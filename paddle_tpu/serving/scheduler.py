@@ -1399,36 +1399,16 @@ class Server:
                 # gap + decode segment + collect
                 self._beat = time.monotonic()
                 try:
-                    self._gap()
-                    if self._active or self._adm is not None:
-                        # with only a chunked admission in flight the
-                        # segment is a fast no-op and the loop spins
-                        # straight back into _gap for the next chunk
-                        sp = trace.NULL_SPAN
-                        if trace.enabled() and self._active:
-                            # batch-wide event: carries the live
-                            # request set so each one's timeline()
-                            # includes its segments — plus the LoRA
-                            # adapter mix decoding in it (which
-                            # fine-tunes shared this program run)
-                            ad = tuple(sorted(
-                                {h.cfg.adapter for h
-                                 in self._active.values()
-                                 if getattr(h.cfg, "adapter", None)
-                                 is not None}))
-                            attrs = {"adapters": ad} if ad else {}
-                            sp = trace.span(
-                                "segment", steps=self.segment_steps,
-                                rids=tuple(h._trace_rid for h
-                                           in self._active.values()),
-                                **attrs)
-                        with sp:
-                            self._guard(
-                                "decode",
-                                lambda: self.engine.decode_segment(
-                                    self.segment_steps))
-                        self._guard("collect", self._collect)
-                    else:
+                    # one span per iteration that has work, the root of
+                    # everything the loop does in it; the idle wait
+                    # stays outside (an idle loop must not drown the
+                    # flight ring in empty spans)
+                    sp = trace.NULL_SPAN
+                    if trace.enabled() and self._has_work():
+                        sp = trace.span("step")
+                    with sp:
+                        ran = self._step(sp is not trace.NULL_SPAN)
+                    if not ran:
                         with self._idle_cv:
                             self._idle_cv.notify_all()
                         self._wake.wait(self.idle_wait_s)
@@ -1617,6 +1597,14 @@ class Server:
                 self._replay.append(sig.handle)
             return False
         self._restarts += 1      # counts ATTEMPTED-and-allowed restarts
+        sp = trace.NULL_SPAN
+        if trace.enabled():
+            sp = trace.span("recover", site=sig.site,
+                            restarts=self._restarts)
+        with sp:
+            return self._recover_allowed(sig)
+
+    def _recover_allowed(self, sig: _EngineFaultSignal) -> bool:
         t0 = time.monotonic()
         self._set_degraded(
             f"recovering from engine fault at {sig.site}: "
@@ -1720,9 +1708,6 @@ class Server:
         if monitor.enabled():
             self._recovery_hist().labels(
                 server=self.monitor_server).observe(dt)
-        if trace.enabled():
-            trace.record("recover", dur_ns=int(dt * 1e9), site=sig.site,
-                         restarts=self._restarts)
         # refresh the heartbeat BEFORE dropping the degraded flag: the
         # beat is stale by the whole recovery (backoff included), and a
         # watchdog tick landing between the clear and the loop's next
@@ -1899,7 +1884,44 @@ class Server:
             # next recovery/gap — nothing is stranded or duplicated
             self._replay = still + pending + self._replay
 
-    def _gap(self) -> None:  # lint: hot-path
+    def _has_work(self) -> bool:
+        return bool(self._active or self._adm is not None
+                    or self._replay or self.queue.depth)
+
+    def _step(self, traced: bool) -> bool:  # lint: hot-path
+        """One loop iteration: gap, then (with anything live) a decode
+        segment and its collection. ``traced`` says the caller opened
+        the iteration's ``step`` span (tracing on and work waiting).
+        False when no segment ran: the caller waits."""
+        self._gap(traced)
+        if not self._active and self._adm is None:
+            return False
+        # with only a chunked admission in flight the segment is a
+        # fast no-op and the loop spins straight back into _gap for
+        # the next chunk
+        seg = trace.NULL_SPAN
+        if trace.enabled() and self._active:
+            # batch-wide event: carries the live request set so each
+            # one's timeline() includes its segments — plus the LoRA
+            # adapter mix decoding in it (which fine-tunes shared this
+            # program run)
+            ad = tuple(sorted(
+                {h.cfg.adapter for h in self._active.values()
+                 if getattr(h.cfg, "adapter", None) is not None}))
+            attrs = {"adapters": ad} if ad else {}
+            seg = trace.span(
+                "segment", steps=self.segment_steps,
+                rids=tuple(h._trace_rid for h in self._active.values()),
+                **attrs)
+        with seg:
+            self._guard(
+                "decode",
+                lambda: self.engine.decode_segment(self.segment_steps))
+        with trace.span("collect"):
+            self._guard("collect", self._collect)
+        return True
+
+    def _gap(self, busy: bool) -> None:  # lint: hot-path
         """The inter-segment gap: cancellations first (they free
         capacity), then ONE chunk of any in-flight chunked admission
         (bounded gap work — decode segments run between chunks), then
@@ -1917,11 +1939,9 @@ class Server:
         guard (:class:`PagePoolExhausted`, an engine-scoped fault)
         never fires under this scheduler."""
         self._admitting = True
-        # the gap span only when there is WORK: an idle loop gaps ~50x/s
-        # and would drown the flight ring in empty spans
-        busy = bool(trace.enabled()
-                    and (self._active or self._adm is not None
-                         or self._replay or self.queue.depth))
+        # the gap span only when there is WORK (``busy``: the caller
+        # opened a ``step`` span): an idle loop gaps ~50x/s and would
+        # drown the flight ring in empty spans
         try:
             with (trace.span("gap") if busy else trace.NULL_SPAN):
                 self._gap_body()
@@ -1930,7 +1950,9 @@ class Server:
                 # observe->act loop last, on the post-admission state
                 # (rate-limited inside ControlPlane.tick): pure host
                 # bookkeeping, no engine work
-                self._control_tick()
+                with (trace.span("control") if busy
+                      else trace.NULL_SPAN):
+                    self._control_tick()
         finally:
             self._admitting = False
         self._depth_gauge()

@@ -32,15 +32,36 @@ two questions production serving actually debugs with:
 Cost model — the same bar as ``FLAGS_enable_monitor``: every recording
 entry point checks one module-level bool first, so with tracing off the
 instrumented paths pay a branch (plus one no-op context manager on the
-span sites) and nothing else. Recording granularity is per
-request-lifecycle edge and per decode segment — never per token and
-never per op — so tracing ON stays cheap enough for production serving
-(the ``serve_bench --trace-ab`` record in PERF.md quantifies it).
+span sites) and nothing else: no span object, no profiler annotation.
+Recording granularity is per request-lifecycle edge, per admission step
+and per decode segment — never per token and never per op — so tracing
+ON stays cheap enough for production serving (PERF.md §6, PR 25: six
+alternating pairs of the chat cell on one TPU v5e, tracing on against
+off, read TPOT p50 38.35 against 38.14 ms and 309.06 against 309.12
+tokens/s, inside either side's own spread).
 
 Event shape (dict form, what every surface returns)::
 
     {"phase": "admit", "rid": "server0:3", "ts_ns": ..., "dur_ns": ...,
+     "span.id": 41, "span.parent": 40,
      **attrs}                      # dur_ns == 0 marks an instant event
+
+A span is a node of a TREE: ``span.id`` is unique in the process (from
+1), ``span.parent`` is the id of the span that was open on the same
+thread when this one began (0: none). An instant event has
+``span.id`` 0 and the id of the span it happened in as its parent. The
+two keys hold a dot so that no keyword attribute can collide with them.
+
+ONE CLOCK WITH THE CHIP: a span also enters a
+``jax.profiler.TraceAnnotation`` named ``pt:<phase>`` that carries
+``id``, ``parent`` and ``rid`` (and ``pid`` where the span has no
+parent). While a ``jax.profiler`` session is open the span is thereby
+written into the session's host plane, on the clock of the device
+planes: in xprof / Perfetto the serving path's spans show above the
+TPU's operations, and a reader joins them to this ring by id
+(``benchmark/lib/host_spans.py``). With no session open the annotation
+is the profiler's own no-op. The ring stays the store of the attributes
+and the flight recorder.
 
 ``rid`` is the SERVING-layer request key (``<server_label>:<handle id>``
 for scheduler-driven requests — unique across concurrent servers in one
@@ -76,7 +97,7 @@ from typing import Any, Dict, List, Optional
 
 __all__ = [
     "enable", "disable", "enabled", "configure", "clear",
-    "event", "span", "record", "events", "timeline",
+    "event", "span", "events", "timeline",
     "export_chrome", "dump", "NULL_SPAN",
     "DEFAULT_CAPACITY",
 ]
@@ -85,11 +106,24 @@ DEFAULT_CAPACITY = 65536
 
 _enabled = False  # synced from FLAGS_enable_trace below
 _lock = threading.Lock()
-# ring entries: (ts_ns, dur_ns, rid, phase, attrs_or_None). One bounded
-# deque is both the per-request event store AND the flight recorder —
-# timelines are assembled on demand by scanning it, so the hot path is
-# a single locked append
+# ring entries: (ts_ns, dur_ns, rid, phase, attrs_or_None, id, parent).
+# One bounded deque is both the per-request event store AND the flight
+# recorder — timelines are assembled on demand by scanning it, so the
+# hot path is a single locked append
 _ring: deque = deque(maxlen=DEFAULT_CAPACITY)
+_ids = itertools.count(1)           # span ids; 0 means "no span"
+
+
+class _Open(threading.local):
+    """Per thread: the ids of its open spans, innermost last."""
+
+    def __init__(self):
+        self.stack = []
+
+
+_open = _Open()
+_annotation = None                  # jax.profiler.TraceAnnotation, bound
+                                    # when the first span is entered
 _dump_dir: Optional[str] = None     # None -> tempfile.gettempdir()
 _dump_seq = itertools.count()
 
@@ -155,33 +189,35 @@ def clear() -> None:
 # -- recording ---------------------------------------------------------------
 
 
-def record(phase: str, rid=None, dur_ns: int = 0, **attrs) -> None:
-    """Low-level append: one event with an explicit duration (0 = an
-    instant). Call sites that already measured a wall time use this;
-    everyone else uses :func:`event` / :func:`span`. No-op while
-    disabled."""
-    if not _enabled:
-        return
-    ev = (time.perf_counter_ns() - int(dur_ns), int(dur_ns), rid, phase,
-          attrs or None)
-    with _lock:
-        _ring.append(ev)
+def _bind_annotation():
+    """``jax`` is imported here, at the first span, and not with this
+    module: importing the package touches no backend."""
+    global _annotation
+    from jax.profiler import TraceAnnotation
+
+    _annotation = TraceAnnotation
+    return TraceAnnotation
 
 
 def event(phase: str, rid=None, **attrs) -> None:
-    """One instant event (``dur_ns == 0``). No-op while disabled."""
+    """One instant event (``dur_ns == 0``), child of the span open on
+    this thread. No-op while disabled."""
     if not _enabled:
         return
-    ev = (time.perf_counter_ns(), 0, rid, phase, attrs or None)
+    stack = _open.stack
+    ev = (time.perf_counter_ns(), 0, rid, phase, attrs or None, 0,
+          stack[-1] if stack else 0)
     with _lock:
         _ring.append(ev)
 
 
 class _Span:
     """Context manager recording one complete event on exit, stamped
-    with its entry time (so timelines sort spans by when they BEGAN)."""
+    with its entry time (so timelines sort spans by when they BEGAN),
+    and entering the profiler annotation of the same extent."""
 
-    __slots__ = ("_phase", "_rid", "_attrs", "_t0")
+    __slots__ = ("_phase", "_rid", "_attrs", "_t0", "_id", "_parent",
+                 "_ann")
 
     def __init__(self, phase, rid, attrs):
         self._phase = phase
@@ -189,14 +225,41 @@ class _Span:
         self._attrs = attrs or None
         self._t0 = None
 
+    def set(self, **attrs) -> None:
+        """Attributes known only at the end (``emitted``, ``admitted``),
+        set before the span closes."""
+        if self._attrs is None:
+            self._attrs = attrs
+        else:
+            self._attrs.update(attrs)
+
     def __enter__(self):
+        stack = _open.stack
+        self._id = next(_ids)
+        self._parent = stack[-1] if stack else 0
+        stack.append(self._id)
+        kw = {"id": self._id, "parent": self._parent}
+        if self._rid is not None:
+            kw["rid"] = self._rid
+        if not self._parent:
+            # a root names its process: a reader tells its own
+            # process's trace from another's
+            kw["pid"] = os.getpid()
+        self._ann = (_annotation or _bind_annotation())(
+            "pt:" + self._phase, **kw)
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        if self._t0 is not None and _enabled:
-            ev = (self._t0, time.perf_counter_ns() - self._t0,
-                  self._rid, self._phase, self._attrs)
+        if self._t0 is None:
+            return False
+        dur = time.perf_counter_ns() - self._t0
+        self._ann.__exit__(*exc)
+        _open.stack.pop()
+        if _enabled:
+            ev = (self._t0, dur, self._rid, self._phase, self._attrs,
+                  self._id, self._parent)
             with _lock:
                 _ring.append(ev)
         return False
@@ -207,6 +270,9 @@ class _NullSpan:
     two trivial method calls and zero allocation."""
 
     __slots__ = ()
+
+    def set(self, **attrs) -> None:
+        pass
 
     def __enter__(self):
         return self
@@ -234,13 +300,15 @@ def span(phase: str, rid=None, **attrs):
 
 
 def _to_dict(ev) -> Dict[str, Any]:
-    ts, dur, rid, phase, attrs = ev
+    ts, dur, rid, phase, attrs, sid, parent = ev
     d: Dict[str, Any] = dict(attrs) if attrs else {}
-    # the four fixed keys win over attr-name collisions
+    # the fixed keys win over attr-name collisions
     d["phase"] = phase
     d["rid"] = rid
     d["ts_ns"] = ts
     d["dur_ns"] = dur
+    d["span.id"] = sid
+    d["span.parent"] = parent
     return d
 
 
@@ -285,7 +353,7 @@ def timeline(rid) -> List[Dict[str, Any]]:
 def _chrome_events(snap) -> List[dict]:
     out = []
     pid = os.getpid()
-    for ts, dur, rid, phase, attrs in snap:
+    for ts, dur, rid, phase, attrs, sid, parent in snap:
         ev = {"name": phase, "ts": ts / 1e3, "pid": pid, "tid": 0,
               "cat": "serving"}
         if dur:
@@ -297,6 +365,10 @@ def _chrome_events(snap) -> List[dict]:
         args = dict(attrs) if attrs else {}
         if rid is not None:
             args["rid"] = rid
+        if sid:
+            args["span.id"] = sid
+        if parent:
+            args["span.parent"] = parent
         if args:
             # Perfetto chokes on non-JSON values; everything we record
             # is already json-able (str/int/float/bool/tuples)

@@ -19,16 +19,25 @@ Covers ``paddle_tpu.tracing`` end to end on CPU:
   honest 404;
 - the serve_bench TTFT decomposition (queue/prefill/gap shares sum to
   the server-side TTFT) and the ``monitor_report --trace`` phase
-  table / slowest-requests view.
+  table / slowest-requests view;
+- the span TREE (ISSUE 25): ids and parents across nested spans, across
+  threads and for instants; the old four keys of an event; no profiler
+  annotation at a span site while tracing is off; under a CPU
+  ``jax.profiler`` session every ring span is in ``/host:CPU`` by id;
+  behind ``Server`` the loop's ``step`` > ``gap`` > ``admit`` > the five
+  ``engine.*`` children nest by parent id, and ``engine.segment``
+  carries the context lengths the test knows.
 
 The flight-recorder triggers (engine fault / stall / preemption storm)
 are exercised where the faults are injected — the chaos suite
 (``tests/test_serving_faults.py`` ``TestFlightRecorder``); the
 monitor-registry retirement regression lives in ``tests/test_monitor.py``.
 """
+import glob
 import json
 import os
 import sys
+import threading
 import urllib.error
 import urllib.request
 
@@ -92,7 +101,6 @@ class TestRecorder:
         trace.disable()
         trace.clear()
         trace.event("x", rid=1)
-        trace.record("y", rid=1, dur_ns=100)
         assert trace.events() == []
         # the disabled span is THE shared null object: no allocation
         assert trace.span("z", rid=1) is trace.NULL_SPAN
@@ -150,8 +158,8 @@ class TestRecorder:
         with trace.span("admit", rid="s:1", plen=6, bucket=8):
             pass
         # batch-wide event carrying both requests
-        trace.record("segment", dur_ns=1000, rids=("s:1", "s:2"),
-                     steps=4)
+        with trace.span("segment", rids=("s:1", "s:2"), steps=4):
+            pass
         trace.event("finish", rid="s:2", status="finished")
         t1 = trace.timeline("s:1")
         assert [e["phase"] for e in t1] == ["queue.enqueue", "admit",
@@ -166,7 +174,9 @@ class TestRecorder:
 
     def test_export_chrome_and_dump(self, tr, tmp_path):
         trace.event("queue.enqueue", rid="s:1", depth=2)
-        trace.record("admit", rid="s:1", dur_ns=2_000_000, bucket=16)
+        with trace._lock:   # a span of a known duration
+            trace._ring.append((1_000, 2_000_000, "s:1", "admit",
+                                {"bucket": 16}, 1, 0))
         p = trace.export_chrome(str(tmp_path / "t.json"))
         doc = json.load(open(p))
         assert doc["displayTimeUnit"] == "ms"
@@ -302,6 +312,323 @@ class TestServerTimeline:
             srv.shutdown()
 
 
+def _by_id(evs):
+    return {e["span.id"]: e for e in evs if e["span.id"]}
+
+
+def _chain(ev, spans):
+    """Phases from ``ev`` up to its root, by parent id."""
+    out = [ev["phase"]]
+    while ev["span.parent"]:
+        ev = spans[ev["span.parent"]]
+        out.append(ev["phase"])
+    return out
+
+
+ENGINE_CHILDREN = ("engine.mini_cache", "engine.prefill", "engine.reserve",
+                   "engine.install", "engine.first_token")
+
+
+class TestSpanTree:
+    def test_nested_ids_and_parents(self, tr):
+        with trace.span("a") as a:
+            with trace.span("b"):
+                with trace.span("c"):
+                    pass
+            with trace.span("d"):
+                pass
+        evs = {e["phase"]: e for e in trace.events()}
+        ids = [evs[k]["span.id"] for k in "abcd"]
+        assert len(set(ids)) == 4 and all(i > 0 for i in ids)
+        assert evs["a"]["span.parent"] == 0
+        assert evs["b"]["span.parent"] == evs["a"]["span.id"]
+        assert evs["c"]["span.parent"] == evs["b"]["span.id"]
+        # a sibling opened after b closed hangs under a, not under b
+        assert evs["d"]["span.parent"] == evs["a"]["span.id"]
+        # what is known only at the end is set before the span closes
+        with trace.span("e", steps=2) as e:
+            e.set(emitted=7)
+        got = trace.events()[-1]
+        assert got["steps"] == 2 and got["emitted"] == 7
+        assert a is not trace.NULL_SPAN
+
+    def test_parents_do_not_cross_threads(self, tr):
+        inner = threading.Event()
+        leave = threading.Event()
+
+        def other():
+            with trace.span("t2.root"):
+                with trace.span("t2.child"):
+                    inner.set()
+                    assert leave.wait(10)
+
+        th = threading.Thread(target=other)
+        with trace.span("t1.root"):
+            th.start()
+            assert inner.wait(10)
+            # opened while the other thread's spans are open
+            with trace.span("t1.child"):
+                pass
+            leave.set()
+            th.join(10)
+        assert not th.is_alive()
+        evs = {e["phase"]: e for e in trace.events()}
+        assert evs["t1.root"]["span.parent"] == 0
+        assert evs["t2.root"]["span.parent"] == 0
+        assert evs["t1.child"]["span.parent"] == evs["t1.root"]["span.id"]
+        assert evs["t2.child"]["span.parent"] == evs["t2.root"]["span.id"]
+        assert len({e["span.id"] for e in evs.values()}) == 4
+
+    def test_instant_parent(self, tr):
+        trace.event("outside")
+        with trace.span("s"):
+            trace.event("inside", rid="s:1")
+        evs = {e["phase"]: e for e in trace.events()}
+        assert evs["outside"]["span.id"] == 0
+        assert evs["outside"]["span.parent"] == 0
+        assert evs["inside"]["span.id"] == 0
+        assert evs["inside"]["span.parent"] == evs["s"]["span.id"]
+
+    @pytest.mark.parametrize("surface", ["events", "timeline"])
+    def test_old_keys_stay(self, tr, surface):
+        """Every caller of PR 8's shape keeps working: the four keys
+        are there, the two new ones hold a dot, and no attribute can
+        shadow either."""
+        trace.event("queue.enqueue", rid="s:1", depth=2)
+        with trace.span("admit", rid="s:1", plen=6, ts_ns=1):
+            pass
+        got = (trace.events() if surface == "events"
+               else trace.timeline("s:1"))
+        assert [e["phase"] for e in got] == ["queue.enqueue", "admit"]
+        for e in got:
+            assert {"phase", "rid", "ts_ns", "dur_ns"} <= set(e)
+            assert {"span.id", "span.parent"} <= set(e)
+            assert e["rid"] == "s:1"
+        assert got[0]["depth"] == 2 and got[0]["dur_ns"] == 0
+        assert got[1]["plen"] == 6 and got[1]["ts_ns"] > 1
+        assert not hasattr(trace, "record")
+
+    def test_off_constructs_no_annotation(self, monkeypatch):
+        """With tracing off a span site builds nothing: not a span, not
+        a profiler annotation — through a whole served request."""
+        import jax.profiler
+
+        made = []
+
+        class Counting:
+            def __init__(self, name, **kw):
+                made.append(name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+        monkeypatch.setattr(trace, "_annotation", None)
+        trace.disable()
+        trace.clear()
+        assert trace.span("gap") is trace.NULL_SPAN
+        trace.NULL_SPAN.set(emitted=1)
+        model, mcfg = tiny_model()
+        srv = Server(paged_engine(model), segment_steps=4)
+        try:
+            srv.submit(_prompts(mcfg, 1)[0], _greedy(6)).result(
+                timeout=120)
+        finally:
+            srv.shutdown()
+        assert made == [] and trace.events() == []
+        # and on, the same site does (the fake proves the seam is live)
+        trace.enable()
+        try:
+            with trace.span("gap", rid="s:1"):
+                pass
+        finally:
+            trace.disable()
+            trace.clear()
+        assert made == ["pt:gap"]
+
+    def test_ring_spans_in_profiler_host_plane(self, tr, tmp_path):
+        """One clock: under a ``jax.profiler`` session (python tracer
+        off, as the benchmark runs it) every ring span of the session is
+        in ``/host:CPU`` under its id, as long as the ring says within
+        1 ms, its parent beside it; a root names the process."""
+        import jax
+        from jax.profiler import ProfileData
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        model, mcfg = tiny_model()
+        srv = Server(paged_engine(model), segment_steps=4)
+        try:
+            srv.submit(_prompts(mcfg, 1)[0], _greedy(6)).result(
+                timeout=120)            # programs compiled
+            trace.clear()
+            jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+            try:
+                srv.submit(_prompts(mcfg, 1, seed=1)[0],
+                           _greedy(6)).result(timeout=120)
+            finally:
+                jax.profiler.stop_trace()
+            ring = _by_id(trace.events())
+        finally:
+            srv.shutdown()
+        path = glob.glob(os.path.join(
+            str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))[0]
+        host = {}
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name != "/host:CPU":
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("pt:"):
+                        stats = dict(ev.stats)
+                        host[stats["id"]] = (ev, stats)
+        assert len(ring) >= 10
+        for sid, e in ring.items():
+            assert sid in host, e
+            ev, stats = host[sid]
+            assert ev.name == "pt:" + e["phase"]
+            assert stats["parent"] == e["span.parent"]
+            assert abs(ev.duration_ns - e["dur_ns"]) < 1e6
+            if e["rid"] is not None:
+                assert stats["rid"] == e["rid"]
+            if not e["span.parent"]:
+                assert stats["pid"] == os.getpid()
+        # the profiler's clock orders the spans as the ring's does
+        steps = sorted((e for e in ring.values() if e["phase"] == "step"),
+                       key=lambda e: e["ts_ns"])
+        on_host = [host[e["span.id"]][0].start_ns for e in steps]
+        assert on_host == sorted(on_host) and len(steps) >= 2
+
+
+class TestServingTree:
+    def test_step_gap_admit_children_nest(self, tr):
+        model, mcfg = tiny_model()
+        srv = Server(paged_engine(model), segment_steps=4)
+        try:
+            h = srv.submit(_prompts(mcfg, 1)[0], _greedy(9))
+            h.result(timeout=120)
+        finally:
+            srv.shutdown()
+        evs = trace.events()
+        spans = _by_id(evs)
+        admit = next(e for e in evs if e["phase"] == "admit")
+        assert _chain(admit, spans) == ["admit", "gap", "step"]
+        kids = [e for e in sorted(spans.values(), key=lambda e: e["ts_ns"])
+                if e["span.parent"] == admit["span.id"]]
+        assert tuple(e["phase"] for e in kids) == ENGINE_CHILDREN
+        assert sum(e["dur_ns"] for e in kids) <= admit["dur_ns"]
+        pre = kids[1]
+        assert (pre["plen"], pre["bucket"], pre["cached"]) == (6, 16, 0)
+        # the instants of an admission hang under the iteration too
+        deq = next(e for e in evs if e["phase"] == "queue.dequeue")
+        assert _chain(deq, spans)[1:] == ["gap", "step"]
+        # every iteration: gap, then segment > engine.segment, then
+        # collect, all children of ONE step
+        for st in (e for e in spans.values() if e["phase"] == "step"):
+            seq = [e["phase"] for e in
+                   sorted(spans.values(), key=lambda e: e["ts_ns"])
+                   if e["span.parent"] == st["span.id"]]
+            assert seq[0] == "gap"
+            if "segment" in seq:
+                assert seq[seq.index("segment") + 1] == "collect"
+        segs = [e for e in spans.values() if e["phase"] == "engine.segment"]
+        assert segs and all(
+            spans[e["span.parent"]]["phase"] == "segment" for e in segs)
+        # the idle wait has no span: nothing but step is a root here
+        roots = {e["phase"] for e in spans.values()
+                 if not e["span.parent"]}
+        assert roots == {"step"}
+
+    def test_segment_counters_match_known_lengths(self, tr):
+        """``ctx_tokens`` is the sum over live rows of prompt + tokens
+        generated before the segment; one request of 6 + 9 behind the
+        server, then two rows of different lengths on the engine."""
+        model, mcfg = tiny_model()
+        srv = Server(paged_engine(model), segment_steps=4)
+        try:
+            srv.submit(_prompts(mcfg, 1)[0], _greedy(9)).result(
+                timeout=120)
+        finally:
+            srv.shutdown()
+        segs = sorted((e for e in trace.events()
+                       if e["phase"] == "engine.segment"),
+                      key=lambda e: e["ts_ns"])
+        # admission samples token 1; two segments of 4 make the other 8
+        assert [(e["rows"], e["ctx_tokens"], e["steps"], e["emitted"])
+                for e in segs] == [(1, 7, 4, 4), (1, 11, 4, 4)]
+        trace.clear()
+        eng = paged_engine(model)
+        try:
+            a, b = (_prompts(mcfg, 1, plen=6)[0],
+                    _prompts(mcfg, 1, plen=9, seed=1)[0])
+            eng.add_request(a, _greedy(12))
+            eng.decode_segment(4)                  # a: 6 + 1
+            eng.add_request(b, _greedy(3))
+            eng.decode_segment(4)                  # a: 6 + 5, b: 9 + 1
+            eng.decode_segment(4)                  # b retired at 3
+        finally:
+            eng.close()
+        segs = [e for e in trace.events()
+                if e["phase"] == "engine.segment"]
+        assert [(e["rows"], e["ctx_tokens"], e["emitted"])
+                for e in segs] == [(1, 7, 4), (2, 21, 6), (1, 15, 3)]
+        # driven without a scheduler the engine's spans are roots
+        assert all(e["span.parent"] == 0 for e in segs)
+
+    def test_chunked_and_warm_prefill_spans(self, tr):
+        """A chunked admission's programs are children of its
+        ``prefill_chunk`` spans (the claim and the mini under
+        ``admit.begin``); a prefix-cache hit's tail program reports a
+        numeric bucket and what it did not compute."""
+        model, mcfg = tiny_model()
+        eng = paged_engine(model, num_pages=64, max_pages=16,
+                           prefill_chunk=8)
+        srv = Server(eng, segment_steps=4)
+        try:
+            srv.submit(_prompts(mcfg, 1, plen=20)[0],
+                       _greedy(6)).result(timeout=120)
+        finally:
+            srv.shutdown()
+        evs = trace.events()
+        spans = _by_id(evs)
+        pres = sorted((e for e in evs if e["phase"] == "engine.prefill"),
+                      key=lambda e: e["ts_ns"])
+        assert [(e["plen"], e["bucket"], e["cached"]) for e in pres] == [
+            (8, 8, 0), (16, 8, 8), (20, 8, 16)]
+        assert all(_chain(e, spans)[:4] == [
+            "engine.prefill", "prefill_chunk", "gap", "step"]
+            for e in pres)
+        begin = next(e for e in evs if e["phase"] == "admit.begin")
+        assert [e["phase"] for e in
+                sorted(spans.values(), key=lambda e: e["ts_ns"])
+                if e["span.parent"] == begin["span.id"]] == [
+            "engine.reserve", "engine.mini_cache"]
+        last_chunk = spans[pres[-1]["span.parent"]]
+        assert [e["phase"] for e in
+                sorted(spans.values(), key=lambda e: e["ts_ns"])
+                if e["span.parent"] == last_chunk["span.id"]] == [
+            "engine.prefill", "engine.install", "engine.first_token"]
+
+        trace.clear()
+        eng = paged_engine(model, num_pages=64, max_pages=16,
+                           prefix_cache=True)
+        try:
+            p = _prompts(mcfg, 1, plen=20)[0]
+            eng.add_request(p, _greedy(2))
+            eng.add_request(p, _greedy(2))         # the same prompt: warm
+        finally:
+            eng.close()
+        cold, warm = [e for e in trace.events()
+                      if e["phase"] == "engine.prefill"]
+        assert (cold["plen"], cold["bucket"], cold["cached"]) == (20, 32, 0)
+        assert warm["plen"] == 20 and warm["cached"] > 0
+        assert isinstance(warm["bucket"], int)
+        assert warm["plen"] - warm["cached"] <= warm["bucket"]
+
+
 def _tools():
     sys.path.insert(0, os.path.join(os.path.dirname(__file__),
                                     os.pardir, "tools"))
@@ -323,19 +650,20 @@ class TestToolViews:
 
         t0 = _t.perf_counter_ns()
         with trace._lock:   # hand-build deterministic timestamps
-            trace._ring.append((t0, 0, "s:1", "queue.enqueue", None))
+            trace._ring.append((t0, 0, "s:1", "queue.enqueue", None,
+                                0, 0))
             trace._ring.append((t0 + 10_000_000, 0, "s:1",
-                                "queue.dequeue", None))
+                                "queue.dequeue", None, 0, 0))
             trace._ring.append((t0 + 10_000_000, 30_000_000, "s:1",
-                                "admit", None))
+                                "admit", None, 1, 0))
             trace._ring.append((t0 + 50_000_000, 0, "s:1",
-                                "first_token", None))
+                                "first_token", None, 0, 0))
         # a preempted request's REPLAY re-admission lands after the
         # first token (ring order is end-time order) and must NOT
         # inflate the prefill share
         with trace._lock:
             trace._ring.append((t0 + 90_000_000, 40_000_000, "s:1",
-                                "admit", {"replay": True}))
+                                "admit", {"replay": True}, 2, 0))
         qs, ps, gs = serve_bench._ttft_decomposition()
         assert qs == [pytest.approx(0.010)]
         assert ps == [pytest.approx(0.030)]

@@ -8,7 +8,7 @@ at a time: a ``trace.event(...)`` whose kwargs are eagerly built, a
 append behind no branch. Two rules:
 
 1. CALL SITES anywhere in the tree — a trace-recording call
-   (``trace.event`` / ``trace.record`` / ``tracing.event`` ...) or a
+   (``trace.event`` / ``tracing.event`` / ``_trace.event``) or a
    monitor mutation chain (``....labels(...).inc/.set/.observe/.dec``
    or ``monitor.counter/gauge/histogram(...).inc/...``) must be
    dominated by an enable check: lexically inside an ``if`` whose test
@@ -34,7 +34,7 @@ from typing import List, Optional
 from ..core import Finding, Module, dotted_name
 
 _TRACE_MODULES = {"trace", "tracing", "_trace", "_tracing"}
-_TRACE_RECORDERS = {"event", "record"}
+_TRACE_RECORDERS = {"event"}
 _MUTATORS = {"inc", "dec", "set", "observe"}
 _CTORS = {"counter", "gauge", "histogram"}
 _ENABLED_RE = re.compile(r"\benabled\b|\b_enabled\b")
