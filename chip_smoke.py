@@ -263,13 +263,15 @@ def _check_serving_kernels(eng, widths, segment_steps, kernels, tag=""):
     bucket widths in use and its decode segment, with the arguments the
     engine itself passes, and look for the kernels."""
     import jax
-    import jax.numpy as jnp
 
     tp = "tp_" if eng.tp_degree > 1 else ""
+    pools, pt = eng.caches
     for w in widths:
-        lowered = eng._prefill._jitted.lower(
-            eng.params, np.zeros((1, w), np.int32), eng._warmup_mini(w),
-            jnp.int32(w - 1), eng._bank(), jnp.int32(0))
+        # the program of a cold admission: mini cache, prefill and page
+        # install in one (it is only lowered: nothing is donated)
+        lowered = eng._prefill_paged._jitted.lower(
+            eng.params, np.zeros((1, w), np.int32), pools, pt,
+            np.int32(0), np.int32(w), eng._bank(), np.int32(0))
         check_kernels(
             f"{tag}cb_prefill[{w}]",
             tp + ("prefill_wide" if w % 128 == 0 else "prefill_narrow"),

@@ -663,6 +663,15 @@ class CausalLMEngine:
         return np.concatenate([ids, np.asarray([out], np.int32)], axis=1)
 
 
+def _lora_rows(bank, aidx, ids):
+    """The model forwards' ``lora`` input for a prefill of ``ids`` under
+    ONE adapter: (bank, per-row index), or None for an empty bank (the
+    exact pre-LoRA trace)."""
+    if not bank:
+        return None
+    return bank, jnp.full((ids.shape[0],), aidx, jnp.int32)
+
+
 class _ChunkedAdmission:
     """Host-side state of one in-flight CHUNKED admission. The slot (and,
     paged, the request's worst-case pages) is already claimed; ``mini``
@@ -873,10 +882,8 @@ class ContinuousBatchingEngine:
             # inputs (aidx traced — one program serves every adapter;
             # an empty bank is trace-static and falls back to the
             # exact pre-LoRA prefill)
-            lora = ((bank, jnp.full((ids.shape[0],), aidx, jnp.int32))
-                    if bank else None)
-            logits, mini = self._fwd_prefill(params, ids, mini,
-                                             lora=lora)
+            logits, mini = self._fwd_prefill(
+                params, ids, mini, lora=_lora_rows(bank, aidx, ids))
             return logits[:, last_idx], mini
 
         self._prefill = monitor.monitored_jit(
@@ -887,15 +894,25 @@ class ContinuousBatchingEngine:
                              aidx):
             # traced offset -> ops.pallas.prefix_chunk_attention: ONE
             # compiled program serves every chunk of every admission
-            lora = ((bank, jnp.full((ids.shape[0],), aidx, jnp.int32))
-                    if bank else None)
-            logits, mini = self._fwd_prefill(params, ids, mini, pos,
-                                             lora=lora)
+            logits, mini = self._fwd_prefill(
+                params, ids, mini, pos, lora=_lora_rows(bank, aidx, ids))
             return logits[:, last_idx], mini
 
         self._prefill_chunk = monitor.monitored_jit(
             prefill_chunk_fn, name="cb_prefill_chunk",
             owner=self._monitor_engine, donate_argnums=(2,))
+
+        def mini_cache(width):
+            # one admission's B=1 dense mini cache, where a prefill
+            # writes the prompt's KV before it installs into the pool:
+            # every layer's zeros in ONE program per width (eager, a
+            # layer's two jnp.zeros were 2L dispatches an admission),
+            # sharded on the head axis like the pool they feed, so the
+            # gather/scatter install programs move head-local rows.
+            # Plain jit: a program with no FLOPs is not the ledger's
+            return self._tp_kv(self.model.init_cache(1, width))
+
+        self._mini_cache = jax.jit(mini_cache, static_argnums=(0,))
 
         def admit(caches, mini, slot):
             return jax.tree.map(
@@ -1029,14 +1046,6 @@ class ContinuousBatchingEngine:
         from .tp import tp_shard_kv
 
         return tp_shard_kv(caches, self.tp_mesh)
-
-    def _mini_cache(self, width: int):
-        """One admission's B=1 dense mini cache, TP-placed: the mini is
-        where prefill writes the prompt's KV before it installs into
-        the pool, so it shards on the head axis exactly like the pool
-        it feeds — the gather/scatter install programs then move
-        head-local rows with zero cross-chip traffic."""
-        return self._tp_kv(self.model.init_cache(1, width))
 
     def _make_caches(self):
         """Cache layout hook — the paged subclass replaces the dense
@@ -1287,18 +1296,20 @@ class ContinuousBatchingEngine:
             hlen = len(tail) + 1     # + the first token (set in-program)
         # a token sampled from a TP program carries the mesh in its
         # type; a host-made one (warmup, eos=None) does not — commit
-        # both to the mesh so the program has one signature
+        # both to the mesh so the program has one signature. The
+        # scalars are numpy: they ride as arguments, where a
+        # jnp.int32() each is a device program of its own
         (self.lens, self.last, self.done_dev, self.active_dev,
          self.samp, self.hist, self.hist_len) = self._admit_state(
             self.lens, self.last, self.done_dev, self.active_dev,
-            self.samp, self.hist, self.hist_len, jnp.int32(slot),
-            jnp.int32(plen), self._tp_rep(first),
-            self._tp_rep(tok_done), jnp.float32(cfg.temperature),
-            jnp.int32(cfg.top_k), jnp.float32(cfg.top_p),
-            jnp.asarray(cfg.do_sample), jnp.int32(eos),
-            jnp.int32(cfg.seed % (2 ** 31)),
-            jnp.int32(self._spec_k_for(cfg)), jnp.int32(aidx),
-            jnp.asarray(hrow), jnp.int32(hlen))
+            self.samp, self.hist, self.hist_len, np.int32(slot),
+            np.int32(plen), self._tp_rep(first),
+            self._tp_rep(tok_done), np.float32(cfg.temperature),
+            np.int32(cfg.top_k), np.float32(cfg.top_p),
+            np.bool_(cfg.do_sample), np.int32(eos),
+            np.int32(cfg.seed % (2 ** 31)),
+            np.int32(self._spec_k_for(cfg)), np.int32(aidx),
+            hrow, np.int32(hlen))
 
     def _register(self, slot: int, rid: int, first, tok_done, cfg,
                   t0: float) -> int:
@@ -1348,21 +1359,33 @@ class ContinuousBatchingEngine:
                 ("engine", "bucket")).labels(
                 engine=self._monitor_engine, bucket=str(bucket)).inc()
 
+    def _prefill_span(self, plen: int, bucket: int, cached: int = 0,
+                      fused: int = 0):
+        """The ``engine.prefill`` span: the DISPATCH of one prefill
+        program, not its device time. ``bucket`` (the compiled
+        program's width) is the observable that explains its latency
+        class, ``plen - cached`` is what it computes of the prompt,
+        ``fused`` = 1 when the mini cache and the install ride inside
+        the program."""
+        if not trace.enabled():
+            return trace.NULL_SPAN
+        return trace.span("engine.prefill", engine=self._monitor_engine,
+                          plen=plen, bucket=bucket, cached=cached,
+                          fused=fused)
+
+    def _cold_width(self, plen: int) -> int:
+        """Program width of a cold one-shot prefill, counted."""
+        width = self._prefill_width(plen)
+        self._count_prefill(width if self.prefill_buckets is not None
+                            else "exact")
+        return width
+
     def _run_prefill(self, ids, plen: int, mini, aidx: int = 0):
         """Pad the prompt to its bucket and run the one-shot prefill
         program (under the request's adapter, when any); returns
         (last-position logits [1, V], mini)."""
-        width = self._prefill_width(plen)
-        self._count_prefill(width if self.prefill_buckets is not None
-                            else "exact")
-        sp = trace.NULL_SPAN
-        if trace.enabled():
-            # the bucket CHOICE is the observable that explains a
-            # prefill's latency class (compiled-program width); the
-            # span is the DISPATCH of the program, not its device time
-            sp = trace.span("engine.prefill", engine=self._monitor_engine,
-                            plen=plen, bucket=width, cached=0)
-        with sp:
+        width = self._cold_width(plen)
+        with self._prefill_span(plen, width):
             return self._prefill(self.params, _pad_ids(ids, width), mini,
                                  jnp.int32(plen - 1), self._bank(),
                                  jnp.int32(aidx))
@@ -1371,7 +1394,7 @@ class ContinuousBatchingEngine:
         """Cache-layout hook: prefill the prompt and install its KV into
         slot's cache; returns the prompt's last-position logits. The
         dense base scatters a max_len mini cache; the paged subclass
-        reserves pages and scatters a bucket-sized one."""
+        reserves pages and runs ONE program that fills them."""
         with trace.span("engine.mini_cache"):
             mini = self._mini_cache(self.max_len)
         last_logits, mini = self._run_prefill(
@@ -1617,15 +1640,8 @@ class ContinuousBatchingEngine:
             last = adm.off + r >= adm.plen
             if r < C:       # only the FINAL chunk may be partial
                 chunk = _pad_ids(chunk, C)
-            sp = trace.NULL_SPAN
-            if trace.enabled():
-                # ``cached``: the prompt tokens already in the mini,
-                # so plen - cached is what THIS program computes
-                sp = trace.span("engine.prefill",
-                                engine=self._monitor_engine,
-                                plen=adm.off + r, bucket=C,
-                                cached=adm.off)
-            with sp:
+            # ``cached``: the prompt tokens already in the mini
+            with self._prefill_span(adm.off + r, C, cached=adm.off):
                 adm.last_logits, adm.mini = self._prefill_chunk(
                     self.params, chunk, adm.mini, jnp.int32(adm.off),
                     jnp.int32(r - 1), self._bank(), jnp.int32(aidx))
@@ -1681,25 +1697,16 @@ class ContinuousBatchingEngine:
         widths = self.prefill_buckets or ()
         for w in widths:
             t0 = time.perf_counter()
-            ids = np.zeros((1, w), np.int32)
-            mini = self._warmup_mini(w)
-            _, mini = self._prefill(self.params, ids, mini,
-                                    jnp.int32(w - 1), self._bank(),
-                                    jnp.int32(0))
-            # also warms the per-bucket cache-install program; slot 0 is
-            # free, so the zero-prompt KV it scatters is dead weight the
-            # next admission overwrites (paged: dropped — no pages
-            # mapped)
-            self._install_mini(0, mini, w)
+            self._warmup_prefill(w)
             out[f"prefill_{w}"] = time.perf_counter() - t0
         if self.prefill_chunk is not None:
             t0 = time.perf_counter()
-            mini = self._mini_cache(self.max_len)
-            self._prefill_chunk(self.params,
-                                np.zeros((1, self.prefill_chunk),
-                                         np.int32),
-                                mini, jnp.int32(0), jnp.int32(0),
-                                self._bank(), jnp.int32(0))
+            _, mini = self._prefill_chunk(
+                self.params, np.zeros((1, self.prefill_chunk), np.int32),
+                self._mini_cache(self.max_len), jnp.int32(0),
+                jnp.int32(0), self._bank(), jnp.int32(0))
+            # and the install of a max_len mini (into the free slot 0)
+            self._install_mini(0, mini, self.prefill_chunk)
             out["prefill_chunk"] = time.perf_counter() - t0
         # slot-state install program (values match the initial state,
         # except the active flag — reset below)
@@ -1768,10 +1775,16 @@ class ContinuousBatchingEngine:
                 engine=self._monitor_engine).set(out["total"])
         return out
 
-    def _warmup_mini(self, width: int):
-        """Mini cache matching what an admission of a width-token prompt
-        allocates (dense: the max_len slab; paged: bucket-sized)."""
-        return self._mini_cache(self.max_len)
+    def _warmup_prefill(self, width: int) -> None:
+        """Run what a cold admission of a ``width``-token prompt runs
+        (the jitted programs directly, not the dispatch helpers). Slot
+        0 is free, so the zero-prompt KV it installs is dead weight the
+        next admission overwrites (paged: dropped — no page mapped)."""
+        _, mini = self._prefill(
+            self.params, np.zeros((1, width), np.int32),
+            self._mini_cache(self.max_len), jnp.int32(width - 1),
+            self._bank(), jnp.int32(0))
+        self._install_mini(0, mini, width)
 
     def _warmup_prefix(self) -> dict:
         """Pre-compile the prefix-cache warm-admission programs (paged
@@ -2726,8 +2739,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         # builds the pools.
         self.kv_dtype = kv_dtype
         # slot -> warm-admission info ({"ids","c_map","hashes","saved"})
-        # staged between the admission's prefill and its cache install;
-        # popped by _install_mini / _abort_admit
+        # staged from the admission's lookup until its rows are in the
+        # pages; popped by _index_prompt / _abort_admit
         self._prefix_stash = {}
         # segment count a clean grow_for_segment covered; decode_segment
         # consumes it to skip its (device-syncing) exhaustion re-check
@@ -2773,6 +2786,29 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self._reset_scales = monitor.monitored_jit(
             reset_scales, name="cb_reset_scales",
             owner=self._monitor_engine, donate_argnums=(0,))
+
+        def prefill_one(params, ids, pools, page_table, slot, plen, bank,
+                        aidx):
+            # a cold one-shot admission is this ONE program per bucket:
+            # the bucket-wide mini cache is made in here (zeros XLA need
+            # not materialise), the base engine's prefill runs on it,
+            # and every layer's rows go into the DONATED pools by
+            # write_tokens' own arithmetic (unmapped pages drop; int8
+            # drops past plen). slot / plen / aidx are traced
+            from .paged_cache import write_prompt
+
+            mini = self._tp_kv(self.model.init_cache(1, ids.shape[1]))
+            logits, mini = self._fwd_prefill(
+                params, ids, mini, lora=_lora_rows(bank, aidx, ids))
+            return (logits[:, plen - 1],
+                    write_prompt(pools, page_table, slot, plen, mini))
+
+        # under the base prefill's names (monitor "cb_prefill", XLA module
+        # jit_prefill_one): the miss counters and the benchmark's readers
+        # find a prompt's prefill by them
+        self._prefill_paged = monitor.monitored_jit(
+            prefill_one, name="cb_prefill",
+            owner=self._monitor_engine, donate_argnums=(2,))
 
     def _make_caches(self):
         # TP: pools (and int8 scales) shard on the kv-head axis; the
@@ -3099,19 +3135,42 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             if c_map > 0:
                 return self._admit_cache_warm(slot, ids, plen, cfg,
                                               pids, c_map)
-        # COLD path: prefill into a dense mini cache sized to the
-        # prompt's BUCKET (no max_len slab — the pool is the whole
+        # COLD path: claim the pages (the program needs the slot's
+        # page-table row; a claim that fails, fails before any device
+        # work), then ONE program prefills into a mini cache sized to
+        # the prompt's BUCKET (no max_len slab — the pool is the whole
         # point; the bucket keys the compiled program count to
-        # O(len(buckets))), then scatter the prompt's KV rows into
-        # freshly reserved pages
-        with trace.span("engine.mini_cache"):
-            mini = self._mini_cache(self._prefill_width(plen))
-        last_logits, mini = self._run_prefill(
-            ids, plen, mini, aidx=self._aidx_stash.get(slot, 0))
+        # O(len(buckets))) and scatters its rows into those pages
         with trace.span("engine.reserve"):
             self._reserve_admit(slot, plen, cfg)
-        with trace.span("engine.install"):
-            self._install_mini(slot, mini, plen)
+        return self._run_prefill_paged(
+            slot, ids, plen, aidx=self._aidx_stash.get(slot, 0))
+
+    def _run_prefill_paged(self, slot: int, ids, plen: int,
+                           aidx: int = 0):
+        """``_run_prefill`` with the mini cache and the install inside
+        the program (``engine.prefill{fused=1}``): pad the prompt to its
+        bucket, run it into ``slot``'s claimed pages; returns the
+        last-position logits [1, V]."""
+        width = self._cold_width(plen)
+        # int8: the claimed pages' scale rows reset BEFORE the program
+        # runs its running absmax against them
+        self._flush_fresh_scales()
+        with self._prefill_span(plen, width, fused=1):
+            last_logits = self._prefill_install(
+                slot, _pad_ids(ids, width), plen, aidx)
+        self._index_prompt(slot, plen)
+        return last_logits
+
+    def _prefill_install(self, slot: int, ids, plen: int, aidx: int):
+        pt = self._tp_rep(jnp.asarray(self.alloc.page_table))
+        pools, _ = self.caches
+        # numpy scalars ride as arguments: a jnp.int32() is a device
+        # program of its own
+        last_logits, pools = self._prefill_paged(
+            self.params, ids, pools, pt, np.int32(slot), np.int32(plen),
+            self._bank(), np.int32(aidx))
+        self.caches = (pools, pt)
         return last_logits
 
     def _degrade_partial_hit(self, slot: int, plen: int, cfg, pids,
@@ -3162,14 +3221,9 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             mini = self._mini_cache(self.max_len)
             mini = self._gather_mini(mini, pids)
         self._count_prefill("warm")
-        sp = trace.NULL_SPAN
-        if trace.enabled():
-            # bucket: the tail program's width; plen - cached is what
-            # it computes of the prompt
-            sp = trace.span("engine.prefill", engine=self._monitor_engine,
-                            plen=plen, bucket=wt, cached=c_cmp)
         tail_ids = _pad_ids(ids[:, c_cmp:], wt)
-        with sp:
+        # bucket: the tail program's width
+        with self._prefill_span(plen, wt, cached=c_cmp):
             last_logits, mini = self._prefill_chunk(
                 self.params, tail_ids, mini, jnp.int32(c_cmp),
                 jnp.int32(tail - 1), self._bank(),
@@ -3244,56 +3298,42 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             else self._optimistic_claim(plen, cfg))
 
     def _install_mini(self, slot: int, mini, plen: int) -> None:
-        from .paged_cache import write_tokens, write_tokens_q
+        from .paged_cache import install_prompt
 
         # int8: reset freshly claimed pages' scale rows BEFORE the
         # quantized install runs its running absmax against them
         self._flush_fresh_scales()
-        info = (self._prefix_stash.pop(slot, None)
-                if self.prefix_cache else None)
+        info = self._prefix_stash.get(slot)
         if info is not None and info["c_map"] > 0:
             self._install_mini_warm(slot, mini, plen, info)
         else:
-            # COLD scatter: bucket-width rows (fixed shapes per bucket
-            # — the scatter program count stays O(len(buckets)), not
-            # O(#plens)): rows past plen land on reserved-but-unwritten
-            # positions the decode mask hides and decode writes
-            # overwrite, or on unmapped pages where write_tokens drops
-            # them
-            width = min(self._prefill_width(plen), mini[0][0].shape[1])
+            # COLD scatter of a mini that outlived its programs (a
+            # chunked admission's): the whole mini in ONE program, the
+            # scatter the fused prefill ends with. Rows past plen land
+            # on reserved-but-unwritten positions the decode mask
+            # hides and decode writes overwrite, or drop (unmapped
+            # pages; int8: everything past plen)
             pt = self._tp_rep(jnp.asarray(self.alloc.page_table))
-            slots_v = jnp.full((width,), slot, jnp.int32)
-            pos_v = jnp.arange(width, dtype=jnp.int32)
             pools, _ = self.caches
-            new_pools = []
-            if self.kv_dtype == "int8":
-                # limit=plen: the pad tail past the prompt DROPS
-                # instead of ratcheting headroom pages' running absmax
-                # (their floor-reset scales already read stale rows
-                # as ~0)
-                for (kp, vp, ks, vs), (mk, mv) in zip(pools, mini):
-                    kp, vp, ks, vs = write_tokens_q(
-                        kp, vp, ks, vs, pt, slots_v, pos_v,
-                        mk[0, :width], mv[0, :width],
-                        limit=jnp.int32(plen))
-                    new_pools.append((kp, vp, ks, vs))
-            else:
-                for (kp, vp), (mk, mv) in zip(pools, mini):
-                    kp, vp = write_tokens(kp, vp, pt, slots_v, pos_v,
-                                          mk[0, :width], mv[0, :width])
-                    new_pools.append((kp, vp))
-            self.caches = (new_pools, pt)
-        if info is not None:
-            # a cold admission POPULATES the cache; a warm one extends
-            # it — either way the prompt's fully-written private blocks
-            # become future hits (in the admission's adapter namespace)
-            ps = self.page_size
-            self.alloc.register_blocks(
-                slot, info["hashes"], info["ids"][0],
-                info["c_map"] // ps, plen // ps,
-                salt=info.get("salt", b""))
-            if info["c_map"] > 0:
-                self.alloc.count_prefix_hit(info["saved"])
+            self.caches = (install_prompt(pools, pt, np.int32(slot),
+                                          np.int32(plen), mini), pt)
+        self._index_prompt(slot, plen)
+
+    def _index_prompt(self, slot: int, plen: int) -> None:
+        """Prefix cache: a cold admission POPULATES the cache, a warm
+        one extends it — either way the prompt's fully-written private
+        blocks become future hits (in the admission's adapter
+        namespace). Runs once the rows are in the pages."""
+        info = self._prefix_stash.pop(slot, None)
+        if info is None:
+            return
+        ps = self.page_size
+        self.alloc.register_blocks(
+            slot, info["hashes"], info["ids"][0],
+            info["c_map"] // ps, plen // ps,
+            salt=info.get("salt", b""))
+        if info["c_map"] > 0:
+            self.alloc.count_prefix_hit(info["saved"])
 
     def _install_mini_warm(self, slot: int, mini, plen: int,
                            info) -> None:
@@ -3345,8 +3385,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             pools, _ = self.caches
             self.caches = (pools, pt)
 
-    def _warmup_mini(self, width: int):
-        return self._mini_cache(width)
+    def _warmup_prefill(self, width: int) -> None:
+        self._prefill_install(0, np.zeros((1, width), np.int32), width, 0)
 
     def _begin_admit_cache(self, slot: int, ids, plen: int, cfg):
         if not self.prefix_cache or self.prefix_pause:

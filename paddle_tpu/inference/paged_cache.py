@@ -56,7 +56,7 @@ __all__ = ["PageAllocator", "PagedKVCache", "write_tokens",
            "gather_dense", "scatter_rows", "copy_page", "gather_pages",
            "install_page", "write_tokens_q", "scatter_rows_q",
            "copy_page_q", "gather_pages_q", "gather_dense_q",
-           "install_page_q"]
+           "install_page_q", "write_prompt", "install_prompt"]
 
 # chain-hash root: the "parent" of a prompt's first block
 _ROOT = b"\x00" * 16
@@ -332,6 +332,36 @@ def gather_dense_q(pool, scales, page_table, row):
     idx = jnp.maximum(page_table[row], 0)
     return dequantize_page(pool[idx], scales[idx][:, None, :]).reshape(
         -1, *pool.shape[2:])
+
+
+def write_prompt(pools, page_table, slot, limit, mini):
+    """Scatter every row of a B=1 dense mini cache into ``slot``'s
+    pages, all layers (pure: the body the paged engine's fused prefill
+    program ends with, and of :func:`install_prompt`). Row ``i`` of the
+    mini is position ``i``; the index arithmetic is
+    :func:`write_tokens`'s own, so rows on unmapped pages drop. Int8
+    pools (4-tuples) take :func:`write_tokens_q` with ``limit`` (the
+    prompt length): the pad tail drops instead of ratcheting the
+    headroom pages' scales. Float pools write it: it lands past the
+    prompt in the slot's own pages, where the decode mask hides it and
+    decode's writes overwrite it."""
+    width = mini[0][0].shape[1]
+    slots = jnp.full((width,), slot, jnp.int32)
+    pos = jnp.arange(width, dtype=jnp.int32)
+    out = []
+    for pool, (mk, mv) in zip(pools, mini):
+        if len(pool) == 4:
+            out.append(write_tokens_q(*pool, page_table, slots, pos,
+                                      mk[0], mv[0], limit=limit))
+        else:
+            out.append(write_tokens(*pool, page_table, slots, pos,
+                                    mk[0], mv[0]))
+    return out
+
+
+# one program for the whole layer list (chunked admissions, whose mini
+# outlives a program); the pools are DONATED as in write_tokens
+install_prompt = jax.jit(write_prompt, donate_argnums=(0,))
 
 
 class PageAllocator:

@@ -493,3 +493,186 @@ class TestChunkedPrefillSoak:
                 srv.shutdown(drain=False)
 
         assert outputs(prefill_chunk=16) == outputs(prefill_chunk=None)
+
+
+# one prompt a bucket of the tiny engines below: 16, 32 and 64
+FUSED_PLENS = (9, 20, 40)
+
+
+def _fused_paged(model, **kw):
+    kw.setdefault("debug_pages", True)
+    return PagedContinuousBatchingEngine(
+        model, max_batch=3, num_pages=32, page_size=8, max_pages=8, **kw)
+
+
+def _unfused_admit(eng, prompt, gc):
+    """A cold admission by its separate steps, as the engine ran it
+    before the fused program (and as a chunked admission still ends):
+    mini cache, prefill, claim, install, first token."""
+    import heapq
+
+    ids = np.asarray(prompt, np.int32)[None]
+    plen = ids.shape[1]
+    slot = heapq.heappop(eng._free)
+    eng._aidx_stash[slot] = 0
+    rid = eng._next_req
+    eng._next_req += 1
+    mini = eng._mini_cache(eng._prefill_width(plen))
+    logits, mini = eng._run_prefill(ids, plen, mini)
+    eng._reserve_admit(slot, plen, gc)
+    eng._install_mini(slot, mini, plen)
+    eng._first_token(slot, rid, ids, plen, logits, gc, 0, 0.0)
+    return rid, slot, logits
+
+
+def _drain(eng, steps=4):
+    while eng.decode_segment(steps):
+        pass
+    return {r: list(t) for r, t in eng.collect_finished().items()}
+
+
+class TestFusedColdAdmission:
+    """ISSUE 26: a cold one-shot admission of the paged engine is ONE
+    program per bucket (mini cache, prefill and page install), with the
+    pages claimed before it. Same values as the separate steps, as the
+    dense engine and as ``CausalLMEngine``."""
+
+    @pytest.mark.parametrize("plen", FUSED_PLENS)
+    def test_tokens_match_dense_and_reference(self, plen):
+        model, cfg = tiny_model()
+        p = np.random.RandomState(plen).randint(
+            0, cfg.vocab_size, (plen,)).astype(np.int32)
+        gc = GenerationConfig(max_new_tokens=6, eos_token_id=None)
+        want = list(CausalLMEngine(model, max_batch=1, max_len=64)
+                    .generate(p[None], gc)[0][plen:])
+        dense = _serve(ContinuousBatchingEngine(
+            model, max_batch=3, max_len=64), [p], gc)[0]
+        eng = _fused_paged(model)
+        assert eng._prefill_width(plen) == {9: 16, 20: 32, 40: 64}[plen]
+        got = _serve(eng, [p], gc)[0]
+        assert got == dense == want
+        eng.alloc.check()
+        assert eng.alloc.free_pages == eng.num_pages
+
+    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+    def test_pools_and_tokens_equal_the_separate_steps(self, kv_dtype):
+        """Fused against unfused on two engines fed the same prompts
+        in the same order: the same pages hold the same rows (int8:
+        the same scales too), bit for bit, and the tokens agree."""
+        model, cfg = tiny_model()
+        gc = GenerationConfig(max_new_tokens=5, eos_token_id=None)
+        rng = np.random.RandomState(11)
+        prompts = [rng.randint(0, cfg.vocab_size, (n,)).astype(np.int32)
+                   for n in FUSED_PLENS]
+        fused = _fused_paged(model, kv_dtype=kv_dtype)
+        plain = _fused_paged(model, kv_dtype=kv_dtype)
+        for p in prompts:
+            fused.add_request(p, gc)
+            _unfused_admit(plain, p, gc)
+        np.testing.assert_array_equal(fused.alloc.page_table,
+                                      plain.alloc.page_table)
+        ps = fused.page_size
+        for slot, p in enumerate(prompts):
+            # positions below the prompt length: the pad tail past it
+            # is not state (float pools hold it, int8 pools drop it)
+            pos = np.arange(len(p))
+            pages = fused.alloc.page_table[slot, pos // ps]
+            assert (pages >= 0).all()
+            for a, b in zip(fused.caches[0], plain.caches[0]):
+                for x, y in zip(a[:2], b[:2]):
+                    np.testing.assert_array_equal(
+                        _val(x)[pages, pos % ps], _val(y)[pages, pos % ps])
+                for x, y in zip(a[2:], b[2:]):      # int8: scale rows
+                    np.testing.assert_array_equal(_val(x)[pages],
+                                                  _val(y)[pages])
+        assert _drain(fused) == _drain(plain)
+        fused.alloc.check()
+
+    def test_lora_adapter_rides_the_fused_program(self):
+        from test_lora_serving import make_adapter
+
+        model, cfg = tiny_model(layers=1)
+        params = make_adapter(model, 7)
+        gc = GenerationConfig(max_new_tokens=6, eos_token_id=None,
+                              adapter="a")
+        base = GenerationConfig(max_new_tokens=6, eos_token_id=None)
+        p = np.arange(1, 21, dtype=np.int32)
+        kw = dict(lora_capacity=2, lora_rank=2, lora_targets=("q", "v"))
+        dense = ContinuousBatchingEngine(model, max_batch=3, max_len=64,
+                                         **kw)
+        eng = _fused_paged(model, **kw)
+        for e in (dense, eng):
+            e.load_adapter("a", params, alpha=4)
+        want, got = _serve(dense, [p], gc)[0], _serve(eng, [p], gc)[0]
+        assert got == want
+        # and the adapter did something: the base model answers otherwise
+        assert _serve(eng, [p], base)[0] != got
+        dense.close()
+        eng.close()
+
+    def test_admitted_while_others_decode(self):
+        model, cfg = tiny_model()
+        gc = GenerationConfig(max_new_tokens=10, eos_token_id=None)
+        rng = np.random.RandomState(4)
+        a, b = (rng.randint(0, cfg.vocab_size, (n,)).astype(np.int32)
+                for n in (12, 33))
+        want = [_serve(_fused_paged(model), [p], gc)[0] for p in (a, b)]
+        eng = _fused_paged(model)
+        ra = eng.add_request(a, gc)
+        assert eng.decode_segment(3)          # a is mid-decode
+        rb = eng.add_request(b, gc)           # its pools are donated on
+        done = _drain(eng)
+        assert [done[ra], done[rb]] == want
+        eng.alloc.check()
+
+    def test_one_admission_is_a_handful_of_programs(self, tmp_path):
+        """The count of device programs one cold admission executes on
+        a warmed engine does not grow with the layers: the prefill,
+        and what samples and installs the first token. A per-layer
+        loop (2 zeros + 2 slices + 1 write a layer before) would read
+        20 more here."""
+        import glob
+        import os
+
+        import jax
+        from jax.profiler import ProfileData
+
+        model, cfg = tiny_model(layers=4)
+        eng = _fused_paged(model)
+        eng.warmup(segment_steps=4)
+        gc = GenerationConfig(max_new_tokens=4, eos_token_id=None)
+        eng.add_request(np.arange(9, dtype=np.int32), gc)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            eng.add_request(np.arange(1, 11, dtype=np.int32), gc)
+        finally:
+            jax.profiler.stop_trace()
+        path = glob.glob(os.path.join(
+            str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))[0]
+        names = [ev.name for plane in ProfileData.from_file(path).planes
+                 if plane.name == "/host:CPU"
+                 for line in plane.lines for ev in line.events]
+        runs = names.count("PjRtCpuExecutable::Execute")
+        calls = {n[len("PjitFunction("):-1] for n in names
+                 if n.startswith("PjitFunction(")}
+        assert "prefill_one" in calls
+        assert not {"write_tokens", "write_prompt", "mini_cache",
+                    "broadcast_in_dim"} & calls, calls
+        assert 1 <= runs <= 10, (runs, calls)
+
+    def test_warmup_covers_every_bucket(self, mon):
+        """After ``warmup()`` a request a bucket adds no jit miss of any
+        entry point, float and int8 pools."""
+        model, cfg = tiny_model(layers=1)
+        gc = GenerationConfig(max_new_tokens=4, eos_token_id=None)
+        for kv_dtype in ("bf16", "int8"):
+            eng = _fused_paged(model, kv_dtype=kv_dtype)
+            eng.warmup(segment_steps=4)
+            before = monitor.jit_miss_by_fn()
+            assert before.get("cb_prefill", 0) >= len(eng.prefill_buckets)
+            for n in FUSED_PLENS:
+                _serve(eng, [np.arange(n, dtype=np.int32)], gc)
+            assert monitor.jit_miss_by_fn() == before
+            eng.close()

@@ -290,6 +290,73 @@ class TestRequestContainment:
             srv.shutdown(drain=False)
 
 
+class TestClaimBeforeDispatch:
+    """ISSUE 26: the paged engine's cold admission claims its pages
+    BEFORE its one program is dispatched (the program needs the slot's
+    page-table row), so both ways an admission can fail past the probe
+    must hand back slot and pages."""
+
+    def _spy(self, eng):
+        calls = []
+        real = eng._prefill_paged
+        eng._prefill_paged = lambda *a: (calls.append(1), real(*a))[1]
+        return calls
+
+    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+    def test_failed_claim_fails_before_any_device_work(self, kv_dtype):
+        model, _ = tiny_model()
+        eng = paged_engine(model, max_batch=2, num_pages=3,
+                           kv_dtype=kv_dtype)
+        calls = self._spy(eng)
+        eng._can_admit = lambda plen, cfg: True    # a caller past the probe
+        p = np.arange(1, 31, dtype=np.int32)       # 30 + 8 tokens: 5 pages
+        with pytest.raises(RuntimeError, match="exhausted"):
+            eng.add_request(p, _greedy(8))
+        assert calls == []
+        _assert_no_leaks(eng)
+        eng.alloc.check()
+        # the capacity is whole: a request that fits is served
+        del eng._can_admit
+        rid = eng.add_request(p[:9], _greedy(4))
+        assert calls == [1]
+        while eng.decode_segment(4):
+            pass
+        assert len(eng.collect_finished()[rid]) == 4
+        _assert_no_leaks(eng)
+        eng.alloc.check()
+
+    @pytest.mark.parametrize("prefix_cache", [False, True])
+    def test_prefill_fault_fires_after_the_claim(self, prefix_cache):
+        model, _ = tiny_model()
+        raw = paged_engine(model, prefix_cache=prefix_cache)
+        calls = self._spy(raw)
+        seen = []
+
+        def fault():
+            # at the seam the slot and its pages are already claimed
+            seen.append((raw.free_slots(), raw.alloc.free_pages))
+            return InjectedFault("injected fault @ prefill")
+
+        eng = FaultyEngine(raw, FaultPlan().raise_at("prefill", exc=fault))
+        p = np.arange(1, 12, dtype=np.int32)
+        with pytest.raises(InjectedFault):
+            eng.add_request(p, _greedy(6))
+        assert seen == [(raw.max_batch - 1, raw.num_pages - 3)]
+        assert calls == []                   # and nothing was dispatched
+        _assert_no_leaks(raw)
+        raw.alloc.check()
+        assert not raw._prefix_stash
+        want = _oracle(model, [p], [6])[0]
+        rid = eng.add_request(p, _greedy(6))         # the plan is spent
+        while eng.decode_segment(4):
+            pass
+        np.testing.assert_array_equal(eng.collect_finished()[rid], want)
+        # (a served prompt's full block parks in the prefix cache)
+        assert raw.free_slots() == raw.max_batch
+        assert raw.alloc.available_pages == raw.num_pages
+        raw.alloc.check()
+
+
 class TestEngineRecovery:
     def test_decode_fault_recovers_with_identical_tokens(self, mon):
         """An EngineFault mid-serving triggers ONE supervised restart;

@@ -325,8 +325,10 @@ def _chain(ev, spans):
     return out
 
 
-ENGINE_CHILDREN = ("engine.mini_cache", "engine.prefill", "engine.reserve",
-                   "engine.install", "engine.first_token")
+# a cold one-shot admission of the paged engine: the claim, then ONE
+# program (mini cache, prefill and install), then the first token
+ENGINE_CHILDREN = ("engine.reserve", "engine.prefill",
+                   "engine.first_token")
 
 
 class TestSpanTree:
@@ -521,7 +523,8 @@ class TestServingTree:
         assert tuple(e["phase"] for e in kids) == ENGINE_CHILDREN
         assert sum(e["dur_ns"] for e in kids) <= admit["dur_ns"]
         pre = kids[1]
-        assert (pre["plen"], pre["bucket"], pre["cached"]) == (6, 16, 0)
+        assert (pre["plen"], pre["bucket"], pre["cached"],
+                pre["fused"]) == (6, 16, 0, 1)
         # the instants of an admission hang under the iteration too
         deq = next(e for e in evs if e["phase"] == "queue.dequeue")
         assert _chain(deq, spans)[1:] == ["gap", "step"]
@@ -596,8 +599,9 @@ class TestServingTree:
         spans = _by_id(evs)
         pres = sorted((e for e in evs if e["phase"] == "engine.prefill"),
                       key=lambda e: e["ts_ns"])
-        assert [(e["plen"], e["bucket"], e["cached"]) for e in pres] == [
-            (8, 8, 0), (16, 8, 8), (20, 8, 16)]
+        assert [(e["plen"], e["bucket"], e["cached"], e["fused"])
+                for e in pres] == [
+            (8, 8, 0, 0), (16, 8, 8, 0), (20, 8, 16, 0)]
         assert all(_chain(e, spans)[:4] == [
             "engine.prefill", "prefill_chunk", "gap", "step"]
             for e in pres)
@@ -623,8 +627,17 @@ class TestServingTree:
             eng.close()
         cold, warm = [e for e in trace.events()
                       if e["phase"] == "engine.prefill"]
-        assert (cold["plen"], cold["bucket"], cold["cached"]) == (20, 32, 0)
+        assert (cold["plen"], cold["bucket"], cold["cached"],
+                cold["fused"]) == (20, 32, 0, 1)
         assert warm["plen"] == 20 and warm["cached"] > 0
+        assert warm["fused"] == 0
+        # the warm hit keeps its separate mini cache and install
+        phases = [e["phase"] for e in trace.events()
+                  if e["phase"].startswith("engine.")]
+        assert phases == [
+            "engine.reserve", "engine.prefill", "engine.first_token",
+            "engine.mini_cache", "engine.prefill", "engine.reserve",
+            "engine.install", "engine.first_token"]
         assert isinstance(warm["bucket"], int)
         assert warm["plen"] - warm["cached"] <= warm["bucket"]
 
