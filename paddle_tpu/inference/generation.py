@@ -1073,16 +1073,20 @@ class ContinuousBatchingEngine:
             kw["tp"] = self._tp
         return kw
 
-    def _fwd_prefill(self, params, ids, caches, pos=0, lora=None):
+    def _fwd_prefill(self, params, ids, caches, pos=0, lora=None, **kw):
         from ..core.autograd import no_grad
 
         with substituted_state(self.model, params), no_grad():
             logits, caches = self.model.forward_with_cache(
-                Tensor(ids), caches, pos, **self._fwd_kwargs(lora))
+                Tensor(ids), caches, pos, **self._fwd_kwargs(lora), **kw)
         return (logits.value if isinstance(logits, Tensor) else logits,
                 caches)
 
     def _fwd_ragged(self, params, tok, caches, lens, live, lora=None):
+        """One decode step: ``(logits, caches, aux)``. ``aux`` is a dict
+        of int32 counters the model's step hands out (the paged engine's,
+        for a model that routes experts) or None; the segment program
+        sums them over its steps and returns them beside its tokens."""
         from ..core.autograd import no_grad
 
         with substituted_state(self.model, params), no_grad():
@@ -1090,7 +1094,7 @@ class ContinuousBatchingEngine:
                 Tensor(tok), caches, lens, live,
                 **self._fwd_kwargs(lora))
         return (logits.value if isinstance(logits, Tensor) else logits,
-                caches)
+                caches, None)
 
     # -- admission / retirement (host-side, between segments) ---------------
     def _can_admit(self, prompt_len: int, cfg) -> bool:
@@ -1720,7 +1724,7 @@ class ContinuousBatchingEngine:
             # (live rows mask to nothing), so running it only compiles
             t0 = time.perf_counter()
             key = jax.random.PRNGKey(0)
-            (_, self.last, self.lens, self.done_dev, self.caches) = \
+            (_, self.last, self.lens, self.done_dev, self.caches, _) = \
                 self._segment_fn(segment_steps)(
                     self.params, self.last, self.lens, self.done_dev,
                     self.active_dev, self.samp, self._bank(),
@@ -1807,7 +1811,7 @@ class ContinuousBatchingEngine:
                 def step(carry, _):
                     last, lens, done, caches, key = carry
                     live = active & ~done & (lens < max_len)
-                    logits, caches = self._fwd_ragged(
+                    logits, caches, aux = self._fwd_ragged(
                         params, last[:, None], caches, lens, live,
                         lora)
                     key, sub = jax.random.split(key)
@@ -1817,13 +1821,15 @@ class ContinuousBatchingEngine:
                     done = done | (live & (samp["eos"] >= 0)
                                    & (nxt == samp["eos"]))
                     done = done | (lens >= max_len)
-                    return (nxt, lens, done, caches, key), nxt
+                    return (nxt, lens, done, caches, key), (nxt, aux)
 
-                (last, lens, done, caches, _), toks = jax.lax.scan(
+                (last, lens, done, caches, _), (toks, aux) = jax.lax.scan(
                     step, (last, lens, done, caches, key), None,
                     length=n_steps)
+                if aux is not None:
+                    aux = {k: v.sum(axis=0) for k, v in aux.items()}
                 return (jnp.swapaxes(toks, 0, 1), last, lens, done,
-                        caches)
+                        caches, aux)
 
             self._segment_cache[n_steps] = monitor.monitored_jit(
                 segment, name="cb_segment",
@@ -2434,7 +2440,7 @@ class ContinuousBatchingEngine:
         key = jax.random.fold_in(
             jax.random.PRNGKey(cfg.seed if cfg is not None else 0),
             self._segments_run)
-        toks, self.last, self.lens, self.done_dev, self.caches = \
+        toks, self.last, self.lens, self.done_dev, self.caches, aux = \
             self._segment_fn(n_steps)(
                 self.params, self.last, self.lens, self.done_dev,
                 self.active_dev, self.samp, self._bank(), self.caches,
@@ -2442,6 +2448,10 @@ class ContinuousBatchingEngine:
         # lint: allow-host-sync(collection itself: ONE readback per
         # n_steps-step segment — tokens must reach handles/streams)
         toks = np.asarray(toks)
+        if aux is not None and trace.enabled():
+            # the step's own counters (routing), out of the same program
+            # lint: allow-host-sync(a few scalars beside the tokens)
+            sp.set(**{k: int(v) for k, v in aux.items()})
         # lint: allow-host-sync(same once-per-segment collection pull)
         done = np.asarray(self.done_dev)
         emitted = 0
@@ -2752,10 +2762,36 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self._gap_sync = None
         self.num_pages = num_pages
         self.page_size = page_size
-        self.alloc = PageAllocator(num_pages, page_size, max_batch,
-                                   max_pages, debug=debug_pages,
-                                   prefix_cache=prefix_cache,
-                                   kv_dtype=kv_dtype)
+        # a model whose layers keep their KV in TWO geometries (full and
+        # sliding-window attention side by side) says so; None = one
+        # table serves every layer
+        layout = getattr(model, "paged_layout", None)
+        self._layout = layout(page_size) if layout is not None else None
+        if self._layout is not None:
+            refused = {"tp_degree": tp_degree != 1,
+                       "kv_dtype='int8'": kv_dtype != "bf16",
+                       "draft_k (speculative decoding)": draft_k != 0,
+                       "prefix_cache": prefix_cache,
+                       "prefill_chunk": prefill_chunk is not None,
+                       "lora_capacity (LoRA)": lora_capacity != 0}
+            for feature, asked in refused.items():
+                if asked:
+                    raise ValueError(
+                        f"{feature} is not implemented for a model with "
+                        f"sliding-window layers or routed experts "
+                        f"({type(model).__name__}): its window layers "
+                        f"keep a ring of pages that this feature's "
+                        f"programs do not read or write")
+            from .paged_cache import WindowedPageAllocator
+
+            self.alloc = WindowedPageAllocator(
+                num_pages, page_size, max_batch, max_pages,
+                self._layout["ring_pages"], debug=debug_pages)
+        else:
+            self.alloc = PageAllocator(num_pages, page_size, max_batch,
+                                       max_pages, debug=debug_pages,
+                                       prefix_cache=prefix_cache,
+                                       kv_dtype=kv_dtype)
         super().__init__(model, max_batch,
                          max_len=max_pages * page_size,
                          prefill_buckets=prefill_buckets,
@@ -2798,6 +2834,15 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             from .paged_cache import write_prompt
 
             mini = self._tp_kv(self.model.init_cache(1, ids.shape[1]))
+            if self._layout is not None:
+                # the model is told the last position: it computes that
+                # position's logits alone and routes no padding, and its
+                # window layers' rows go into their rings
+                logits, mini = self._fwd_prefill(params, ids, mini,
+                                                 last_idx=plen - 1)
+                return (logits[:, 0], write_prompt(
+                    pools, page_table, slot, plen, mini,
+                    window_layers=self._layout["window_layers"]))
             logits, mini = self._fwd_prefill(
                 params, ids, mini, lora=_lora_rows(bank, aidx, ids))
             return (logits[:, plen - 1],
@@ -2823,11 +2868,22 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                     f"kv_dtype='int8' needs a model whose "
                     f"init_paged_cache accepts kv_dtype (llama does); "
                     f"{type(self.model).__name__} does not") from e
-            return (self._tp_kv(pools),
-                    self._tp_rep(jnp.asarray(self.alloc.page_table)))
+            return self._tp_kv(pools), self._device_tables()
+        if self._layout is not None:
+            return (self.model.init_paged_cache(
+                        self.num_pages, self.page_size,
+                        window_pages=self.alloc.window.num_pages),
+                    self._device_tables())
         return (self._tp_kv(self.model.init_paged_cache(
                     self.num_pages, self.page_size)),
-                self._tp_rep(jnp.asarray(self.alloc.page_table)))
+                self._device_tables())
+
+    def _device_tables(self):
+        """The host page table(s) as the device programs take them: one
+        array, or ``(full, ring)`` for a model with window layers."""
+        if self._layout is not None:
+            return tuple(jnp.asarray(t) for t in self.alloc.tables())
+        return self._tp_rep(jnp.asarray(self.alloc.page_table))
 
     def _measure_quant_savings(self) -> None:
         """Price the int8 layout from the REAL pool arrays: HBM bytes
@@ -3047,11 +3103,26 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
 
         pools, pt = caches
         with substituted_state(self.model, params), no_grad():
-            logits, pools = self.model.forward_decode_paged(
+            # a model that routes experts returns its routing counts too
+            logits, pools, *aux = self.model.forward_decode_paged(
                 Tensor(tok), pools, pt, lens, live,
                 **self._fwd_kwargs(lora))
         return (logits.value if isinstance(logits, Tensor) else logits,
-                (pools, pt))
+                (pools, pt), aux[0] if aux else None)
+
+    def _decode_segment_plain(self, n_steps: int, cfg, sp):
+        if self._layout is not None and trace.enabled():
+            # what the two geometries hold at the segment's start, and
+            # the tokens a window layer's attention reads (a full
+            # layer's: ctx_tokens)
+            w = self._layout["window"]
+            held = [self.alloc.held_pages(slot) for slot in self._slot_req]
+            sp.set(ctx_tokens_window=sum(
+                       min(self._plen[rid] + len(self._tokens[rid]), w)
+                       for rid in self._slot_req.values()),
+                   pages_full=sum(h[0] for h in held),
+                   pages_window=sum(h[1] for h in held))
+        return super()._decode_segment_plain(n_steps, cfg, sp)
 
     def _fwd_spec(self, params, inp, caches, lens, live, lora=None):
         from ..core.autograd import no_grad
@@ -3156,14 +3227,19 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         # int8: the claimed pages' scale rows reset BEFORE the program
         # runs its running absmax against them
         self._flush_fresh_scales()
-        with self._prefill_span(plen, width, fused=1):
+        with self._prefill_span(plen, width, fused=1) as sp:
+            if self._layout is not None and trace.enabled():
+                # rows of the prompt that go into the window layers' rings
+                ps, ring = self.page_size, self._layout["ring_pages"]
+                first_page = max((plen - 1) // ps - ring + 1, 0)
+                sp.set(window_rows=plen - first_page * ps)
             last_logits = self._prefill_install(
                 slot, _pad_ids(ids, width), plen, aidx)
         self._index_prompt(slot, plen)
         return last_logits
 
     def _prefill_install(self, slot: int, ids, plen: int, aidx: int):
-        pt = self._tp_rep(jnp.asarray(self.alloc.page_table))
+        pt = self._device_tables()
         pools, _ = self.caches
         # numpy scalars ride as arguments: a jnp.int32() is a device
         # program of its own
@@ -3313,7 +3389,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             # on reserved-but-unwritten positions the decode mask
             # hides and decode writes overwrite, or drop (unmapped
             # pages; int8: everything past plen)
-            pt = self._tp_rep(jnp.asarray(self.alloc.page_table))
+            pt = self._device_tables()
             pools, _ = self.caches
             self.caches = (install_prompt(pools, pt, np.int32(slot),
                                           np.int32(plen), mini), pt)
@@ -3354,7 +3430,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         p0 = c_map if c_map < plen else plen
         if p0 % ps and self.alloc.needs_cow(slot, p0):
             self._cow_page(slot, p0 // ps)
-        pt = self._tp_rep(jnp.asarray(self.alloc.page_table))
+        pt = self._device_tables()
         if c_map < plen:
             mini_len = mini[0][0].shape[1]
             width = (plen - c_map if self.prefill_buckets is None
@@ -3456,7 +3532,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                                            jnp.int32(0)))
         self.caches = (new_pools, pt)
         out["prefix_gather_copy"] = time.perf_counter() - t0
-        pt_dev = self._tp_rep(jnp.asarray(self.alloc.page_table))
+        pt_dev = self._device_tables()
         for w in (self.prefill_buckets or ()):
             t0 = time.perf_counter()
             _, mini = self._prefill_chunk(
@@ -3665,6 +3741,5 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                     slot, int(lens[slot]),
                     write_ahead=1 + self._spec_k_of(rid))
         pools, _ = self.caches
-        self.caches = (pools,
-                       self._tp_rep(jnp.asarray(self.alloc.page_table)))
+        self.caches = (pools, self._device_tables())
         return super().decode_segment(n_steps, cfg)

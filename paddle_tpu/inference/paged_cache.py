@@ -56,7 +56,8 @@ __all__ = ["PageAllocator", "PagedKVCache", "write_tokens",
            "gather_dense", "scatter_rows", "copy_page", "gather_pages",
            "install_page", "write_tokens_q", "scatter_rows_q",
            "copy_page_q", "gather_pages_q", "gather_dense_q",
-           "install_page_q", "write_prompt", "install_prompt"]
+           "install_page_q", "write_prompt", "install_prompt",
+           "WindowedPageAllocator", "write_ring_tokens"]
 
 # chain-hash root: the "parent" of a prompt's first block
 _ROOT = b"\x00" * 16
@@ -334,7 +335,30 @@ def gather_dense_q(pool, scales, page_table, row):
         -1, *pool.shape[2:])
 
 
-def write_prompt(pools, page_table, slot, limit, mini):
+def write_ring_tokens(k_pool, v_pool, ring_table, slots, positions, limit,
+                      k_new, v_new):
+    """:func:`write_tokens` for a WINDOW layer, whose table row is a ring
+    of ``ring_table.shape[1]`` slots: position p lives at slot
+    ``(p // page_size) % ring``. Of a prompt of ``limit`` tokens only the
+    pages the ring can hold are written, the last ``ring`` of them: an
+    earlier position would land on a later one's slot (and is out of
+    every window to come). Positions at or past ``limit`` (a bucket's
+    padding) DROP as well: in a ring they would overwrite live rows,
+    where a full layer's land on headroom that decode rewrites."""
+    ps, ring = k_pool.shape[1], ring_table.shape[1]
+    pidx = positions // ps
+    keep = (positions < limit) & (pidx > (limit - 1) // ps - ring)
+    pages = ring_table[slots, pidx % ring]
+    pages = jnp.where(keep & (pages >= 0), pages, k_pool.shape[0])
+    offs = positions % ps
+    k_pool = k_pool.at[pages, offs].set(k_new.astype(k_pool.dtype),
+                                        mode="drop")
+    v_pool = v_pool.at[pages, offs].set(v_new.astype(v_pool.dtype),
+                                        mode="drop")
+    return k_pool, v_pool
+
+
+def write_prompt(pools, page_table, slot, limit, mini, window_layers=None):
     """Scatter every row of a B=1 dense mini cache into ``slot``'s
     pages, all layers (pure: the body the paged engine's fused prefill
     program ends with, and of :func:`install_prompt`). Row ``i`` of the
@@ -344,11 +368,23 @@ def write_prompt(pools, page_table, slot, limit, mini):
     prompt length): the pad tail drops instead of ratcheting the
     headroom pages' scales. Float pools write it: it lands past the
     prompt in the slot's own pages, where the decode mask hides it and
-    decode's writes overwrite it."""
+    decode's writes overwrite it.
+
+    ``window_layers`` (one bool a layer; static): ``page_table`` is then
+    the pair ``(full, ring)`` of a :class:`WindowedPageAllocator`, and a
+    layer marked True goes into its ring (:func:`write_ring_tokens`)."""
     width = mini[0][0].shape[1]
     slots = jnp.full((width,), slot, jnp.int32)
     pos = jnp.arange(width, dtype=jnp.int32)
     out = []
+    if window_layers is not None:
+        full, ring = page_table
+        for pool, (mk, mv), win in zip(pools, mini, window_layers):
+            out.append(write_ring_tokens(*pool, ring, slots, pos, limit,
+                                         mk[0], mv[0]) if win
+                       else write_tokens(*pool, full, slots, pos,
+                                         mk[0], mv[0]))
+        return out
     for pool, (mk, mv) in zip(pools, mini):
         if len(pool) == 4:
             out.append(write_tokens_q(*pool, page_table, slots, pos,
@@ -1285,6 +1321,67 @@ class PageAllocator:
             self.close()
         except Exception:
             pass
+
+
+class WindowedPageAllocator(PageAllocator):
+    """Two cache geometries in one manager, for a model whose layers are
+    part full attention and part sliding window.
+
+    This allocator IS the full layers' (every position of a row holds a
+    page, as in :class:`PageAllocator`; the capacity, occupancy and
+    pressure that admission and the serving surface read are the full
+    layers', the pool that runs out first). ``self.window`` is a second
+    allocator, over the window layers' own (smaller) pools: a row's table
+    there is a RING of ``ring_pages`` slots, position p at slot
+    ``(p // page_size) % ring_pages``, so a row never holds more than
+    ``ring_pages`` pages in a window layer and holds none for positions
+    that have left the window: their slot is the one being rewritten.
+    The window pool is sized for every slot's whole ring
+    (``max_batch * ring_pages``: a window layer's worst case is small),
+    so it never refuses what the full pool grants. Claims and releases
+    go to both; no prefix sharing, no int8 scales (the engine refuses
+    both with such a model)."""
+
+    def __init__(self, num_pages: int, page_size: int, max_batch: int,
+                 max_pages: int, ring_pages: int, debug: bool = False):
+        super().__init__(num_pages, page_size, max_batch, max_pages,
+                         debug=debug)
+        self.ring_pages = min(ring_pages, max_pages)
+        self.window = PageAllocator(max_batch * self.ring_pages, page_size,
+                                    max_batch, self.ring_pages, debug=debug)
+
+    def _ring_tokens(self, n_tokens: int) -> int:
+        return min(n_tokens, self.ring_pages * self.page_size)
+
+    def tables(self):
+        """The host tables ``(full, ring)``, as the device programs take
+        them."""
+        return self.page_table, self.window.page_table
+
+    def held_pages(self, slot: int):
+        """Pages ``slot`` holds in (a full layer, a window layer)."""
+        return (len(self._owned.get(slot, ())),
+                len(self.window._owned.get(slot, ())))
+
+    def can_fit(self, slot: int, n_tokens: int) -> bool:
+        return (super().can_fit(slot, n_tokens)
+                and self.window.can_fit(slot, self._ring_tokens(n_tokens)))
+
+    def ensure(self, slot: int, n_tokens: int) -> None:
+        super().ensure(slot, n_tokens)
+        self.window.ensure(slot, self._ring_tokens(n_tokens))
+
+    def free_slot(self, slot: int) -> None:
+        super().free_slot(slot)
+        self.window.free_slot(slot)
+
+    def check(self) -> None:
+        super().check()
+        self.window.check()
+
+    def close(self) -> None:
+        super().close()
+        self.window.close()
 
 
 class PagedKVCache(PageAllocator):

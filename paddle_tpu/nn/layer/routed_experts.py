@@ -1,0 +1,159 @@
+"""Routed experts: a token-choice sparse FFN with no capacity and no
+dropped token, every shape static.
+
+Each token scores every expert (sigmoid of a float32 product), the
+``top_k`` largest of ``score + bias`` are chosen (the bias steers the
+choice only), their scores are renormalised and scaled, and the token's
+output is the weighted sum of the chosen experts' SwiGLU. The products
+run as three grouped matrix products over the (token, choice) rows sorted
+by expert (:func:`paddle_tpu.ops.pallas.grouped_matmul`), so an expert's
+weights are read once for all its rows and an expert no row chose is not
+read at all. Reference analog: the reference's capacity-dispatch MoE
+(incubate/distributed/models/moe) pads every expert to a capacity and
+drops what overflows; this layer is the serving form, exact for any skew.
+"""
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+
+from ...core.autograd import apply_op
+from ..initializer import Constant, Initializer, XavierUniform
+from .layers import Layer
+
+__all__ = ["RoutedExperts", "route_top_k", "routed_experts_ffn"]
+
+# How far an expert's initial weights lie from the other experts', as a
+# share of their norm (see :class:`_Upcycled`).
+EXPERT_SPREAD = 0.01
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "dtype", "limit"))
+def _upcycled_uniform(key, shape, dtype, limit):
+    base_key, own_key = jax.random.split(key)
+    base = jax.random.uniform(base_key, shape[1:], jnp.float32, -limit, limit)
+    own = jax.random.uniform(own_key, shape, jnp.float32, -limit, limit)
+    return (math.sqrt(1.0 - EXPERT_SPREAD ** 2) * base
+            + EXPERT_SPREAD * own).astype(dtype)
+
+
+class _Upcycled(Initializer):
+    """Stacked expert weights [E, fan_in, fan_out] as sparse upcycling
+    leaves them (Komatsuzaki et al. 2022: every expert starts as a copy of
+    one dense FFN) plus a part of each expert's own:
+    ``sqrt(1 - EXPERT_SPREAD^2)`` x one Xavier-uniform draw shared by all
+    experts + ``EXPERT_SPREAD`` x a draw for each, so an element keeps the
+    Xavier variance.
+
+    Why not a draw for each expert alone: between the k-th and the
+    (k+1)-th of many router scores lies less than a bf16 activation
+    resolves for a few tokens in a hundred, whatever the router's scale,
+    so bf16 inference and a float32 reference now and then choose another
+    last expert. With independent random experts that one choice replaces
+    an eighth of the routed sum by something unrelated, a far larger error
+    than lower precision or a wrong mask makes, and a comparison of logits
+    can then tell neither from a sound run. Experts ``EXPERT_SPREAD``
+    apart turn the near-tie into an error of that size, and leave a
+    dropped term, a wrong weight or fp8 arithmetic as visible as they
+    are."""
+
+    def _generate(self, key, shape, dtype):
+        limit = math.sqrt(6.0 / (shape[1] + shape[2]))
+        return _upcycled_uniform(key, shape, jnp.dtype(dtype), limit)
+
+
+def route_top_k(x, router, bias, top_k: int, route_scale: float = 1.0,
+                route_norm: bool = True):
+    """x [T, h] -> (experts [T, k] int32, weights [T, k] float32).
+
+    The product and the scores are float32 at the highest matmul
+    precision whatever ``x``'s dtype: near-ties between the k-th and the
+    (k+1)-th score decide which expert runs, and bf16 cannot tell them
+    apart."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, sel = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, sel, axis=-1)
+    if route_norm:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return sel.astype(jnp.int32), w * route_scale
+
+
+def routed_experts_ffn(x, sel, w, gate, up, down, valid=None):
+    """sum_k w[t, k] * expert_{sel[t, k]}(x[t]) for x [T, h].
+
+    gate/up [E, h, m], down [E, m, h]. ``valid`` [T] bool: rows that are
+    padding or dead take no expert (their output is 0, and they make no
+    expert's weights be read). Returns (out [T, h] in x's dtype, stats)
+    with ``stats`` = {"experts_hit": experts with at least one row,
+    "expert_rows_max": rows of the busiest expert}, int32 scalars."""
+    from ...ops.pallas import grouped_matmul
+
+    t, k = sel.shape
+    n_exp = gate.shape[0]
+    ids = sel.reshape(-1)
+    if valid is not None:
+        # past every group: sorted last, visited by no product
+        ids = jnp.where(jnp.repeat(valid, k), ids, n_exp)
+    order = jnp.argsort(ids)                      # stable: by expert
+    sizes = jnp.zeros((n_exp + 1,), jnp.int32).at[ids].add(1)[:n_exp]
+    xs = jnp.take(x, order // k, axis=0)          # [T*k, h]
+    g = grouped_matmul(xs, gate, sizes, preferred_element_type=x.dtype)
+    u = grouped_matmul(xs, up, sizes, preferred_element_type=x.dtype)
+    mid = (jax.nn.silu(g.astype(jnp.float32))
+           * u.astype(jnp.float32)).astype(x.dtype)
+    ys = grouped_matmul(mid, down, sizes,
+                        preferred_element_type=jnp.float32)
+    in_group = jnp.arange(t * k) < jnp.sum(sizes)
+    ys = jnp.where(in_group[:, None], ys, 0.0) \
+        * jnp.take(w.reshape(-1), order)[:, None]
+    back = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))       # inverse permutation
+    out = jnp.take(ys, back, axis=0).reshape(t, k, -1).sum(axis=1)
+    stats = {"experts_hit": jnp.sum((sizes > 0).astype(jnp.int32)),
+             "expert_rows_max": jnp.max(sizes)}
+    return out.astype(x.dtype), stats
+
+
+class RoutedExperts(Layer):
+    """Router + stacked SwiGLU experts. ``forward(x, valid=None)`` takes
+    x [..., h] and returns (out, stats) — see :func:`routed_experts_ffn`.
+    ``expert_bias`` is a float32 parameter, zero at initialisation, that
+    enters the choice of experts only."""
+
+    def __init__(self, hidden_size: int, expert_width: int,
+                 num_experts: int, top_k: int, route_scale: float = 1.0,
+                 route_norm: bool = True):
+        super().__init__()
+        self.top_k = top_k
+        self.route_scale = route_scale
+        self.route_norm = route_norm
+        h, m, e = hidden_size, expert_width, num_experts
+        self.router = self.create_parameter(
+            [h, e], default_initializer=XavierUniform(h, e))
+        self.expert_bias = self.create_parameter(
+            [e], dtype="float32", default_initializer=Constant(0.0))
+        self.gate_proj = self.create_parameter(
+            [e, h, m], default_initializer=_Upcycled())
+        self.up_proj = self.create_parameter(
+            [e, h, m], default_initializer=_Upcycled())
+        self.down_proj = self.create_parameter(
+            [e, m, h], default_initializer=_Upcycled())
+
+    def forward(self, x, valid=None):
+        def f(xv, router, bias, gate, up, down):
+            flat = xv.reshape(-1, xv.shape[-1])
+            sel, w = route_top_k(flat, router, bias, self.top_k,
+                                 self.route_scale, self.route_norm)
+            out, stats = routed_experts_ffn(
+                flat, sel, w, gate, up, down,
+                valid=None if valid is None else valid.reshape(-1))
+            return out.reshape(xv.shape), stats
+
+        return apply_op(f, x, self.router, self.expert_bias,
+                        self.gate_proj, self.up_proj, self.down_proj,
+                        op_name="routed_experts")

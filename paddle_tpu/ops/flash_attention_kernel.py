@@ -181,19 +181,24 @@ def _keep_mask(seed, b, h, q0, k0, bq, bk, dropout_p):
     return bits >= thresh  # P(keep) = 1 - dropout_p
 
 
-def _causal_valid(iq, ik, block_q, block_k, offset):
+def _causal_valid(iq, ik, block_q, block_k, offset, window=None):
     """Bottom-right-aligned validity for the (iq, ik) score block: query i
-    attends keys <= i + offset. Shared by fwd and both bwd kernels so the
-    alignment convention can never diverge between them."""
+    attends keys <= i + offset, and with a ``window`` only the last
+    ``window`` of them (keys > i + offset - window). Shared by fwd and
+    both bwd kernels so the alignment convention can never diverge
+    between them."""
     qpos = (iq * block_q
             + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0))
     kpos = (ik * block_k
             + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1))
-    return kpos <= qpos + offset
+    valid = kpos <= qpos + offset
+    if window is not None:
+        valid = valid & (kpos > qpos + offset - window)
+    return valid
 
 
 def _apply_causal_mask(s, causal, iq, ik, block_q, block_k, offset,
-                       lead_batch: bool = False):
+                       lead_batch: bool = False, window=None):
     """Causal masking for a score block (``s`` is (bq, bk), or (H, bq, bk)
     with ``lead_batch``), SPECIALIZED to diagonal blocks: blocks entirely
     below the causal boundary skip the iota/compare/select passes (at
@@ -209,7 +214,7 @@ def _apply_causal_mask(s, causal, iq, ik, block_q, block_k, offset,
         return s, None
 
     def mask(x):
-        v = _causal_valid(iq, ik, block_q, block_k, offset)
+        v = _causal_valid(iq, ik, block_q, block_k, offset, window)
         return jnp.where(v[None] if lead_batch else v, x, _NEG_INF), v
 
     if offset < 0:
@@ -218,16 +223,23 @@ def _apply_causal_mask(s, causal, iq, ik, block_q, block_k, offset,
     # does this block contain ANY masked entry? (bottom-right alignment:
     # the block's last key position vs its first query's boundary)
     is_diag = (ik * block_k + block_k - 1) > (iq * block_q + offset)
+    if window is not None:
+        # ... or the window's lower edge: the block's first key is at or
+        # under the lowest key its LAST query still sees
+        is_diag = is_diag | (ik * block_k <= iq * block_q + block_q - 1
+                             + offset - window)
     s = jax.lax.cond(is_diag, lambda x: mask(x)[0], lambda x: x, s)
     return s, None
 
 
-def _block_scores(q, k, sm_scale, causal, iq, ik, block_q, block_k, offset):
+def _block_scores(q, k, sm_scale, causal, iq, ik, block_q, block_k, offset,
+                  window=None):
     """Masked fp32 score block for the per-head kernel."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * sm_scale
-    return _apply_causal_mask(s, causal, iq, ik, block_q, block_k, offset)
+    return _apply_causal_mask(s, causal, iq, ik, block_q, block_k, offset,
+                              window=window)
 
 
 def _dropped(p, seed, b, h, iq, ik, block_q, block_k, dropout_p):
@@ -245,7 +257,7 @@ def _dropped(p, seed, b, h, iq, ik, block_q, block_k, dropout_p):
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref, *, sm_scale, causal, dropout_p,
-                offset, block_q, block_k, dpad):
+                offset, block_q, block_k, dpad, window=None):
     b, h, iq, ik = (pl.program_id(i) for i in range(4))
     nk = pl.num_programs(3)
 
@@ -260,7 +272,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         k = _pad_d(k_ref[0, 0], dpad)        # (bk, Dp)
         v = _pad_d(v_ref[0, 0], dpad)
         s, valid = _block_scores(q, k, sm_scale, causal, iq, ik,
-                                 block_q, block_k, offset)
+                                 block_q, block_k, offset, window)
         # single-column running stats: alpha's exp runs on (bq, 1), not the
         # (bq, 128) replicated buffer — transcendentals are the VPU cost
         m_prev = m_ref[:, 0:1]               # (bq, 1)
@@ -292,6 +304,11 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     if causal:
         needed = ik * block_k <= iq * block_q + block_q - 1 + offset
+        if window is not None:
+            # a block wholly under the window of its FIRST query (the
+            # lowest any of its queries sees) is skipped, not masked
+            needed = needed & (ik * block_k + block_k - 1
+                               > iq * block_q + offset - window)
         pl.when(needed)(_compute)
     else:
         _compute()
@@ -306,8 +323,16 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, 0] = m_ref[:, 0:1] + jnp.log(l_safe)  # (bq, 1)
 
 
+def _window_blocks(i, block_q, block_k, offset, window, nk):
+    """First and last key block that query block ``i`` of a causal window
+    needs (``_fwd_kernel``'s ``needed``, solved for ``ik``)."""
+    lo = jnp.maximum((i * block_q + offset - window + 1) // block_k, 0)
+    hi = jnp.minimum((i * block_q + block_q - 1 + offset) // block_k, nk - 1)
+    return lo, hi
+
+
 def _fwd_impl(q, k, v, seed, causal, sm_scale, dropout_p, block_q, block_k,
-              interpret):
+              interpret, window=None):
     in_dtype = q.dtype
     d_orig = q.shape[-1]
     mode, dp = _sublane_plan(d_orig, in_dtype, interpret)
@@ -323,20 +348,27 @@ def _fwd_impl(q, k, v, seed, causal, sm_scale, dropout_p, block_q, block_k,
     nq, nk = sq // bq, sk // bk
     offset = sk - sq
     dpad = dp if mode == "kpad" else d
+    def kv_map(b, h, i, j, g=group):
+        if window is None:
+            return (b, h // g, j, 0)
+        # a skipped step aims at the nearest block the row needs: the
+        # same block as its neighbour's, so no copy is issued for it
+        lo, hi = _window_blocks(i, bq, bk, offset, window, nk)
+        return (b, h // g, jnp.clip(j, lo, hi), 0)
+
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                           dropout_p=dropout_p, offset=offset,
-                          block_q=bq, block_k=bk, dpad=dpad),
+                          block_q=bq, block_k=bk, dpad=dpad,
+                          window=window),
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((bsz, hq, sq, 1), jnp.float32)],
         grid=(bsz, hq, nq, nk),
         in_specs=[
             _SMEM_SPEC,
             pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b, h, i, j, g=group: (b, h // g, j, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b, h, i, j, g=group: (b, h // g, j, 0)),
+            pl.BlockSpec((1, 1, bk, d), kv_map),
+            pl.BlockSpec((1, 1, bk, d), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
@@ -582,10 +614,16 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = False,
                          dropout_p: float = 0.0, seed=None,
                          block_q: Optional[int] = None,
                          block_k: Optional[int] = None,
-                         interpret: Optional[bool] = None):
+                         interpret: Optional[bool] = None,
+                         window: Optional[int] = None):
     """Flash attention over ``[B, H, S, D]`` tensors (GQA allowed: K/V may
     have ``Hq / G`` heads). Differentiable; bwd recomputes attention from
     the saved ``[B, H, S]`` fp32 log-sum-exp.
+
+    ``window`` (static, causal only): query i sees keys in
+    ``(i - window, i]`` (bottom-right aligned like the causal edge); key
+    blocks wholly outside a query block's windows are neither computed
+    nor copied. Forward only: the serving prefill's path.
 
     ``dropout_p`` applies attention-probability dropout inside the kernel,
     seeded by ``seed`` (int32 scalar/array); the same mask is regenerated in
@@ -621,5 +659,10 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = False,
             or {}
         block_q = block_q or tuned.get("block_q", 1024)
         block_k = block_k or tuned.get("block_k", 1024)
+    if window is not None:
+        if not causal or dropout_p:
+            raise ValueError("window needs causal=True and no dropout")
+        return _fwd_impl(q, k, v, seed, True, float(sm_scale), 0.0,
+                         block_q, block_k, it, window=int(window))[0]
     return _flash(q, k, v, seed, causal, float(sm_scale), float(dropout_p),
                   block_q, block_k, it)

@@ -57,7 +57,7 @@ def _interpret() -> bool:
 
 def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                          acc_ref, m_ref, l_ref, *, scale, page_size,
-                         ks_ref=None, vs_ref=None):
+                         ks_ref=None, vs_ref=None, window=None):
     """One (batch row, page) step of the online-softmax recurrence.
 
     ``pt_ref``/``len_ref`` are scalar-prefetched; the K/V blocks arriving
@@ -76,10 +76,15 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     ln = len_ref[ib]
-
     # skip pages entirely past the valid length (same contract as
     # decode_mha: short rows cost O(their length))
-    @pl.when(jp * page_size < ln)
+    needed = jp * page_size < ln
+    if window is not None:
+        # ... and pages entirely under the window [ln - window, ln)
+        lo = jnp.maximum(ln - window, 0)
+        needed = needed & ((jp + 1) * page_size > lo)
+
+    @pl.when(needed)
     def _compute():
         q = q_ref[0].astype(jnp.float32)            # [Hq, D]
         k = k_ref[0].astype(jnp.float32)            # [ps, Hkv, D]
@@ -98,6 +103,8 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         pos = jp * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (page_size, 1), 0)
         mask = pos < ln                             # [ps, 1]
+        if window is not None:
+            mask = mask & (pos >= lo)
         s = jnp.where(mask, s, -1e30)
         m_prev = m_ref[...]                         # [1, H]
         m_cur = jnp.max(s, axis=0, keepdims=True)
@@ -150,10 +157,10 @@ def _paged_decode_ref(q, k_pool, v_pool, page_table, seq_lens,
     return jnp.einsum("blh,blhd->bhd", p, v).astype(q.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "tp"))
+@functools.partial(jax.jit, static_argnames=("interpret", "tp", "window"))
 def paged_decode_mha(q, k_pool, v_pool, page_table, seq_lens,
                      k_scale=None, v_scale=None, interpret=None,
-                     tp=None):
+                     tp=None, window=None):
     """Single-step decode attention over a paged KV pool.
 
     q: [B, Hq, D] (this step's query)
@@ -178,6 +185,11 @@ def paged_decode_mha(q, k_pool, v_pool, page_table, seq_lens,
         what keeps the sharded pools' HBM win real — without it the
         Mosaic custom call would force an all-gather of the pool every
         decode step.
+    window: static int — a row attends only its last ``window``
+        positions, ``[seq_len - window, seq_len)``; pages wholly under
+        that range are neither computed nor copied. A table whose first
+        column is not position 0 (a sliding layer's ring turned to its
+        window's first page) passes lengths counted from that column.
     Returns [B, H, D].
     """
     if (k_scale is None) != (v_scale is None):
@@ -195,7 +207,8 @@ def paged_decode_mha(q, k_pool, v_pool, page_table, seq_lens,
             operands += [k_scale, v_scale]
             in_specs += [sc, sc]
         return shard_map(
-            lambda *a: paged_decode_mha(*a, interpret=interpret),
+            lambda *a: paged_decode_mha(*a, interpret=interpret,
+                                        window=window),
             mesh=mesh, in_specs=tuple(in_specs), out_specs=head,
             check_vma=False)(*operands)
     b, h, d = q.shape
@@ -207,13 +220,22 @@ def paged_decode_mha(q, k_pool, v_pool, page_table, seq_lens,
     scale = 1.0 / math.sqrt(d)
     it = _interpret() if interpret is None else interpret
 
-    def _page(bi, pi, pt, _lens):
+    def _column(bi, pi, lens):
+        if window is None:
+            return pi
+        # a skipped step aims at the nearest page the row needs: its
+        # neighbour's page, so no copy is issued for it
+        ln = lens[bi]
+        return jnp.clip(pi, jnp.maximum(ln - window, 0) // page_size,
+                        jnp.maximum(ln - 1, 0) // page_size)
+
+    def _page(bi, pi, pt, lens):
         # clamp: skipped steps (page beyond seq_len, table entry -1)
         # still issue a DMA — aim it at page 0 harmlessly
-        return (jnp.maximum(pt[bi, pi], 0), 0, 0, 0)
+        return (jnp.maximum(pt[bi, _column(bi, pi, lens)], 0), 0, 0, 0)
 
-    def _page_scale(bi, pi, pt, _lens):
-        return (jnp.maximum(pt[bi, pi], 0), 0, 0)
+    def _page_scale(bi, pi, pt, lens):
+        return (jnp.maximum(pt[bi, _column(bi, pi, lens)], 0), 0, 0)
 
     quant = k_scale is not None
     in_specs = [
@@ -240,7 +262,7 @@ def paged_decode_mha(q, k_pool, v_pool, page_table, seq_lens,
         _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref,
                              o_ref, acc_ref, m_ref, l_ref, scale=scale,
                              page_size=page_size, ks_ref=ks_ref,
-                             vs_ref=vs_ref)
+                             vs_ref=vs_ref, window=window)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

@@ -46,7 +46,7 @@ def _kernel_routable(x) -> bool:
 
 
 def _chunked_attention(q, k, v, causal: bool, sm_scale: float,
-                       chunk: int = 512, q_offset=None):
+                       chunk: int = 512, q_offset=None, window=None):
     """Memory-efficient attention fallback: online-softmax over key chunks
     (the flash-attention recurrence expressed in XLA; no [S,S] buffer).
 
@@ -54,7 +54,8 @@ def _chunked_attention(q, k, v, causal: bool, sm_scale: float,
     ABSOLUTE positions: query row i sits at position ``q_offset + i`` and
     attends keys at ``kpos <= q_offset + i`` — the chunked-prefill form,
     where q is one fixed-shape chunk of a prompt and k/v are the whole
-    (partially written) KV cache."""
+    (partially written) KV cache. ``window`` (causal only) keeps the last
+    ``window`` keys of each query's causal range."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
     nchunk = max(1, (sk + chunk - 1) // chunk)
@@ -86,6 +87,9 @@ def _chunked_attention(q, k, v, causal: bool, sm_scale: float,
             # tril(k=sk-sq) — query i attends keys <= i + (sk - sq)
             valid = valid[None, :] & (
                 qpos[:, None] + (sk - sq) >= kpos[None, :])
+            if window is not None:
+                valid = valid & (kpos[None, :]
+                                 > qpos[:, None] + (sk - sq) - window)
         else:
             valid = jnp.broadcast_to(valid[None, :], (sq, csize))
         s = jnp.where(valid[None, None], s, -jnp.inf)
@@ -110,8 +114,13 @@ def _chunked_attention(q, k, v, causal: bool, sm_scale: float,
 
 
 def flash_attention(q, k, v, causal: bool = False, sm_scale: float = None,
-                    dropout_p: float = 0.0, seed=None, tp=None):
+                    dropout_p: float = 0.0, seed=None, tp=None,
+                    window: int = None):
     """[B, S, H, D] paddle layout; GQA allowed (K/V may carry fewer heads).
+
+    ``window`` (static; causal, no dropout, forward only): query i sees
+    keys in ``(i - window, i]`` — a sliding-window layer's prefill. The
+    kernel skips key blocks wholly outside it.
 
     ``tp=(mesh, axis)`` shard_maps the whole call over the head axis
     (q on H, k/v on their own Hkv) — the tensor-parallel serving
@@ -135,7 +144,7 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale: float = None,
         return shard_map(
             lambda q_, k_, v_: flash_attention(
                 q_, k_, v_, causal=causal, sm_scale=sm_scale,
-                dropout_p=dropout_p, seed=seed),
+                dropout_p=dropout_p, seed=seed, window=window),
             mesh=mesh, in_specs=(hs, hs, hs), out_specs=hs,
             check_vma=False)(q, k, v)
     d = q.shape[-1]
@@ -147,7 +156,7 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale: float = None,
     from ..framework.flags import get_flags
 
     if get_flags("FLAGS_flash_head_batched")["FLAGS_flash_head_batched"] \
-            and _on_tpu():
+            and _on_tpu() and window is None:
         from .flash_attention_hb import (flash_attention_bshd_hb,
                                          supports_hb)
 
@@ -166,7 +175,8 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale: float = None,
         _kernel_routable(q) or dropout_p > 0.0)
     if use_kernel:
         out = flash_attention_bhsd(qt, kt, vt, causal=causal, sm_scale=scale,
-                                   dropout_p=dropout_p, seed=seed)
+                                   dropout_p=dropout_p, seed=seed,
+                                   window=window)
     else:
         if dropout_p > 0.0:
             raise ValueError("dropout requires the Pallas kernel path "
@@ -175,7 +185,7 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale: float = None,
             rep = qt.shape[1] // kt.shape[1]
             kt = jnp.repeat(kt, rep, axis=1)
             vt = jnp.repeat(vt, rep, axis=1)
-        out = _chunked_attention(qt, kt, vt, causal, scale)
+        out = _chunked_attention(qt, kt, vt, causal, scale, window=window)
     return jnp.swapaxes(out, 1, 2)
 
 
@@ -243,21 +253,41 @@ def paged_attention(q, k_pages, v_pages, lengths, page_indices, **kw):
     return _pa(q, k_pages, v_pages, lengths, page_indices, **kw)
 
 
+def _gmm_tiling(m: int, k: int, n: int):
+    """Tile sizes for the megablox kernel at (m, k, n): few, wide steps,
+    so that one step streams one group's [tk, tn] slab of weights. By the
+    chip's clock at the expert widths (experiments/exp_grouped_matmul.py,
+    v5e, 128 groups, k 2048, n 1024): a decode batch of 256 rows (a couple
+    a group) streams 690 GB/s at (128, 2048, 1024); a prefill of 4,096 to
+    65,536 rows is fastest at (256, 2048, 1024) (114 TFLOP/s at 65,536);
+    512 rows a tile with a 1024-wide slab runs out of VMEM."""
+    return 256 if m >= 4096 else 128, min(k, 2048), min(n, 1024)
+
+
 def grouped_matmul(lhs, rhs, group_sizes, preferred_element_type=jnp.float32):
     """MoE expert grouped GEMM (reference analog:
-    phi/kernels/fusion/cutlass/moe_kernel.cu). TPU: megablox gmm kernel."""
+    phi/kernels/fusion/cutlass/moe_kernel.cu): rows of ``lhs`` [m, k],
+    sorted by group, times their group's matrix of ``rhs`` [groups, k, n];
+    ``group_sizes`` [groups] int32. Rows past ``sum(group_sizes)`` belong
+    to no group and their result is UNDEFINED (the kernel never visits
+    them): mask them. Only the groups that have rows are read.
+
+    TPU: the megablox gmm kernel, tiled by :func:`_gmm_tiling`.
+    Elsewhere: ``jax.lax.ragged_dot``."""
     if _on_tpu():
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-        return gmm(lhs, rhs, group_sizes,
-                   preferred_element_type=preferred_element_type)
-    # fallback: segment-wise dense matmul
-    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                              jnp.cumsum(group_sizes)[:-1].astype(jnp.int32)])
-    n_groups = rhs.shape[0]
-    rows = lhs.shape[0]
-    row_ids = jnp.arange(rows)
-    seg = jnp.sum(row_ids[:, None] >= starts[None, :], axis=1) - 1
-    seg = jnp.clip(seg, 0, n_groups - 1)
-    picked = rhs[seg]  # [rows, K, N]
-    return jnp.einsum("rk,rkn->rn", lhs, picked).astype(preferred_element_type)
+        m, k = lhs.shape
+        tm, tk, tn = _gmm_tiling(m, k, rhs.shape[2])
+        pad = -m % tm
+        if pad:
+            lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+        out = gmm(lhs, rhs, group_sizes.astype(jnp.int32),
+                  preferred_element_type=preferred_element_type,
+                  tiling=(tm, tk, tn))
+        return out[:m] if pad else out
+    # off the TPU: XLA's own ragged product (no [rows, K, N] gather of the
+    # weights, so the routing code runs at sizes that fit a CPU test)
+    return jax.lax.ragged_dot(
+        lhs, rhs, group_sizes.astype(jnp.int32),
+        preferred_element_type=preferred_element_type)

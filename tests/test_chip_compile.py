@@ -116,3 +116,51 @@ def test_unrepairable_width_raises_by_name(chip):
     with pytest.raises(ValueError, match="fused_linear_param_grad_add.*128"):
         _compile(chip, pk.fused_linear_param_grad_add, ((256, 100), BF16),
                  ((256, 256), BF16), ((100, 256), F32))
+
+
+# -- the sparse-expert, window-attention decoder's kernels at its widths ----------
+def test_window_flash_fwd(chip):
+    """The widest prefill bucket of a sliding layer: 32 query / 4 KV heads
+    x 128, 8192 positions, window 2048."""
+    def fwd(q, k, v):
+        return fk.flash_attention_bhsd(q, k, v, causal=True, window=2048)
+
+    assert _compile(chip, fwd, ((1, 32, 8192, 128), BF16),
+                    ((1, 4, 8192, 128), BF16),
+                    ((1, 4, 8192, 128), BF16)) == 1
+
+
+@pytest.mark.parametrize("window, cols", [(None, 544), (2048, 129)],
+                         ids=["full", "ring"])
+def test_paged_decode_two_geometries(chip, window, cols):
+    """32 rows: a full layer's table of 544 pages, a window layer's ring
+    of 129."""
+    import functools
+
+    b, h, hkv, d, ps = 32, 32, 4, 128, 16
+    pool = ((b * cols, ps, hkv, d), BF16)
+    fn = functools.partial(pa.paged_decode_mha, window=window)
+    assert _compile(chip, fn, ((b, h, d), BF16), pool, pool,
+                    ((b, cols), I32), ((b,), I32)) == 1
+
+
+@pytest.mark.parametrize("rows", [256, 4096, 65536],
+                         ids=["decode", "bucket512", "bucket8192"])
+def test_grouped_matmul_expert_products(chip, rows, monkeypatch):
+    """The three expert products (128 experts, 2048 -> 1024 -> 2048) of a
+    decode step (32 rows x 8 choices) and of the narrowest and the widest
+    prefill bucket, with the tiles ``_gmm_tiling`` picks."""
+    from paddle_tpu.ops import pallas as ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+
+    def up(x, w, sizes):
+        return ops.grouped_matmul(x, w, sizes, preferred_element_type=BF16)
+
+    def down(x, w, sizes):
+        return ops.grouped_matmul(x, w, sizes, preferred_element_type=F32)
+
+    assert _compile(chip, up, ((rows, 2048), BF16),
+                    ((128, 2048, 1024), BF16), ((128,), I32)) == 1
+    assert _compile(chip, down, ((rows, 1024), BF16),
+                    ((128, 1024, 2048), BF16), ((128,), I32)) == 1
