@@ -3122,6 +3122,14 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                        for rid in self._slot_req.values()),
                    pages_full=sum(h[0] for h in held),
                    pages_window=sum(h[1] for h in held))
+        elif trace.enabled():
+            # the pages the live rows' contexts span at the segment's
+            # start (what ``paged_decode`` walks), of the table's
+            ps = self.page_size
+            sp.set(pages_live=sum(
+                       -(-(self._plen[rid] + len(self._tokens[rid])) // ps)
+                       for rid in self._slot_req.values()),
+                   pages_table=self.alloc.page_table.size)
         return super()._decode_segment_plain(n_steps, cfg, sp)
 
     def _fwd_spec(self, params, inp, caches, lens, live, lora=None):

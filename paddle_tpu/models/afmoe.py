@@ -225,7 +225,8 @@ class AfmoeAttention(Layer):
             c = cos[lens][:, None, None, :]
             sn = sin[lens][:, None, None, :]
             qh, kh, vh = self._heads(qv, kv, vv, qw, kw, c, sn)
-            new_len = lens + live.astype(jnp.int32)
+            # a dead row attends nothing: length 0 costs the kernel no page
+            new_len = jnp.where(live, lens + 1, 0)
             col = lens // ps
             table = page_table
             if self.window is not None:

@@ -407,10 +407,11 @@ class LlamaAttention(Layer):
                                        mode="drop")
             from ..ops.paged_attention import paged_decode_mha
 
-            lv = live.astype(jnp.int32)
+            # a dead row attends nothing: length 0 costs the kernel no
+            # page
             ctx = jnp.stack(
                 [paged_decode_mha(qh[:, i], kp, vp, page_table,
-                                  lens + lv * (i + 1), tp=tp)
+                                  jnp.where(live, lens + i + 1, 0), tp=tp)
                  for i in range(w)], axis=1)
             return ctx.reshape(b, w, self.num_heads * hd), kp, vp
 
@@ -436,7 +437,6 @@ class LlamaAttention(Layer):
             safe = jnp.minimum(page.reshape(-1), kp.shape[0] - 1)
             snap_k, snap_v = kp[safe], vp[safe]
             snap_ks, snap_vs = ks, vs
-            lv = live.astype(jnp.int32)
             ctxs = []
             for i in range(w):
                 kp, ks = quant_store_rows(kp, ks, page[:, i],
@@ -445,7 +445,7 @@ class LlamaAttention(Layer):
                                           offs[:, i], vh[:, i])
                 ctxs.append(paged_decode_mha(
                     qh[:, i], kp, vp, page_table,
-                    lens + lv * (i + 1), ks, vs, tp=tp))
+                    jnp.where(live, lens + i + 1, 0), ks, vs, tp=tp))
             ctx = jnp.stack(ctxs, axis=1)
             return (ctx.reshape(b, w, self.num_heads * hd), kp, vp,
                     ks, vs, snap_k, snap_v, snap_ks, snap_vs,
@@ -503,9 +503,10 @@ class LlamaAttention(Layer):
                                        mode="drop")
             from ..ops.paged_attention import paged_decode_mha
 
+            # a dead row (retired slots keep their ``lens``) attends
+            # nothing: length 0 costs the kernel no page
             ctx = paged_decode_mha(qh, kp, vp, page_table,
-                                   lens + live.astype(jnp.int32),
-                                   tp=tp)
+                                   jnp.where(live, lens + 1, 0), tp=tp)
             return ctx.reshape(b, 1, self.num_heads * hd), kp, vp
 
         def attend_q(qv, kv, vv, kp, vp, ks, vs):
@@ -520,7 +521,7 @@ class LlamaAttention(Layer):
             kp, ks = quant_store_rows(kp, ks, page, offs, kh)
             vp, vs = quant_store_rows(vp, vs, page, offs, vh)
             ctx = paged_decode_mha(qh, kp, vp, page_table,
-                                   lens + live.astype(jnp.int32),
+                                   jnp.where(live, lens + 1, 0),
                                    ks, vs, tp=tp)
             return (ctx.reshape(b, 1, self.num_heads * hd), kp, vp,
                     ks, vs)

@@ -11,30 +11,41 @@ row of a ``page_table`` — so HBM holds the tokens actually in flight
 (rounded up to pages), not ``max_batch * max_len``, and admission never
 fails on fragmentation (any free page serves any slot).
 
-TPU-native mechanism: the page table rides Pallas SCALAR PREFETCH
-(``pltpu.PrefetchScalarGridSpec``) — block index maps read the
-prefetched table to aim each K/V page DMA, which is the idiomatic TPU
-form of paged attention (indirect addressing happens at DMA-issue time,
-not as a gather in the kernel body). The softmax math is byte-for-byte
-the ragged ``decode_mha`` recurrence (pallas_kernels.py): online
-softmax over pages, block-skip past each row's length, so a short row
-costs O(its length).
+TPU-native mechanism: the kernel's work follows the pages live rows
+hold. Page table and lengths ride Pallas SCALAR PREFETCH
+(``pltpu.PrefetchScalarGridSpec``); the pools stay in HBM
+(``memory_space=pl.ANY``). The grid runs over batch rows only, and a
+row's step loops over the pages that row attends, ``[first, last)`` of
+its table row (``last`` from its length, ``first`` from the window), in
+compute blocks of several pages: each page is one copy the kernel issues
+itself (``pltpu.make_async_copy`` from ``pool.at[table[row, j]]``, a
+``[page_size, Hkv, D]`` slab that lies contiguous) into one of two VMEM
+slots, the next block's copies, or the next live row's first, in flight
+while this block is computed. Trip counts come from the lengths, so one
+compiled program serves every length; a table entry outside
+``[first, last)`` is never looked at, a row of length 0 costs one grid
+step and no copy. (The kernel this replaced had a ``(rows, table pages)``
+grid with a block spec a page: it skipped the arithmetic of a page past a
+row's length but still walked every block of the table, about 0.1 us
+each, so its time followed the table's size and not what was live: 2.9 %
+of its bandwidth roofline with a tenth of the table live, PERF.md PR 28.)
+A block's arithmetic is the online-softmax recurrence of the ragged
+``decode_mha`` (pallas_kernels.py) with both products on the matrix unit
+(``_block_update``): bf16 operands as stored, everything else float32.
 
 ``PagedKVCache`` (inference/paged_cache.py) owns the pool + free-list;
 this module is the pure compute.
 
 QUANTIZED pools (``kv_dtype="int8"`` serving): pass the per-(page,
 kv_head) absmax scale arrays and the kernel dequantizes AFTER the page
-DMA (``paddle_tpu.quantization.kv`` conventions) — decode's HBM read
+copy (``paddle_tpu.quantization.kv`` conventions): decode's HBM read
 is half the bytes, which is the whole lever on bandwidth-bound decode.
 
-Relationship to ``ops/pallas.py::paged_attention``: that function wraps
-the STOCK ``jax.experimental.pallas.ops.tpu.paged_attention`` kernel
-(same pool/page-table layout) and is the TPU-only, tuned option; THIS
-kernel is the framework's own from-scratch implementation — it also
-runs in interpret mode (CPU tests) and is the one the parity suite and
-PagedKVCache exercise. Numerics agree; fixes to the page-table
-convention (-1 unmapped, clamp-on-skip) must land in both.
+``ops/pallas.py::paged_attention`` wraps the STOCK
+``jax.experimental.pallas.ops.tpu.paged_attention`` kernel, whose copy
+pattern this one shares; the pool layout (a page holds all KV heads),
+the -1 table convention, the window and the fused dequant are this
+module's own, and it also runs in interpret mode (CPU tests).
 """
 from __future__ import annotations
 
@@ -55,72 +66,164 @@ def _interpret() -> bool:
     return jax.devices()[0].platform != "tpu"
 
 
-def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, scale, page_size,
-                         ks_ref=None, vs_ref=None, window=None):
-    """One (batch row, page) step of the online-softmax recurrence.
+def _pages_per_block(page_size, hkv):
+    """Pages of one compute block: about 1024 (token, KV head) rows, so
+    a block's scores are one ``[Hq, 1024]`` product whatever the head
+    geometry."""
+    return max(1, 1024 // (page_size * hkv))
 
-    ``pt_ref``/``len_ref`` are scalar-prefetched; the K/V blocks arriving
-    here were already DMA'd from the page the index map selected. With
-    ``ks_ref``/``vs_ref`` bound (int8 pools) the K/V block is int8 and
-    the per-(page, kv_head) absmax scales dequantize it HERE, after the
-    DMA — the HBM read is half the bytes, which is the whole point on
-    bandwidth-bound decode."""
-    ib, jp = pl.program_id(0), pl.program_id(1)
-    npg = pl.num_programs(1)
 
-    @pl.when(jp == 0)
+def _dot(a, b, contract):
+    """``a x b`` on the matrix unit, accumulated in float32. bf16 operands
+    multiply exactly; float32 operands are not rounded to bf16 (Mosaic
+    refuses that precision on a bf16 product, so it is asked for only
+    where it says something)."""
+    exact = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=exact,
+                               preferred_element_type=jnp.float32)
+
+
+def _block_update(carry, q, k, v, pos0, lo, hi, scale):
+    """One compute block of the online-softmax recurrence, both products
+    on the matrix unit over the whole block.
+
+    ``q``: ``[Hq, D]``; ``k``/``v``: ``[T, Hkv, D]``, the block's pages as
+    they lie, token ``t`` at position ``pos0 + t``; the row attends
+    positions ``[lo, hi)``. K and V are read as ``[T x Hkv, D]`` and the
+    scores are ``q x k^T`` for EVERY (query head, KV head) pair, of which
+    the mask keeps a query head's own KV head: ``Hkv`` times the products
+    on a unit that would idle, and no page is transposed or repeated. A
+    masked ``p`` is 0, so ``p x v`` sums a query head's own KV head only;
+    that product is float32 by float32 (``v`` widened), so ``p`` is not
+    rounded. ``carry``: running maximum and sum ``[Hq, 1]`` and the
+    accumulator ``[Hq, D]``."""
+    m_prev, l_prev, acc = carry
+    (hq, d), (t, hkv) = q.shape, k.shape[:2]
+    if q.dtype != k.dtype:        # a dequantized pool, a float32 query
+        q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, t * hkv), 1)
+    pos = pos0 + col // hkv
+    own = jax.lax.broadcasted_iota(jnp.int32, (hq, 1), 0) // (hq // hkv)
+    mask = (col % hkv == own) & (pos >= lo) & (pos < hi)     # [Hq, N]
+    k, v = k.reshape(t * hkv, d), v.reshape(t * hkv, d)
+    s = jnp.where(mask, _dot(q, k, ((1,), (1,))) * scale, -1e30)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc = acc * alpha + _dot(p, v.astype(jnp.float32), ((1,), (0,)))
+    return m_new, l_new, acc
+
+
+def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
+                         scale, window, quant):
+    """One batch row a grid step; the body walks the pages the row
+    attends, ``[first, last)`` of its table row, in compute blocks, and
+    issues every page copy itself.
+
+    ``pt_ref``/``len_ref`` are scalar-prefetched, the pools stay in HBM
+    (an int8 pool's scales arrive as the row's block, gathered by its
+    table). A block's pages land in one of two VMEM slots; while a block
+    is computed the copies of the row's next block, or of the next live
+    row's first, are in flight (``state`` carries the slot across grid
+    steps, which run in order on one core; the first live row's first
+    block is issued at row 0). A row of length 0 issues no copy and
+    writes zeros. A page past ``last`` in a row's last block is not
+    copied: the slot keeps an older page's finite values there (the slots
+    start zeroed), which the positions' mask leaves out."""
+    if quant:
+        ks_ref, vs_ref, o_ref, k_buf, v_buf, sem, state = rest
+    else:
+        o_ref, k_buf, v_buf, sem, state = rest
+    streams = ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1))
+    row, nrows = pl.program_id(0), pl.num_programs(0)
+    _, cpb, ps, hkv, d = k_buf.shape
+    hq = q_ref.shape[1]
+
+    def length(r):
+        # a length past the table's end attends what the table holds
+        return jnp.minimum(len_ref[r], pt_ref.shape[1] * ps)
+
+    def page_range(r):
+        ln = length(r)
+        first = 0 if window is None else jnp.maximum(ln - window, 0) // ps
+        return first, (ln + ps - 1) // ps
+
+    def next_live(r):
+        """The first row at or after ``r`` that holds a page (``nrows``
+        if none does)."""
+        return jax.lax.while_loop(
+            lambda i: (i < nrows) & (len_ref[jnp.minimum(i, nrows - 1)] == 0),
+            lambda i: i + 1, r)
+
+    def block_copies(r, blk, slot, do):
+        """start / wait for every page copy of block ``blk`` of row ``r``."""
+        first, last = page_range(r)
+        col0 = first + blk * cpb
+
+        def page_copies(i, _):
+            # an unmapped (-1) entry inside the range is the caller's
+            # fault; it reads page 0 and not outside the pool
+            page = jnp.maximum(pt_ref[r, col0 + i], 0)
+            for src, dst, s in streams:
+                do(pltpu.make_async_copy(src.at[page], dst.at[slot, i],
+                                         sem.at[s, slot]))
+
+        jax.lax.fori_loop(0, jnp.minimum(last - col0, cpb), page_copies,
+                          None)
+
+    @pl.when(row == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        state[0] = 0           # the slot the next block lands in
+        for _, dst, _ in streams:
+            dst[...] = jnp.zeros_like(dst)
+        r0 = next_live(0)
 
-    ln = len_ref[ib]
-    # skip pages entirely past the valid length (same contract as
-    # decode_mha: short rows cost O(their length))
-    needed = jp * page_size < ln
-    if window is not None:
-        # ... and pages entirely under the window [ln - window, ln)
-        lo = jnp.maximum(ln - window, 0)
-        needed = needed & ((jp + 1) * page_size > lo)
+        @pl.when(r0 < nrows)
+        def _():
+            block_copies(r0, 0, 0, lambda c: c.start())
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)            # [Hq, D]
-        k = k_ref[0].astype(jnp.float32)            # [ps, Hkv, D]
-        v = v_ref[0].astype(jnp.float32)
-        if ks_ref is not None:
-            # fused dequant (quantization.kv conventions): the scale
-            # block is this page's [Hkv] absmax row, selected by the
-            # same prefetched-table index map that aimed the K/V DMA
-            k = k * (ks_ref[0, 0] * (1.0 / _KV_QMAX))[None, :, None]
-            v = v * (vs_ref[0, 0] * (1.0 / _KV_QMAX))[None, :, None]
-        g = q.shape[0] // k.shape[1]
-        if g > 1:                                   # GQA: share KV heads
-            k = jnp.repeat(k, g, axis=1)            # VMEM-local repeat
-            v = jnp.repeat(v, g, axis=1)
-        s = jnp.sum(q[None] * k, axis=-1) * scale   # [ps, Hq]
-        pos = jp * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, 1), 0)
-        mask = pos < ln                             # [ps, 1]
-        if window is not None:
-            mask = mask & (pos >= lo)
-        s = jnp.where(mask, s, -1e30)
-        m_prev = m_ref[...]                         # [1, H]
-        m_cur = jnp.max(s, axis=0, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                      # [ps, H]
-        p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)             # [1, H]
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=0, keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = (acc_ref[...] * jnp.transpose(alpha)
-                        + jnp.sum(p[:, :, None] * v, axis=0))  # [H, D]
+    ln = length(row)
+    first, last = page_range(row)
+    nblk = (last - first + cpb - 1) // cpb
+    slot0 = state[0]
+    nxt = next_live(row + 1)
+    lo = 0 if window is None else jnp.maximum(ln - window, 0)
+    q = q_ref[0]
 
-    @pl.when(jp == npg - 1)
-    def _finalize():
-        l_safe = jnp.maximum(jnp.transpose(l_ref[...]), 1e-30)  # [H, 1]
-        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+    def block(blk, carry):
+        slot = (slot0 + blk) % 2
+
+        # in flight while this block is computed: the row's next block,
+        # or after its last the first block of the next live row
+        ends = blk + 1 == nblk
+        nr = jnp.where(ends, nxt, row)
+
+        @pl.when(nr < nrows)
+        def _prefetch():
+            block_copies(nr, jnp.where(ends, 0, blk + 1), 1 - slot,
+                         lambda c: c.start())
+
+        block_copies(row, blk, slot, lambda c: c.wait())
+        k, v = k_buf[slot], v_buf[slot]                # [cpb, ps, Hkv, D]
+        if quant:
+            # fused dequant (quantization.kv conventions), after the copy:
+            # the HBM read stays int8
+            cols = pl.ds(first + blk * cpb, cpb)
+            ksc = ks_ref[0, cols, :] * (1.0 / _KV_QMAX)    # [cpb, Hkv]
+            vsc = vs_ref[0, cols, :] * (1.0 / _KV_QMAX)
+            k = k.astype(jnp.float32) * ksc[:, None, :, None]
+            v = v.astype(jnp.float32) * vsc[:, None, :, None]
+        return _block_update(
+            carry, q, k.reshape(cpb * ps, hkv, d), v.reshape(cpb * ps, hkv, d),
+            (first + blk * cpb) * ps, lo, ln, scale)
+
+    init = (jnp.full((hq, 1), -1e30, jnp.float32),
+            jnp.zeros((hq, 1), jnp.float32),
+            jnp.zeros((hq, d), jnp.float32))
+    _, l_fin, acc = jax.lax.fori_loop(0, nblk, block, init)
+    state[0] = (slot0 + nblk) % 2
+    o_ref[0] = (acc / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
 
 
 def _paged_decode_ref(q, k_pool, v_pool, page_table, seq_lens,
@@ -167,13 +270,15 @@ def paged_decode_mha(q, k_pool, v_pool, page_table, seq_lens,
     k_pool/v_pool: [num_pages, page_size, Hkv, D] shared pools (GQA:
         Hq may be a multiple of Hkv — KV heads are shared in-kernel)
     page_table: [B, max_pages] int32 — page ids per sequence, in order;
-        entries past a row's length are never dereferenced (clamped to 0
-        for the skipped DMA)
+        only the entries of the pages a row attends are read (the rest
+        may be -1, or anything)
     seq_lens: [B] int32 valid lengths (the new token's k/v must already
-        be written at position seq_lens-1 via PagedKVCache.write_tokens)
+        be written at position seq_lens-1 via PagedKVCache.write_tokens).
+        A row of length 0 (a dead slot: the models pass 0 for
+        ``live=False``) costs no page and returns zeros
     k_scale/v_scale: [num_pages, Hkv] f32 per-page-per-head absmax
         scales for INT8 pools (quantization.kv conventions) — pass both
-        or neither. Dequant fuses into the kernel after the page DMA,
+        or neither. Dequant fuses into the kernel after the page copy,
         so the HBM read stays int8 (the bandwidth win quantized KV
         exists for); the output is f32-accumulated either way.
     tp: tensor-parallel handle ``(mesh, axis)`` (static) — wraps the
@@ -216,71 +321,44 @@ def paged_decode_mha(q, k_pool, v_pool, page_table, seq_lens,
     if h % hkv:
         raise ValueError(f"Hq={h} not a multiple of Hkv={hkv}")
     page_size = k_pool.shape[1]
-    npages = page_table.shape[1]
-    scale = 1.0 / math.sqrt(d)
+    quant = k_scale is not None
+    cpb = _pages_per_block(page_size, hkv)
     it = _interpret() if interpret is None else interpret
 
-    def _column(bi, pi, lens):
-        if window is None:
-            return pi
-        # a skipped step aims at the nearest page the row needs: its
-        # neighbour's page, so no copy is issued for it
-        ln = lens[bi]
-        return jnp.clip(pi, jnp.maximum(ln - window, 0) // page_size,
-                        jnp.maximum(ln - 1, 0) // page_size)
-
-    def _page(bi, pi, pt, lens):
-        # clamp: skipped steps (page beyond seq_len, table entry -1)
-        # still issue a DMA — aim it at page 0 harmlessly
-        return (jnp.maximum(pt[bi, _column(bi, pi, lens)], 0), 0, 0, 0)
-
-    def _page_scale(bi, pi, pt, lens):
-        return (jnp.maximum(pt[bi, _column(bi, pi, lens)], 0), 0, 0)
-
-    quant = k_scale is not None
-    in_specs = [
-        pl.BlockSpec((1, h, d), lambda bi, pi, pt, ln: (bi, 0, 0)),
-        pl.BlockSpec((1, page_size, hkv, d), _page),
-        pl.BlockSpec((1, page_size, hkv, d), _page),
-    ]
+    row = pl.BlockSpec((1, h, d), lambda bi, pt, ln: (bi, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     operands = [q, k_pool, v_pool]
+    scratch = [pltpu.VMEM((2, cpb) + k_pool.shape[1:], k_pool.dtype),
+               pltpu.VMEM((2, cpb) + v_pool.shape[1:], v_pool.dtype)]
+    in_specs = [row, hbm, hbm]
     if quant:
-        # one page's [Hkv] scale row as a (1, 1, Hkv) block of a
-        # [num_pages, 1, Hkv] view: a (1, Hkv) block of the 2-D array is
-        # refused by the TPU lowering (second-to-last block dim must be
-        # a multiple of 8 or the whole axis)
-        in_specs += [pl.BlockSpec((1, 1, hkv), _page_scale),
-                     pl.BlockSpec((1, 1, hkv), _page_scale)]
-        operands += [k_scale[:, None, :], v_scale[:, None, :]]
-
-    def kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest):
-        if quant:
-            ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-        else:
-            ks_ref = vs_ref = None
-            o_ref, acc_ref, m_ref, l_ref = rest
-        _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref,
-                             o_ref, acc_ref, m_ref, l_ref, scale=scale,
-                             page_size=page_size, ks_ref=ks_ref,
-                             vs_ref=vs_ref, window=window)
-
+        # a row's scales, gathered by its table outside the kernel (32
+        # bytes a page: too narrow a slab for a copy of its own) and
+        # padded by a block, so the last block's slice stays inside
+        ids = jnp.pad(jnp.clip(page_table, 0, k_pool.shape[0] - 1),
+                      ((0, 0), (0, cpb)))
+        operands += [k_scale[ids], v_scale[ids]]
+        in_specs += [pl.BlockSpec((1, ids.shape[1], hkv),
+                                  lambda bi, pt, ln: (bi, 0, 0))] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, npages),
+        grid=(b,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, d), lambda bi, pi, pt, ln: (bi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, d), jnp.float32),
-            pltpu.VMEM((1, h), jnp.float32),
-            pltpu.VMEM((1, h), jnp.float32),
-        ],
+        out_specs=row,
+        scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2, 2)),
+                                  pltpu.SMEM((1,), jnp.int32)],
     )
     return pl.pallas_call(
-        kernel,
+        functools.partial(_paged_decode_kernel, scale=1.0 / math.sqrt(d),
+                          window=window, quant=quant),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=grid_spec,
+        # rows in order on one core: the slots and ``state`` carry a
+        # prefetch from one row to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=it,
         # a stable name: compiled text and profiler traces find the
-        # kernel by it (the body is a closure called ``kernel``)
+        # kernel by it
         name="paged_decode",
-    )(page_table, seq_lens, q, *operands[1:])
+    )(page_table, seq_lens, *operands)

@@ -241,9 +241,11 @@ def prefix_chunk_attention(q, k_cache, v_cache, pos, sm_scale: float = None,
 def paged_attention(q, k_pages, v_pages, lengths, page_indices, **kw):
     """Decode-time KV-cache attention over paged KV (reference analog:
     masked_multihead_attention_kernel in fused_multi_transformer_op.cu.h:745).
-    TPU: JAX Pallas paged_attention kernel. See also the framework's own
-    ``ops/paged_attention.py::paged_decode_mha`` (same layout, runs in
-    interpret mode too, integrates with inference.PagedKVCache).
+    TPU: JAX Pallas paged_attention kernel, over pools laid out
+    ``[Hkv, pages, page_size, D]``. The serving engines use the
+    framework's own ``ops/paged_attention.py::paged_decode_mha`` (a page
+    holds all KV heads; runs in interpret mode too, integrates with
+    inference.PagedKVCache).
     Quantized (int8) pools are NOT supported here — the stock kernel
     has no scale inputs; the serving engines' ``kv_dtype="int8"`` path
     uses ``paged_decode_mha``'s fused dequant instead."""

@@ -26,7 +26,7 @@ BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
 
 @pytest.fixture(scope="module")
-def chip():
+def topo():
     from jax.experimental import topologies
 
     try:
@@ -35,6 +35,11 @@ def chip():
     except Exception as e:  # no libtpu here: nothing to ask
         pytest.skip(f"the v5e topology cannot be described: {e}")
     assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -83,6 +88,31 @@ def test_paged_decode(chip, hkv, kv_dtype):
     if kv_dtype == "int8":   # per-(page, kv_head) scales
         shapes += [((pages, hkv), F32)] * 2
     assert _compile(chip, pa.paged_decode_mha, *shapes) == 1
+
+
+def test_paged_decode_head_sharded_over_four_chips(topo):
+    """``tp=``: the kernel under ``shard_map`` over the head axis of a
+    four-chip mesh (Mistral's 32 / 8 heads: 8 / 2 a chip), pools sharded
+    on KV heads; no chip gathers a pool."""
+    import functools
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("mp",))
+    b, h, hkv, d, pages, ps, maxp = 32, 32, 8, 128, 2048, 16, 64
+
+    def arg(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    pool = arg((pages, ps, hkv, d), BF16, None, None, "mp", None)
+    text = jax.jit(functools.partial(
+        pa.paged_decode_mha, tp=(mesh, "mp"))).lower(
+            arg((b, h, d), BF16, None, "mp", None), pool, pool,
+            arg((b, maxp), I32), arg((b,), I32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "all-gather" not in text
 
 
 @pytest.mark.parametrize("heads", [16, 32])

@@ -484,7 +484,12 @@ class TestEngineParity:
     def test_cow_at_block_boundary_identical(self):
         """int8 × CoW: divergence mid-block forces a copy-on-write
         whose scale copy rides along (debug_pages would fail loudly
-        otherwise); greedy tokens match bf16."""
+        otherwise); the logits stay within 0.1 of the bf16 engine's on
+        the same path. (Held token for token until PR 29: on these
+        prompts the float engine's 11th token is a tie inside the
+        quantization noise of about 0.03, which the reference softmax
+        and the kernel's block-wise one break the other way from the
+        old page-wise one.)"""
         model, _ = tiny_model()
         shared = RNG.randint(0, 256, size=(10,)).astype(np.int32)
         p1 = np.concatenate([shared,
@@ -495,11 +500,11 @@ class TestEngineParity:
                              RNG.randint(0, 256, (5,)).astype(np.int32)])
         eb = paged_engine(model, "int8", prefix_cache=True)
         _serve(eb, [p1])
-        o2 = _serve(eb, [p2])[0]
-        assert eb.alloc.cow_copies >= 1
         ea = paged_engine(model, prefix_cache=True)
         _serve(ea, [p1])
-        np.testing.assert_array_equal(_serve(ea, [p2])[0], o2)
+        r = max_logit_divergence(ea, eb, [p2], steps=12)
+        assert eb.alloc.cow_copies >= 1
+        assert r["tokens"] >= 10 and r["max_logit_div"] < 0.1
         _assert_no_leaks(eb)
 
     def test_preempt_replay_under_pressure_identical(self):

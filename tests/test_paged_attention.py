@@ -505,3 +505,222 @@ class TestSpeculativeDecoding:
             # earlier occurrence of the last token
             if ctx[:L][:-1].count(ctx[L - 1]) == 0:
                 assert got == [ctx[L - 1]] * 4
+
+
+# -- the kernel walks the pages live rows hold (a loop over [first, last) of a
+# -- row's table with hand-issued copies), at the serving cells' head geometries
+PS = 16
+GEOMETRIES = {"mistral_32_8_128": (32, 8, 128), "trinity_32_4_128": (32, 4, 128)}
+MAXP = 20                       # 2-3 compute blocks a full row
+ROW_LENGTHS = {
+    "len0": [0, 0],
+    "len1": [1, 1],
+    "one_page": [PS, PS],
+    "one_page_and_a_token": [PS + 1, PS + 1],
+    "full_table": [MAXP * PS, MAXP * PS],
+    "mixed": [0, 1, PS, PS + 1, 300, 0, MAXP * PS, 45],
+}
+
+
+def _softmax_attend(q, k, v):
+    """One row: ``q`` [Hq, D] over keys / values [L, Hkv, D]."""
+    g = q.shape[0] // k.shape[1]
+    k, v = np.repeat(k, g, 1), np.repeat(v, g, 1)
+    s = np.einsum("hd,lhd->hl", q, k) / np.sqrt(q.shape[-1])
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return np.einsum("hl,lhd->hd", p / p.sum(-1, keepdims=True), v)
+
+
+def _pool_and_table(lens, hq, hkv, d, maxp=MAXP, seed=0, spare=3):
+    """Random float32 pools (``spare`` pages no row holds at the end), a
+    table of distinct scattered pages, and queries."""
+    rs = np.random.RandomState(seed)
+    b = len(lens)
+    pages = b * maxp + spare
+    k = rs.randn(pages, PS, hkv, d).astype(np.float32)
+    v = rs.randn(pages, PS, hkv, d).astype(np.float32)
+    q = rs.randn(b, hq, d).astype(np.float32)
+    table = rs.permutation(b * maxp).reshape(b, maxp).astype(np.int32)
+    return q, k, v, table
+
+
+@pytest.mark.parametrize("rows", list(ROW_LENGTHS))
+@pytest.mark.parametrize("geometry", list(GEOMETRIES))
+def test_live_page_walk_matches_reference(geometry, rows):
+    from paddle_tpu.ops.paged_attention import _paged_decode_ref
+
+    lens = jnp.asarray(ROW_LENGTHS[rows], jnp.int32)
+    q, k, v, table = map(jnp.asarray, _pool_and_table(
+        ROW_LENGTHS[rows], *GEOMETRIES[geometry]))
+    got = paged_decode_mha(q, k, v, table, lens)
+    want = _paged_decode_ref(q, k, v, table, lens)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    # a row of length 0 is zeros, not the reference's mean of page 0
+    dead = np.asarray(lens) == 0
+    assert not np.asarray(got)[dead].any()
+
+
+@pytest.mark.parametrize("geometry", list(GEOMETRIES))
+def test_live_page_walk_int8_pools(geometry):
+    """Fused dequant: per-(page, KV head) absmax scales, gathered by the
+    table outside the kernel."""
+    from paddle_tpu.ops.paged_attention import _paged_decode_ref
+    from paddle_tpu.quantization.kv import KV_QMAX
+
+    lens = ROW_LENGTHS["mixed"]
+    q, k, v, table = _pool_and_table(lens, *GEOMETRIES[geometry])
+
+    def quantize(x):
+        scale = np.abs(x).max(axis=(1, 3))                  # [pages, Hkv]
+        return (np.round(x / scale[:, None, :, None] * KV_QMAX).astype(
+            np.int8), scale.astype(np.float32))
+
+    (kq, ks), (vq, vs) = quantize(k), quantize(v)
+    args = [jnp.asarray(a) for a in (q, kq, vq, table)] + [
+        jnp.asarray(lens, jnp.int32), jnp.asarray(ks), jnp.asarray(vs)]
+    np.testing.assert_allclose(np.asarray(paged_decode_mha(*args)),
+                               np.asarray(_paged_decode_ref(*args)),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("lens", [[0, 5, 64, 65], [200, 333, 79, 0]],
+                         ids=["inside_the_window", "ring_wrapped"])
+def test_live_page_walk_on_a_ring_turned_table(lens):
+    """A window layer's table as ``afmoe.forward_decode_paged`` hands it
+    over: a ring of pages in which position p lives at slot
+    (p // page_size) % ring, turned so that the window's first page comes
+    first, the lengths counted from that page."""
+    window, ring = 64, 5                       # 4 pages of window + 1
+    hq, hkv, d = GEOMETRIES["trinity_32_4_128"]
+    rs = np.random.RandomState(1)
+    b = len(lens)
+    kfull = rs.randn(b, max(lens) + 1, hkv, d).astype(np.float32)
+    vfull = rs.randn(b, max(lens) + 1, hkv, d).astype(np.float32)
+    q = rs.randn(b, hq, d).astype(np.float32)
+    ring_table = rs.permutation(b * ring).reshape(b, ring).astype(np.int32)
+    k = np.zeros((b * ring, PS, hkv, d), np.float32)
+    v = np.zeros_like(k)
+    for r, ln in enumerate(lens):             # later positions overwrite
+        for p in range(ln):
+            page = ring_table[r, (p // PS) % ring]
+            k[page, p % PS], v[page, p % PS] = kfull[r, p], vfull[r, p]
+    first = np.maximum(np.asarray(lens) - window, 0) // PS
+    turn = (first[:, None] + np.arange(ring)[None, :]) % ring
+    table = np.take_along_axis(ring_table, turn, axis=1)
+    got = paged_decode_mha(
+        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(table),
+        jnp.asarray(np.asarray(lens) - first * PS, jnp.int32), window=window)
+    want = np.zeros_like(q)
+    for r, ln in enumerate(lens):
+        if ln:
+            attended = slice(max(ln - window, 0), ln)
+            want[r] = _softmax_attend(q[r], kfull[r, attended],
+                                      vfull[r, attended])
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("window", [None, 40], ids=["full", "window"])
+def test_no_page_outside_a_rows_live_range_is_copied(window):
+    """Table entries outside a row's live range name a page the pool does
+    not have, and every page no live row holds is NaN: a copy of either
+    raises (out-of-bounds reads raise in this interpreter) or poisons the
+    output."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    lens = [0, 1, PS, PS + 1, 300, 0, MAXP * PS, 45]
+    hq, hkv, d = GEOMETRIES["mistral_32_8_128"]
+    q, k, v, table = _pool_and_table(lens, hq, hkv, d)
+    want = np.zeros_like(q)
+    held = np.zeros(k.shape[0], bool)
+    for r, ln in enumerate(lens):
+        lo = 0 if window is None else max(ln - window, 0)
+        first, last = lo // PS, -(-ln // PS)
+        held[table[r, first:last]] = True
+        if ln:
+            pages = table[r, :last]
+            want[r] = _softmax_attend(
+                q[r], k[pages].reshape(-1, hkv, d)[lo:ln],
+                v[pages].reshape(-1, hkv, d)[lo:ln])
+        table[r, :first] = k.shape[0] + 7
+        table[r, last:] = k.shape[0] + 7
+    k[~held], v[~held] = np.nan, np.nan
+    got = np.asarray(paged_decode_mha(
+        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(table),
+        jnp.asarray(lens, jnp.int32), window=window,
+        interpret=pltpu.InterpretParams(out_of_bounds_reads="raise")))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert not got[np.asarray(lens) == 0].any()
+
+
+class _SeenLengths:
+    """``paged_decode_mha`` replaced by a recorder of the lengths the
+    model hands it (the model looks the kernel up at call time)."""
+
+    def __init__(self, monkeypatch):
+        from paddle_tpu.ops import paged_attention
+
+        self.lens = []
+        real = paged_attention.paged_decode_mha
+
+        def record(q, k, v, table, seq_lens, *a, **kw):
+            self.lens.append(np.asarray(seq_lens).tolist())
+            return real(q, k, v, table, seq_lens, *a, **kw)
+
+        monkeypatch.setattr(paged_attention, "paged_decode_mha", record)
+
+
+def test_dead_row_reaches_the_kernel_with_length_0(monkeypatch):
+    """A retired slot keeps its ``lens``; ``live=False`` must hand the
+    kernel 0 for it, so that it walks no page."""
+    from paddle_tpu.inference.generation import PagedContinuousBatchingEngine
+    from paddle_tpu.models import LlamaForCausalLM, llama_config
+    import paddle_tpu as paddle
+
+    paddle.seed(0)
+    model = LlamaForCausalLM(llama_config("tiny", num_hidden_layers=2))
+    eng = PagedContinuousBatchingEngine(model, max_batch=3, num_pages=12,
+                                        page_size=4, max_pages=4)
+    seen = _SeenLengths(monkeypatch)
+    eng._fwd_ragged(eng.params, jnp.zeros((3, 1), jnp.int32), eng.caches,
+                    jnp.asarray([5, 9, 0], jnp.int32),
+                    jnp.asarray([True, False, False]))
+    assert seen.lens == [[6, 0, 0]] * 2
+
+
+def test_dead_row_reaches_the_kernel_with_length_0_window_layers(
+        monkeypatch):
+    """The same through the sparse decoder's two geometries: a window
+    layer's lengths count from the window's first page, and a dead row's
+    from 0."""
+    from test_moe_window_decoder import PAGE, WINDOW, tiny_engine, tiny_model
+
+    _, model, _ = tiny_model()
+    eng = tiny_engine(model)
+    seen = _SeenLengths(monkeypatch)
+    eng._fwd_ragged(eng.params, jnp.zeros((2, 1), jnp.int32), eng.caches,
+                    jnp.asarray([37, 50], jnp.int32),
+                    jnp.asarray([True, False]))
+    in_window = 38 - (38 - WINDOW) // PAGE * PAGE
+    # layer types S F S F
+    assert seen.lens == [[in_window, 0], [38, 0]] * 2
+
+
+def test_p_enters_the_second_product_unrounded():
+    """Over a bf16 pool ``q x k^T`` multiplies bf16 by bf16 (exact in the
+    float32 it accumulates in) and ``p x v`` float32 by float32 at the
+    highest precision: ``p`` is never rounded to the pool's dtype."""
+    from paddle_tpu.ops.paged_attention import _block_update
+
+    hq, hkv, d = GEOMETRIES["mistral_32_8_128"]
+    carry = (jnp.zeros((hq, 1)), jnp.zeros((hq, 1)), jnp.zeros((hq, d)))
+    q, k, v = (jnp.zeros(s, jnp.bfloat16)
+               for s in ((hq, d), (PS, hkv, d), (PS, hkv, d)))
+    jaxpr = jax.make_jaxpr(_block_update)(carry, q, k, v, 0, 0, 5, 0.1)
+    dots = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "dot_general"]
+    assert [[str(x.aval.dtype) for x in e.invars] for e in dots] == [
+        ["bfloat16", "bfloat16"], ["float32", "float32"]]
+    assert all(str(e.outvars[0].aval.dtype) == "float32" for e in dots)
+    assert all(pr == jax.lax.Precision.HIGHEST
+               for pr in dots[1].params["precision"])

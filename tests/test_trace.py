@@ -581,6 +581,27 @@ class TestServingTree:
         # driven without a scheduler the engine's spans are roots
         assert all(e["span.parent"] == 0 for e in segs)
 
+    def test_segment_page_counters(self, tr):
+        """``pages_live``: the pages the live rows' contexts span at the
+        segment's start (what ``paged_decode`` walks); ``pages_table``:
+        every entry of the table (what the grid kernel it replaced
+        walked). Pages of 4 tokens, a table of 4 rows x 8."""
+        model, mcfg = tiny_model()
+        eng = paged_engine(model)
+        try:
+            eng.add_request(_prompts(mcfg, 1, plen=6)[0], _greedy(12))
+            eng.decode_segment(4)                  # 6 + 1 = 7: 2 pages
+            eng.add_request(_prompts(mcfg, 1, plen=9, seed=1)[0],
+                            _greedy(3))
+            eng.decode_segment(4)                  # 11 and 10: 3 + 3
+            eng.decode_segment(4)                  # 15: 4; b retired
+        finally:
+            eng.close()
+        segs = [e for e in trace.events()
+                if e["phase"] == "engine.segment"]
+        assert [(e["pages_live"], e["pages_table"]) for e in segs] == [
+            (2, 32), (6, 32), (4, 32)]
+
     def test_chunked_and_warm_prefill_spans(self, tr):
         """A chunked admission's programs are children of its
         ``prefill_chunk`` spans (the claim and the mini under
