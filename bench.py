@@ -510,74 +510,6 @@ def vit_bench():
         "params": n_params, "platform": platform})
 
 
-def ragged_bench():
-    """Mixed-length decode throughput (VERDICT r3 #6): tokens/s on a
-    ragged batch must NOT degrade to the uniform-max-length cost — the
-    decode kernel's per-row seq_lens skip S-blocks past each row's length
-    (reference serves mixed lengths after remove_padding,
-    fused_multi_transformer_op.cu.h:1641). Prints one JSON line comparing
-    a batch of all-long rows vs the same batch with mixed lengths."""
-    import jax
-    import jax.numpy as jnp
-
-    platform = _platform()
-    on_tpu = platform == "tpu"
-
-    from paddle_tpu.inference.generation import (ContinuousBatchingEngine,
-                                                 GenerationConfig)
-    from paddle_tpu.models import LlamaForCausalLM, llama_config
-
-    if on_tpu:
-        cfg_m = llama_config("350m", dtype="bfloat16",
-                             num_attention_heads=8, num_key_value_heads=8,
-                             max_position_embeddings=2048)
-        B, max_len, steps = 8, 2048, 64
-        long_len = 1792
-        mixed = [128, 256, 384, 512, 768, 1024, 1536, 1792]
-    else:
-        cfg_m = llama_config("tiny")
-        B, max_len, steps = 4, 256, 16
-        long_len = 192
-        mixed = [16, 48, 96, 192]
-
-    model = LlamaForCausalLM(cfg_m)
-    model.eval()
-    rng = np.random.RandomState(0)
-    gcfg = GenerationConfig(max_new_tokens=steps + 1)
-
-    def rate(lens):
-        eng = ContinuousBatchingEngine(model, max_batch=B, max_len=max_len)
-        for n in lens:
-            eng.add_request(
-                rng.randint(0, cfg_m.vocab_size, (n,)).astype(np.int32),
-                gcfg)
-        seg = eng._segment_fn(steps)
-        args = (eng.params, eng.last, eng.lens, eng.done_dev,
-                eng.active_dev, eng.samp, eng._bank(), eng.caches)
-        key = jax.random.PRNGKey(0)
-        out = seg(*args, key)                      # compile + warm
-        _ = float(jnp.sum(out[0]))
-        eng.caches = out[4]
-        t0 = time.perf_counter()
-        out = seg(eng.params, out[1], out[2], out[3], eng.active_dev,
-                  eng.samp, eng._bank(), eng.caches, key)
-        _ = float(jnp.sum(out[0]))
-        dt = time.perf_counter() - t0
-        return B * steps / dt
-
-    uniform = rate([long_len] * B)
-    ragged = rate(mixed)
-    _emit({
-        "metric": "ragged_decode_speedup" if on_tpu
-        else "ragged_decode_speedup_tiny",
-        "value": round(ragged / uniform, 3), "unit": "x vs uniform-long",
-        "vs_baseline": round(ragged / uniform, 3),
-        "uniform_tok_s": round(uniform, 1),
-        "ragged_tok_s": round(ragged, 1),
-        "mean_len_ratio": round(sum(mixed) / (long_len * len(mixed)), 3),
-        "platform": platform})
-
-
 def hybrid_bench():
     """BASELINE config 3 (Llama-2 13B/65B hybrid TP x PP x sharding):
     COMPILE-ONLY per-device memory feasibility at real dims over virtual
@@ -679,8 +611,6 @@ if __name__ == "__main__":
         vit_bench()
     elif mode == "hybrid":
         hybrid_bench()
-    elif mode == "ragged":
-        ragged_bench()
     elif mode == "train":
         main(sys.argv[2] if len(sys.argv) > 2 else "350m")
     elif mode == "1.3b":
@@ -688,4 +618,4 @@ if __name__ == "__main__":
     else:
         raise SystemExit(
             f"unknown bench mode {mode!r} "
-            "(train|decode|spec|resnet|moe|vit|1.3b|hybrid|ragged)")
+            "(train|decode|spec|resnet|moe|vit|1.3b|hybrid)")

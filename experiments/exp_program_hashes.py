@@ -1,0 +1,118 @@
+"""sha256 of the lowered text of the serving engine's programs, for one fixed
+tiny dense model and one tiny sparse-expert, window-attention model.
+
+A refactor of the engine that must not change what the chip runs is held to
+this: run it at the parent commit and at the change and compare the columns
+(`PERF.md` section 6, PR 30). Lowering traces and never compiles, so it
+runs on the CPU in seconds:
+
+    JAX_PLATFORMS=cpu python experiments/exp_program_hashes.py [REPO_ROOT]
+"""
+import hashlib
+import os
+import sys
+
+ROOT = os.path.abspath(sys.argv[1] if len(sys.argv) > 1 else
+                       os.path.join(os.path.dirname(__file__), ".."))
+sys.path.insert(0, ROOT)
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+import paddle_tpu as paddle  # noqa: E402
+from paddle_tpu.inference.generation import \
+    PagedContinuousBatchingEngine  # noqa: E402
+from paddle_tpu.models import LlamaForCausalLM, llama_config  # noqa: E402
+from paddle_tpu.models.afmoe import (AfmoeConfig,  # noqa: E402
+                                     AfmoeForCausalLM)
+
+STEPS, WIDTH, CHUNK, DRAFT_K = 4, 16, 8, 3
+
+
+def sha(lowered) -> str:
+    return hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
+
+
+def dense_model():
+    paddle.seed(0)
+    model = LlamaForCausalLM(llama_config(
+        "tiny", num_hidden_layers=2, num_key_value_heads=2))
+    model.eval()
+    return model
+
+
+def sparse_model():
+    paddle.seed(3)
+    model = AfmoeForCausalLM(AfmoeConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        moe_intermediate_size=32, num_hidden_layers=4,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        sliding_window=16, num_dense_layers=1, num_experts=8,
+        num_experts_per_tok=2,
+        layer_types=["sliding_attention", "full_attention",
+                     "sliding_attention", "full_attention"]))
+    model.eval()
+    return model
+
+
+def programs(eng):
+    """(name, lowered) of each program the engine can run, with the
+    arguments the engine itself passes."""
+    mb = eng.max_batch
+    key = jax.random.PRNGKey(0)
+    pools, pt = eng.caches
+    yield "jit_prefill_one", eng._prefill_paged._jitted.lower(
+        eng.params, np.zeros((1, WIDTH), np.int32), pools, pt, np.int32(0),
+        np.int32(WIDTH), eng._bank(), np.int32(0))
+    yield "jit_segment", eng._segment_fn(STEPS)._jitted.lower(
+        eng.params, eng.last, eng.lens, eng.done_dev, eng.active_dev,
+        eng.samp, eng._bank(), eng.caches, key)
+    yield "cb_admit_state", eng._admit_state._jitted.lower(
+        eng.lens, eng.last, eng.done_dev, eng.active_dev, eng.samp,
+        eng.hist, eng.hist_len, np.int32(0), np.int32(5), jnp.int32(1),
+        jnp.asarray(False), np.float32(1.0), np.int32(0), np.float32(1.0),
+        np.bool_(False), np.int32(-1), np.int32(0), np.int32(0),
+        np.int32(0), np.zeros((eng.spec_history,), np.int32), np.int32(0))
+    if eng.prefill_chunk is not None:
+        yield "cb_prefill_chunk", eng._prefill_chunk._jitted.lower(
+            eng.params, np.zeros((1, eng.prefill_chunk), np.int32),
+            eng._mini_cache(eng.max_len), jnp.int32(0), jnp.int32(0),
+            eng._bank(), jnp.int32(0))
+    if eng.draft_k and eng.spec_mode == "host":
+        yield "cb_spec_step", eng._spec_step_fn()._jitted.lower(
+            eng.params, eng.last, eng.lens, eng.active_dev, eng.samp,
+            eng._bank(), eng.caches, key,
+            jnp.zeros((mb, eng.draft_k), jnp.int32), jnp.zeros((mb,), bool),
+            jnp.zeros((mb,), jnp.int32))
+    if eng.draft_k and eng.spec_mode == "device":
+        yield ("cb_spec_device_segment",
+               eng._spec_segment_device_fn(STEPS)._jitted.lower(
+                   eng.params, eng.last, eng.lens, eng.done_dev,
+                   eng.active_dev, eng.samp, eng._bank(), eng.caches,
+                   eng.hist, eng.hist_len, jnp.zeros((mb,), jnp.int32),
+                   jnp.zeros((mb,), jnp.int32), key))
+
+
+def main():
+    geometry = dict(max_batch=2, num_pages=16, page_size=8, max_pages=8,
+                    prefill_buckets=[WIDTH, 64])
+    engines = [
+        ("dense", dense_model, dict(prefill_chunk=CHUNK)),
+        ("dense int8+lora", dense_model,
+         dict(kv_dtype="int8", lora_capacity=2, lora_rank=2)),
+        ("dense spec host", dense_model, dict(draft_k=DRAFT_K)),
+        ("dense spec device", dense_model,
+         dict(draft_k=DRAFT_K, spec_mode="device")),
+        ("afmoe", sparse_model, dict(num_pages=64, page_size=4,
+                                     max_pages=16)),
+    ]
+    for tag, make, kw in engines:
+        eng = PagedContinuousBatchingEngine(make(), **{**geometry, **kw})
+        for name, lowered in programs(eng):
+            print(f"{tag:18s} {name:24s} {sha(lowered)}")
+        eng.close()
+
+
+if __name__ == "__main__":
+    main()

@@ -27,7 +27,6 @@ from ..nn.functional_call import substituted_state
 from .ngram import NgramIndex, NgramProposer, propose_device
 
 __all__ = ["GenerationConfig", "CausalLMEngine",
-           "ContinuousBatchingEngine",
            "PagedContinuousBatchingEngine", "prefill_buckets_for",
            "RequestFault", "EngineFault", "classify_fault",
            "REQUEST_SITES", "PagePoolExhausted", "ADMISSION_MODES",
@@ -65,7 +64,7 @@ class RequestFault(RuntimeError):
 class EngineFault(RuntimeError):
     """A fault that poisons the ENGINE's device state (e.g. a device
     error mid decode segment): the supervisor must rebuild state
-    (:meth:`ContinuousBatchingEngine.reset_state`) and replay in-flight
+    (:meth:`PagedContinuousBatchingEngine.reset_state`) and replay in-flight
     requests from their stored prompt + tokens emitted so far."""
 
 
@@ -77,7 +76,7 @@ REQUEST_SITES = frozenset({"admit", "prefill", "chunk"})
 # paged-engine admission policies (see PagedContinuousBatchingEngine)
 ADMISSION_MODES = ("reserved", "optimistic")
 
-# speculative-decoding execution modes (see ContinuousBatchingEngine):
+# speculative-decoding execution modes (see PagedContinuousBatchingEngine):
 # "host" proposes on host with a device→host readback per verify step;
 # "device" fuses propose→verify→accept into one compiled segment loop
 # (the history ring IS the draft source — one readback per segment)
@@ -281,7 +280,7 @@ class GenerationConfig:
         self.eos_token_id = (None if eos_token_id is None
                              else int(eos_token_id))
         self.seed = int(seed)
-        # speculative decoding opt-in (continuous-batching engines
+        # speculative decoding opt-in (continuous-batching engine
         # built with draft_k > 0): greedy requests propose/verify
         # n-gram drafts per segment step; sampled requests fall back to
         # plain decode (lossless acceptance needs the argmax target).
@@ -321,11 +320,11 @@ def _sample_rows(logits, key, samp):
     parameter (greedy-vs-sample, temperature, top-k, top-p, eos) is a
     per-slot device VECTOR installed at admission, not a trace constant
     — so ONE compiled segment program serves any mix of per-request
-    GenerationConfigs (the continuous-batching engines' online form;
+    GenerationConfigs (the continuous-batching engine's online form;
     the old cfg-keyed specialization recompiled per distinct config).
 
     Greedy rows reduce to the exact argmax `_sample` computes, so mixed
-    batches keep bitwise greedy parity with the dense engine. Rows with
+    batches keep bitwise greedy parity with ``CausalLMEngine``. Rows with
     top_k == 0 / top_p == 1.0 skip those filters (same gating as
     `_sample`'s `if` branches, expressed as masks).
 
@@ -673,8 +672,8 @@ def _lora_rows(bank, aidx, ids):
 
 
 class _ChunkedAdmission:
-    """Host-side state of one in-flight CHUNKED admission. The slot (and,
-    paged, the request's worst-case pages) is already claimed; ``mini``
+    """Host-side state of one in-flight CHUNKED admission. The slot (and
+    the request's claim of pages) is already taken; ``mini``
     accumulates the prompt's KV chunk by chunk until the final chunk
     installs it and the request goes live under ``rid``. Drive with
     ``engine.admit_chunk``; reclaim with ``engine.abort_admit``."""
@@ -699,54 +698,173 @@ class _ChunkedAdmission:
         self.last_logits = None
 
 
-class ContinuousBatchingEngine:
-    """Ragged / continuous batching decode service.
+class PagedContinuousBatchingEngine:
+    """Continuous batching over a PAGED KV pool: the engine that serves.
 
-    The dense :class:`CausalLMEngine` serves one common-length batch per
-    ``generate()``. The reference's decode kernel instead removes padding
-    and serves MIXED-length batches with per-sequence lengths
-    (fused_multi_transformer_op.cu.h:1641 remove_padding, :1680 the
-    length-indexed masked MHA). This engine is the TPU-native equivalent:
+    :class:`CausalLMEngine` runs one common-length batch per
+    ``generate()`` over dense slabs and is the independent reference
+    served tokens are held to. This engine serves MIXED-length traffic
+    (the reference's fused_multi_transformer_op.cu.h:1641 remove_padding
+    and :1680 length-indexed masked MHA; the page layout is vLLM's, Kwon
+    et al. SOSP'23):
 
-    - a fixed pool of ``max_batch`` cache SLOTS, each with its own
-      ``seq_len`` (the decode_mha kernel's per-row ``seq_lens`` vector —
-      its S-block grid skips blocks past each row's length, so a short
-      row costs O(its length), not O(max_len));
-    - requests are ADMITTED into free slots between jitted decode
-      segments (prefill is per-request B=1, its rows scattered into the
-      pool), and finished rows are retired between segments — new work
-      starts without waiting for the longest running request;
-    - one compiled segment program serves every slot occupancy pattern
-      AND every mix of per-request GenerationConfigs (slot ids, lengths
-      and sampling parameters are traced values, not shapes or trace
-      constants — see ``_sample_rows``);
-    - prefill compiles are BOUNDED: prompts pad to ``prefill_buckets``
-      (default powers of two — len(buckets) compiled prefill programs,
-      not one per distinct prompt length, all pre-compilable via
-      :meth:`warmup`), and prompts longer than ``prefill_chunk`` can
-      admit chunk-by-chunk across inter-segment gaps
-      (:meth:`begin_admit` / :meth:`admit_chunk`) so one long prompt
-      never monopolizes the gap. Both are numerically exact — see
-      PERF.md "Prefill cost".
+    - ``max_batch`` SLOTS, each a page-table row into shared per-layer
+      pools: HBM holds ``num_pages * page_size`` tokens (the tokens in
+      flight), any free page serves any slot, and a row's decode
+      attention walks the pages ITS length spans. A slot holds at most
+      ``max_len = max_pages * page_size`` positions;
+    - requests are ADMITTED into free slots and finished rows retire
+      between jitted decode segments, so new work never waits for the
+      longest running request. A cold admission is ONE program per
+      prompt bucket (``jit_prefill_one``: a bucket-wide mini cache, the
+      prefill, the scatter of every layer's rows into the claimed
+      pages); ``prefill_buckets`` bounds the compiles and
+      :meth:`warmup` runs them all ahead of traffic; with
+      ``prefill_chunk`` a long prompt admits chunk by chunk across gaps
+      (:meth:`begin_admit` / :meth:`admit_chunk`);
+    - ONE segment program (``jit_segment``) serves every occupancy
+      pattern and every mix of GenerationConfigs and LoRA adapters:
+      slot ids, lengths, sampling parameters and adapter indices are
+      traced per-slot vectors, never shapes or trace constants.
+
+    The model contract: ``forward`` (training, plain inference),
+    ``forward_with_cache`` (prefill here; ``CausalLMEngine``'s decode
+    too), ``init_paged_cache`` + ``forward_decode_paged`` (a decode step
+    through the page table) and, where it speculates,
+    ``forward_decode_spec_paged`` (the W-position verify step). A model
+    whose layers keep KV in two geometries (full and sliding-window
+    attention) also gives ``paged_layout``: the engine then keeps two
+    page tables (``paged_cache.WindowedPageAllocator``) and refuses, by
+    name, the features whose programs do not read a ring of pages.
+
+    ``admission_mode``: ``"reserved"`` (default) claims a request's
+    worst case (prompt + max_new_tokens) at admission, so a running
+    request can never exhaust the pool; ``"optimistic"`` claims the
+    prompt plus one page and grows each live slot per gap
+    (:meth:`grow_for_segment`). When growth cannot be met the CALLER
+    relieves pressure with :meth:`preempt_request` (greedy
+    preempt-resume is bitwise an unpreempted run) or
+    ``decode_segment`` raises :class:`PagePoolExhausted`, never a
+    silent dropped write; ``kv_watermark`` pauses new admissions while
+    the pool is under pressure.
+
+    ``prefix_cache=True``: admission hashes the prompt in page_size
+    blocks, maps resident blocks READ-ONLY into the slot's table (only
+    the uncached tail is computed, at a traced offset), and the first
+    write into a shared page is copied on write in the gap; released
+    cached pages park in an LRU the pool reclaims on demand.
+    ``kv_dtype="int8"``: int8 pages with per-(page, kv_head)
+    running-absmax scales (``quantization.kv``). ``draft_k > 0``:
+    n-gram speculative decoding per slot (``spec_mode`` ``"host"`` or a
+    fused ``"device"`` segment). ``lora_capacity > 0``: a hot-loadable
+    adapter bank. ``tp_degree > 1``: weights and pools sharded on the
+    head axis of a 1-D mesh (``inference/tp.py``). ``debug_pages=True``
+    runs the allocator's invariant checks at every gap.
 
     Usage::
 
-        eng = ContinuousBatchingEngine(model, max_batch=4, max_len=512)
+        eng = PagedContinuousBatchingEngine(
+            model, max_batch=4, num_pages=256, page_size=16, max_pages=32)
         outs = eng.serve([ids1, ids2, ...], GenerationConfig(...))
     """
 
-    def __init__(self, model, max_batch: int, max_len: int,
+    def __init__(self, model, max_batch: int, num_pages: int,
+                 page_size: int, max_pages: int,
                  prefill_buckets="auto",
                  prefill_chunk: Optional[int] = None,
+                 admission_mode: str = "reserved",
+                 kv_watermark: float = 0.9,
+                 debug_pages: bool = False,
+                 prefix_cache: bool = False,
+                 kv_dtype: str = "bf16",
                  draft_k: int = 0, ngram_max: int = 3,
                  spec_mode: str = "host", spec_draft: str = "ngram",
                  spec_history: int = 128,
                  lora_capacity: int = 0, lora_rank: int = 8,
                  lora_targets=("q", "k", "v", "o"),
                  tp_degree: int = 1, tp_devices=None):
+        from ..quantization.kv import KV_DTYPES
+        from .paged_cache import PageAllocator
         from .tp import (TP_AXIS, make_tp_mesh, shard_params_tp,
                          validate_tp_model)
 
+        if admission_mode not in ADMISSION_MODES:
+            raise ValueError(
+                f"admission_mode must be one of {ADMISSION_MODES}, got "
+                f"{admission_mode!r}")
+        if not (isinstance(kv_watermark, (int, float))
+                and 0 < kv_watermark <= 1):
+            raise ValueError(
+                f"kv_watermark must satisfy 0 < w <= 1 (fraction of "
+                f"the page pool), got {kv_watermark!r}")
+        if kv_dtype not in KV_DTYPES:
+            raise ValueError(
+                f"kv_dtype must be one of {KV_DTYPES}, got "
+                f"{kv_dtype!r}")
+        self.admission_mode = admission_mode
+        self.kv_watermark = float(kv_watermark)
+        self.prefix_cache = bool(prefix_cache)
+        # overload-control actuator (serving.control brownout rung 4):
+        # while True, NEW admissions skip prefix-cache lookup/insert
+        # and take the plain cold path (already warmed — pausing
+        # compiles nothing, and no CoW/shared pages are minted under
+        # pressure). Resident cached blocks stay mapped; in-flight
+        # warm admissions finish normally. Host bool, flipped by the
+        # serving scheduler thread between segments.
+        self.prefix_pause = False
+        # KV page storage: "bf16" = the model's cache dtype, bitwise
+        # the pre-quantization path; "int8" stores pages int8 with
+        # per-(page, kv_head) running-absmax scales riding the page
+        # table — half the bytes per decode read, ~2x the pages at
+        # fixed HBM, correctness bar bounded-not-bitwise (see
+        # quantization.kv). Must be set before _init_decode_state
+        # builds the pools.
+        self.kv_dtype = kv_dtype
+        # slot -> warm-admission info ({"ids","c_map","hashes","saved"})
+        # staged from the admission's lookup until its rows are in the
+        # pages; popped by _index_prompt / _abort_admit
+        self._prefix_stash = {}
+        # segment count a clean grow_for_segment covered; decode_segment
+        # consumes it to skip its (device-syncing) exhaustion re-check
+        self._growth_stamp: Optional[int] = None
+        # (lens, done) host copies shared by every grow_for_segment call
+        # in ONE gap — relief that preempts k victims re-runs the grow
+        # loop k+1 times, but lens/done only change when a segment runs
+        # (decode) or a slot admits (_register), both of which clear it
+        self._gap_sync = None
+        self.num_pages = num_pages
+        self.page_size = page_size
+        max_len = max_pages * page_size
+        # a model whose layers keep their KV in TWO geometries (full and
+        # sliding-window attention side by side) says so; None = one
+        # table serves every layer
+        layout = getattr(model, "paged_layout", None)
+        self._layout = layout(page_size) if layout is not None else None
+        if self._layout is not None:
+            refused = {"tp_degree": tp_degree != 1,
+                       "kv_dtype='int8'": kv_dtype != "bf16",
+                       "draft_k (speculative decoding)": draft_k != 0,
+                       "prefix_cache": prefix_cache,
+                       "prefill_chunk": prefill_chunk is not None,
+                       "lora_capacity (LoRA)": lora_capacity != 0}
+            for feature, asked in refused.items():
+                if asked:
+                    raise ValueError(
+                        f"{feature} is not implemented for a model with "
+                        f"sliding-window layers or routed experts "
+                        f"({type(model).__name__}): its window layers "
+                        f"keep a ring of pages that this feature's "
+                        f"programs do not read or write")
+            from .paged_cache import WindowedPageAllocator
+
+            self.alloc = WindowedPageAllocator(
+                num_pages, page_size, max_batch, max_pages,
+                self._layout["ring_pages"], debug=debug_pages)
+        else:
+            self.alloc = PageAllocator(num_pages, page_size, max_batch,
+                                       max_pages, debug=debug_pages,
+                                       prefix_cache=prefix_cache,
+                                       kv_dtype=kv_dtype)
         if (isinstance(draft_k, bool)
                 or not isinstance(draft_k, (int, np.integer))
                 or not 0 <= draft_k <= 256):
@@ -875,18 +993,38 @@ class ContinuousBatchingEngine:
         self._next_req = 0
         self._segments_run = 0         # PRNG stream position for sampling
 
-        def prefill_one(params, ids, mini, last_idx, bank, aidx):
-            # last_idx (the true last prompt position of a BUCKET-padded
-            # prompt) is traced: compiled programs are keyed per bucket
-            # width, not per prompt length. bank/aidx are the LoRA
-            # inputs (aidx traced — one program serves every adapter;
-            # an empty bank is trace-static and falls back to the
-            # exact pre-LoRA prefill)
+        def prefill_one(params, ids, pools, page_table, slot, plen, bank,
+                        aidx):
+            # a cold one-shot admission is this ONE program per bucket:
+            # the bucket-wide mini cache is made in here (zeros XLA need
+            # not materialise), the prefill runs on it, and every
+            # layer's rows go into the DONATED pools by write_tokens'
+            # own arithmetic (unmapped pages drop; int8 drops past
+            # plen). slot / plen / aidx are traced: programs are keyed
+            # per bucket width, not per prompt length, and one program
+            # serves every adapter (an empty bank is trace-static and
+            # falls back to the exact pre-LoRA prefill)
+            from .paged_cache import write_prompt
+
+            mini = self._tp_kv(self.model.init_cache(1, ids.shape[1]))
+            if self._layout is not None:
+                # the model is told the last position: it computes that
+                # position's logits alone and routes no padding, and its
+                # window layers' rows go into their rings
+                logits, mini = self._fwd_prefill(params, ids, mini,
+                                                 last_idx=plen - 1)
+                return (logits[:, 0], write_prompt(
+                    pools, page_table, slot, plen, mini,
+                    window_layers=self._layout["window_layers"]))
             logits, mini = self._fwd_prefill(
                 params, ids, mini, lora=_lora_rows(bank, aidx, ids))
-            return logits[:, last_idx], mini
+            return (logits[:, plen - 1],
+                    write_prompt(pools, page_table, slot, plen, mini))
 
-        self._prefill = monitor.monitored_jit(
+        # monitor "cb_prefill", XLA module jit_prefill_one: the miss
+        # counters and the benchmark's readers find a prompt's prefill
+        # by them
+        self._prefill_paged = monitor.monitored_jit(
             prefill_one, name="cb_prefill",
             owner=self._monitor_engine, donate_argnums=(2,))
 
@@ -903,8 +1041,9 @@ class ContinuousBatchingEngine:
             owner=self._monitor_engine, donate_argnums=(2,))
 
         def mini_cache(width):
-            # one admission's B=1 dense mini cache, where a prefill
-            # writes the prompt's KV before it installs into the pool:
+            # a B=1 dense mini cache that OUTLIVES a program (a warm
+            # prefix hit's, a chunked admission's), where chunks write
+            # the prompt's KV before it installs into the pages:
             # every layer's zeros in ONE program per width (eager, a
             # layer's two jnp.zeros were 2L dispatches an admission),
             # sharded on the head axis like the pool they feed, so the
@@ -913,17 +1052,6 @@ class ContinuousBatchingEngine:
             return self._tp_kv(self.model.init_cache(1, width))
 
         self._mini_cache = jax.jit(mini_cache, static_argnums=(0,))
-
-        def admit(caches, mini, slot):
-            return jax.tree.map(
-                lambda c, m: jax.lax.dynamic_update_slice_in_dim(
-                    c, m.astype(c.dtype), slot, axis=0), caches, mini)
-
-        # mini is NOT donated: its rows are dtype-cast into the pool, so
-        # the buffers can't alias (donation would only warn)
-        self._admit = monitor.monitored_jit(admit, name="cb_admit",
-                                            owner=self._monitor_engine,
-                                            donate_argnums=(0,))
 
         H = self.spec_history
 
@@ -965,6 +1093,25 @@ class ContinuousBatchingEngine:
             owner=self._monitor_engine,
             donate_argnums=(0, 1, 2, 3, 4, 5, 6))
         self._segment_cache = {}
+        self._measure_quant_savings()
+
+        def reset_scales(pools, mask):
+            # ONE fixed-shape program per pool shape: freshly claimed
+            # pages' scale rows (a previous owner's absmax leftovers)
+            # drop to the floor before any write — per-page dispatches
+            # or a count-shaped index vector would recompile per gap
+            from ..quantization.kv import KV_SCALE_FLOOR
+
+            out = []
+            for kp, vp, ks, vs in pools:
+                ks = jnp.where(mask[:, None], KV_SCALE_FLOOR, ks)
+                vs = jnp.where(mask[:, None], KV_SCALE_FLOOR, vs)
+                out.append((kp, vp, ks, vs))
+            return out
+
+        self._reset_scales = monitor.monitored_jit(
+            reset_scales, name="cb_reset_scales",
+            owner=self._monitor_engine, donate_argnums=(0,))
 
     def _init_decode_state(self) -> None:
         """Fresh device-side decode state: caches, per-slot scalars,
@@ -1048,10 +1195,113 @@ class ContinuousBatchingEngine:
         return tp_shard_kv(caches, self.tp_mesh)
 
     def _make_caches(self):
-        """Cache layout hook — the paged subclass replaces the dense
-        [max_batch, max_len] slabs with page pools."""
-        return self._tp_kv(
-            self.model.init_cache(self.max_batch, self.max_len))
+        """``(pools, page tables)``: the per-layer page pools and the
+        host page table(s) as device arrays. TP: pools (and int8 scales)
+        shard on the kv-head axis; the page TABLE replicates — page
+        indices are mesh-invariant, so the allocator/prefix-cache host
+        logic needs no fork."""
+        if self.kv_dtype == "int8":
+            try:
+                pools = self.model.init_paged_cache(
+                    self.num_pages, self.page_size, kv_dtype="int8")
+            except TypeError as e:
+                raise ValueError(
+                    f"kv_dtype='int8' needs a model whose "
+                    f"init_paged_cache accepts kv_dtype (llama does); "
+                    f"{type(self.model).__name__} does not") from e
+            return self._tp_kv(pools), self._device_tables()
+        if self._layout is not None:
+            return (self.model.init_paged_cache(
+                        self.num_pages, self.page_size,
+                        window_pages=self.alloc.window.num_pages),
+                    self._device_tables())
+        return (self._tp_kv(self.model.init_paged_cache(
+                    self.num_pages, self.page_size)),
+                self._device_tables())
+
+    def _device_tables(self):
+        """The host page table(s) as the device programs take them: one
+        array, or ``(full, ring)`` for a model with window layers."""
+        if self._layout is not None:
+            return tuple(jnp.asarray(t) for t in self.alloc.tables())
+        return self._tp_rep(jnp.asarray(self.alloc.page_table))
+
+    def _measure_quant_savings(self) -> None:
+        """Price the int8 layout from the REAL pool arrays: HBM bytes
+        per page a bf16 pool would need minus what the int8 pools +
+        scales actually take — the allocator counts it per claimed
+        page (``paddle_tpu_kv_quant_bytes_saved_total``)."""
+        if self.kv_dtype != "int8":
+            self.alloc.bytes_saved_per_page = 0
+            return
+        pools, _ = self.caches
+        base = quant = 0
+        for kp, vp, ks, vs in pools:
+            base += (kp.size + vp.size) * 2          # bf16 baseline
+            quant += (kp.nbytes + vp.nbytes + ks.nbytes + vs.nbytes)
+        self.alloc.bytes_saved_per_page = max(
+            (base - quant) // self.num_pages, 0)
+
+    def kv_page_cost(self) -> dict:
+        """HBM cost of one page under the current storage dtype:
+        ``{"bytes_per_page"}`` is the actual cost (scales included);
+        ``{"bf16_equiv_bytes_per_page"}`` prices the SAME page at
+        2 bytes/element — the production-baseline denominator for
+        serve_bench's effective-capacity record, independent of the
+        CPU test model's f32 cache dtype."""
+        pools, _ = self.caches
+        total = elems = 0
+        for entry in pools:
+            total += sum(a.nbytes for a in entry)
+            elems += entry[0].size + entry[1].size
+        return {"bytes_per_page": total // self.num_pages,
+                "bf16_equiv_bytes_per_page":
+                    2 * elems // self.num_pages}
+
+    def set_kv_dtype(self, kv_dtype: str) -> None:
+        """Swap the pool storage dtype on an IDLE engine (the
+        ``Server(kv_dtype=...)`` mirror hook): rebuilds the pools —
+        any cached prefix KV dies with them, so the content index
+        clears too — and keeps every compiled program (the other
+        dtype's variants stay cached; warmup covers the new ones)."""
+        from ..quantization.kv import KV_DTYPES
+
+        if kv_dtype not in KV_DTYPES:
+            raise ValueError(
+                f"kv_dtype must be one of {KV_DTYPES}, got "
+                f"{kv_dtype!r}")
+        if kv_dtype == self.kv_dtype:
+            return
+        if self._slot_req:
+            raise RuntimeError(
+                "kv_dtype can only be changed on an idle engine")
+        # old pools dropped before the new ones allocate (reset_state's
+        # peak-HBM argument applies here too)
+        self.caches = None
+        self.alloc.clear_prefix_index()
+        self.alloc.set_kv_dtype(kv_dtype)
+        self.kv_dtype = kv_dtype
+        self._prefix_stash.clear()
+        self._growth_stamp = None
+        self._gap_sync = None
+        self.caches = self._make_caches()
+        self._measure_quant_savings()
+
+    def _flush_fresh_scales(self) -> None:
+        """Reset freshly claimed pages' scale rows to the floor (int8;
+        one masked fixed-shape program) — runs at the write choke
+        points (cache install, pre-segment) so no quantized store ever
+        runs absmax against a previous owner's scales."""
+        if self.kv_dtype != "int8":
+            return
+        fresh = self.alloc.take_fresh_scales()
+        if not fresh:
+            return
+        mask = np.zeros((self.num_pages,), bool)
+        mask[fresh] = True
+        pools, pt = self.caches
+        self.caches = (self._reset_scales(pools, jnp.asarray(mask)),
+                       pt)
 
     def _bank(self) -> dict:
         """The LoRA factor bank to pass into the jitted serving
@@ -1083,25 +1333,63 @@ class ContinuousBatchingEngine:
                 caches)
 
     def _fwd_ragged(self, params, tok, caches, lens, live, lora=None):
-        """One decode step: ``(logits, caches, aux)``. ``aux`` is a dict
-        of int32 counters the model's step hands out (the paged engine's,
-        for a model that routes experts) or None; the segment program
-        sums them over its steps and returns them beside its tokens."""
+        """One decode step through the page table: ``(logits, caches,
+        aux)``. ``aux`` is a dict of int32 counters the model's step
+        hands out (a model that routes experts returns its routing
+        counts) or None; the segment program sums them over its steps
+        and returns them beside its tokens."""
         from ..core.autograd import no_grad
 
+        pools, pt = caches
         with substituted_state(self.model, params), no_grad():
-            logits, caches = self.model.forward_decode_ragged(
-                Tensor(tok), caches, lens, live,
+            logits, pools, *aux = self.model.forward_decode_paged(
+                Tensor(tok), pools, pt, lens, live,
                 **self._fwd_kwargs(lora))
         return (logits.value if isinstance(logits, Tensor) else logits,
-                caches, None)
+                (pools, pt), aux[0] if aux else None)
 
-    # -- admission / retirement (host-side, between segments) ---------------
+    def _reserved(self, plen: int, cfg) -> int:
+        return min(plen + cfg.max_new_tokens, self.max_len)
+
+    def _optimistic_claim(self, plen: int, cfg) -> int:
+        """Tokens an OPTIMISTIC admission claims up front: the prompt
+        plus one page of headroom (the first decode step writes at
+        position ``plen``, so bare-prompt coverage would force growth
+        before the very first segment), never more than the worst case
+        the reserved policy would take."""
+        return min(plen + self.page_size, self._reserved(plen, cfg))
+
     def _can_admit(self, prompt_len: int, cfg) -> bool:
-        """Whether the head-of-queue request fits RIGHT NOW (a free slot
-        is assumed). The paged subclass adds page-pool capacity; serve()
-        consults this so a transiently full pool defers admission to the
-        next inter-segment gap instead of raising mid-loop."""
+        """Whether the head-of-queue request's pages fit RIGHT NOW (a
+        free slot is assumed). serve() consults this so a transiently
+        full pool defers admission to the next inter-segment gap
+        instead of raising mid-loop."""
+        # any free slot owns zero pages, so capacity is slot-agnostic.
+        # Prefix caching never tightens this probe: a warm admission
+        # claims at most what a cold one would (shared pages count as
+        # coverage), and when the pool cannot also spare the one
+        # copy-on-write page a partial-block hit needs, admission
+        # DEGRADES the hit to full blocks instead of demanding more
+        # (so a request whose worst case exactly fills the pool still
+        # admits). can_admit saying yes must mean add_request cannot
+        # raise for capacity.
+        probe = self._free[0] if self._free else 0
+        if self.admission_mode == "reserved":
+            return self.alloc.can_fit(probe,
+                                      self._reserved(prompt_len, cfg))
+        claim = self._optimistic_claim(prompt_len, cfg)
+        if not self.alloc.can_fit(probe, claim):
+            return False
+        if self._slot_req:
+            # high watermark: while running requests already crowd the
+            # pool, pause NEW admissions before growth pressure forces
+            # a preemption — running work frees pages by finishing. An
+            # IDLE pool skips the watermark (a lone request must always
+            # be able to admit, or a big claim could wedge forever).
+            used_after = (self.alloc.used_pages
+                          + self.alloc.pages_for(claim))
+            if used_after > self.kv_watermark * self.num_pages:
+                return False
         return True
 
     def free_slots(self) -> int:
@@ -1112,8 +1400,8 @@ class ContinuousBatchingEngine:
 
     def load(self) -> dict:  # lint: hot-path
         """Host-side load snapshot: ``{"free_slots", "active_slots",
-        "max_batch"}`` plus, paged, ``{"free_pages", "total_pages",
-        "occupancy"}``. Everything is host bookkeeping already
+        "max_batch", "max_len", "tp_degree", "free_pages",
+        "total_pages", "occupancy", "kv_dtype"}``. Everything is host bookkeeping already
         maintained between segments — NO device sync, no HTTP, no lock
         beyond what the ints themselves need — so a health endpoint or
         a replica router can read it at any time, including while the
@@ -1133,23 +1421,22 @@ class ContinuousBatchingEngine:
                 "axis": self.tp_mesh.axis_names[0],
                 "devices": [str(d)
                             for d in self.tp_mesh.devices.flat]}
-        alloc = getattr(self, "alloc", None)
-        if alloc is not None:
-            out["free_pages"] = alloc.free_pages
-            out["total_pages"] = alloc.num_pages
-            out["occupancy"] = round(alloc.occupancy, 4)
+        out["free_pages"] = self.alloc.free_pages
+        out["total_pages"] = self.alloc.num_pages
+        out["occupancy"] = round(self.alloc.occupancy, 4)
         if self.adapters is not None:
             # registry snapshot (resident/draining names, capacity) —
             # host dict reads only; the router's adapter-affinity
             # scoring and /healthz both consume it
             out["lora"] = self.adapters.resident()
+        out["kv_dtype"] = self.kv_dtype
         return out
 
     def can_admit(self, prompt_len: int, cfg: GenerationConfig) -> bool:
         """Non-raising admission probe: True iff ``add_request`` with a
         ``prompt_len``-token prompt and ``cfg`` would succeed RIGHT NOW
-        (a free slot exists, the request fits ``max_len``, and — paged —
-        the page pool can reserve its worst case).
+        (a free slot exists, the request fits ``max_len``, and the page
+        pool can take its claim).
 
         Contract: schedulers consult THIS and treat False as "defer to
         the next inter-segment gap" (or reject with backpressure);
@@ -1184,9 +1471,9 @@ class ContinuousBatchingEngine:
             last_logits = self._admit_cache(slot, ids, plen, cfg)
         except BaseException:
             # a failed admission must not leak capacity: the popped
-            # slot (and, paged, any page reservation _admit_cache made;
-            # LoRA, the adapter reference) goes back to the pool before
-            # the error propagates
+            # slot (and any page reservation _admit_cache made; LoRA,
+            # the adapter reference) goes back to the pool before the
+            # error propagates
             self._abort_admit(slot)
             raise
         return self._first_token(slot, rid, ids, plen, last_logits, cfg,
@@ -1283,9 +1570,8 @@ class ContinuousBatchingEngine:
     def _install_state(self, slot: int, plen: int, first, tok_done,
                        cfg, aidx: int = 0, ids=None) -> None:
         """Install the request's per-slot scalars AND sampling parameters
-        (the LoRA adapter index included) in ONE jitted program (shared
-        by the dense and paged engines) instead of separate
-        dispatches. ``ids`` (the host-side prompt, when the caller has
+        (the LoRA adapter index included) in ONE jitted program instead
+        of separate dispatches. ``ids`` (the host-side prompt, when the caller has
         one) seeds the slot's history ring with the prompt's trailing
         window — the device-mode draft source; a replayed request
         re-admits prompt+generated, so the ring rebuilds exactly like
@@ -1320,6 +1606,14 @@ class ContinuousBatchingEngine:
         """Host-side bookkeeping tail of a completed admission (one-shot
         or chunked): record the request, retire degenerate ones, count
         metrics. Runs OUTSIDE the abort guard — no device call left."""
+        # a new live slot may be under-covered for the next segment
+        # (optimistic claims stop at prompt + one page) — any growth
+        # stamp predating it is stale, as is the gap's (lens, done)
+        # snapshot (admission just wrote this slot's rows). Retire/free
+        # paths only RELEASE capacity and never un-cover or advance a
+        # surviving slot, so they keep both.
+        self._growth_stamp = None
+        self._gap_sync = None
         # the admission's adapter reference transfers from the slot
         # stash to the live request; _retire releases it
         self._rid_aidx[rid] = self._aidx_stash.pop(slot, 0)
@@ -1384,47 +1678,304 @@ class ContinuousBatchingEngine:
                             else "exact")
         return width
 
-    def _run_prefill(self, ids, plen: int, mini, aidx: int = 0):
-        """Pad the prompt to its bucket and run the one-shot prefill
-        program (under the request's adapter, when any); returns
-        (last-position logits [1, V], mini)."""
-        width = self._cold_width(plen)
-        with self._prefill_span(plen, width):
-            return self._prefill(self.params, _pad_ids(ids, width), mini,
-                                 jnp.int32(plen - 1), self._bank(),
-                                 jnp.int32(aidx))
+    def _lookup_degraded(self, slot: int, ids, plen: int, cfg):
+        """Shared warm-admission preamble (one-shot AND chunked):
+        longest resident cached prefix — in the admission's ADAPTER
+        namespace (the chain hash is salted with the adapter id, so a
+        base-model block can never warm-hit an adapter's admission or
+        vice versa) — degraded to full blocks when the pool cannot
+        spare the partial page's CoW."""
+        salt = self._adapter_salt(slot)
+        pids, c_map, hashes = self.alloc.lookup_prefix(ids[0],
+                                                       salt=salt)
+        pids, c_map = self._degrade_partial_hit(slot, plen, cfg,
+                                                pids, c_map)
+        return pids, c_map, hashes, salt
 
     def _admit_cache(self, slot: int, ids, plen: int, cfg):
-        """Cache-layout hook: prefill the prompt and install its KV into
-        slot's cache; returns the prompt's last-position logits. The
-        dense base scatters a max_len mini cache; the paged subclass
-        reserves pages and runs ONE program that fills them."""
+        """Prefill the prompt and install its KV into ``slot``'s pages;
+        returns the prompt's last-position logits. A prefix-cache hit
+        takes the warm path; everything else is the fused cold
+        program."""
+        if self.prefix_cache and not self.prefix_pause:
+            pids, c_map, hashes, salt = self._lookup_degraded(
+                slot, ids, plen, cfg)
+            self._prefix_stash[slot] = {
+                "ids": ids, "c_map": c_map, "hashes": hashes,
+                "saved": min(c_map, plen - 1), "salt": salt}
+            if c_map > 0:
+                return self._admit_cache_warm(slot, ids, plen, cfg,
+                                              pids, c_map)
+        # COLD path: claim the pages (the program needs the slot's
+        # page-table row; a claim that fails, fails before any device
+        # work), then ONE program prefills into a mini cache sized to
+        # the prompt's BUCKET (no max_len slab — the pool is the whole
+        # point; the bucket keys the compiled program count to
+        # O(len(buckets))) and scatters its rows into those pages
+        with trace.span("engine.reserve"):
+            self._reserve_admit(slot, plen, cfg)
+        return self._run_prefill_paged(
+            slot, ids, plen, aidx=self._aidx_stash.get(slot, 0))
+
+    def _run_prefill_paged(self, slot: int, ids, plen: int,
+                           aidx: int = 0):
+        """Pad the prompt to its bucket and run the fused cold-admission
+        program (``engine.prefill{fused=1}``: mini cache, prefill under
+        the request's adapter, install) into ``slot``'s claimed pages;
+        returns the last-position logits [1, V]."""
+        width = self._cold_width(plen)
+        # int8: the claimed pages' scale rows reset BEFORE the program
+        # runs its running absmax against them
+        self._flush_fresh_scales()
+        with self._prefill_span(plen, width, fused=1) as sp:
+            if self._layout is not None and trace.enabled():
+                # rows of the prompt that go into the window layers' rings
+                ps, ring = self.page_size, self._layout["ring_pages"]
+                first_page = max((plen - 1) // ps - ring + 1, 0)
+                sp.set(window_rows=plen - first_page * ps)
+            last_logits = self._prefill_install(
+                slot, _pad_ids(ids, width), plen, aidx)
+        self._index_prompt(slot, plen)
+        return last_logits
+
+    def _prefill_install(self, slot: int, ids, plen: int, aidx: int):
+        pt = self._device_tables()
+        pools, _ = self.caches
+        # numpy scalars ride as arguments: a jnp.int32() is a device
+        # program of its own
+        last_logits, pools = self._prefill_paged(
+            self.params, ids, pools, pt, np.int32(slot), np.int32(plen),
+            self._bank(), np.int32(aidx))
+        self.caches = (pools, pt)
+        return last_logits
+
+    def _degrade_partial_hit(self, slot: int, plen: int, cfg, pids,
+                             c_map: int):
+        """A partial-block hit (coverage ending mid-page) maps a page
+        the request must copy-on-write before its first write — one
+        page BEYOND its normal claim. When the pool cannot spare it,
+        DEGRADE the hit to full blocks (drop the partial page) rather
+        than demand extra capacity: a request whose worst case exactly
+        fills the pool must still admit, cache or no cache."""
+        ps = self.page_size
+        if not pids or c_map % ps == 0:
+            return pids, c_map
+        claim = (self._reserved(plen, cfg)
+                 if self.admission_mode == "reserved"
+                 else self._optimistic_claim(plen, cfg))
+        if self.alloc.can_fit(slot, claim + ps):
+            return pids, c_map
+        return pids[:-1], (c_map // ps) * ps
+
+    def _admit_cache_warm(self, slot: int, ids, plen: int, cfg, pids,
+                          c_map: int):
+        """Prefix-cache hit admission: gather the cached prefix KV from
+        the resident pages (a pure copy — bitwise what the original
+        prefill wrote), prefill ONLY the uncached tail at a traced
+        offset through the shared chunk program, then map the cached
+        pages read-only and install the tail. At least the LAST prompt
+        token always recomputes — its logits seed the first sampled
+        token — even when the whole prompt is resident (its KV write
+        is simply masked out then)."""
+        # compute start: everything below is served from cache; cap at
+        # plen-1 so the last position's logits exist
+        c_cmp = min(c_map, plen - 1)
+        wt = (plen - c_cmp if self.prefill_buckets is None
+              else _bucket_for(self.prefill_buckets, plen - c_cmp))
+        # the tail chunk writes mini rows [c_cmp, c_cmp+wt) — pull the
+        # compute start DOWN when the bucket would overhang max_len
+        # (the fwd's dynamic_update_slice clamps, which would corrupt
+        # cached rows); recomputing a few extra cached positions is
+        # value-neutral (their installs are masked out) and keeps the
+        # program keyed on wt alone
+        c_cmp = min(c_cmp, self.max_len - wt)
+        # tokens-saved is the compute actually skipped ([0, c_cmp)),
+        # not the raw coverage — the clamp above shrinks it
+        self._prefix_stash[slot]["saved"] = c_cmp
+        tail = plen - c_cmp
         with trace.span("engine.mini_cache"):
             mini = self._mini_cache(self.max_len)
-        last_logits, mini = self._run_prefill(
-            ids, plen, mini, aidx=self._aidx_stash.get(slot, 0))
+            mini = self._gather_mini(mini, pids)
+        self._count_prefill("warm")
+        tail_ids = _pad_ids(ids[:, c_cmp:], wt)
+        # bucket: the tail program's width
+        with self._prefill_span(plen, wt, cached=c_cmp):
+            last_logits, mini = self._prefill_chunk(
+                self.params, tail_ids, mini, jnp.int32(c_cmp),
+                jnp.int32(tail - 1), self._bank(),
+                jnp.int32(self._aidx_stash.get(slot, 0)))
+        with trace.span("engine.reserve"):
+            self.alloc.map_shared(slot, pids)
+            self._reserve_admit(slot, plen, cfg)
         with trace.span("engine.install"):
             self._install_mini(slot, mini, plen)
         return last_logits
 
+    def _gather_mini(self, mini, pids):
+        """Copy the resident pages into the head of a max_len-width
+        dense mini cache (per layer) — the cached-prefix KV the tail
+        prefill attends over. The page vector is padded to the FULL
+        page-table row width so every warm admission shares one
+        compiled gather program (junk rows for the ``-1`` tail sit
+        past the cached coverage, overwritten or masked)."""
+        from .paged_cache import gather_pages, gather_pages_q
+
+        row = np.full((self.alloc.page_table.shape[1],), -1, np.int32)
+        row[:len(pids)] = pids
+        pages = jnp.asarray(row)
+        pools, _ = self.caches
+        out = []
+        if self.kv_dtype == "int8":
+            # dequantize whole resident pages into the float mini: the
+            # tail prefill attends over exactly the values the fused
+            # decode reads see, so warm and cold agree to quantization
+            # error, never to a format skew
+            for (kp, vp, ks, vs), (mk, mv) in zip(pools, mini):
+                mk, mv = gather_pages_q(kp, vp, ks, vs, pages, mk, mv)
+                out.append((mk, mv))
+            return out
+        for (kp, vp), (mk, mv) in zip(pools, mini):
+            mk, mv = gather_pages(kp, vp, pages, mk, mv)
+            out.append((mk, mv))
+        return out
+
+    def _cow_page(self, slot: int, page_idx: int) -> None:
+        """Host-side copy-on-write of one shared page in the
+        inter-segment gap: claim a fresh page (allocator bookkeeping),
+        copy the pool rows on device, swap the table entry (shipped at
+        the next segment)."""
+        from .paged_cache import copy_page, copy_page_q
+
+        old, new = self.alloc.cow(slot, page_idx)
+        pools, pt = self.caches
+        new_pools = []
+        if self.kv_dtype == "int8":
+            # the copy carries the page's SCALES with its rows (int8
+            # rows are meaningless under another page's scale); the
+            # note tells the allocator's scale accounting the copy
+            # happened — forgetting either fails check() loudly
+            for kp, vp, ks, vs in pools:
+                kp, vp, ks, vs = copy_page_q(kp, vp, ks, vs,
+                                             jnp.int32(old),
+                                             jnp.int32(new))
+                new_pools.append((kp, vp, ks, vs))
+            self.caches = (new_pools, pt)
+            self.alloc.note_scale_copied(new)
+            return
+        for kp, vp in pools:
+            kp, vp = copy_page(kp, vp, jnp.int32(old), jnp.int32(new))
+            new_pools.append((kp, vp))
+        self.caches = (new_pools, pt)
+
     def _reserve_admit(self, slot: int, plen: int, cfg) -> None:
-        """Claim everything (beyond the slot) the admission will need UP
-        FRONT — the paged override reserves the worst-case pages — so a
-        chunked admission can never fail for capacity halfway through."""
+        """Claim the pages the admission will need UP FRONT (reserved:
+        the worst case; optimistic: prompt + one page), so a chunked
+        admission can never fail for capacity halfway through."""
+        self.alloc.ensure(
+            slot, self._reserved(plen, cfg)
+            if self.admission_mode == "reserved"
+            else self._optimistic_claim(plen, cfg))
 
     def _install_mini(self, slot: int, mini, plen: int) -> None:
-        """Install a prefilled mini cache into ``slot``'s share of the
-        pool (dense: scatter the max_len slab row)."""
-        self.caches = self._admit(self.caches, mini, jnp.int32(slot))
+        """Install a prefilled mini cache (a warm hit's, a chunked
+        admission's) into ``slot``'s pages."""
+        from .paged_cache import install_prompt
+
+        # int8: reset freshly claimed pages' scale rows BEFORE the
+        # quantized install runs its running absmax against them
+        self._flush_fresh_scales()
+        info = self._prefix_stash.get(slot)
+        if info is not None and info["c_map"] > 0:
+            self._install_mini_warm(slot, mini, plen, info)
+        else:
+            # COLD scatter of a mini that outlived its programs (a
+            # chunked admission's): the whole mini in ONE program, the
+            # scatter the fused prefill ends with. Rows past plen land
+            # on reserved-but-unwritten positions the decode mask
+            # hides and decode writes overwrite, or drop (unmapped
+            # pages; int8: everything past plen)
+            pt = self._device_tables()
+            pools, _ = self.caches
+            self.caches = (install_prompt(pools, pt, np.int32(slot),
+                                          np.int32(plen), mini), pt)
+        self._index_prompt(slot, plen)
+
+    def _index_prompt(self, slot: int, plen: int) -> None:
+        """Prefix cache: a cold admission POPULATES the cache, a warm
+        one extends it — either way the prompt's fully-written private
+        blocks become future hits (in the admission's adapter
+        namespace). Runs once the rows are in the pages."""
+        info = self._prefix_stash.pop(slot, None)
+        if info is None:
+            return
+        ps = self.page_size
+        self.alloc.register_blocks(
+            slot, info["hashes"], info["ids"][0],
+            info["c_map"] // ps, plen // ps,
+            salt=info.get("salt", b""))
+        if info["c_map"] > 0:
+            self.alloc.count_prefix_hit(info["saved"])
+
+    def _install_mini_warm(self, slot: int, mini, plen: int,
+                           info) -> None:
+        """Install a warm admission's UNCACHED suffix: copy-on-write
+        the shared page the first write would land in (divergent
+        suffix mid-block — or, fully-cached prompts, the partial tail
+        page decode will append into), then scatter exactly the rows
+        ``[c_map, plen)``. Shared pages are never written: positions
+        below the cached coverage are masked out of the scatter, and
+        the garbage tail past ``plen`` lands only in private headroom
+        pages or drops on unmapped ones."""
+        from .paged_cache import scatter_rows, scatter_rows_q
+
+        ps = self.page_size
+        c_map = info["c_map"]
+        # first position this slot will EVER write: the uncached
+        # suffix's start, or (fully cached) decode's first append
+        p0 = c_map if c_map < plen else plen
+        if p0 % ps and self.alloc.needs_cow(slot, p0):
+            self._cow_page(slot, p0 // ps)
+        pt = self._device_tables()
+        if c_map < plen:
+            mini_len = mini[0][0].shape[1]
+            width = (plen - c_map if self.prefill_buckets is None
+                     else _bucket_for(self.prefill_buckets,
+                                      plen - c_map))
+            width = min(width, mini_len)
+            pools, _ = self.caches
+            new_pools = []
+            if self.kv_dtype == "int8":
+                # masked-out rows drop from the quantized scatter too,
+                # so shared read-only pages keep rows AND scales; the
+                # CoW'd partial page's copied scales seed the running
+                # absmax for the suffix rows landing in it
+                for (kp, vp, ks, vs), (mk, mv) in zip(pools, mini):
+                    kp, vp, ks, vs = scatter_rows_q(
+                        kp, vp, ks, vs, pt, jnp.int32(slot),
+                        jnp.int32(c_map), jnp.int32(plen), mk, mv,
+                        width=width)
+                    new_pools.append((kp, vp, ks, vs))
+            else:
+                for (kp, vp), (mk, mv) in zip(pools, mini):
+                    kp, vp = scatter_rows(
+                        kp, vp, pt, jnp.int32(slot), jnp.int32(c_map),
+                        jnp.int32(plen), mk, mv, width=width)
+                    new_pools.append((kp, vp))
+            self.caches = (new_pools, pt)
+        else:
+            pools, _ = self.caches
+            self.caches = (pools, pt)
 
     def _abort_admit(self, slot: int) -> None:
-        """Undo a failed admission's capacity claim (slot back to the
-        free list, adapter reference released; the paged override also
-        releases pages)."""
+        """Undo a failed admission's capacity claim: adapter reference
+        released, slot back to the free list, its pages back to the
+        pool."""
         aidx = self._aidx_stash.pop(slot, 0)
         if aidx and self.adapters is not None:
             self.adapters.release(aidx)
         heapq.heappush(self._free, slot)
+        self._prefix_stash.pop(slot, None)
+        self.alloc.free_slot(slot)   # release any reserved pages
 
     def _retire(self, slot, event: str = "finished"):
         rid = self._slot_req.pop(slot)
@@ -1455,6 +2006,7 @@ class ContinuousBatchingEngine:
                 "paddle_tpu_requests_total",
                 "serving requests by lifecycle event",
                 ("event",)).labels(event=event).inc()
+        self.alloc.free_slot(slot)
 
     def _evict_active(self, rid: int, event: str):
         """Shared reclaim for the early-removal paths (cancel, preempt):
@@ -1472,7 +2024,7 @@ class ContinuousBatchingEngine:
 
     def cancel_request(self, rid: int):
         """Cancel an ACTIVE request and reclaim its capacity: the slot
-        (and, paged, its pages) returns to the pool immediately and the
+        and its pages return to the pool immediately and the
         request never appears in ``collect_finished()``. Returns the
         partial tokens generated so far (np.int32), or None when ``rid``
         is not active (unknown, already finished, or already cancelled).
@@ -1482,6 +2034,25 @@ class ContinuousBatchingEngine:
         at the next inter-segment gap, which is what keeps cancelled
         slots from leaking mid-segment."""
         return self._evict_active(rid, "cancelled")
+
+    def preempt_request(self, rid: int, reason: str = "pressure"):
+        """Preempt an ACTIVE request under memory pressure: reclaim its
+        slot AND pages immediately (mirroring ``cancel_request``'s
+        reclaim) and return the partial tokens generated so far
+        (np.int32) — the caller owns parking them and replaying
+        ``prompt + tokens`` through normal admission later (greedy
+        replay is bitwise-identical to an unpreempted run; see the
+        serving scheduler's replay machinery). Returns None when
+        ``rid`` is not active. The request never appears in
+        ``collect_finished()``; the retirement event and the pool's
+        ``paddle_tpu_kv_preemptions_total{reason}`` counter record it.
+
+        Like ``cancel_request``: call only from the thread driving the
+        engine, BETWEEN decode segments."""
+        out = self._evict_active(rid, "preempted")
+        if out is not None:
+            self.alloc.count_preemption(reason)
+        return out
 
     def partial_tokens(self, rid: int, start: int = 0):
         """Copy of the tokens generated so far for an ACTIVE request,
@@ -1496,8 +2067,8 @@ class ContinuousBatchingEngine:
     def reset_state(self) -> None:
         """Drop EVERY request and rebuild the engine's device-side
         decode state from scratch: fresh caches, lengths, done/active
-        flags, per-slot sampling vectors, and a full free-slot list
-        (paged: the whole page pool). Compiled programs are KEPT — after
+        flags, per-slot sampling vectors, a full free-slot list and the
+        whole page pool. Compiled programs are KEPT — after
         an engine-scoped fault (:class:`EngineFault`, a device error mid
         ``decode_segment``) the device arrays are suspect but the jitted
         programs are not, so a supervised restart pays device re-init
@@ -1508,6 +2079,22 @@ class ContinuousBatchingEngine:
         their stored prompt + tokens emitted so far. ``_next_req`` is
         NOT reset — request ids stay unique across restarts, so a stale
         pre-restart rid can never alias a replayed request."""
+        # every slot's pages go back to the pool BEFORE the rebuild
+        # reads alloc.page_table into the fresh cache tuple — a restart
+        # must leave zero pages leaked no matter what the fault
+        # interrupted
+        for slot in range(self.max_batch):
+            self.alloc.free_slot(slot)
+        # the pools are rebuilt from zeros below: every cached block's
+        # KV is gone, so the content index must go with it (parked
+        # pages return to the free heap)
+        self.alloc.clear_prefix_index()
+        # the fresh pools below start at floor scales: pending resets
+        # refer to arrays about to be dropped
+        self.alloc.take_fresh_scales()
+        self._prefix_stash.clear()
+        self._growth_stamp = None
+        self._gap_sync = None
         # drop the old pool BEFORE the rebuild allocates the new one:
         # both alive at once would double peak KV HBM at the exact
         # moment (device-fault recovery, pool sized near capacity) a
@@ -1567,10 +2154,141 @@ class ContinuousBatchingEngine:
                 "lora_capacity=K at construction")
         return self.adapters.unload(name)
 
+    def export_kv_pages(self, tokens, salt: bytes = b"") -> dict:
+        """Export the resident cached KV pages covering a prompt's
+        longest FULL-BLOCK prefix: the read half of a cross-process
+        page handoff (disaggregated prefill/decode). Returns a payload
+        of chain-hashed blocks plus per-layer page rows — raw pool
+        dtype (int8 rows ship with their per-page scales), so the
+        transfer is a page COPY, never a format conversion.
+
+        Must run on the scheduler thread in the inter-segment gap
+        (``Server.export_kv`` marshals there): the pools are DONATED
+        by device writes, so no other thread may read ``self.caches``.
+        Partial-block tails never export — the importer parks blocks
+        refcount-0 with no CoW discipline attached, so only token-
+        complete, hash-verified pages are safe to ship."""
+        from .paged_cache import _chain_root
+
+        ids = np.ascontiguousarray(
+            np.asarray(tokens).reshape(-1), np.int32)
+        pids, cov, hashes = self.alloc.lookup_prefix(ids, salt=salt)
+        ps = self.page_size
+        nfull = min(len(pids), cov // ps, len(hashes))
+        pids = pids[:nfull]
+        root = _chain_root(salt)
+        blocks = []
+        for b in range(nfull):
+            blocks.append({
+                "hash": hashes[b].hex(),
+                "parent": (hashes[b - 1] if b else root).hex(),
+                "tokens": ids[b * ps:(b + 1) * ps].tolist()})
+        pools, _pt = self.caches
+        idx = np.asarray(pids, np.int32)
+        layers = []
+        for pool in pools:
+            if self.kv_dtype == "int8":
+                kp, vp, ks, vs = pool
+                layers.append({"k": np.asarray(kp[idx]),
+                               "v": np.asarray(vp[idx]),
+                               "k_scale": np.asarray(ks[idx]),
+                               "v_scale": np.asarray(vs[idx])})
+            else:
+                kp, vp = pool
+                layers.append({"k": np.asarray(kp[idx]),
+                               "v": np.asarray(vp[idx])})
+        return {"version": 1, "kv_dtype": self.kv_dtype,
+                "page_size": ps, "salt": salt.hex(),
+                "coverage": nfull * ps, "blocks": blocks,
+                "layers": layers}
+
+    def import_kv_pages(self, payload: dict) -> dict:
+        """Install exported KV pages into this engine's pools and
+        prefix index: the write half of the cross-process handoff.
+        Every block re-derives its chain hash from (parent, tokens)
+        before adoption — a corrupted or mis-framed page can never
+        enter the content index — and an already-resident hash is a
+        dedup no-op (``PageAllocator.adopt_block``), which makes a
+        replayed handoff idempotent. Imported pages PARK (refcount 0,
+        LRU-reclaimable): the next admission of the matching prompt
+        warm-hits them read-only through the ordinary prefix-cache
+        path. Same gap-only threading contract as
+        :meth:`export_kv_pages`. Returns
+        ``{"imported", "deduped", "coverage"}``."""
+        from .paged_cache import (_block_hash, install_page,
+                                  install_page_q)
+
+        if payload.get("kv_dtype") != self.kv_dtype:
+            raise ValueError(
+                f"kv_dtype mismatch: payload "
+                f"{payload.get('kv_dtype')!r} vs engine "
+                f"{self.kv_dtype!r} — KV handoff is a page copy, "
+                f"never a format conversion")
+        if int(payload.get("page_size", -1)) != self.page_size:
+            raise ValueError(
+                f"page_size mismatch: payload "
+                f"{payload.get('page_size')} vs engine "
+                f"{self.page_size}")
+        pools, _pt = self.caches
+        layers = payload.get("layers") or []
+        if len(layers) != len(pools):
+            raise ValueError(
+                f"layer count mismatch: payload {len(layers)} vs "
+                f"engine {len(pools)}")
+        blocks = payload.get("blocks") or []
+        kp0 = pools[0][0]
+        for lay in layers:
+            for key in (("k", "v", "k_scale", "v_scale")
+                        if self.kv_dtype == "int8" else ("k", "v")):
+                arr = lay.get(key)
+                if arr is None or len(arr) != len(blocks):
+                    raise ValueError(
+                        f"payload layer missing/short {key!r} rows")
+            if (tuple(lay["k"].shape[1:]) != tuple(kp0.shape[1:])
+                    or lay["k"].dtype != kp0.dtype):
+                raise ValueError(
+                    f"page geometry mismatch: payload "
+                    f"{lay['k'].dtype}{lay['k'].shape[1:]} vs pool "
+                    f"{kp0.dtype}{tuple(kp0.shape[1:])}")
+        imported = deduped = 0
+        for b, blk in enumerate(blocks):
+            h = bytes.fromhex(blk["hash"])
+            parent = bytes.fromhex(blk["parent"])
+            toks = np.ascontiguousarray(
+                np.asarray(blk["tokens"]).reshape(-1), np.int32)
+            if _block_hash(parent, toks) != h:
+                raise ValueError(
+                    f"block {b}: chain hash does not match "
+                    f"(parent, tokens) — corrupted handoff rejected")
+            pid = self.alloc.adopt_block(h, parent, toks)
+            if pid is None:
+                deduped += 1
+                continue
+            pools, pt = self.caches
+            new_pools = []
+            if self.kv_dtype == "int8":
+                for (kp, vp, ks, vs), lay in zip(pools, layers):
+                    kp, vp, ks, vs = install_page_q(
+                        kp, vp, ks, vs, jnp.int32(pid),
+                        lay["k"][b], lay["v"][b],
+                        lay["k_scale"][b], lay["v_scale"][b])
+                    new_pools.append((kp, vp, ks, vs))
+                self.caches = (new_pools, pt)
+                self.alloc.note_scale_copied(pid)
+            else:
+                for (kp, vp), lay in zip(pools, layers):
+                    kp, vp = install_page(kp, vp, jnp.int32(pid),
+                                          lay["k"][b], lay["v"][b])
+                    new_pools.append((kp, vp))
+                self.caches = (new_pools, pt)
+            imported += 1
+        return {"imported": imported, "deduped": deduped,
+                "coverage": len(blocks) * self.page_size}
+
     # -- chunked admission (host-driven, one chunk per inter-segment gap) ----
     def begin_admit(self, prompt_ids, cfg: GenerationConfig):
-        """Start a CHUNKED admission: claim the slot AND (paged) the
-        request's worst-case pages up front — the existing
+        """Start a CHUNKED admission: claim the slot AND the request's
+        pages up front — the existing
         ``_can_admit``/``_abort_admit`` contract, so a partial admission
         can never leak capacity or fail for capacity halfway through —
         then return the admission object. The caller (the serving
@@ -1615,18 +2333,46 @@ class ContinuousBatchingEngine:
 
     def _begin_admit_cache(self, slot: int, ids, plen: int, cfg):
         """Claim a chunked admission's capacity and build its mini
-        cache; returns ``(mini, chunk_start)``. Base: reserve via
-        ``_reserve_admit`` and start chunking at 0 — chunk programs are
+        cache; returns ``(mini, chunk_start)``. Chunk programs are
         keyed on the FIXED (chunk, max_len) shapes, so every chunked
-        admission shares one compiled program (the paged engine pays a
-        transient dense mini slab for the admission's lifetime — same
-        slab the dense engine always uses). The paged prefix-cache
-        override maps cached prefix pages first and starts chunking
-        past them."""
+        admission shares one compiled program (at the price of a
+        transient max_len mini slab for the admission's lifetime).
+        With the prefix cache on, cached prefix pages are mapped first
+        and chunking starts past them."""
+        if not self.prefix_cache or self.prefix_pause:
+            with trace.span("engine.reserve"):
+                self._reserve_admit(slot, plen, cfg)
+            with trace.span("engine.mini_cache"):
+                return self._mini_cache(self.max_len), 0
+        pids, c_map, hashes, salt = self._lookup_degraded(slot, ids,
+                                                          plen, cfg)
+        C = self.prefill_chunk
+        # chunk windows must stay C-aligned (an overhanging window
+        # would clamp and corrupt earlier KV), so the cursor starts at
+        # the cached coverage aligned DOWN — the [start, c_map) sliver
+        # recomputes but its writes are masked out at install
+        start = (min(c_map, plen - 1) // C) * C
+        self._prefix_stash[slot] = {"ids": ids, "c_map": c_map,
+                                    "hashes": hashes, "saved": start,
+                                    "salt": salt}
         with trace.span("engine.reserve"):
+            self.alloc.map_shared(slot, pids)
             self._reserve_admit(slot, plen, cfg)
+            # copy-on-write the partial shared page EAGERLY, while the
+            # claim is atomic with the reservation — install runs gaps
+            # later, and the spare page must not be stolen by growth or
+            # another admission in between
+            p0 = c_map if c_map < plen else plen
+            if p0 % self.page_size and self.alloc.needs_cow(slot, p0):
+                self._cow_page(slot, p0 // self.page_size)
         with trace.span("engine.mini_cache"):
-            return self._mini_cache(self.max_len), 0
+            mini = self._mini_cache(self.max_len)
+            if pids:
+                # full cached coverage gathered (fixed-shape program);
+                # rows the chunks recompute from `start` just overwrite
+                # their gathered copies with bitwise-identical values
+                mini = self._gather_mini(mini, pids)
+        return mini, start
 
     def admit_chunk(self, adm: _ChunkedAdmission) -> bool:
         """Run ONE fixed-shape prefill chunk of an admission started
@@ -1781,19 +2527,66 @@ class ContinuousBatchingEngine:
 
     def _warmup_prefill(self, width: int) -> None:
         """Run what a cold admission of a ``width``-token prompt runs
-        (the jitted programs directly, not the dispatch helpers). Slot
-        0 is free, so the zero-prompt KV it installs is dead weight the
-        next admission overwrites (paged: dropped — no page mapped)."""
-        _, mini = self._prefill(
-            self.params, np.zeros((1, width), np.int32),
-            self._mini_cache(self.max_len), jnp.int32(width - 1),
-            self._bank(), jnp.int32(0))
-        self._install_mini(0, mini, width)
+        (the jitted program directly, not the dispatch helpers). Slot 0
+        is free and maps no page, so every row it scatters drops."""
+        self._prefill_install(0, np.zeros((1, width), np.int32), width, 0)
 
     def _warmup_prefix(self) -> dict:
-        """Pre-compile the prefix-cache warm-admission programs (paged
-        engine with ``prefix_cache=True``; no-op otherwise)."""
-        return {}
+        """Pre-compile every program a WARM admission can hit — the
+        page gather, the CoW page copy, and one tail-prefill + masked
+        scatter per prefill bucket — so the first cache hit never pays
+        an XLA compile inside the latency-critical gap. All calls are
+        value-neutral: nothing is mapped, every scatter row is masked
+        out (limit 0), and the page-0 self-copy happens before any
+        request owns it. Under int8 the fresh-scale flush program
+        warms here too (all-False mask — a no-op write)."""
+        out = {}
+        if self.kv_dtype == "int8":
+            t0 = time.perf_counter()
+            pools, pt = self.caches
+            self.caches = (self._reset_scales(
+                pools, jnp.zeros((self.num_pages,), bool)), pt)
+            out["reset_scales"] = time.perf_counter() - t0
+        if not self.prefix_cache:
+            return out
+        from .paged_cache import (copy_page, copy_page_q, scatter_rows,
+                                  scatter_rows_q)
+
+        quant = self.kv_dtype == "int8"
+        t0 = time.perf_counter()
+        mini = self._gather_mini(self._mini_cache(self.max_len), [])
+        pools, pt = self.caches
+        new_pools = []
+        for entry in pools:
+            if quant:
+                new_pools.append(copy_page_q(*entry, jnp.int32(0),
+                                             jnp.int32(0)))
+            else:
+                new_pools.append(copy_page(*entry, jnp.int32(0),
+                                           jnp.int32(0)))
+        self.caches = (new_pools, pt)
+        out["prefix_gather_copy"] = time.perf_counter() - t0
+        pt_dev = self._device_tables()
+        for w in (self.prefill_buckets or ()):
+            t0 = time.perf_counter()
+            _, mini = self._prefill_chunk(
+                self.params, np.zeros((1, w), np.int32), mini,
+                jnp.int32(0), jnp.int32(0), self._bank(),
+                jnp.int32(0))
+            pools, _ = self.caches
+            new_pools = []
+            for entry, (mk, mv) in zip(pools, mini):
+                if quant:
+                    new_pools.append(scatter_rows_q(
+                        *entry, pt_dev, jnp.int32(0), jnp.int32(0),
+                        jnp.int32(0), mk, mv, width=w))
+                else:
+                    new_pools.append(scatter_rows(
+                        *entry, pt_dev, jnp.int32(0), jnp.int32(0),
+                        jnp.int32(0), mk, mv, width=w))
+            self.caches = (new_pools, pt)
+            out[f"prefix_warm_{w}"] = time.perf_counter() - t0
+        return out
 
     def _segment_fn(self, n_steps: int):
         # keyed on n_steps ALONE: sampling parameters AND the LoRA
@@ -1836,23 +2629,23 @@ class ContinuousBatchingEngine:
                 owner=self._monitor_engine, donate_argnums=(7,))
         return self._segment_cache[n_steps]
 
-    # -- batched speculative decoding (per-slot capability) ------------------
     def _fwd_spec(self, params, inp, caches, lens, live, lora=None):
-        """W-token verify forward at per-row offsets (cache-layout
-        hook; the paged subclass routes through the page pool).
-        Returns ``(logits, caches, aux)`` — ``aux`` is the window-write
-        rows the int8 paged path hands back for the post-acceptance
-        commit (:meth:`_commit_spec_rows`); ``None`` here (dense
-        caches write exact floats, rejected rows are plain overwritten
-        garbage)."""
+        """W-token verify forward at per-row offsets through the page
+        table. Returns ``(logits, caches, aux)`` — ``aux`` is the
+        window-write rows the int8 path hands back for the
+        post-acceptance commit (:meth:`_commit_spec_rows`); None per
+        layer on float pools, whose rejected rows are plain overwritten
+        garbage."""
         from ..core.autograd import no_grad
 
+        pools, pt = caches
         with substituted_state(self.model, params), no_grad():
-            logits, caches = self.model.forward_decode_spec(
-                Tensor(inp), caches, lens, live,
-                **self._fwd_kwargs(lora))
+            logits, pools, aux = \
+                self.model.forward_decode_spec_paged(
+                    Tensor(inp), pools, pt, lens, live,
+                    **self._fwd_kwargs(lora))
         return (logits.value if isinstance(logits, Tensor) else logits,
-                caches, None)
+                (pools, pt), aux)
 
     def _commit_spec_rows(self, caches, aux, n_acc):
         """Post-acceptance KV commit for the verify window: restore
@@ -1869,7 +2662,7 @@ class ContinuousBatchingEngine:
         byte-for-byte what W single-token decode stores of the
         accepted tokens would have produced: same scale-growth events,
         same requant cascades, same rounding order — so spec-vs-plain
-        token parity survives quantization. No-op on dense/bf16 caches
+        token parity survives quantization. No-op on float pools
         (``aux`` is None — their rejected rows are exact-overwritten
         garbage, nothing persists)."""
         if aux is None or not any(a is not None for a in aux):
@@ -1967,11 +2760,11 @@ class ContinuousBatchingEngine:
 
     def _coverage_limit(self, slot: int) -> int:
         """Absolute position this slot's cache writes are valid up to
-        (dense slabs: the whole cache; the paged engine reports the
-        slot's mapped pages) — the spec step's per-row acceptance cap,
-        so a window reaching past grown coverage degrades to fewer
-        accepted tokens, never to reads of dropped writes."""
-        return self.max_len
+        (its mapped pages): the spec step may only ACCEPT tokens whose
+        KV writes landed in mapped pages, so a window reaching past
+        grown coverage degrades to fewer accepted tokens, never to
+        reads of writes the sentinel dropped."""
+        return min(self.alloc.covered_tokens(slot), self.max_len)
 
     def _spec_segment_device_fn(self, n_steps: int):
         """ONE fused compiled speculative segment
@@ -2407,6 +3200,65 @@ class ContinuousBatchingEngine:
         driver — omitted, the base stream is seeded from 0)."""
         if not self._slot_req:
             return 0
+        if self.admission_mode == "optimistic":
+            # final guard: a driver that skipped pressure relief must
+            # fail LOUDLY here, not let write_tokens silently drop KV
+            # writes past the mapped range and corrupt the request's
+            # decode. When the scheduler's gap already ran a clean
+            # grow_for_segment(n_steps) (stamp matches, slot set
+            # unchanged since), the re-check — two blocking device
+            # fetches + an O(active) allocator pass — is skipped; the
+            # stamp is single-shot because this segment advances lens
+            short = ([] if self._growth_stamp == n_steps
+                     else self.grow_for_segment(n_steps))
+            self._growth_stamp = None
+            self._gap_sync = None    # the segment advances lens/done
+            if short:
+                raise PagePoolExhausted(
+                    short,
+                    f"page pool exhausted in the inter-segment gap: "
+                    f"requests {short} cannot grow for the next "
+                    f"{n_steps}-step segment "
+                    f"({self.alloc.available_pages} pages reclaimable) "
+                    f"— preempt victims (preempt_request) or grow "
+                    f"num_pages")
+        # int8: pages the gap claimed (growth, reserves) get their
+        # scale rows floored before this segment's quantized writes
+        self._flush_fresh_scales()
+        # reserved mode: admission reserved every running request's
+        # worst case, so no growth can fail — just ship the table
+        if self.alloc.debug:
+            self.alloc.check()
+            if self.kv_dtype == "int8":
+                # device half of the scale invariants: every live
+                # page's scales finite and positive (layer 0 stands
+                # for all layers — one program writes them all)
+                pools, _ = self.caches
+                self.alloc.check_scales(pools[0][2], pools[0][3])
+            # write_tokens drops out-of-mapping writes SILENTLY (one
+            # compiled program) and a forgotten copy-on-write would
+            # mutate a shared page other requests read — both surface
+            # as wrong tokens far downstream. Under debug_pages the gap
+            # re-asserts, per live slot, that the live length is inside
+            # the mapped pages and the imminent write lands in a
+            # private page.
+            # lint: allow-host-sync(debug_pages-only invariant check —
+            # never on the production path; the pull is the price of
+            # validating coverage before a silent-drop write)
+            lens = np.asarray(self.lens)
+            # lint: allow-host-sync(same debug_pages-only pull)
+            done = np.asarray(self.done_dev)
+            for slot, rid in self._slot_req.items():
+                if bool(done[slot]):
+                    continue
+                # a speculating row's imminent writes span its whole
+                # draft window, not just the next position — the
+                # shared-page (missing-CoW) net must cover all of it
+                self.alloc.check_coverage(
+                    slot, int(lens[slot]),
+                    write_ahead=1 + self._spec_k_of(rid))
+        pools, _ = self.caches
+        self.caches = (pools, self._device_tables())
         run, phase = self._decode_segment_plain, "engine.segment"
         if self._spec:
             # at least one live slot is speculating: the whole batch
@@ -2433,6 +3285,25 @@ class ContinuousBatchingEngine:
 
     # lint: hot-path
     def _decode_segment_plain(self, n_steps: int, cfg, sp):
+        if self._layout is not None and trace.enabled():
+            # what the two geometries hold at the segment's start, and
+            # the tokens a window layer's attention reads (a full
+            # layer's: ctx_tokens)
+            w = self._layout["window"]
+            held = [self.alloc.held_pages(slot) for slot in self._slot_req]
+            sp.set(ctx_tokens_window=sum(
+                       min(self._plen[rid] + len(self._tokens[rid]), w)
+                       for rid in self._slot_req.values()),
+                   pages_full=sum(h[0] for h in held),
+                   pages_window=sum(h[1] for h in held))
+        elif trace.enabled():
+            # the pages the live rows' contexts span at the segment's
+            # start (what ``paged_decode`` walks), of the table's
+            ps = self.page_size
+            sp.set(pages_live=sum(
+                       -(-(self._plen[rid] + len(self._tokens[rid])) // ps)
+                       for rid in self._slot_req.values()),
+                   pages_table=self.alloc.page_table.size)
         t0 = time.perf_counter()
         # every segment must draw fresh sampling noise even when no
         # request was admitted in between — fold in a segment counter
@@ -2534,15 +3405,70 @@ class ContinuousBatchingEngine:
         out, self._finished = self._finished, {}
         return out
 
-    # -- convenience driver -------------------------------------------------
-    def grow_for_segment(self, n_steps: int):
-        """Pre-segment capacity hook: grow every live request's cache
-        coverage for the coming ``n_steps``-step segment and return the
-        request ids that could NOT be covered (the caller must preempt
-        victims before decoding). Dense slabs and reserved-mode paged
-        pools pre-cover the worst case, so the base is a no-op; the
-        paged engine's optimistic mode overrides it."""
-        return []
+    # -- optimistic-mode memory pressure (host-side, between segments) -------
+    def grow_for_segment(self, n_steps: int):  # lint: hot-path
+        """Grow every live slot's page mapping to cover the coming
+        ``n_steps``-step decode segment (optimistic mode; a no-op in
+        reserved mode, where admission pre-claimed the worst case).
+        Returns the request ids whose growth could NOT be satisfied —
+        the pool is dry and the caller must preempt victims (or accept
+        :class:`PagePoolExhausted` from ``decode_segment``).
+
+        OLDEST request first (ascending rid — admission order), so
+        pressure always lands on the youngest work: combined with a
+        scheduler that never preempts the oldest survivor, the head of
+        the line always makes forward progress and pressure can never
+        deadlock the loop. A row's target is capped by its remaining
+        budget: a segment emits at most ``min(n_steps, budget)`` kept
+        tokens, whose last cache write lands at position
+        ``len + min(n_steps, budget) - 1`` — device steps past the
+        budget write into (and read from) uncovered positions, but
+        every token they produce is discarded host-side at collection,
+        so capping is safe and saves pages. NO partial growth: a slot
+        either covers the full target or joins the short list —
+        partially covered steps would emit garbage tokens the host
+        KEEPS."""
+        if self.admission_mode != "optimistic" or not self._slot_req:
+            return []
+        if self._gap_sync is None:
+            # lint: allow-host-sync(ONE cached lens/done pull per gap —
+            # growth decisions need real lengths; decode_segment's
+            # re-check reuses this exact pull via _gap_sync)
+            self._gap_sync = (np.asarray(self.lens),
+                              np.asarray(self.done_dev))
+        lens, done = self._gap_sync
+        short = []
+        for slot, rid in sorted(self._slot_req.items(),
+                                key=lambda kv: kv[1]):
+            if bool(done[slot]):
+                continue       # frozen rows never write
+            # a SPECULATING row can accept up to spec_k+1 tokens per
+            # verify step, so its per-segment growth target scales by
+            # its window width (still budget-capped: acceptance never
+            # outruns the tokens the host will keep). Draft-scratch
+            # writes past the target drop harmlessly — the spec step
+            # caps acceptance at the grown coverage.
+            w = self._spec_k_of(rid) + 1
+            target = min(int(lens[slot])
+                         + min(n_steps * w, self._budget[rid]),
+                         self.max_len)
+            if self.alloc.can_fit(slot, target):
+                self.alloc.ensure(slot, target)
+            else:
+                short.append(rid)
+        # a clean pass covers the coming segment: decode_segment(n_steps)
+        # may skip its re-check until the slot set changes (_register) or
+        # the segment runs (lens advance)
+        self._growth_stamp = n_steps if not short else None
+        if short and trace.enabled():
+            # ENGINE rids (not serving trace keys): the pool could not
+            # cover these rows' growth — the preemptions that follow in
+            # the flight ring are this event's consequence
+            trace.event("engine.grow_short",
+                        engine=self._monitor_engine,
+                        engine_rids=tuple(short),
+                        free_pages=self.alloc.free_pages)
+        return short
 
     def serve(self, prompts, cfg: Optional[GenerationConfig] = None,
               segment_steps: int = 8):
@@ -2633,1121 +3559,3 @@ class ContinuousBatchingEngine:
         # foreign requests finished during our segments stay collectable
         self._finished.update(foreign)
         return [results[i] for i in range(len(prompts))]
-
-
-class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
-    """ContinuousBatchingEngine over a PAGED KV pool (vLLM-style layout
-    the reference's contiguous CacheKV slabs cannot express): cache
-    slots are page-table rows into shared per-layer pools, so HBM holds
-    ``num_pages * page_size`` tokens total — the tokens in flight — not
-    ``max_batch * max_len``, and any free page serves any slot.
-
-    Two ``admission_mode`` policies govern the page pool:
-
-    - ``"reserved"`` (default): admission RESERVES a request's worst
-      case (prompt + max_new_tokens, capped at max_len) so a running
-      request can never exhaust the pool mid-decode — safe, but
-      concurrency is capped by the worst case while most requests
-      finish early on EOS;
-    - ``"optimistic"`` (vLLM-style, Kwon et al. SOSP'23): admission
-      claims only the prompt's pages plus ONE page of headroom, and
-      the engine grows each live slot's mapping per inter-segment gap
-      (:meth:`grow_for_segment`, capped by the request's remaining
-      budget). When growth cannot be satisfied the CALLER must relieve
-      pressure — :meth:`preempt_request` reclaims a victim's slot and
-      pages exactly like ``cancel_request`` and returns its partial
-      tokens for replay (the serving scheduler parks the handle on its
-      replay list; greedy preempt-resume is bitwise-identical to an
-      unpreempted run). ``decode_segment`` re-checks growth and raises
-      :class:`PagePoolExhausted` if pressure was left unhandled —
-      never a silent dropped write. ``kv_watermark`` (fraction of the
-      pool, optimistic mode only) pauses NEW admissions while the pool
-      is already under pressure, so preemption is the fallback, not
-      the steady state.
-
-    ``prefix_cache=True`` turns on AUTOMATIC PREFIX CACHING (vLLM-style
-    content-addressable pages; RadixAttention generalizes the same
-    reuse to a tree): admission hashes the prompt in page_size-token
-    blocks, maps already-resident blocks READ-ONLY into the new slot's
-    page table (refcount++ — prefill and page claiming skip them; only
-    the uncached tail runs through the bucketed/chunked prefill at a
-    traced offset), and the first write into a shared page — a
-    divergent suffix mid-block, or decode appending into a
-    partially-filled shared tail page — goes through host-side
-    COPY-ON-WRITE in the inter-segment gap: claim a fresh page, copy
-    the pool rows, swap the table entry. Retirement decrements
-    refcounts instead of freeing; fully-released cached pages park in
-    an LRU free-but-indexed state the pool reclaims on demand, so
-    cache capacity is whatever the pool isn't actively using. Shared
-    pages (refcount > 1) are never preemption victims — preempting a
-    request releases only ITS references. Warm-prefix admissions are
-    bitwise-identical (greedy) to cold runs: the gathered prefix KV is
-    the very KV the original prefill wrote, and the tail rides the
-    same traced-offset program chunked admission already proves
-    bitwise-equal to one-shot prefill.
-
-    ``serve`` defers admission while the pool is transiently full and
-    raises only for requests that could never fit. The page table
-    lives host-side (numpy) and is shipped to the device once per
-    segment. ``debug_pages=True`` runs the allocator's ``check()``
-    invariant validator at every gap and after every page operation,
-    plus a per-gap write-coverage assert (no live slot's length past
-    its mapped pages, no imminent write into a shared page — the
-    forgotten-CoW / silent-drop net). Requires the model to implement
-    ``init_paged_cache`` / ``forward_decode_paged`` (llama does; see
-    LlamaAttention.forward_decode_paged).
-    """
-
-    def __init__(self, model, max_batch: int, num_pages: int,
-                 page_size: int, max_pages: int,
-                 prefill_buckets="auto",
-                 prefill_chunk: Optional[int] = None,
-                 admission_mode: str = "reserved",
-                 kv_watermark: float = 0.9,
-                 debug_pages: bool = False,
-                 prefix_cache: bool = False,
-                 kv_dtype: str = "bf16",
-                 draft_k: int = 0, ngram_max: int = 3,
-                 spec_mode: str = "host", spec_draft: str = "ngram",
-                 spec_history: int = 128,
-                 lora_capacity: int = 0, lora_rank: int = 8,
-                 lora_targets=("q", "k", "v", "o"),
-                 tp_degree: int = 1, tp_devices=None):
-        from ..quantization.kv import KV_DTYPES
-        from .paged_cache import PageAllocator
-
-        if admission_mode not in ADMISSION_MODES:
-            raise ValueError(
-                f"admission_mode must be one of {ADMISSION_MODES}, got "
-                f"{admission_mode!r}")
-        if not (isinstance(kv_watermark, (int, float))
-                and 0 < kv_watermark <= 1):
-            raise ValueError(
-                f"kv_watermark must satisfy 0 < w <= 1 (fraction of "
-                f"the page pool), got {kv_watermark!r}")
-        if kv_dtype not in KV_DTYPES:
-            raise ValueError(
-                f"kv_dtype must be one of {KV_DTYPES}, got "
-                f"{kv_dtype!r}")
-        self.admission_mode = admission_mode
-        self.kv_watermark = float(kv_watermark)
-        self.prefix_cache = bool(prefix_cache)
-        # overload-control actuator (serving.control brownout rung 4):
-        # while True, NEW admissions skip prefix-cache lookup/insert
-        # and take the plain cold path (already warmed — pausing
-        # compiles nothing, and no CoW/shared pages are minted under
-        # pressure). Resident cached blocks stay mapped; in-flight
-        # warm admissions finish normally. Host bool, flipped by the
-        # serving scheduler thread between segments.
-        self.prefix_pause = False
-        # KV page storage: "bf16" = the model's cache dtype, bitwise
-        # the pre-quantization path; "int8" stores pages int8 with
-        # per-(page, kv_head) running-absmax scales riding the page
-        # table — half the bytes per decode read, ~2x the pages at
-        # fixed HBM, correctness bar bounded-not-bitwise (see
-        # quantization.kv). Must be set before the base __init__
-        # builds the pools.
-        self.kv_dtype = kv_dtype
-        # slot -> warm-admission info ({"ids","c_map","hashes","saved"})
-        # staged from the admission's lookup until its rows are in the
-        # pages; popped by _index_prompt / _abort_admit
-        self._prefix_stash = {}
-        # segment count a clean grow_for_segment covered; decode_segment
-        # consumes it to skip its (device-syncing) exhaustion re-check
-        self._growth_stamp: Optional[int] = None
-        # (lens, done) host copies shared by every grow_for_segment call
-        # in ONE gap — relief that preempts k victims re-runs the grow
-        # loop k+1 times, but lens/done only change when a segment runs
-        # (decode) or a slot admits (_register), both of which clear it
-        self._gap_sync = None
-        self.num_pages = num_pages
-        self.page_size = page_size
-        # a model whose layers keep their KV in TWO geometries (full and
-        # sliding-window attention side by side) says so; None = one
-        # table serves every layer
-        layout = getattr(model, "paged_layout", None)
-        self._layout = layout(page_size) if layout is not None else None
-        if self._layout is not None:
-            refused = {"tp_degree": tp_degree != 1,
-                       "kv_dtype='int8'": kv_dtype != "bf16",
-                       "draft_k (speculative decoding)": draft_k != 0,
-                       "prefix_cache": prefix_cache,
-                       "prefill_chunk": prefill_chunk is not None,
-                       "lora_capacity (LoRA)": lora_capacity != 0}
-            for feature, asked in refused.items():
-                if asked:
-                    raise ValueError(
-                        f"{feature} is not implemented for a model with "
-                        f"sliding-window layers or routed experts "
-                        f"({type(model).__name__}): its window layers "
-                        f"keep a ring of pages that this feature's "
-                        f"programs do not read or write")
-            from .paged_cache import WindowedPageAllocator
-
-            self.alloc = WindowedPageAllocator(
-                num_pages, page_size, max_batch, max_pages,
-                self._layout["ring_pages"], debug=debug_pages)
-        else:
-            self.alloc = PageAllocator(num_pages, page_size, max_batch,
-                                       max_pages, debug=debug_pages,
-                                       prefix_cache=prefix_cache,
-                                       kv_dtype=kv_dtype)
-        super().__init__(model, max_batch,
-                         max_len=max_pages * page_size,
-                         prefill_buckets=prefill_buckets,
-                         prefill_chunk=prefill_chunk,
-                         draft_k=draft_k, ngram_max=ngram_max,
-                         spec_mode=spec_mode, spec_draft=spec_draft,
-                         spec_history=spec_history,
-                         lora_capacity=lora_capacity,
-                         lora_rank=lora_rank,
-                         lora_targets=lora_targets,
-                         tp_degree=tp_degree, tp_devices=tp_devices)
-        self._measure_quant_savings()
-
-        def reset_scales(pools, mask):
-            # ONE fixed-shape program per pool shape: freshly claimed
-            # pages' scale rows (a previous owner's absmax leftovers)
-            # drop to the floor before any write — per-page dispatches
-            # or a count-shaped index vector would recompile per gap
-            from ..quantization.kv import KV_SCALE_FLOOR
-
-            out = []
-            for kp, vp, ks, vs in pools:
-                ks = jnp.where(mask[:, None], KV_SCALE_FLOOR, ks)
-                vs = jnp.where(mask[:, None], KV_SCALE_FLOOR, vs)
-                out.append((kp, vp, ks, vs))
-            return out
-
-        self._reset_scales = monitor.monitored_jit(
-            reset_scales, name="cb_reset_scales",
-            owner=self._monitor_engine, donate_argnums=(0,))
-
-        def prefill_one(params, ids, pools, page_table, slot, plen, bank,
-                        aidx):
-            # a cold one-shot admission is this ONE program per bucket:
-            # the bucket-wide mini cache is made in here (zeros XLA need
-            # not materialise), the base engine's prefill runs on it,
-            # and every layer's rows go into the DONATED pools by
-            # write_tokens' own arithmetic (unmapped pages drop; int8
-            # drops past plen). slot / plen / aidx are traced
-            from .paged_cache import write_prompt
-
-            mini = self._tp_kv(self.model.init_cache(1, ids.shape[1]))
-            if self._layout is not None:
-                # the model is told the last position: it computes that
-                # position's logits alone and routes no padding, and its
-                # window layers' rows go into their rings
-                logits, mini = self._fwd_prefill(params, ids, mini,
-                                                 last_idx=plen - 1)
-                return (logits[:, 0], write_prompt(
-                    pools, page_table, slot, plen, mini,
-                    window_layers=self._layout["window_layers"]))
-            logits, mini = self._fwd_prefill(
-                params, ids, mini, lora=_lora_rows(bank, aidx, ids))
-            return (logits[:, plen - 1],
-                    write_prompt(pools, page_table, slot, plen, mini))
-
-        # under the base prefill's names (monitor "cb_prefill", XLA module
-        # jit_prefill_one): the miss counters and the benchmark's readers
-        # find a prompt's prefill by them
-        self._prefill_paged = monitor.monitored_jit(
-            prefill_one, name="cb_prefill",
-            owner=self._monitor_engine, donate_argnums=(2,))
-
-    def _make_caches(self):
-        # TP: pools (and int8 scales) shard on the kv-head axis; the
-        # page TABLE replicates — page indices are mesh-invariant, so
-        # the allocator/prefix-cache host logic needs no fork
-        if self.kv_dtype == "int8":
-            try:
-                pools = self.model.init_paged_cache(
-                    self.num_pages, self.page_size, kv_dtype="int8")
-            except TypeError as e:
-                raise ValueError(
-                    f"kv_dtype='int8' needs a model whose "
-                    f"init_paged_cache accepts kv_dtype (llama does); "
-                    f"{type(self.model).__name__} does not") from e
-            return self._tp_kv(pools), self._device_tables()
-        if self._layout is not None:
-            return (self.model.init_paged_cache(
-                        self.num_pages, self.page_size,
-                        window_pages=self.alloc.window.num_pages),
-                    self._device_tables())
-        return (self._tp_kv(self.model.init_paged_cache(
-                    self.num_pages, self.page_size)),
-                self._device_tables())
-
-    def _device_tables(self):
-        """The host page table(s) as the device programs take them: one
-        array, or ``(full, ring)`` for a model with window layers."""
-        if self._layout is not None:
-            return tuple(jnp.asarray(t) for t in self.alloc.tables())
-        return self._tp_rep(jnp.asarray(self.alloc.page_table))
-
-    def _measure_quant_savings(self) -> None:
-        """Price the int8 layout from the REAL pool arrays: HBM bytes
-        per page a bf16 pool would need minus what the int8 pools +
-        scales actually take — the allocator counts it per claimed
-        page (``paddle_tpu_kv_quant_bytes_saved_total``)."""
-        if self.kv_dtype != "int8":
-            self.alloc.bytes_saved_per_page = 0
-            return
-        pools, _ = self.caches
-        base = quant = 0
-        for kp, vp, ks, vs in pools:
-            base += (kp.size + vp.size) * 2          # bf16 baseline
-            quant += (kp.nbytes + vp.nbytes + ks.nbytes + vs.nbytes)
-        self.alloc.bytes_saved_per_page = max(
-            (base - quant) // self.num_pages, 0)
-
-    def kv_page_cost(self) -> dict:
-        """HBM cost of one page under the current storage dtype:
-        ``{"bytes_per_page"}`` is the actual cost (scales included);
-        ``{"bf16_equiv_bytes_per_page"}`` prices the SAME page at
-        2 bytes/element — the production-baseline denominator for
-        serve_bench's effective-capacity record, independent of the
-        CPU test model's f32 cache dtype."""
-        pools, _ = self.caches
-        total = elems = 0
-        for entry in pools:
-            total += sum(a.nbytes for a in entry)
-            elems += entry[0].size + entry[1].size
-        return {"bytes_per_page": total // self.num_pages,
-                "bf16_equiv_bytes_per_page":
-                    2 * elems // self.num_pages}
-
-    def set_kv_dtype(self, kv_dtype: str) -> None:
-        """Swap the pool storage dtype on an IDLE engine (the
-        ``Server(kv_dtype=...)`` mirror hook): rebuilds the pools —
-        any cached prefix KV dies with them, so the content index
-        clears too — and keeps every compiled program (the other
-        dtype's variants stay cached; warmup covers the new ones)."""
-        from ..quantization.kv import KV_DTYPES
-
-        if kv_dtype not in KV_DTYPES:
-            raise ValueError(
-                f"kv_dtype must be one of {KV_DTYPES}, got "
-                f"{kv_dtype!r}")
-        if kv_dtype == self.kv_dtype:
-            return
-        if self._slot_req:
-            raise RuntimeError(
-                "kv_dtype can only be changed on an idle engine")
-        # old pools dropped before the new ones allocate (reset_state's
-        # peak-HBM argument applies here too)
-        self.caches = None
-        self.alloc.clear_prefix_index()
-        self.alloc.set_kv_dtype(kv_dtype)
-        self.kv_dtype = kv_dtype
-        self._prefix_stash.clear()
-        self._growth_stamp = None
-        self._gap_sync = None
-        self.caches = self._make_caches()
-        self._measure_quant_savings()
-
-    def _flush_fresh_scales(self) -> None:
-        """Reset freshly claimed pages' scale rows to the floor (int8;
-        one masked fixed-shape program) — runs at the write choke
-        points (cache install, pre-segment) so no quantized store ever
-        runs absmax against a previous owner's scales."""
-        if self.kv_dtype != "int8":
-            return
-        fresh = self.alloc.take_fresh_scales()
-        if not fresh:
-            return
-        mask = np.zeros((self.num_pages,), bool)
-        mask[fresh] = True
-        pools, pt = self.caches
-        self.caches = (self._reset_scales(pools, jnp.asarray(mask)),
-                       pt)
-
-    def load(self) -> dict:
-        out = super().load()
-        out["kv_dtype"] = self.kv_dtype
-        return out
-
-    def export_kv_pages(self, tokens, salt: bytes = b"") -> dict:
-        """Export the resident cached KV pages covering a prompt's
-        longest FULL-BLOCK prefix: the read half of a cross-process
-        page handoff (disaggregated prefill/decode). Returns a payload
-        of chain-hashed blocks plus per-layer page rows — raw pool
-        dtype (int8 rows ship with their per-page scales), so the
-        transfer is a page COPY, never a format conversion.
-
-        Must run on the scheduler thread in the inter-segment gap
-        (``Server.export_kv`` marshals there): the pools are DONATED
-        by device writes, so no other thread may read ``self.caches``.
-        Partial-block tails never export — the importer parks blocks
-        refcount-0 with no CoW discipline attached, so only token-
-        complete, hash-verified pages are safe to ship."""
-        from .paged_cache import _chain_root
-
-        ids = np.ascontiguousarray(
-            np.asarray(tokens).reshape(-1), np.int32)
-        pids, cov, hashes = self.alloc.lookup_prefix(ids, salt=salt)
-        ps = self.page_size
-        nfull = min(len(pids), cov // ps, len(hashes))
-        pids = pids[:nfull]
-        root = _chain_root(salt)
-        blocks = []
-        for b in range(nfull):
-            blocks.append({
-                "hash": hashes[b].hex(),
-                "parent": (hashes[b - 1] if b else root).hex(),
-                "tokens": ids[b * ps:(b + 1) * ps].tolist()})
-        pools, _pt = self.caches
-        idx = np.asarray(pids, np.int32)
-        layers = []
-        for pool in pools:
-            if self.kv_dtype == "int8":
-                kp, vp, ks, vs = pool
-                layers.append({"k": np.asarray(kp[idx]),
-                               "v": np.asarray(vp[idx]),
-                               "k_scale": np.asarray(ks[idx]),
-                               "v_scale": np.asarray(vs[idx])})
-            else:
-                kp, vp = pool
-                layers.append({"k": np.asarray(kp[idx]),
-                               "v": np.asarray(vp[idx])})
-        return {"version": 1, "kv_dtype": self.kv_dtype,
-                "page_size": ps, "salt": salt.hex(),
-                "coverage": nfull * ps, "blocks": blocks,
-                "layers": layers}
-
-    def import_kv_pages(self, payload: dict) -> dict:
-        """Install exported KV pages into this engine's pools and
-        prefix index: the write half of the cross-process handoff.
-        Every block re-derives its chain hash from (parent, tokens)
-        before adoption — a corrupted or mis-framed page can never
-        enter the content index — and an already-resident hash is a
-        dedup no-op (``PageAllocator.adopt_block``), which makes a
-        replayed handoff idempotent. Imported pages PARK (refcount 0,
-        LRU-reclaimable): the next admission of the matching prompt
-        warm-hits them read-only through the ordinary prefix-cache
-        path. Same gap-only threading contract as
-        :meth:`export_kv_pages`. Returns
-        ``{"imported", "deduped", "coverage"}``."""
-        from .paged_cache import (_block_hash, install_page,
-                                  install_page_q)
-
-        if payload.get("kv_dtype") != self.kv_dtype:
-            raise ValueError(
-                f"kv_dtype mismatch: payload "
-                f"{payload.get('kv_dtype')!r} vs engine "
-                f"{self.kv_dtype!r} — KV handoff is a page copy, "
-                f"never a format conversion")
-        if int(payload.get("page_size", -1)) != self.page_size:
-            raise ValueError(
-                f"page_size mismatch: payload "
-                f"{payload.get('page_size')} vs engine "
-                f"{self.page_size}")
-        pools, _pt = self.caches
-        layers = payload.get("layers") or []
-        if len(layers) != len(pools):
-            raise ValueError(
-                f"layer count mismatch: payload {len(layers)} vs "
-                f"engine {len(pools)}")
-        blocks = payload.get("blocks") or []
-        kp0 = pools[0][0]
-        for lay in layers:
-            for key in (("k", "v", "k_scale", "v_scale")
-                        if self.kv_dtype == "int8" else ("k", "v")):
-                arr = lay.get(key)
-                if arr is None or len(arr) != len(blocks):
-                    raise ValueError(
-                        f"payload layer missing/short {key!r} rows")
-            if (tuple(lay["k"].shape[1:]) != tuple(kp0.shape[1:])
-                    or lay["k"].dtype != kp0.dtype):
-                raise ValueError(
-                    f"page geometry mismatch: payload "
-                    f"{lay['k'].dtype}{lay['k'].shape[1:]} vs pool "
-                    f"{kp0.dtype}{tuple(kp0.shape[1:])}")
-        imported = deduped = 0
-        for b, blk in enumerate(blocks):
-            h = bytes.fromhex(blk["hash"])
-            parent = bytes.fromhex(blk["parent"])
-            toks = np.ascontiguousarray(
-                np.asarray(blk["tokens"]).reshape(-1), np.int32)
-            if _block_hash(parent, toks) != h:
-                raise ValueError(
-                    f"block {b}: chain hash does not match "
-                    f"(parent, tokens) — corrupted handoff rejected")
-            pid = self.alloc.adopt_block(h, parent, toks)
-            if pid is None:
-                deduped += 1
-                continue
-            pools, pt = self.caches
-            new_pools = []
-            if self.kv_dtype == "int8":
-                for (kp, vp, ks, vs), lay in zip(pools, layers):
-                    kp, vp, ks, vs = install_page_q(
-                        kp, vp, ks, vs, jnp.int32(pid),
-                        lay["k"][b], lay["v"][b],
-                        lay["k_scale"][b], lay["v_scale"][b])
-                    new_pools.append((kp, vp, ks, vs))
-                self.caches = (new_pools, pt)
-                self.alloc.note_scale_copied(pid)
-            else:
-                for (kp, vp), lay in zip(pools, layers):
-                    kp, vp = install_page(kp, vp, jnp.int32(pid),
-                                          lay["k"][b], lay["v"][b])
-                    new_pools.append((kp, vp))
-                self.caches = (new_pools, pt)
-            imported += 1
-        return {"imported": imported, "deduped": deduped,
-                "coverage": len(blocks) * self.page_size}
-
-    def _fwd_ragged(self, params, tok, caches, lens, live, lora=None):
-        from ..core.autograd import no_grad
-
-        pools, pt = caches
-        with substituted_state(self.model, params), no_grad():
-            # a model that routes experts returns its routing counts too
-            logits, pools, *aux = self.model.forward_decode_paged(
-                Tensor(tok), pools, pt, lens, live,
-                **self._fwd_kwargs(lora))
-        return (logits.value if isinstance(logits, Tensor) else logits,
-                (pools, pt), aux[0] if aux else None)
-
-    def _decode_segment_plain(self, n_steps: int, cfg, sp):
-        if self._layout is not None and trace.enabled():
-            # what the two geometries hold at the segment's start, and
-            # the tokens a window layer's attention reads (a full
-            # layer's: ctx_tokens)
-            w = self._layout["window"]
-            held = [self.alloc.held_pages(slot) for slot in self._slot_req]
-            sp.set(ctx_tokens_window=sum(
-                       min(self._plen[rid] + len(self._tokens[rid]), w)
-                       for rid in self._slot_req.values()),
-                   pages_full=sum(h[0] for h in held),
-                   pages_window=sum(h[1] for h in held))
-        elif trace.enabled():
-            # the pages the live rows' contexts span at the segment's
-            # start (what ``paged_decode`` walks), of the table's
-            ps = self.page_size
-            sp.set(pages_live=sum(
-                       -(-(self._plen[rid] + len(self._tokens[rid])) // ps)
-                       for rid in self._slot_req.values()),
-                   pages_table=self.alloc.page_table.size)
-        return super()._decode_segment_plain(n_steps, cfg, sp)
-
-    def _fwd_spec(self, params, inp, caches, lens, live, lora=None):
-        from ..core.autograd import no_grad
-
-        pools, pt = caches
-        with substituted_state(self.model, params), no_grad():
-            logits, pools, aux = \
-                self.model.forward_decode_spec_paged(
-                    Tensor(inp), pools, pt, lens, live,
-                    **self._fwd_kwargs(lora))
-        return (logits.value if isinstance(logits, Tensor) else logits,
-                (pools, pt), aux)
-
-    def _coverage_limit(self, slot: int) -> int:
-        # the spec step may only ACCEPT tokens whose KV writes landed
-        # in mapped pages — cap each row's acceptance at its grown
-        # coverage (writes past it are dropped by the sentinel)
-        return min(self.alloc.covered_tokens(slot), self.max_len)
-
-    def _reserved(self, plen: int, cfg) -> int:
-        return min(plen + cfg.max_new_tokens, self.max_len)
-
-    def _optimistic_claim(self, plen: int, cfg) -> int:
-        """Tokens an OPTIMISTIC admission claims up front: the prompt
-        plus one page of headroom (the first decode step writes at
-        position ``plen``, so bare-prompt coverage would force growth
-        before the very first segment), never more than the worst case
-        the reserved policy would take."""
-        return min(plen + self.page_size, self._reserved(plen, cfg))
-
-    def _can_admit(self, prompt_len: int, cfg) -> bool:
-        # any free slot owns zero pages, so capacity is slot-agnostic.
-        # Prefix caching never tightens this probe: a warm admission
-        # claims at most what a cold one would (shared pages count as
-        # coverage), and when the pool cannot also spare the one
-        # copy-on-write page a partial-block hit needs, admission
-        # DEGRADES the hit to full blocks instead of demanding more
-        # (so a request whose worst case exactly fills the pool still
-        # admits). can_admit saying yes must mean add_request cannot
-        # raise for capacity.
-        probe = self._free[0] if self._free else 0
-        if self.admission_mode == "reserved":
-            return self.alloc.can_fit(probe,
-                                      self._reserved(prompt_len, cfg))
-        claim = self._optimistic_claim(prompt_len, cfg)
-        if not self.alloc.can_fit(probe, claim):
-            return False
-        if self._slot_req:
-            # high watermark: while running requests already crowd the
-            # pool, pause NEW admissions before growth pressure forces
-            # a preemption — running work frees pages by finishing. An
-            # IDLE pool skips the watermark (a lone request must always
-            # be able to admit, or a big claim could wedge forever).
-            used_after = (self.alloc.used_pages
-                          + self.alloc.pages_for(claim))
-            if used_after > self.kv_watermark * self.num_pages:
-                return False
-        return True
-
-    def _lookup_degraded(self, slot: int, ids, plen: int, cfg):
-        """Shared warm-admission preamble (one-shot AND chunked):
-        longest resident cached prefix — in the admission's ADAPTER
-        namespace (the chain hash is salted with the adapter id, so a
-        base-model block can never warm-hit an adapter's admission or
-        vice versa) — degraded to full blocks when the pool cannot
-        spare the partial page's CoW."""
-        salt = self._adapter_salt(slot)
-        pids, c_map, hashes = self.alloc.lookup_prefix(ids[0],
-                                                       salt=salt)
-        pids, c_map = self._degrade_partial_hit(slot, plen, cfg,
-                                                pids, c_map)
-        return pids, c_map, hashes, salt
-
-    def _admit_cache(self, slot: int, ids, plen: int, cfg):
-        if self.prefix_cache and not self.prefix_pause:
-            pids, c_map, hashes, salt = self._lookup_degraded(
-                slot, ids, plen, cfg)
-            self._prefix_stash[slot] = {
-                "ids": ids, "c_map": c_map, "hashes": hashes,
-                "saved": min(c_map, plen - 1), "salt": salt}
-            if c_map > 0:
-                return self._admit_cache_warm(slot, ids, plen, cfg,
-                                              pids, c_map)
-        # COLD path: claim the pages (the program needs the slot's
-        # page-table row; a claim that fails, fails before any device
-        # work), then ONE program prefills into a mini cache sized to
-        # the prompt's BUCKET (no max_len slab — the pool is the whole
-        # point; the bucket keys the compiled program count to
-        # O(len(buckets))) and scatters its rows into those pages
-        with trace.span("engine.reserve"):
-            self._reserve_admit(slot, plen, cfg)
-        return self._run_prefill_paged(
-            slot, ids, plen, aidx=self._aidx_stash.get(slot, 0))
-
-    def _run_prefill_paged(self, slot: int, ids, plen: int,
-                           aidx: int = 0):
-        """``_run_prefill`` with the mini cache and the install inside
-        the program (``engine.prefill{fused=1}``): pad the prompt to its
-        bucket, run it into ``slot``'s claimed pages; returns the
-        last-position logits [1, V]."""
-        width = self._cold_width(plen)
-        # int8: the claimed pages' scale rows reset BEFORE the program
-        # runs its running absmax against them
-        self._flush_fresh_scales()
-        with self._prefill_span(plen, width, fused=1) as sp:
-            if self._layout is not None and trace.enabled():
-                # rows of the prompt that go into the window layers' rings
-                ps, ring = self.page_size, self._layout["ring_pages"]
-                first_page = max((plen - 1) // ps - ring + 1, 0)
-                sp.set(window_rows=plen - first_page * ps)
-            last_logits = self._prefill_install(
-                slot, _pad_ids(ids, width), plen, aidx)
-        self._index_prompt(slot, plen)
-        return last_logits
-
-    def _prefill_install(self, slot: int, ids, plen: int, aidx: int):
-        pt = self._device_tables()
-        pools, _ = self.caches
-        # numpy scalars ride as arguments: a jnp.int32() is a device
-        # program of its own
-        last_logits, pools = self._prefill_paged(
-            self.params, ids, pools, pt, np.int32(slot), np.int32(plen),
-            self._bank(), np.int32(aidx))
-        self.caches = (pools, pt)
-        return last_logits
-
-    def _degrade_partial_hit(self, slot: int, plen: int, cfg, pids,
-                             c_map: int):
-        """A partial-block hit (coverage ending mid-page) maps a page
-        the request must copy-on-write before its first write — one
-        page BEYOND its normal claim. When the pool cannot spare it,
-        DEGRADE the hit to full blocks (drop the partial page) rather
-        than demand extra capacity: a request whose worst case exactly
-        fills the pool must still admit, cache or no cache."""
-        ps = self.page_size
-        if not pids or c_map % ps == 0:
-            return pids, c_map
-        claim = (self._reserved(plen, cfg)
-                 if self.admission_mode == "reserved"
-                 else self._optimistic_claim(plen, cfg))
-        if self.alloc.can_fit(slot, claim + ps):
-            return pids, c_map
-        return pids[:-1], (c_map // ps) * ps
-
-    def _admit_cache_warm(self, slot: int, ids, plen: int, cfg, pids,
-                          c_map: int):
-        """Prefix-cache hit admission: gather the cached prefix KV from
-        the resident pages (a pure copy — bitwise what the original
-        prefill wrote), prefill ONLY the uncached tail at a traced
-        offset through the shared chunk program, then map the cached
-        pages read-only and install the tail. At least the LAST prompt
-        token always recomputes — its logits seed the first sampled
-        token — even when the whole prompt is resident (its KV write
-        is simply masked out then)."""
-        # compute start: everything below is served from cache; cap at
-        # plen-1 so the last position's logits exist
-        c_cmp = min(c_map, plen - 1)
-        wt = (plen - c_cmp if self.prefill_buckets is None
-              else _bucket_for(self.prefill_buckets, plen - c_cmp))
-        # the tail chunk writes mini rows [c_cmp, c_cmp+wt) — pull the
-        # compute start DOWN when the bucket would overhang max_len
-        # (the fwd's dynamic_update_slice clamps, which would corrupt
-        # cached rows); recomputing a few extra cached positions is
-        # value-neutral (their installs are masked out) and keeps the
-        # program keyed on wt alone
-        c_cmp = min(c_cmp, self.max_len - wt)
-        # tokens-saved is the compute actually skipped ([0, c_cmp)),
-        # not the raw coverage — the clamp above shrinks it
-        self._prefix_stash[slot]["saved"] = c_cmp
-        tail = plen - c_cmp
-        with trace.span("engine.mini_cache"):
-            mini = self._mini_cache(self.max_len)
-            mini = self._gather_mini(mini, pids)
-        self._count_prefill("warm")
-        tail_ids = _pad_ids(ids[:, c_cmp:], wt)
-        # bucket: the tail program's width
-        with self._prefill_span(plen, wt, cached=c_cmp):
-            last_logits, mini = self._prefill_chunk(
-                self.params, tail_ids, mini, jnp.int32(c_cmp),
-                jnp.int32(tail - 1), self._bank(),
-                jnp.int32(self._aidx_stash.get(slot, 0)))
-        with trace.span("engine.reserve"):
-            self.alloc.map_shared(slot, pids)
-            self._reserve_admit(slot, plen, cfg)
-        with trace.span("engine.install"):
-            self._install_mini(slot, mini, plen)
-        return last_logits
-
-    def _gather_mini(self, mini, pids):
-        """Copy the resident pages into the head of a max_len-width
-        dense mini cache (per layer) — the cached-prefix KV the tail
-        prefill attends over. The page vector is padded to the FULL
-        page-table row width so every warm admission shares one
-        compiled gather program (junk rows for the ``-1`` tail sit
-        past the cached coverage, overwritten or masked)."""
-        from .paged_cache import gather_pages, gather_pages_q
-
-        row = np.full((self.alloc.page_table.shape[1],), -1, np.int32)
-        row[:len(pids)] = pids
-        pages = jnp.asarray(row)
-        pools, _ = self.caches
-        out = []
-        if self.kv_dtype == "int8":
-            # dequantize whole resident pages into the float mini: the
-            # tail prefill attends over exactly the values the fused
-            # decode reads see, so warm and cold agree to quantization
-            # error, never to a format skew
-            for (kp, vp, ks, vs), (mk, mv) in zip(pools, mini):
-                mk, mv = gather_pages_q(kp, vp, ks, vs, pages, mk, mv)
-                out.append((mk, mv))
-            return out
-        for (kp, vp), (mk, mv) in zip(pools, mini):
-            mk, mv = gather_pages(kp, vp, pages, mk, mv)
-            out.append((mk, mv))
-        return out
-
-    def _cow_page(self, slot: int, page_idx: int) -> None:
-        """Host-side copy-on-write of one shared page in the
-        inter-segment gap: claim a fresh page (allocator bookkeeping),
-        copy the pool rows on device, swap the table entry (shipped at
-        the next segment)."""
-        from .paged_cache import copy_page, copy_page_q
-
-        old, new = self.alloc.cow(slot, page_idx)
-        pools, pt = self.caches
-        new_pools = []
-        if self.kv_dtype == "int8":
-            # the copy carries the page's SCALES with its rows (int8
-            # rows are meaningless under another page's scale); the
-            # note tells the allocator's scale accounting the copy
-            # happened — forgetting either fails check() loudly
-            for kp, vp, ks, vs in pools:
-                kp, vp, ks, vs = copy_page_q(kp, vp, ks, vs,
-                                             jnp.int32(old),
-                                             jnp.int32(new))
-                new_pools.append((kp, vp, ks, vs))
-            self.caches = (new_pools, pt)
-            self.alloc.note_scale_copied(new)
-            return
-        for kp, vp in pools:
-            kp, vp = copy_page(kp, vp, jnp.int32(old), jnp.int32(new))
-            new_pools.append((kp, vp))
-        self.caches = (new_pools, pt)
-
-    def _reserve_admit(self, slot: int, plen: int, cfg) -> None:
-        self.alloc.ensure(
-            slot, self._reserved(plen, cfg)
-            if self.admission_mode == "reserved"
-            else self._optimistic_claim(plen, cfg))
-
-    def _install_mini(self, slot: int, mini, plen: int) -> None:
-        from .paged_cache import install_prompt
-
-        # int8: reset freshly claimed pages' scale rows BEFORE the
-        # quantized install runs its running absmax against them
-        self._flush_fresh_scales()
-        info = self._prefix_stash.get(slot)
-        if info is not None and info["c_map"] > 0:
-            self._install_mini_warm(slot, mini, plen, info)
-        else:
-            # COLD scatter of a mini that outlived its programs (a
-            # chunked admission's): the whole mini in ONE program, the
-            # scatter the fused prefill ends with. Rows past plen land
-            # on reserved-but-unwritten positions the decode mask
-            # hides and decode writes overwrite, or drop (unmapped
-            # pages; int8: everything past plen)
-            pt = self._device_tables()
-            pools, _ = self.caches
-            self.caches = (install_prompt(pools, pt, np.int32(slot),
-                                          np.int32(plen), mini), pt)
-        self._index_prompt(slot, plen)
-
-    def _index_prompt(self, slot: int, plen: int) -> None:
-        """Prefix cache: a cold admission POPULATES the cache, a warm
-        one extends it — either way the prompt's fully-written private
-        blocks become future hits (in the admission's adapter
-        namespace). Runs once the rows are in the pages."""
-        info = self._prefix_stash.pop(slot, None)
-        if info is None:
-            return
-        ps = self.page_size
-        self.alloc.register_blocks(
-            slot, info["hashes"], info["ids"][0],
-            info["c_map"] // ps, plen // ps,
-            salt=info.get("salt", b""))
-        if info["c_map"] > 0:
-            self.alloc.count_prefix_hit(info["saved"])
-
-    def _install_mini_warm(self, slot: int, mini, plen: int,
-                           info) -> None:
-        """Install a warm admission's UNCACHED suffix: copy-on-write
-        the shared page the first write would land in (divergent
-        suffix mid-block — or, fully-cached prompts, the partial tail
-        page decode will append into), then scatter exactly the rows
-        ``[c_map, plen)``. Shared pages are never written: positions
-        below the cached coverage are masked out of the scatter, and
-        the garbage tail past ``plen`` lands only in private headroom
-        pages or drops on unmapped ones."""
-        from .paged_cache import scatter_rows, scatter_rows_q
-
-        ps = self.page_size
-        c_map = info["c_map"]
-        # first position this slot will EVER write: the uncached
-        # suffix's start, or (fully cached) decode's first append
-        p0 = c_map if c_map < plen else plen
-        if p0 % ps and self.alloc.needs_cow(slot, p0):
-            self._cow_page(slot, p0 // ps)
-        pt = self._device_tables()
-        if c_map < plen:
-            mini_len = mini[0][0].shape[1]
-            width = (plen - c_map if self.prefill_buckets is None
-                     else _bucket_for(self.prefill_buckets,
-                                      plen - c_map))
-            width = min(width, mini_len)
-            pools, _ = self.caches
-            new_pools = []
-            if self.kv_dtype == "int8":
-                # masked-out rows drop from the quantized scatter too,
-                # so shared read-only pages keep rows AND scales; the
-                # CoW'd partial page's copied scales seed the running
-                # absmax for the suffix rows landing in it
-                for (kp, vp, ks, vs), (mk, mv) in zip(pools, mini):
-                    kp, vp, ks, vs = scatter_rows_q(
-                        kp, vp, ks, vs, pt, jnp.int32(slot),
-                        jnp.int32(c_map), jnp.int32(plen), mk, mv,
-                        width=width)
-                    new_pools.append((kp, vp, ks, vs))
-            else:
-                for (kp, vp), (mk, mv) in zip(pools, mini):
-                    kp, vp = scatter_rows(
-                        kp, vp, pt, jnp.int32(slot), jnp.int32(c_map),
-                        jnp.int32(plen), mk, mv, width=width)
-                    new_pools.append((kp, vp))
-            self.caches = (new_pools, pt)
-        else:
-            pools, _ = self.caches
-            self.caches = (pools, pt)
-
-    def _warmup_prefill(self, width: int) -> None:
-        self._prefill_install(0, np.zeros((1, width), np.int32), width, 0)
-
-    def _begin_admit_cache(self, slot: int, ids, plen: int, cfg):
-        if not self.prefix_cache or self.prefix_pause:
-            return super()._begin_admit_cache(slot, ids, plen, cfg)
-        pids, c_map, hashes, salt = self._lookup_degraded(slot, ids,
-                                                          plen, cfg)
-        C = self.prefill_chunk
-        # chunk windows must stay C-aligned (an overhanging window
-        # would clamp and corrupt earlier KV), so the cursor starts at
-        # the cached coverage aligned DOWN — the [start, c_map) sliver
-        # recomputes but its writes are masked out at install
-        start = (min(c_map, plen - 1) // C) * C
-        self._prefix_stash[slot] = {"ids": ids, "c_map": c_map,
-                                    "hashes": hashes, "saved": start,
-                                    "salt": salt}
-        with trace.span("engine.reserve"):
-            self.alloc.map_shared(slot, pids)
-            self._reserve_admit(slot, plen, cfg)
-            # copy-on-write the partial shared page EAGERLY, while the
-            # claim is atomic with the reservation — install runs gaps
-            # later, and the spare page must not be stolen by growth or
-            # another admission in between
-            p0 = c_map if c_map < plen else plen
-            if p0 % self.page_size and self.alloc.needs_cow(slot, p0):
-                self._cow_page(slot, p0 // self.page_size)
-        with trace.span("engine.mini_cache"):
-            mini = self._mini_cache(self.max_len)
-            if pids:
-                # full cached coverage gathered (fixed-shape program);
-                # rows the chunks recompute from `start` just overwrite
-                # their gathered copies with bitwise-identical values
-                mini = self._gather_mini(mini, pids)
-        return mini, start
-
-    def _warmup_prefix(self) -> dict:
-        """Pre-compile every program a WARM admission can hit — the
-        page gather, the CoW page copy, and one tail-prefill + masked
-        scatter per prefill bucket — so the first cache hit never pays
-        an XLA compile inside the latency-critical gap. All calls are
-        value-neutral: nothing is mapped, every scatter row is masked
-        out (limit 0), and the page-0 self-copy happens before any
-        request owns it. Under int8 the fresh-scale flush program
-        warms here too (all-False mask — a no-op write)."""
-        out = {}
-        if self.kv_dtype == "int8":
-            t0 = time.perf_counter()
-            pools, pt = self.caches
-            self.caches = (self._reset_scales(
-                pools, jnp.zeros((self.num_pages,), bool)), pt)
-            out["reset_scales"] = time.perf_counter() - t0
-        if not self.prefix_cache:
-            return out
-        from .paged_cache import (copy_page, copy_page_q, scatter_rows,
-                                  scatter_rows_q)
-
-        quant = self.kv_dtype == "int8"
-        t0 = time.perf_counter()
-        mini = self._gather_mini(self._mini_cache(self.max_len), [])
-        pools, pt = self.caches
-        new_pools = []
-        for entry in pools:
-            if quant:
-                new_pools.append(copy_page_q(*entry, jnp.int32(0),
-                                             jnp.int32(0)))
-            else:
-                new_pools.append(copy_page(*entry, jnp.int32(0),
-                                           jnp.int32(0)))
-        self.caches = (new_pools, pt)
-        out["prefix_gather_copy"] = time.perf_counter() - t0
-        pt_dev = self._device_tables()
-        for w in (self.prefill_buckets or ()):
-            t0 = time.perf_counter()
-            _, mini = self._prefill_chunk(
-                self.params, np.zeros((1, w), np.int32), mini,
-                jnp.int32(0), jnp.int32(0), self._bank(),
-                jnp.int32(0))
-            pools, _ = self.caches
-            new_pools = []
-            for entry, (mk, mv) in zip(pools, mini):
-                if quant:
-                    new_pools.append(scatter_rows_q(
-                        *entry, pt_dev, jnp.int32(0), jnp.int32(0),
-                        jnp.int32(0), mk, mv, width=w))
-                else:
-                    new_pools.append(scatter_rows(
-                        *entry, pt_dev, jnp.int32(0), jnp.int32(0),
-                        jnp.int32(0), mk, mv, width=w))
-            self.caches = (new_pools, pt)
-            out[f"prefix_warm_{w}"] = time.perf_counter() - t0
-        return out
-
-    def _abort_admit(self, slot: int) -> None:
-        super()._abort_admit(slot)
-        self._prefix_stash.pop(slot, None)
-        self.alloc.free_slot(slot)   # release any reserved pages
-
-    def _register(self, slot: int, rid: int, first, tok_done, cfg,
-                  t0: float) -> int:
-        # a new live slot may be under-covered for the next segment
-        # (optimistic claims stop at prompt + one page) — any growth
-        # stamp predating it is stale, as is the gap's (lens, done)
-        # snapshot (admission just wrote this slot's rows). Retire/free
-        # paths only RELEASE capacity and never un-cover or advance a
-        # surviving slot, so they keep both.
-        self._growth_stamp = None
-        self._gap_sync = None
-        return super()._register(slot, rid, first, tok_done, cfg, t0)
-
-    def _retire(self, slot, event: str = "finished"):
-        super()._retire(slot, event)
-        self.alloc.free_slot(slot)
-
-    def reset_state(self) -> None:
-        # every slot's pages go back to the pool BEFORE the base rebuild
-        # reads alloc.page_table into the fresh cache tuple — a restart
-        # must leave zero pages leaked no matter what the fault
-        # interrupted
-        for slot in range(self.max_batch):
-            self.alloc.free_slot(slot)
-        # the pools are rebuilt from zeros below: every cached block's
-        # KV is gone, so the content index must go with it (parked
-        # pages return to the free heap)
-        self.alloc.clear_prefix_index()
-        # the fresh pools below start at floor scales: pending resets
-        # refer to arrays about to be dropped
-        self.alloc.take_fresh_scales()
-        self._prefix_stash.clear()
-        self._growth_stamp = None
-        self._gap_sync = None
-        super().reset_state()
-
-    # -- optimistic-mode memory pressure (host-side, between segments) -------
-    def grow_for_segment(self, n_steps: int):  # lint: hot-path
-        """Grow every live slot's page mapping to cover the coming
-        ``n_steps``-step decode segment (optimistic mode; a no-op in
-        reserved mode, where admission pre-claimed the worst case).
-        Returns the request ids whose growth could NOT be satisfied —
-        the pool is dry and the caller must preempt victims (or accept
-        :class:`PagePoolExhausted` from ``decode_segment``).
-
-        OLDEST request first (ascending rid — admission order), so
-        pressure always lands on the youngest work: combined with a
-        scheduler that never preempts the oldest survivor, the head of
-        the line always makes forward progress and pressure can never
-        deadlock the loop. A row's target is capped by its remaining
-        budget: a segment emits at most ``min(n_steps, budget)`` kept
-        tokens, whose last cache write lands at position
-        ``len + min(n_steps, budget) - 1`` — device steps past the
-        budget write into (and read from) uncovered positions, but
-        every token they produce is discarded host-side at collection,
-        so capping is safe and saves pages. NO partial growth: a slot
-        either covers the full target or joins the short list —
-        partially covered steps would emit garbage tokens the host
-        KEEPS."""
-        if self.admission_mode != "optimistic" or not self._slot_req:
-            return []
-        if self._gap_sync is None:
-            # lint: allow-host-sync(ONE cached lens/done pull per gap —
-            # growth decisions need real lengths; decode_segment's
-            # re-check reuses this exact pull via _gap_sync)
-            self._gap_sync = (np.asarray(self.lens),
-                              np.asarray(self.done_dev))
-        lens, done = self._gap_sync
-        short = []
-        for slot, rid in sorted(self._slot_req.items(),
-                                key=lambda kv: kv[1]):
-            if bool(done[slot]):
-                continue       # frozen rows never write
-            # a SPECULATING row can accept up to spec_k+1 tokens per
-            # verify step, so its per-segment growth target scales by
-            # its window width (still budget-capped: acceptance never
-            # outruns the tokens the host will keep). Draft-scratch
-            # writes past the target drop harmlessly — the spec step
-            # caps acceptance at the grown coverage.
-            w = self._spec_k_of(rid) + 1
-            target = min(int(lens[slot])
-                         + min(n_steps * w, self._budget[rid]),
-                         self.max_len)
-            if self.alloc.can_fit(slot, target):
-                self.alloc.ensure(slot, target)
-            else:
-                short.append(rid)
-        # a clean pass covers the coming segment: decode_segment(n_steps)
-        # may skip its re-check until the slot set changes (_register) or
-        # the segment runs (lens advance)
-        self._growth_stamp = n_steps if not short else None
-        if short and trace.enabled():
-            # ENGINE rids (not serving trace keys): the pool could not
-            # cover these rows' growth — the preemptions that follow in
-            # the flight ring are this event's consequence
-            trace.event("engine.grow_short",
-                        engine=self._monitor_engine,
-                        engine_rids=tuple(short),
-                        free_pages=self.alloc.free_pages)
-        return short
-
-    def preempt_request(self, rid: int, reason: str = "pressure"):
-        """Preempt an ACTIVE request under memory pressure: reclaim its
-        slot AND pages immediately (mirroring ``cancel_request``'s
-        reclaim) and return the partial tokens generated so far
-        (np.int32) — the caller owns parking them and replaying
-        ``prompt + tokens`` through normal admission later (greedy
-        replay is bitwise-identical to an unpreempted run; see the
-        serving scheduler's replay machinery). Returns None when
-        ``rid`` is not active. The request never appears in
-        ``collect_finished()``; the retirement event and the pool's
-        ``paddle_tpu_kv_preemptions_total{reason}`` counter record it.
-
-        Like ``cancel_request``: call only from the thread driving the
-        engine, BETWEEN decode segments."""
-        out = self._evict_active(rid, "preempted")
-        if out is not None:
-            self.alloc.count_preemption(reason)
-        return out
-
-    # lint: hot-path
-    def decode_segment(self, n_steps: int,
-                       cfg: Optional[GenerationConfig] = None):
-        if not self._slot_req:
-            return 0
-        if self.admission_mode == "optimistic":
-            # final guard: a driver that skipped pressure relief must
-            # fail LOUDLY here, not let write_tokens silently drop KV
-            # writes past the mapped range and corrupt the request's
-            # decode. When the scheduler's gap already ran a clean
-            # grow_for_segment(n_steps) (stamp matches, slot set
-            # unchanged since), the re-check — two blocking device
-            # fetches + an O(active) allocator pass — is skipped; the
-            # stamp is single-shot because this segment advances lens
-            short = ([] if self._growth_stamp == n_steps
-                     else self.grow_for_segment(n_steps))
-            self._growth_stamp = None
-            self._gap_sync = None    # the segment advances lens/done
-            if short:
-                raise PagePoolExhausted(
-                    short,
-                    f"page pool exhausted in the inter-segment gap: "
-                    f"requests {short} cannot grow for the next "
-                    f"{n_steps}-step segment "
-                    f"({self.alloc.available_pages} pages reclaimable) "
-                    f"— preempt victims (preempt_request) or grow "
-                    f"num_pages")
-        # int8: pages the gap claimed (growth, reserves) get their
-        # scale rows floored before this segment's quantized writes
-        self._flush_fresh_scales()
-        # reserved mode: admission reserved every running request's
-        # worst case, so no growth can fail — just ship the table
-        if self.alloc.debug:
-            self.alloc.check()
-            if self.kv_dtype == "int8":
-                # device half of the scale invariants: every live
-                # page's scales finite and positive (layer 0 stands
-                # for all layers — one program writes them all)
-                pools, _ = self.caches
-                self.alloc.check_scales(pools[0][2], pools[0][3])
-            # write_tokens drops out-of-mapping writes SILENTLY (one
-            # compiled program) and a forgotten copy-on-write would
-            # mutate a shared page other requests read — both surface
-            # as wrong tokens far downstream. Under debug_pages the gap
-            # re-asserts, per live slot, that the live length is inside
-            # the mapped pages and the imminent write lands in a
-            # private page.
-            # lint: allow-host-sync(debug_pages-only invariant check —
-            # never on the production path; the pull is the price of
-            # validating coverage before a silent-drop write)
-            lens = np.asarray(self.lens)
-            # lint: allow-host-sync(same debug_pages-only pull)
-            done = np.asarray(self.done_dev)
-            for slot, rid in self._slot_req.items():
-                if bool(done[slot]):
-                    continue
-                # a speculating row's imminent writes span its whole
-                # draft window, not just the next position — the
-                # shared-page (missing-CoW) net must cover all of it
-                self.alloc.check_coverage(
-                    slot, int(lens[slot]),
-                    write_ahead=1 + self._spec_k_of(rid))
-        pools, _ = self.caches
-        self.caches = (pools, self._device_tables())
-        return super().decode_segment(n_steps, cfg)

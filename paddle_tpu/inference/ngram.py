@@ -4,7 +4,7 @@ The draft source for LOSSLESS n-gram speculative decoding (prompt
 lookup): continue the longest recent-suffix match found earlier in the
 context. Extracted from ``generate_speculative`` so the OFFLINE path
 (:meth:`CausalLMEngine.generate_speculative`) and the BATCHED serving
-path (per-slot proposers inside the continuous-batching engines'
+path (per-slot proposers inside the continuous-batching engine's
 speculative decode segments) share one tested unit instead of two
 copies of the suffix-match logic.
 

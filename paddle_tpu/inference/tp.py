@@ -11,7 +11,7 @@ engines shard their device state over:
   on the out-dim, row-parallel o/down on the in-dim, vocab-parallel
   embedding/lm_head) — GSPMD partitions the projections and inserts
   exactly one psum per block at the row-parallel reductions;
-- **KV pools / dense cache slabs / prefill minis** shard on the
+- **KV pools / prefill minis** shard on the
   (kv_)head axis — attention is head-parallel, so the decode read never
   crosses chips; per-(page, kv_head) int8 scales shard the same way;
 - **everything per-slot** (sampling vectors, spec_k, adapter_idx, lens,
@@ -160,8 +160,7 @@ def _kv_spec(arr) -> P:
 
 
 def tp_shard_kv(caches, mesh: Mesh):
-    """Shard a per-layer cache list (dense slabs, page pools, or
-    prefill minis; entries are ``(k, v)`` or int8
+    """Shard a per-layer cache list (page pools or prefill minis; entries are ``(k, v)`` or int8
     ``(k, v, k_scale, v_scale)`` tuples) on the kv-head axis. Pure
     placement — values are untouched, so a sharded pool reads back
     bitwise what an unsharded one holds."""

@@ -200,7 +200,7 @@ class LlamaAttention(Layer):
         the cache. Returns (out, new_cache). The decode step is the
         masked_multihead_attention analog (reference
         fused_multi_transformer_op.cu.h:745); prefill uses the flash path.
-        ``lora`` (here and on every decode variant below) is the
+        ``lora`` (here and on the paged decode forwards below) is the
         per-row batched-adapter input — see :func:`_lora_add`;
         ``tp`` is the serving engine's tensor-parallel handle
         ``(mesh, axis)`` (see ``inference/tp.py``) — threaded into the
@@ -254,128 +254,30 @@ class LlamaAttention(Layer):
         val = lambda t: t.value if isinstance(t, Tensor) else t  # noqa: E731
         return self._o_lora(ctx, lora), (val(kc), val(vc))
 
-    def forward_decode_ragged(self, x, cos_full, sin_full, cache, lens,
-                              live, lora=None, tp=None):
-        """Ragged decode step: mixed-length rows, padding-free semantics.
-
-        x: [B, 1, h]; lens: [B] int32 tokens already in each ROW's cache
-        (per-row positions — rows need not agree); live: [B] bool — only
-        live rows write their k/v and advance. Reference: the reference
-        decode kernel serves mixed-length batches after remove_padding
-        (fused_multi_transformer_op.cu.h:1641) with per-sequence lengths
-        (:1680); here the per-row state IS the seq_lens vector the
-        decode_mha kernel already takes (its S-block grid skips blocks
-        past each row's length, so compute is O(lens[b]), not O(max_len)).
-        """
-        b = x.shape[0]
-        hd = self.config.head_dim
-        q, k, v = self._qkv_lora(x, lora)
-        kc0, vc0 = cache
-
-        def attend(qv, kv, vv, kc, vc):
-            max_len = kc.shape[1]
-            idx = jnp.minimum(lens, max_len - 1)
-            c = cos_full[idx][:, None, None, :]    # [B, 1, 1, d2] per row
-            s = sin_full[idx][:, None, None, :]
-            qh = apply_rotary_emb(
-                qv.reshape(b, 1, self.num_heads, hd), c, s)[:, 0]
-            kh = apply_rotary_emb(
-                kv.reshape(b, 1, self.kv_heads, hd), c, s)[:, 0]
-            vh = vv.reshape(b, self.kv_heads, hd)
-            ar = jnp.arange(b)
-            # dead rows re-write their existing cell (no-op write): the
-            # scatter stays unconditional = one compiled program
-            kw = jnp.where(live[:, None, None], kh.astype(kc.dtype),
-                           kc[ar, idx])
-            vw = jnp.where(live[:, None, None], vh.astype(vc.dtype),
-                           vc[ar, idx])
-            kc = kc.at[ar, idx].set(kw)
-            vc = vc.at[ar, idx].set(vw)
-            from ..ops._decode import gqa_decode_attention
-
-            ctx = gqa_decode_attention(
-                qh, kc, vc, lens + live.astype(jnp.int32), tp=tp)
-            return ctx.reshape(b, 1, self.num_heads * hd), kc, vc
-
-        ctx, kc, vc = apply_op(attend, q, k, v, kc0, vc0,
-                               op_name="ragged_attention")
-        val = lambda t: t.value if isinstance(t, Tensor) else t  # noqa: E731
-        return self._o_lora(ctx, lora), (val(kc), val(vc))
-
-    def forward_decode_spec(self, x, cos_full, sin_full, cache, lens,
-                            live, lora=None, tp=None):
-        """Speculative VERIFY step over the dense ragged cache: W query
-        positions per row at per-row offsets (x: [B, W, h]; position i
-        of row b sits at absolute position ``lens[b] + i``).
-
-        The serving form of the offline spec-verify forward: all W
-        tokens' K/V are written at their per-row positions first
-        (writes of dead rows or positions past max_len are DROPPED via
-        an out-of-range sentinel, so the step stays one compiled
-        program), then each query position runs the SAME
-        ``gqa_decode_attention`` call the one-token ragged step uses,
-        with its own length ``lens + i + 1`` — so position i attends
-        exactly the history a sequential decode would have, and when
-        the input tokens match the greedy continuation the logits are
-        BITWISE what ``forward_decode_ragged`` would have produced one
-        token at a time. Rejected drafts leave stale KV past the
-        accepted length; every read is length-masked and later writes
-        overwrite it (the offline path's documented convention).
-        """
-        b, w = x.shape[0], x.shape[1]
-        hd = self.config.head_dim
-        q, k, v = self._qkv_lora(x, lora)
-        kc0, vc0 = cache
-
-        def attend(qv, kv, vv, kc, vc):
-            max_len = kc.shape[1]
-            pos = lens[:, None] + jnp.arange(w, dtype=jnp.int32)[None]
-            idx = jnp.minimum(pos, max_len - 1)
-            c = cos_full[idx][:, :, None, :]   # [B, W, 1, d2] per row
-            s = sin_full[idx][:, :, None, :]
-            qh = apply_rotary_emb(qv.reshape(b, w, self.num_heads, hd),
-                                  c, s)
-            kh = apply_rotary_emb(kv.reshape(b, w, self.kv_heads, hd),
-                                  c, s)
-            vh = vv.reshape(b, w, self.kv_heads, hd)
-            ar = jnp.arange(b)
-            # dead rows / positions past the cache -> sentinel row
-            # index, dropped (NOT clamped: a clamp would overwrite the
-            # last valid cell with draft garbage)
-            tgt = jnp.where(live[:, None] & (pos < max_len), pos,
-                            max_len)
-            kc = kc.at[ar[:, None], tgt].set(kh.astype(kc.dtype),
-                                             mode="drop")
-            vc = vc.at[ar[:, None], tgt].set(vh.astype(vc.dtype),
-                                             mode="drop")
-            from ..ops._decode import gqa_decode_attention
-
-            lv = live.astype(jnp.int32)
-            # one masked decode attention per window position (W is
-            # small and static — the unroll shares the compiled step):
-            # position i's length is lens + i + 1, exactly the
-            # sequential decode's, so acceptance-matched positions
-            # reduce bitwise-identically to the one-token path
-            ctx = jnp.stack(
-                [gqa_decode_attention(qh[:, i], kc, vc,
-                                      lens + lv * (i + 1), tp=tp)
-                 for i in range(w)], axis=1)       # [B, W, Hq, hd]
-            return ctx.reshape(b, w, self.num_heads * hd), kc, vc
-
-        ctx, kc, vc = apply_op(attend, q, k, v, kc0, vc0,
-                               op_name="spec_attention")
-        val = lambda t: t.value if isinstance(t, Tensor) else t  # noqa: E731
-        return self._o_lora(ctx, lora), (val(kc), val(vc))
-
     def forward_decode_spec_paged(self, x, cos_full, sin_full, cache,
                                   page_table, lens, live, lora=None,
                                   tp=None):
-        """Paged twin of :meth:`forward_decode_spec`: W per-row query
-        positions over the shared page pool. Writes to dead rows,
-        unmapped pages, or positions past the table width are DROPPED
-        (the ``write_tokens`` sentinel convention), so a draft window
-        reaching past a slot's grown coverage degrades to fewer
-        accepted tokens instead of corrupting a neighbour's page."""
+        """Speculative VERIFY step over the page pool: W query
+        positions per row at per-row offsets (x: [B, W, h]; position i
+        of row b sits at absolute position ``lens[b] + i``).
+
+        All W tokens' K/V are written at their per-row positions first;
+        writes to dead rows, unmapped pages, or positions past the
+        table width are DROPPED (the ``write_tokens`` sentinel
+        convention — never clamped: a clamp would overwrite the last
+        valid cell with draft garbage), so the step stays one compiled
+        program and a draft window reaching past a slot's grown
+        coverage degrades to fewer accepted tokens instead of
+        corrupting a neighbour's page. Then each query position runs
+        the SAME ``paged_decode_mha`` call the one-token step uses,
+        with its own length ``lens + i + 1`` — position i attends
+        exactly the history a sequential decode would have, so when
+        the input tokens match the greedy continuation the logits are
+        BITWISE what :meth:`forward_decode_paged` would have produced
+        one token at a time. Rejected drafts leave stale KV past the
+        accepted length; every read is length-masked and later writes
+        overwrite it. The third result is the int8 path's window-write
+        rows (None on float pools)."""
         b, w = x.shape[0], x.shape[1]
         hd = self.config.head_dim
         q, k, v = self._qkv_lora(x, lora)
@@ -429,7 +331,7 @@ class LlamaAttention(Layer):
             # pages + scale tables snapshot BEFORE any store and ride
             # out as aux with the float rows: the engine restores the
             # snapshot post-acceptance and replays only the accepted
-            # prefix (ContinuousBatchingEngine._commit_spec_rows).
+            # prefix (PagedContinuousBatchingEngine._commit_spec_rows).
             from ..ops.paged_attention import paged_decode_mha
             from ..quantization.kv import quant_store_rows
 
@@ -469,12 +371,22 @@ class LlamaAttention(Layer):
     def forward_decode_paged(self, x, cos_full, sin_full, cache,
                              page_table, lens, live, lora=None,
                              tp=None):
-        """Paged decode step: like forward_decode_ragged but the KV cache
-        is this layer's slice of a shared page pool (ops/paged_attention
-        + inference/paged_cache — the vLLM-style serving layout the
-        reference's contiguous CacheKV slabs cannot express). Writes to
-        dead rows and unmapped pages are DROPPED via an out-of-range
-        sentinel, so the step stays one compiled program."""
+        """Paged decode step: mixed-length rows, padding-free semantics,
+        the KV cache this layer's slice of a shared page pool
+        (ops/paged_attention + inference/paged_cache — the vLLM-style
+        serving layout the reference's contiguous CacheKV slabs cannot
+        express).
+
+        x: [B, 1, h]; lens: [B] int32 tokens already in each ROW's cache
+        (per-row positions — rows need not agree); live: [B] bool — only
+        live rows write their k/v and advance. The reference's decode
+        kernel serves mixed-length batches after remove_padding
+        (fused_multi_transformer_op.cu.h:1641) with per-sequence lengths
+        (:1680); here the per-row state is the lengths vector
+        ``paged_decode_mha`` takes, which walks the pages each row's
+        length spans. Writes to dead rows and unmapped pages are DROPPED
+        via an out-of-range sentinel, so the step stays one compiled
+        program."""
         b = x.shape[0]
         hd = self.config.head_dim
         q, k, v = self._qkv_lora(x, lora)
@@ -613,30 +525,12 @@ class LlamaDecoderLayer(Layer):
         x = x + self.mlp(self.post_attention_layernorm(x), lora=lora)
         return x, cache
 
-    def forward_decode_ragged(self, x, cos_full, sin_full, cache, lens,
-                              live, lora=None, tp=None):
-        attn, cache = self.self_attn.forward_decode_ragged(
-            self.input_layernorm(x), cos_full, sin_full, cache, lens,
-            live, lora=lora, tp=tp)
-        x = x + attn
-        x = x + self.mlp(self.post_attention_layernorm(x), lora=lora)
-        return x, cache
-
     def forward_decode_paged(self, x, cos_full, sin_full, cache,
                              page_table, lens, live, lora=None,
                              tp=None):
         attn, cache = self.self_attn.forward_decode_paged(
             self.input_layernorm(x), cos_full, sin_full, cache,
             page_table, lens, live, lora=lora, tp=tp)
-        x = x + attn
-        x = x + self.mlp(self.post_attention_layernorm(x), lora=lora)
-        return x, cache
-
-    def forward_decode_spec(self, x, cos_full, sin_full, cache, lens,
-                            live, lora=None, tp=None):
-        attn, cache = self.self_attn.forward_decode_spec(
-            self.input_layernorm(x), cos_full, sin_full, cache, lens,
-            live, lora=lora, tp=tp)
         x = x + attn
         x = x + self.mlp(self.post_attention_layernorm(x), lora=lora)
         return x, cache
@@ -707,22 +601,6 @@ class LlamaModel(Layer):
             new_caches.append(cache)
         return self.norm(x), new_caches
 
-    def forward_decode_ragged(self, input_ids, caches, lens, live,
-                              lora=None, tp=None):
-        cfg = self.config
-        x = self.embed_tokens(input_ids)
-        max_len = caches[0][0].shape[1]
-        cos_full, sin_full = _rope_cos_sin(
-            max_len, cfg.head_dim, cfg.rope_theta,
-            x.value.dtype if isinstance(x, Tensor) else x.dtype)
-        new_caches = []
-        for i, (layer, cache) in enumerate(zip(self.layers, caches)):
-            x, cache = layer.forward_decode_ragged(
-                x, cos_full, sin_full, cache, lens, live,
-                lora=_lora_layer(lora, i), tp=tp)
-            new_caches.append(cache)
-        return self.norm(x), new_caches
-
     def init_paged_cache(self, num_pages: int, page_size: int,
                          kv_dtype: str = "bf16"):
         """Per-layer page POOLS (shared-table layout: one page_table,
@@ -762,25 +640,6 @@ class LlamaModel(Layer):
         for i, (layer, cache) in enumerate(zip(self.layers, caches)):
             x, cache = layer.forward_decode_paged(
                 x, cos_full, sin_full, cache, page_table, lens, live,
-                lora=_lora_layer(lora, i), tp=tp)
-            new_caches.append(cache)
-        return self.norm(x), new_caches
-
-    def forward_decode_spec(self, input_ids, caches, lens, live,
-                            lora=None, tp=None):
-        """Speculative verify step (dense ragged cache): input_ids
-        [B, W] at per-row offsets ``lens`` — see
-        LlamaAttention.forward_decode_spec."""
-        cfg = self.config
-        x = self.embed_tokens(input_ids)
-        max_len = caches[0][0].shape[1]
-        cos_full, sin_full = _rope_cos_sin(
-            max_len, cfg.head_dim, cfg.rope_theta,
-            x.value.dtype if isinstance(x, Tensor) else x.dtype)
-        new_caches = []
-        for i, (layer, cache) in enumerate(zip(self.layers, caches)):
-            x, cache = layer.forward_decode_spec(
-                x, cos_full, sin_full, cache, lens, live,
                 lora=_lora_layer(lora, i), tp=tp)
             new_caches.append(cache)
         return self.norm(x), new_caches
@@ -887,14 +746,6 @@ class LlamaForCausalLM(Layer):
             input_ids, caches, pos, lora=lora, tp=tp)
         return self.logits(hidden), caches
 
-    def forward_decode_ragged(self, input_ids, caches, lens, live,
-                              lora=None, tp=None):
-        """(logits [B, 1, V], new_caches) — the mixed-length decode step
-        (per-row positions; see LlamaAttention.forward_decode_ragged)."""
-        hidden, caches = self.model.forward_decode_ragged(
-            input_ids, caches, lens, live, lora=lora, tp=tp)
-        return self.logits(hidden), caches
-
     def init_paged_cache(self, num_pages: int, page_size: int,
                          kv_dtype: str = "bf16"):
         return self.model.init_paged_cache(num_pages, page_size,
@@ -902,19 +753,12 @@ class LlamaForCausalLM(Layer):
 
     def forward_decode_paged(self, input_ids, caches, page_table, lens,
                              live, lora=None, tp=None):
-        """(logits [B, 1, V], new_caches) — paged decode step (page-pool
-        KV; see LlamaAttention.forward_decode_paged)."""
+        """(logits [B, 1, V], new_caches) — the mixed-length decode step
+        over the page pool (per-row positions; see
+        LlamaAttention.forward_decode_paged)."""
         hidden, caches = self.model.forward_decode_paged(
             input_ids, caches, page_table, lens, live, lora=lora,
             tp=tp)
-        return self.logits(hidden), caches
-
-    def forward_decode_spec(self, input_ids, caches, lens, live,
-                            lora=None, tp=None):
-        """(logits [B, W, V], new_caches) — batched speculative verify
-        step at per-row offsets (dense ragged cache)."""
-        hidden, caches = self.model.forward_decode_spec(
-            input_ids, caches, lens, live, lora=lora, tp=tp)
         return self.logits(hidden), caches
 
     def forward_decode_spec_paged(self, input_ids, caches, page_table,

@@ -2,8 +2,7 @@
 
 The reference stack drives its engine from a server loop above
 AnalysisPredictor; here a dedicated scheduler THREAD owns a
-``ContinuousBatchingEngine`` / ``PagedContinuousBatchingEngine`` and
-drives the stepwise API (``add_request`` / ``decode_segment`` /
+``PagedContinuousBatchingEngine`` and drives the stepwise API (``add_request`` / ``decode_segment`` /
 ``collect_finished``) in an Orca-style iteration loop:
 
     gap:   apply adapter admin (hot LoRA load/unload) → apply
@@ -190,7 +189,7 @@ class Server:
       cannot starve forever under sustained high-priority load.
 
     Speculative-decoding knobs (engines built with ``draft_k > 0`` —
-    see :class:`ContinuousBatchingEngine`):
+    see :class:`PagedContinuousBatchingEngine`):
 
     - ``draft_k`` — convenience mirror of the engine's draft-window
       knob (None leaves the engine's own setting); set it before
@@ -332,7 +331,7 @@ class Server:
             set_fn(kv_dtype)
         if draft_k is not None:
             # convenience mirror of the engine's speculative-decoding
-            # knob (see ContinuousBatchingEngine draft_k): set before
+            # knob (see PagedContinuousBatchingEngine draft_k): set before
             # the scheduler thread starts so warmup pre-compiles the
             # widened verify program. getattr/setattr so a FaultyEngine
             # proxy routes to the wrapped engine.
@@ -350,7 +349,7 @@ class Server:
             engine.draft_k = draft_k
         if spec_mode is not None:
             # convenience mirror of the engine's speculative execution
-            # mode (see ContinuousBatchingEngine spec_mode): "device"
+            # mode (see PagedContinuousBatchingEngine spec_mode): "device"
             # fuses propose→verify→accept into one compiled segment —
             # drafts come from the per-slot device history ring, and
             # the scheduler's gap no longer drives per-step host
@@ -1025,7 +1024,7 @@ class Server:
         return out
 
     def pressure(self):
-        """KV memory-pressure snapshot (None for a dense engine):
+        """KV memory-pressure snapshot (None for an engine without pages):
         ``{"admission_mode", "occupancy", "free_pages",
         "waiting_on_pages", "preemptions"}`` — what ``/healthz``
         reports so an operator can tell "degraded by memory pressure"

@@ -14,10 +14,9 @@ Sites (the seams a serving scheduler drives):
 - ``"admit"``   — ``add_request`` / ``begin_admit`` (the admission call
   seam: the fault fires BEFORE the engine claims any capacity);
 - ``"prefill"`` — the engine's internal prefill dispatch
-  (``_run_prefill`` / ``_run_prefill_paged``), i.e. INSIDE
-  ``add_request`` after the slot (and, paged, the page reservation) was
-  claimed — exercises the admission abort guards, not just the call
-  seam;
+  (``_run_prefill_paged``), i.e. INSIDE ``add_request`` after the slot
+  and the page reservation were claimed — exercises the admission
+  abort guards, not just the call seam;
 - ``"chunk"``   — ``admit_chunk`` (one chunk of a chunked admission);
 - ``"decode"``  — ``decode_segment`` (the batch-wide seam: an injected
   :class:`~paddle_tpu.inference.generation.EngineFault` here drives the
@@ -410,8 +409,8 @@ class FaultyEngine:
     :class:`~paddle_tpu.serving.Server` drives it unchanged.
 
     The ``"prefill"`` site is hooked INSIDE the wrapped engine (its
-    ``_run_prefill`` dispatch, and the paged engine's fused
-    ``_run_prefill_paged``, are shadowed on the instance) so the fault
+    fused ``_run_prefill_paged`` dispatch is shadowed on the instance)
+    so the fault
     fires after admission capacity was claimed — the path that must
     prove the abort guards reclaim the slot and pages. ``warmup`` is
     unaffected (it drives the jitted programs directly, not the
@@ -424,10 +423,9 @@ class FaultyEngine:
     def __init__(self, engine, plan: FaultPlan):
         object.__setattr__(self, "_engine", engine)
         object.__setattr__(self, "plan", plan)
-        for name in ("_run_prefill", "_run_prefill_paged"):
-            orig = getattr(engine, name, None)
-            if orig is not None:
-                setattr(engine, name, self._faulty_prefill(orig))
+        orig = getattr(engine, "_run_prefill_paged", None)
+        if orig is not None:
+            engine._run_prefill_paged = self._faulty_prefill(orig)
 
     def _faulty_prefill(self, orig):
         def faulty_prefill(*a, **kw):

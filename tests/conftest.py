@@ -5,7 +5,7 @@ dryrun uses xla_force_host_platform_device_count the same way).
 Also hosts the tier-1 WALL-TIME BUDGET guard (bottom of this file): a
 full `-m 'not slow'` run that exceeds ~800s fails loudly with the
 move-to-slow-tier playbook instead of silently drifting into the
-driver's 870s kill."""
+driver's 1470s kill."""
 import os
 import shutil
 import sys
@@ -58,15 +58,33 @@ def _fixed_seed():
     yield
 
 
+@pytest.fixture(autouse=True)
+def _default_mesh():
+    """The mesh, the default communication group and fleet's hybrid
+    state are process-wide, and an xdist worker runs file after file in
+    one process: a test that installs an mp or pp mesh (or
+    ``fleet.init`` with ``mp_degree`` 2) must not hand it to whichever
+    test runs next. After every test they go back to their defaults
+    (the next ``get_mesh()`` builds the default mesh)."""
+    yield
+    from paddle_tpu.distributed import fleet
+    from paddle_tpu.distributed.communication import core
+    from paddle_tpu.distributed.topology import set_mesh
+
+    set_mesh(None)
+    core._reset_default_group()
+    fleet._fleet_state.update(initialized=False, hcg=None, strategy=None)
+
+
 # -- tier-1 wall-time budget guard -------------------------------------------
-# The tier-1 suite runs under a hard 870s driver timeout (ROADMAP.md);
+# The tier-1 suite runs under a hard 1470s driver timeout (ROADMAP.md);
 # blowing it kills the run at rc=124 with NO per-test attribution, and
 # PRs 1 and 6 each burned review cycles rediscovering that the fix is
 # moving minutes-scale suites to the slow tier (`pytestmark =
 # pytest.mark.slow`, run via `-m slow`). This guard fails the suite
 # LOUDLY at ~800s — while everything still passes and the slow culprit
 # is attributable via --durations — instead of letting the next PR
-# drift into the silent 870s cliff. Scope: only full tier-1-shaped runs
+# drift into the silent 1470s cliff. Scope: only full tier-1-shaped runs
 # (a `not slow` markexpr over a substantial collection); tune/disable
 # via PADDLE_TPU_TIER1_BUDGET_S (0 = off).
 _TIER1_BUDGET_S = float(os.environ.get("PADDLE_TPU_TIER1_BUDGET_S",
@@ -98,7 +116,7 @@ def pytest_sessionfinish(session, exitstatus):
         return
     tr = session.config.pluginmanager.get_plugin("terminalreporter")
     line = (f"tier-1 wall time: {wall:.0f}s "
-            f"(budget {_TIER1_BUDGET_S:.0f}s, driver timeout 870s)")
+            f"(budget {_TIER1_BUDGET_S:.0f}s, driver timeout 1470s)")
     if wall <= _TIER1_BUDGET_S:
         if tr is not None:
             tr.write_line(line)
@@ -106,7 +124,7 @@ def pytest_sessionfinish(session, exitstatus):
     msg = (
         f"\n{'=' * 72}\n"
         f"TIER-1 WALL-TIME BUDGET EXCEEDED: {line}\n"
-        f"The driver kills this suite at 870s (rc=124, no per-test\n"
+        f"The driver kills this suite at 1470s (rc=124, no per-test\n"
         f"attribution). Move the slow culprits to the slow tier\n"
         f"(`pytestmark = pytest.mark.slow`, run via `-m slow`) — the\n"
         f"PR 1 / PR 6 precedent — before the next PR hits the cliff.\n"

@@ -30,6 +30,7 @@ allocator's invariant validator is armed at every page op and every
 gap, so any reclaim bug in the preemption paths fails the suite
 loudly.
 """
+import functools
 import json
 import time
 import urllib.request
@@ -37,10 +38,11 @@ import urllib.request
 import numpy as np
 import pytest
 
+import engine_helpers
+from engine_helpers import BareEngine
 import paddle_tpu as paddle
 from paddle_tpu.inference.generation import (
-    ADMISSION_MODES, CausalLMEngine, ContinuousBatchingEngine,
-    EngineFault, GenerationConfig, PagedContinuousBatchingEngine,
+    ADMISSION_MODES, CausalLMEngine, EngineFault, GenerationConfig,
     PagePoolExhausted)
 from paddle_tpu.inference.paged_cache import PageAllocator
 from paddle_tpu.serving import (RequestCancelled, RequestFailed, Server,
@@ -64,12 +66,9 @@ def tiny_model():
     return _MODEL
 
 
-def paged_engine(model, max_batch=4, num_pages=64, page_size=4,
-                 max_pages=8, **kw):
-    kw.setdefault("debug_pages", True)
-    return PagedContinuousBatchingEngine(
-        model, max_batch=max_batch, num_pages=num_pages,
-        page_size=page_size, max_pages=max_pages, **kw)
+paged_engine = functools.partial(
+    engine_helpers.paged_engine, max_batch=4, num_pages=64, page_size=4,
+    max_pages=8, debug_pages=True)
 
 
 def _greedy(n, eos=None):
@@ -171,9 +170,9 @@ class TestAdmissionModes:
 
     def test_server_mirror_needs_idle_paged_engine(self):
         model, _ = tiny_model()
-        dense = ContinuousBatchingEngine(model, max_batch=2, max_len=32)
+        bare = BareEngine(paged_engine(model))
         with pytest.raises(ValueError, match="paged engine"):
-            Server(dense, admission_mode="optimistic", start=False)
+            Server(bare, admission_mode="optimistic", start=False)
         with pytest.raises(ValueError, match="admission_mode"):
             Server(paged_engine(model), admission_mode="nope",
                    start=False)
@@ -555,8 +554,7 @@ class TestServerPreemption:
         finally:
             httpd.shutdown()
             srv.shutdown()
-        dense = ContinuousBatchingEngine(model, max_batch=2, max_len=32)
-        srv2 = Server(dense, segment_steps=4)
+        srv2 = Server(BareEngine(paged_engine(model)), segment_steps=4)
         assert srv2.pressure() is None
         httpd2 = serve_http(srv2, port=0)
         try:

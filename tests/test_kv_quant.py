@@ -24,15 +24,17 @@ The tentpole contract, CPU-verified:
   path (same pools, same programs) — int8 is opt-in, bounded-not-
   bitwise.
 """
+
 import numpy as np
 import pytest
 
 import jax.numpy as jnp
 
+import engine_helpers
+from engine_helpers import BareEngine
 import paddle_tpu as paddle
 from paddle_tpu import monitor
-from paddle_tpu.inference.generation import (GenerationConfig,
-                                             PagedContinuousBatchingEngine)
+from paddle_tpu.inference.generation import GenerationConfig
 from paddle_tpu.inference.paged_cache import (PageAllocator,
                                               copy_page_q,
                                               gather_dense,
@@ -61,13 +63,10 @@ def tiny_model(kv_heads=4):
     return _MODELS[kv_heads]
 
 
-def paged_engine(model, kv_dtype="bf16", max_batch=3, num_pages=24,
-                 page_size=4, max_pages=10, **kw):
-    kw.setdefault("debug_pages", True)
-    return PagedContinuousBatchingEngine(
-        model, max_batch=max_batch, num_pages=num_pages,
-        page_size=page_size, max_pages=max_pages, kv_dtype=kv_dtype,
-        **kw)
+def paged_engine(model, kv_dtype="bf16", **kw):
+    kw = {"max_batch": 3, "num_pages": 24, "page_size": 4,
+          "max_pages": 10, "debug_pages": True, **kw}
+    return engine_helpers.paged_engine(model, kv_dtype=kv_dtype, **kw)
 
 
 def _greedy(n, **kw):
@@ -597,12 +596,9 @@ class TestServerAndMetrics:
         model, _ = tiny_model()
         with pytest.raises(ValueError, match="kv_dtype"):
             Server(paged_engine(model), kv_dtype="fp8", start=False)
-        from paddle_tpu.inference.generation import \
-            ContinuousBatchingEngine
-        dense = ContinuousBatchingEngine(model, max_batch=1,
-                                         max_len=32)
         with pytest.raises(ValueError, match="paged"):
-            Server(dense, kv_dtype="int8", start=False)
+            Server(BareEngine(paged_engine(model)), kv_dtype="int8",
+                   start=False)
 
     def test_set_kv_dtype_idle_only(self):
         model, _ = tiny_model()

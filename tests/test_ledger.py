@@ -27,17 +27,18 @@ Covers ``paddle_tpu.monitor.ledger`` end to end on CPU:
   ledger with nonzero cost analysis and a dispatch count matching the
   monitored_jit counters.
 """
+import functools
 import json
 import urllib.request
 
 import numpy as np
 import pytest
 
+import engine_helpers
 import paddle_tpu as paddle
 from paddle_tpu import monitor
 from paddle_tpu.device import peaks as peaks_mod
-from paddle_tpu.inference.generation import (GenerationConfig,
-                                             PagedContinuousBatchingEngine)
+from paddle_tpu.inference.generation import GenerationConfig
 from paddle_tpu.monitor import ledger
 from paddle_tpu.monitor.provenance import env_stamp
 from paddle_tpu.serving import Server, serve_http
@@ -68,12 +69,9 @@ def make_adapter(model, seed, targets=("q", "v"), rank=2, scale=0.6):
             for t, (d_in, d_out) in shapes.items()}
 
 
-def paged_engine(model, max_batch=4, num_pages=64, page_size=4,
-                 max_pages=16, **kw):
-    kw.setdefault("debug_pages", True)
-    return PagedContinuousBatchingEngine(
-        model, max_batch=max_batch, num_pages=num_pages,
-        page_size=page_size, max_pages=max_pages, **kw)
+paged_engine = functools.partial(
+    engine_helpers.paged_engine, max_batch=4, num_pages=64, page_size=4,
+    max_pages=16, debug_pages=True)
 
 
 @pytest.fixture()

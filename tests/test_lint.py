@@ -625,10 +625,9 @@ class TestRepoGate:
                 "_RemoteQueue.depth", "_RemoteAlloc.free_pages",
                 "_RemoteAdapters.__contains__"},
             "paddle_tpu/inference/generation.py": {
-                "ContinuousBatchingEngine.decode_segment",
-                "ContinuousBatchingEngine._decode_segment_spec",
-                "ContinuousBatchingEngine.load",
                 "PagedContinuousBatchingEngine.decode_segment",
+                "PagedContinuousBatchingEngine._decode_segment_spec",
+                "PagedContinuousBatchingEngine.load",
                 "PagedContinuousBatchingEngine.grow_for_segment"},
         }
         for rel, want in expected.items():

@@ -33,18 +33,20 @@ Covers the batched-adapter contract on CPU:
 - router adapter affinity: requests prefer replicas with the adapter
   resident.
 """
+import functools
 import json
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
+import engine_helpers
 import paddle_tpu as paddle
 from paddle_tpu import monitor
-from paddle_tpu.inference.generation import (
-    ContinuousBatchingEngine, EngineFault, GenerationConfig,
-    PagedContinuousBatchingEngine)
+from paddle_tpu.inference.generation import (CausalLMEngine, EngineFault,
+                                             GenerationConfig, _lora_rows)
 from paddle_tpu.serving import AdapterRegistry, Server
 from paddle_tpu.serving.queue import RequestQueue
 
@@ -77,15 +79,10 @@ def make_adapter(model, seed, targets=("q", "v"), rank=2, scale=0.6):
             for t, (d_in, d_out) in shapes.items()}
 
 
-def paged_engine(model, max_batch=4, num_pages=64, page_size=4,
-                 max_pages=8, **kw):
-    kw.setdefault("debug_pages", True)
-    kw.setdefault("lora_capacity", 3)
-    kw.setdefault("lora_rank", 4)
-    kw.setdefault("lora_targets", ("q", "v"))
-    return PagedContinuousBatchingEngine(
-        model, max_batch=max_batch, num_pages=num_pages,
-        page_size=page_size, max_pages=max_pages, **kw)
+paged_engine = functools.partial(
+    engine_helpers.paged_engine, max_batch=4, num_pages=64, page_size=4,
+    max_pages=8, debug_pages=True, lora_capacity=3, lora_rank=4,
+    lora_targets=("q", "v"))
 
 
 def _greedy(n, adapter=None, eos=None):
@@ -110,6 +107,33 @@ def _assert_no_leaks(eng):
 
 
 PROMPT = list(range(1, 9))
+
+
+def _merged_clone(params, scale):
+    """A fresh seeded clone of ``tiny_model(4)`` with the adapter merged
+    into its weights: W' = W + (B A)^T * scale (scale = alpha / r)."""
+    paddle.seed(0)
+    from paddle_tpu.models import LlamaForCausalLM, llama_config
+    merged = LlamaForCausalLM(llama_config(
+        "tiny", num_hidden_layers=1, num_key_value_heads=4))
+    layer = merged.model.layers[0]
+    projs = {"q": layer.self_attn.q_proj, "v": layer.self_attn.v_proj,
+             "gate": layer.mlp.gate_proj}
+    for t, (a, b) in params.items():
+        w = projs[t].weight
+        w.set_value(np.asarray(w.value) + (b @ a).T * scale)
+    return merged
+
+
+def _prefill_logits(eng, model, aidx=0):
+    """PROMPT's last-position logits from the engine's prefill forward
+    under bank row ``aidx``, padded to its bucket."""
+    ids = np.zeros((1, 16), np.int32)
+    ids[0, :len(PROMPT)] = PROMPT
+    logits, _ = jax.jit(lambda p, bank: eng._fwd_prefill(
+        p, ids, model.init_cache(1, 16),
+        lora=_lora_rows(bank, aidx, ids)))(eng.params, eng._bank())
+    return np.asarray(logits[:, len(PROMPT) - 1])
 
 
 # -- registry lifecycle ------------------------------------------------------
@@ -225,13 +249,18 @@ class TestLoraParity:
         eng.close()
 
     def test_mixed_batch_matches_solo_dense(self):
+        """The mixed batch against ``CausalLMEngine``, one request at a
+        time: the base row on the model, the adapter row on a clone
+        with the adapter's deltas merged into its weights."""
         model, _ = tiny_model(4)
-        eng = ContinuousBatchingEngine(model, max_batch=3, max_len=32,
-                                       lora_capacity=2, lora_rank=4,
-                                       lora_targets=("q", "v"))
-        eng.load_adapter("a1", make_adapter(model, 11))
-        solo = {name: _run_one(eng, PROMPT, adapter=name)
-                for name in (None, "a1")}
+        eng = paged_engine(model, max_batch=3, lora_capacity=2)
+        params = make_adapter(model, 11)
+        eng.load_adapter("a1", params)
+        ids = np.asarray([PROMPT], np.int32)
+        solo = {name: list(CausalLMEngine(m, max_batch=1, max_len=32)
+                           .generate(ids, _greedy(6))[0, len(PROMPT):])
+                for name, m in ((None, model),
+                                ("a1", _merged_clone(params, 1.0)))}
         rids = {name: eng.add_request(np.asarray(PROMPT, np.int32),
                                       _greedy(6, name))
                 for name in (None, "a1")}
@@ -263,24 +292,10 @@ class TestLoraParity:
         eng = paged_engine(model, lora_capacity=1,
                            lora_targets=("q", "v", "gate"))
         eng.load_adapter("m", params, alpha=4)   # scale 2.0
-        got = np.asarray(eng._run_prefill(
-            np.asarray([PROMPT], np.int32), len(PROMPT),
-            model.init_cache(1, 16), aidx=1)[0])
-        # merge W' = W + (B A)^T * alpha/r into a fresh seeded clone
-        paddle.seed(0)
-        from paddle_tpu.models import LlamaForCausalLM, llama_config
-        merged = LlamaForCausalLM(llama_config(
-            "tiny", num_hidden_layers=1, num_key_value_heads=4))
-        layer = merged.model.layers[0]
-        projs = {"q": layer.self_attn.q_proj, "v": layer.self_attn.v_proj,
-                 "gate": layer.mlp.gate_proj}
-        for t, (a, b) in params.items():
-            w = projs[t].weight
-            w.set_value(np.asarray(w.value) + (b @ a).T * 2.0)
+        got = _prefill_logits(eng, model, aidx=1)
+        merged = _merged_clone(params, 2.0)
         eng2 = paged_engine(merged, lora_capacity=0)
-        want = np.asarray(eng2._run_prefill(
-            np.asarray([PROMPT], np.int32), len(PROMPT),
-            merged.init_cache(1, 16))[0])
+        want = _prefill_logits(eng2, merged)
         np.testing.assert_allclose(got, want, atol=2e-4, rtol=1e-4)
         eng.close()
         eng2.close()

@@ -30,12 +30,7 @@ def _mesh():
     from paddle_tpu.distributed.communication import core
 
     core._reset_default_group()
-    yield mesh
-    # the mesh is process-wide: left at mp=8 it breaks whichever file
-    # the same xdist worker runs next (a 97-token vocabulary does not
-    # divide by 8); None makes the next get_mesh() build the default
-    set_mesh(None)
-    core._reset_default_group()
+    return mesh
 
 
 class TestColumnRowParallel:

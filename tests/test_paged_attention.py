@@ -198,7 +198,7 @@ class TestPagedEngine:
 
     def test_greedy_matches_ragged_engine(self):
         from paddle_tpu.inference.generation import (
-            ContinuousBatchingEngine, GenerationConfig,
+            CausalLMEngine, GenerationConfig,
             PagedContinuousBatchingEngine)
 
         model, cfg = self._model()
@@ -207,9 +207,8 @@ class TestPagedEngine:
         rng = np.random.RandomState(0)
         prompts = [rng.randint(0, cfg.vocab_size, (n,)).astype(np.int32)
                    for n in (5, 9, 3)]
-        outs_r = ContinuousBatchingEngine(
-            model, max_batch=3, max_len=64).serve(prompts, gcfg,
-                                                  segment_steps=4)
+        ref = CausalLMEngine(model, max_batch=1, max_len=64)
+        outs_r = [ref.generate(p[None], gcfg)[0, len(p):] for p in prompts]
         paged = PagedContinuousBatchingEngine(
             model, max_batch=3, num_pages=12, page_size=8, max_pages=8)
         outs_p = paged.serve(prompts, gcfg, segment_steps=4)
@@ -325,7 +324,7 @@ class TestPagedGQA:
     def test_engine_with_gqa_model(self):
         import paddle_tpu as paddle
         from paddle_tpu.inference.generation import (
-            ContinuousBatchingEngine, GenerationConfig,
+            CausalLMEngine, GenerationConfig,
             PagedContinuousBatchingEngine)
         from paddle_tpu.models import LlamaForCausalLM, llama_config
 
@@ -338,9 +337,8 @@ class TestPagedGQA:
         rng = np.random.RandomState(2)
         prompts = [rng.randint(0, cfg.vocab_size, (n,)).astype(np.int32)
                    for n in (6, 11)]
-        outs_r = ContinuousBatchingEngine(
-            model, max_batch=2, max_len=64).serve(prompts, gcfg,
-                                                  segment_steps=4)
+        ref = CausalLMEngine(model, max_batch=1, max_len=64)
+        outs_r = [ref.generate(p[None], gcfg)[0, len(p):] for p in prompts]
         outs_p = PagedContinuousBatchingEngine(
             model, max_batch=2, num_pages=10, page_size=8,
             max_pages=8).serve(prompts, gcfg, segment_steps=4)

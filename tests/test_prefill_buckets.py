@@ -14,13 +14,15 @@ import threading
 import numpy as np
 import pytest
 
+import jax
+
+from engine_helpers import paged_engine
 import paddle_tpu as paddle
 from paddle_tpu import monitor
 from paddle_tpu.inference.generation import (CausalLMEngine,
-                                             ContinuousBatchingEngine,
                                              GenerationConfig,
                                              PagedContinuousBatchingEngine,
-                                             prefill_buckets_for)
+                                             _pad_ids, prefill_buckets_for)
 from paddle_tpu.models import LlamaForCausalLM, llama_config
 from paddle_tpu.serving import Server, serve_http
 
@@ -77,14 +79,12 @@ class TestBucketSpec:
     def test_engine_knob_validation(self):
         model, _ = tiny_model(layers=1)
         with pytest.raises(ValueError, match="prefill_chunk"):
-            ContinuousBatchingEngine(model, max_batch=1, max_len=32,
-                                     prefill_chunk=0)
+            paged_engine(model, max_batch=1, max_len=32, prefill_chunk=0)
         # a chunk that doesn't divide max_len would let a final chunk
         # window overhang the cache, where dynamic_update_slice CLAMPS
         # and silently overwrites earlier prompt KV — rejected up front
         with pytest.raises(ValueError, match="multiple"):
-            ContinuousBatchingEngine(model, max_batch=1, max_len=100,
-                                     prefill_chunk=64)
+            paged_engine(model, max_batch=1, max_len=96, prefill_chunk=64)
 
 
 class TestPrefillParityBitwise:
@@ -180,11 +180,10 @@ def _prompts(cfg):
 
 def _reference(model, cfg):
     """Unbucketed (exact-length prefill) engine outputs — the parity
-    target for both the dense and paged bucketed engines (their outputs
-    are byte-identical, asserted by PR 2's engine tests)."""
+    target for the bucketed engine."""
     if "want" not in _REF:
         gc = GenerationConfig(max_new_tokens=6, eos_token_id=None)
-        _REF["want"] = _serve(ContinuousBatchingEngine(
+        _REF["want"] = _serve(paged_engine(
             model, max_batch=3, max_len=64, prefill_buckets=None),
             _prompts(cfg), gc)
     return _REF["want"]
@@ -194,22 +193,6 @@ class TestBoundedCompile:
     """ISSUE-3 acceptance: >= 6 requests with distinct prompt lengths
     compile at most len(buckets) prefill programs (monitored_jit miss
     counters), with tokens identical to the unbucketed engine."""
-
-    def test_dense_engine(self, mon):
-        model, cfg = tiny_model(layers=1)
-        prompts = _prompts(cfg)
-        gc = GenerationConfig(max_new_tokens=6, eos_token_id=None)
-        want = _reference(model, cfg)
-        monitor.reset()
-        eng = ContinuousBatchingEngine(model, max_batch=3, max_len=64)
-        assert len(set(PLENS)) >= 6
-        got = _serve(eng, prompts, gc)
-        assert got == want
-        misses = _jit_misses()
-        assert misses.get("cb_prefill", 0) <= len(eng.prefill_buckets), \
-            misses
-        # the mix above actually exercises more lengths than buckets
-        assert len(set(PLENS)) > misses.get("cb_prefill", 0)
 
     def test_paged_engine(self, mon):
         model, cfg = tiny_model(layers=1)
@@ -428,7 +411,7 @@ class TestFreeListDeterminism:
 
     def test_slot_order_after_aborts(self):
         model, cfg = tiny_model(layers=1)
-        eng = ContinuousBatchingEngine(model, max_batch=4, max_len=32)
+        eng = paged_engine(model, max_batch=4, max_len=32)
         gc = GenerationConfig(max_new_tokens=8, eos_token_id=None)
         rng = np.random.RandomState(7)
 
@@ -517,8 +500,11 @@ def _unfused_admit(eng, prompt, gc):
     eng._aidx_stash[slot] = 0
     rid = eng._next_req
     eng._next_req += 1
-    mini = eng._mini_cache(eng._prefill_width(plen))
-    logits, mini = eng._run_prefill(ids, plen, mini)
+    width = eng._prefill_width(plen)
+    logits, mini = jax.jit(lambda params, padded, mini: eng._fwd_prefill(
+        params, padded, mini))(eng.params, _pad_ids(ids, width),
+                               eng._mini_cache(width))
+    logits = logits[:, plen - 1]
     eng._reserve_admit(slot, plen, gc)
     eng._install_mini(slot, mini, plen)
     eng._first_token(slot, rid, ids, plen, logits, gc, 0, 0.0)
@@ -534,8 +520,8 @@ def _drain(eng, steps=4):
 class TestFusedColdAdmission:
     """ISSUE 26: a cold one-shot admission of the paged engine is ONE
     program per bucket (mini cache, prefill and page install), with the
-    pages claimed before it. Same values as the separate steps, as the
-    dense engine and as ``CausalLMEngine``."""
+    pages claimed before it. Same values as the separate steps and as
+    ``CausalLMEngine``."""
 
     @pytest.mark.parametrize("plen", FUSED_PLENS)
     def test_tokens_match_dense_and_reference(self, plen):
@@ -545,12 +531,10 @@ class TestFusedColdAdmission:
         gc = GenerationConfig(max_new_tokens=6, eos_token_id=None)
         want = list(CausalLMEngine(model, max_batch=1, max_len=64)
                     .generate(p[None], gc)[0][plen:])
-        dense = _serve(ContinuousBatchingEngine(
-            model, max_batch=3, max_len=64), [p], gc)[0]
         eng = _fused_paged(model)
         assert eng._prefill_width(plen) == {9: 16, 20: 32, 40: 64}[plen]
         got = _serve(eng, [p], gc)[0]
-        assert got == dense == want
+        assert got == want
         eng.alloc.check()
         assert eng.alloc.free_pages == eng.num_pages
 
@@ -589,7 +573,7 @@ class TestFusedColdAdmission:
         fused.alloc.check()
 
     def test_lora_adapter_rides_the_fused_program(self):
-        from test_lora_serving import make_adapter
+        from test_lora_serving import _merged_clone, make_adapter
 
         model, cfg = tiny_model(layers=1)
         params = make_adapter(model, 7)
@@ -598,16 +582,17 @@ class TestFusedColdAdmission:
         base = GenerationConfig(max_new_tokens=6, eos_token_id=None)
         p = np.arange(1, 21, dtype=np.int32)
         kw = dict(lora_capacity=2, lora_rank=2, lora_targets=("q", "v"))
-        dense = ContinuousBatchingEngine(model, max_batch=3, max_len=64,
-                                         **kw)
         eng = _fused_paged(model, **kw)
-        for e in (dense, eng):
-            e.load_adapter("a", params, alpha=4)
-        want, got = _serve(dense, [p], gc)[0], _serve(eng, [p], gc)[0]
+        eng.load_adapter("a", params, alpha=4)
+        # the reference: the adapter merged into a clone's weights
+        # (alpha 4 over rank 2), one request through CausalLMEngine
+        want = list(CausalLMEngine(_merged_clone(params, 2.0), max_batch=1,
+                                   max_len=64).generate(p[None], base)[
+            0, len(p):])
+        got = _serve(eng, [p], gc)[0]
         assert got == want
         # and the adapter did something: the base model answers otherwise
         assert _serve(eng, [p], base)[0] != got
-        dense.close()
         eng.close()
 
     def test_admitted_while_others_decode(self):

@@ -31,13 +31,15 @@ Every paged engine here runs with ``debug_pages=True`` — the
 refcount-aware validator is armed at every page op and every gap, so
 any sharing bug in these paths fails the suite loudly.
 """
+import functools
+
 import numpy as np
 import pytest
 
+import engine_helpers
 import paddle_tpu as paddle
-from paddle_tpu.inference.generation import (
-    ContinuousBatchingEngine, GenerationConfig,
-    PagedContinuousBatchingEngine)
+from paddle_tpu.inference.generation import (CausalLMEngine,
+                                             GenerationConfig)
 from paddle_tpu.inference.paged_cache import PageAllocator
 from paddle_tpu.serving import Server
 
@@ -69,12 +71,9 @@ def ref_tokens(ids, n=6, kv_heads=4):
     return _run_one(_REFS[kv_heads], np.asarray(ids, np.int32), n=n)
 
 
-def paged_engine(model, max_batch=4, num_pages=64, page_size=4,
-                 max_pages=8, **kw):
-    kw.setdefault("debug_pages", True)
-    return PagedContinuousBatchingEngine(
-        model, max_batch=max_batch, num_pages=num_pages,
-        page_size=page_size, max_pages=max_pages, **kw)
+paged_engine = functools.partial(
+    engine_helpers.paged_engine, max_batch=4, num_pages=64, page_size=4,
+    max_pages=8, debug_pages=True)
 
 
 def _greedy(n, eos=None):
@@ -306,11 +305,12 @@ class TestParity:
         _assert_no_leaks(eng)
 
         if kv_heads == 4:
-            # the dense engine has no prefix-cache machinery at all —
-            # and its tokens agree with the paged warm path
-            dense = ContinuousBatchingEngine(model, max_batch=2,
-                                             max_len=32)
-            assert _run_one(dense, donor) == want
+            # the reference engine has no prefix-cache machinery at all
+            # (no pages either) — and its tokens agree with the paged
+            # warm path
+            dense = CausalLMEngine(model, max_batch=1, max_len=32)
+            assert list(dense.generate(donor[None], _greedy(6))[
+                0, len(donor):]) == want
 
     def test_concurrent_sharing_parity(self):
         model, cfg = tiny_model()

@@ -9,6 +9,7 @@ TTFT / queue-depth via the monitor — plus the engine-level capacity
 probe, cancellation, per-request-config threading, deadline, drain and
 HTTP front-end contracts.
 """
+import functools
 import json
 import threading
 import time
@@ -16,12 +17,11 @@ import time
 import numpy as np
 import pytest
 
+import engine_helpers
 import paddle_tpu as paddle
 from paddle_tpu import monitor
 from paddle_tpu.inference.generation import (CausalLMEngine,
-                                             ContinuousBatchingEngine,
-                                             GenerationConfig,
-                                             PagedContinuousBatchingEngine)
+                                             GenerationConfig)
 from paddle_tpu.models import LlamaForCausalLM, llama_config
 from paddle_tpu.serving import (DeadlineExpired, QueueFull,
                                 RequestCancelled, RequestFailed,
@@ -34,11 +34,9 @@ def tiny_model(layers=1, seed=0):
     return LlamaForCausalLM(cfg), cfg
 
 
-def paged_engine(model, max_batch=3, num_pages=24, page_size=8,
-                 max_pages=8):
-    return PagedContinuousBatchingEngine(
-        model, max_batch=max_batch, num_pages=num_pages,
-        page_size=page_size, max_pages=max_pages)
+paged_engine = functools.partial(
+    engine_helpers.paged_engine, max_batch=3, num_pages=24, page_size=8,
+    max_pages=8)
 
 
 @pytest.fixture()
@@ -148,7 +146,7 @@ class TestCapacityProbe:
 
     def test_dense_probe_and_loud_add(self):
         model, cfg = tiny_model()
-        eng = ContinuousBatchingEngine(model, max_batch=2, max_len=32)
+        eng = engine_helpers.paged_engine(model, max_batch=2, max_len=32)
         gc = GenerationConfig(max_new_tokens=4, eos_token_id=None)
         assert eng.free_slots() == 2
         assert eng.can_admit(5, gc)
@@ -270,7 +268,7 @@ class TestPerRequestConfigs:
             max_new_tokens=10, eos_token_id=None))[0, 7:]
         eos = int(probe[3])
 
-        eng = ContinuousBatchingEngine(model, max_batch=3, max_len=64)
+        eng = engine_helpers.paged_engine(model, max_batch=3, max_len=64)
         r1 = eng.add_request(p_greedy, gc_greedy)
         r2 = eng.add_request(p_samp, GenerationConfig(
             max_new_tokens=6, do_sample=True, temperature=0.7, top_k=9,
@@ -299,8 +297,7 @@ class TestPerRequestConfigs:
         p = rng.randint(0, cfg.vocab_size, (6,)).astype(np.int32)
 
         def run(seed):
-            eng = ContinuousBatchingEngine(model, max_batch=1,
-                                           max_len=64)
+            eng = engine_helpers.paged_engine(model, max_batch=1, max_len=64)
             rid = eng.add_request(p, GenerationConfig(
                 max_new_tokens=16, do_sample=True, temperature=3.0,
                 seed=seed, eos_token_id=None))

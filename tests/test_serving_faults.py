@@ -24,6 +24,7 @@ deterministic injection harness (`paddle_tpu.testing.faults`):
   promptly, monitor fault/restart/degraded export, and the
   serve_bench chaos soak (slow tier).
 """
+import functools
 import json
 import threading
 import time
@@ -31,11 +32,11 @@ import time
 import numpy as np
 import pytest
 
+import engine_helpers
 import paddle_tpu as paddle
 from paddle_tpu import monitor
 from paddle_tpu.inference.generation import (CausalLMEngine, EngineFault,
                                              GenerationConfig,
-                                             PagedContinuousBatchingEngine,
                                              RequestFault, classify_fault)
 from paddle_tpu.serving import (ControlPlane, ControlPolicy,
                                 ElasticController, RequestCancelled,
@@ -53,15 +54,12 @@ def tiny_model(layers=1, seed=0):
     return LlamaForCausalLM(cfg), cfg
 
 
-def paged_engine(model, max_batch=3, num_pages=24, page_size=8,
-                 max_pages=8, **kw):
-    # the whole chaos suite runs with the allocator's invariant
-    # validator armed: a reclaim bug on any abort/retire path fails
-    # loudly at the faulty op instead of corrupting a neighbour's KV
-    kw.setdefault("debug_pages", True)
-    return PagedContinuousBatchingEngine(
-        model, max_batch=max_batch, num_pages=num_pages,
-        page_size=page_size, max_pages=max_pages, **kw)
+# the whole chaos suite runs with the allocator's invariant validator
+# armed: a reclaim bug on any abort/retire path fails loudly at the
+# faulty op instead of corrupting a neighbour's KV
+paged_engine = functools.partial(
+    engine_helpers.paged_engine, max_batch=3, num_pages=24, page_size=8,
+    max_pages=8, debug_pages=True)
 
 
 def faulty_server(plan=None, model_layers=1, **kw):

@@ -14,7 +14,7 @@ The tentpole contract, CPU-verified:
   structurally 0 in device mode (host mode counts one per verify
   forward), and the ledger shows ONE ``cb_spec_device_segment``
   program with dispatches == segments, not steps;
-- FULL-MATRIX COMPOSITION: dense+paged × MHA+GQA × int8 KV × LoRA mix
+- FULL-MATRIX COMPOSITION: MHA+GQA × int8 KV × LoRA mix
   × TP, prefix warm hits with CoW, optimistic-admission preemption and
   engine-restart replay (the history ring rebuilds from
   prompt+generated exactly like the host proposer), all under
@@ -29,8 +29,8 @@ import jax
 
 import paddle_tpu as paddle
 from paddle_tpu import monitor
-from paddle_tpu.inference.generation import (ContinuousBatchingEngine,
-                                             GenerationConfig,
+from engine_helpers import paged_engine
+from paddle_tpu.inference.generation import (GenerationConfig,
                                              PagedContinuousBatchingEngine)
 from paddle_tpu.inference.ngram import NgramIndex, propose_device
 from paddle_tpu.models import LlamaForCausalLM, llama_config
@@ -146,13 +146,13 @@ class TestKnobs:
         model, _ = tiny_model(layers=1)
         kw = dict(max_batch=1, max_len=64, draft_k=4)
         with pytest.raises(ValueError, match="spec_mode"):
-            ContinuousBatchingEngine(model, spec_mode="gpu", **kw)
+            paged_engine(model, spec_mode="gpu", **kw)
         with pytest.raises(ValueError, match="spec_draft"):
-            ContinuousBatchingEngine(model, spec_draft="eagle", **kw)
+            paged_engine(model, spec_draft="eagle", **kw)
         for bad in (7, True, 2.5, "128"):
             with pytest.raises(ValueError, match="spec_history"):
-                ContinuousBatchingEngine(model, spec_history=bad, **kw)
-        eng = ContinuousBatchingEngine(model, spec_mode="device", **kw)
+                paged_engine(model, spec_history=bad, **kw)
+        eng = paged_engine(model, spec_mode="device", **kw)
         assert eng.spec_mode == "device"
         assert eng.spec_draft == "ngram" and eng.spec_history == 128
 
@@ -167,8 +167,7 @@ class TestKnobs:
 
     def test_server_mirror_knob(self):
         model, _ = tiny_model(layers=1)
-        eng = ContinuousBatchingEngine(model, max_batch=1, max_len=64,
-                                       draft_k=3)
+        eng = paged_engine(model, max_batch=1, max_len=64, draft_k=3)
         with pytest.raises(ValueError, match="spec_mode"):
             Server(eng, start=False, spec_mode="turbo")
         assert eng.spec_mode == "host"       # rejected before mutation
@@ -179,56 +178,37 @@ class TestKnobs:
 
 class TestBitwiseParity:
     """Device-mode emitted tokens == host-mode == plain decode, per
-    slot, across engines and head layouts."""
-
-    @pytest.mark.parametrize("kv_heads", [None, 2],
-                             ids=["mha", "gqa"])
-    def test_dense_device_vs_host_vs_plain(self, kv_heads):
-        model, _ = tiny_model(kv_heads=kv_heads)
-        ref = _run(ContinuousBatchingEngine(model, max_batch=2,
-                                            max_len=128),
-                   [REP, RND], [_greedy(24), _greedy(24)])
-        host = _run(ContinuousBatchingEngine(
-            model, max_batch=2, max_len=128, draft_k=6),
-            [REP, RND], [_spec(24), _spec(24)])
-        dev_eng = ContinuousBatchingEngine(
-            model, max_batch=2, max_len=128, draft_k=6,
-            spec_mode="device")
-        dev = _run(dev_eng, [REP, RND], [_spec(24), _spec(24)])
-        for a, b, c in zip(ref, host, dev):
-            np.testing.assert_array_equal(a, b)
-            np.testing.assert_array_equal(a, c)
-        st = dev_eng.spec_stats()
-        assert st["accepted"] > 0           # drafts did real work
-        assert st["emitted"] == st["slot_steps"] + st["accepted"]
-        assert st["host_syncs"] == 0
+    slot, across head layouts."""
 
     @pytest.mark.parametrize("kv_heads", [None, 2],
                              ids=["mha", "gqa"])
     def test_paged_device_vs_plain(self, kv_heads):
         model, _ = tiny_model(kv_heads=kv_heads)
-        ref = _run(PagedContinuousBatchingEngine(
-            model, max_batch=2, num_pages=24, page_size=8,
-            max_pages=16, debug_pages=True),
-            [REP, RND], [_greedy(24), _greedy(24)])
+        kw = dict(max_batch=2, num_pages=24, page_size=8, max_pages=16,
+                  debug_pages=True)
+        ref = _run(PagedContinuousBatchingEngine(model, **kw),
+                   [REP, RND], [_greedy(24), _greedy(24)])
+        host = _run(PagedContinuousBatchingEngine(model, draft_k=6, **kw),
+                    [REP, RND], [_spec(24), _spec(24)])
         eng = PagedContinuousBatchingEngine(
-            model, max_batch=2, num_pages=24, page_size=8,
-            max_pages=16, draft_k=6, spec_mode="device",
-            debug_pages=True)
+            model, draft_k=6, spec_mode="device", **kw)
         out = _run(eng, [REP, RND], [_spec(24), _spec(24)])
-        for a, b in zip(ref, out):
+        for a, b, c in zip(ref, host, out):
             np.testing.assert_array_equal(a, b)
-        assert eng.spec_stats()["accepted"] > 0
+            np.testing.assert_array_equal(a, c)
+        st = eng.spec_stats()
+        assert st["accepted"] > 0           # drafts did real work
+        assert st["emitted"] == st["slot_steps"] + st["accepted"]
+        assert st["host_syncs"] == 0
         assert eng.alloc.free_pages == eng.num_pages
 
     def test_self_draft_parity(self):
         """spec_draft="self" (verify-window logits as next drafts)
         changes the draft SOURCE only — greedy parity is structural."""
         model, _ = tiny_model()
-        ref = _run(ContinuousBatchingEngine(model, max_batch=2,
-                                            max_len=128),
+        ref = _run(paged_engine(model, max_batch=2, max_len=128),
                    [REP, RND], [_greedy(20), _greedy(20)])
-        eng = ContinuousBatchingEngine(
+        eng = paged_engine(
             model, max_batch=2, max_len=128, draft_k=4,
             spec_mode="device", spec_draft="self")
         out = _run(eng, [REP, RND], [_spec(20), _spec(20)])
@@ -239,10 +219,9 @@ class TestBitwiseParity:
 
     def test_budget_smaller_than_draft_window(self):
         model, _ = tiny_model()
-        ref = _run(ContinuousBatchingEngine(model, max_batch=1,
-                                            max_len=128),
+        ref = _run(paged_engine(model, max_batch=1, max_len=128),
                    [REP], [_greedy(3)])
-        eng = ContinuousBatchingEngine(
+        eng = paged_engine(
             model, max_batch=1, max_len=128, draft_k=6,
             spec_mode="device")
         out = _run(eng, [REP], [_spec(3)])
@@ -251,10 +230,9 @@ class TestBitwiseParity:
 
     def test_near_max_len_stops_clean(self):
         model, _ = tiny_model()
-        ref = _run(ContinuousBatchingEngine(model, max_batch=1,
-                                            max_len=32),
+        ref = _run(paged_engine(model, max_batch=1, max_len=32),
                    [REP], [_greedy(8)])
-        eng = ContinuousBatchingEngine(
+        eng = paged_engine(
             model, max_batch=1, max_len=32, draft_k=6,
             spec_mode="device")
         out = _run(eng, [REP], [_spec(8)])
@@ -264,15 +242,13 @@ class TestBitwiseParity:
         """eos landing inside an accepted window truncates ON DEVICE
         (the fused program's per-step mask) — bitwise vs plain."""
         model, _ = tiny_model()
-        probe = ContinuousBatchingEngine(model, max_batch=1,
-                                         max_len=128)
+        probe = paged_engine(model, max_batch=1, max_len=128)
         free = _run(probe, [REP], [_greedy(24)])[0]
         eos = int(free[7])
         kw = dict(max_new_tokens=24, eos_token_id=eos)
-        ref = _run(ContinuousBatchingEngine(model, max_batch=1,
-                                            max_len=128),
+        ref = _run(paged_engine(model, max_batch=1, max_len=128),
                    [REP], [GenerationConfig(**kw)])[0]
-        eng = ContinuousBatchingEngine(
+        eng = paged_engine(
             model, max_batch=1, max_len=128, draft_k=6,
             spec_mode="device")
         out = _run(eng, [REP],
@@ -433,7 +409,7 @@ class TestZeroCompiles:
         """Two segment widths compile two programs; rerunning either
         reuses its first compile (per-request state never keys it)."""
         model, _ = tiny_model(layers=1)
-        eng = ContinuousBatchingEngine(
+        eng = paged_engine(
             model, max_batch=1, max_len=64, draft_k=3,
             spec_mode="device")
         for _ in range(2):
@@ -449,7 +425,7 @@ class TestLedgerDispatches:
         dispatch count equals the number of SEGMENTS run — the fused
         loop never dispatches per verify step."""
         model, _ = tiny_model(layers=1)
-        eng = ContinuousBatchingEngine(
+        eng = paged_engine(
             model, max_batch=2, max_len=128, draft_k=4,
             spec_mode="device")
         eng.add_request(REP, _spec(16))
@@ -474,7 +450,7 @@ class TestStatsAndSyncs:
         model, _ = tiny_model()
         outs, stats = {}, {}
         for mode in ("host", "device"):
-            eng = ContinuousBatchingEngine(
+            eng = paged_engine(
                 model, max_batch=2, max_len=128, draft_k=4,
                 spec_mode=mode)
             outs[mode] = _run(eng, [REP, RND], [_spec(12), _spec(12)])
@@ -494,7 +470,7 @@ class TestStatsAndSyncs:
 
     def test_identity_survives_reset_state(self):
         model, _ = tiny_model()
-        eng = ContinuousBatchingEngine(
+        eng = paged_engine(
             model, max_batch=2, max_len=128, draft_k=4,
             spec_mode="device")
         _run(eng, [REP, RND], [_spec(12), _spec(12)])

@@ -3,8 +3,7 @@
 The tentpole contract, CPU-verified:
 
 - BITWISE-GREEDY PARITY: a speculating request's output is identical
-  to the same request decoded plain, on the dense AND paged engines,
-  MHA and GQA — speculation changes the schedule, never the tokens;
+  to the same request decoded plain, MHA and GQA — speculation changes the schedule, never the tokens;
 - ONE COMPILED PROGRAM: a mixed speculating/plain/sampled batch rides
   a single compiled verify-step program per (engine, draft_k) —
   asserted via the monitored_jit cache-miss counter;
@@ -21,8 +20,8 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import monitor
+from engine_helpers import paged_engine
 from paddle_tpu.inference.generation import (CausalLMEngine,
-                                             ContinuousBatchingEngine,
                                              GenerationConfig,
                                              PagedContinuousBatchingEngine)
 from paddle_tpu.inference.ngram import NgramIndex, NgramProposer
@@ -122,15 +121,14 @@ class TestConfigKnobs:
     def test_engine_draft_k_validation(self):
         model, _ = tiny_model(layers=1)
         with pytest.raises(ValueError, match="draft_k"):
-            ContinuousBatchingEngine(model, max_batch=1, max_len=64,
-                                     draft_k=-1)
+            paged_engine(model, max_batch=1, max_len=64,
+                         draft_k=-1)
 
     def test_spec_k_eligibility(self):
         """Sampled requests and draft_k=0 engines fall back to plain;
         a request's own draft_k caps the engine's, never widens it."""
         model, _ = tiny_model(layers=1)
-        eng = ContinuousBatchingEngine(model, max_batch=1, max_len=64,
-                                       draft_k=6)
+        eng = paged_engine(model, max_batch=1, max_len=64, draft_k=6)
         assert eng._spec_k_for(_spec(4)) == 6
         assert eng._spec_k_for(_spec(4, draft_k=3)) == 3
         assert eng._spec_k_for(_spec(4, draft_k=200)) == 6
@@ -138,32 +136,13 @@ class TestConfigKnobs:
         assert eng._spec_k_for(GenerationConfig(
             max_new_tokens=4, do_sample=True, speculative=True,
             eos_token_id=None)) == 0
-        off = ContinuousBatchingEngine(model, max_batch=1, max_len=64)
+        off = paged_engine(model, max_batch=1, max_len=64)
         assert off._spec_k_for(_spec(4)) == 0
 
 
 class TestBitwiseParity:
     """Greedy spec-vs-plain output is bitwise identical per slot —
-    dense + paged, MHA + GQA, accepting and adversarial prompts."""
-
-    @pytest.mark.parametrize("kv_heads", [None, 2],
-                             ids=["mha", "gqa"])
-    def test_dense(self, kv_heads):
-        model, _ = tiny_model(kv_heads=kv_heads)
-        ref = _run(ContinuousBatchingEngine(model, max_batch=2,
-                                            max_len=128),
-                   [REP, RND], [_greedy(24), _greedy(24)])
-        eng = ContinuousBatchingEngine(model, max_batch=2, max_len=128,
-                                       draft_k=6)
-        out = _run(eng, [REP, RND], [_spec(24), _spec(24)])
-        for a, b in zip(ref, out):
-            np.testing.assert_array_equal(a, b)
-        st = eng.spec_stats()
-        assert st["accepted"] > 0          # drafts did real work
-        assert st["tokens_per_forward"] > 1.0
-        # accounting identity per slot-forward: every emitted token is
-        # either the forward's own pick or an accepted draft
-        assert st["emitted"] == st["slot_steps"] + st["accepted"]
+    MHA + GQA, accepting and adversarial prompts."""
 
     @pytest.mark.parametrize("kv_heads", [None, 2],
                              ids=["mha", "gqa"])
@@ -187,11 +166,9 @@ class TestBitwiseParity:
         """A budget below draft_k must be respected exactly (the
         device lim-cap cuts acceptance; host never over-collects)."""
         model, _ = tiny_model()
-        ref = _run(ContinuousBatchingEngine(model, max_batch=1,
-                                            max_len=128),
+        ref = _run(paged_engine(model, max_batch=1, max_len=128),
                    [REP], [_greedy(3)])
-        eng = ContinuousBatchingEngine(model, max_batch=1, max_len=128,
-                                       draft_k=6)
+        eng = paged_engine(model, max_batch=1, max_len=128, draft_k=6)
         out = _run(eng, [REP], [_spec(3)])
         np.testing.assert_array_equal(ref[0], out[0])
         assert len(out[0]) == 3
@@ -201,11 +178,9 @@ class TestBitwiseParity:
         acceptance there instead of clamp-corrupting the cache tail."""
         model, _ = tiny_model()
         # plen 24 + 8 new = max_len exactly
-        ref = _run(ContinuousBatchingEngine(model, max_batch=1,
-                                            max_len=32),
+        ref = _run(paged_engine(model, max_batch=1, max_len=32),
                    [REP], [_greedy(8)])
-        eng = ContinuousBatchingEngine(model, max_batch=1, max_len=32,
-                                       draft_k=6)
+        eng = paged_engine(model, max_batch=1, max_len=32, draft_k=6)
         out = _run(eng, [REP], [_spec(8)])
         np.testing.assert_array_equal(ref[0], out[0])
 
@@ -216,12 +191,10 @@ class TestMixedBatchOneProgram:
         compiled verify-step program (per draft_k) — and the greedy
         rows keep bitwise parity while riding it."""
         model, _ = tiny_model()
-        ref = _run(ContinuousBatchingEngine(model, max_batch=2,
-                                            max_len=128),
+        ref = _run(paged_engine(model, max_batch=2, max_len=128),
                    [REP, RND], [_greedy(20), _greedy(20)])
         monitor.reset()         # count only the MIXED run's compiles
-        eng = ContinuousBatchingEngine(model, max_batch=3, max_len=128,
-                                       draft_k=6)
+        eng = paged_engine(model, max_batch=3, max_len=128, draft_k=6)
         outs = _run(eng, [REP, RND, REP],
                     [_spec(20), _greedy(20),
                      GenerationConfig(max_new_tokens=10, do_sample=True,
@@ -242,8 +215,7 @@ class TestMixedBatchOneProgram:
         within one engine every segment reuses the first compile."""
         model, _ = tiny_model(layers=1)
         for k in (2, 4):
-            eng = ContinuousBatchingEngine(model, max_batch=1,
-                                           max_len=64, draft_k=k)
+            eng = paged_engine(model, max_batch=1, max_len=64, draft_k=k)
             _run(eng, [REP[:8]], [_spec(10)])
         misses = monitor.jit_miss_by_fn()
         assert misses.get("cb_spec_step") == 2, misses
@@ -255,16 +227,13 @@ class TestEosMidDraft:
         truncates AT eos (stale device tail dies with retirement) and
         matches the plain path bitwise."""
         model, _ = tiny_model()
-        probe = ContinuousBatchingEngine(model, max_batch=1,
-                                         max_len=128)
+        probe = paged_engine(model, max_batch=1, max_len=128)
         free = _run(probe, [REP], [_greedy(24)])[0]
         eos = int(free[7])          # something it emits mid-stream
         kw = dict(max_new_tokens=24, eos_token_id=eos)
-        ref = _run(ContinuousBatchingEngine(model, max_batch=1,
-                                            max_len=128),
+        ref = _run(paged_engine(model, max_batch=1, max_len=128),
                    [REP], [GenerationConfig(**kw)])[0]
-        eng = ContinuousBatchingEngine(model, max_batch=1, max_len=128,
-                                       draft_k=6)
+        eng = paged_engine(model, max_batch=1, max_len=128, draft_k=6)
         out = _run(eng, [REP],
                    [GenerationConfig(speculative=True, **kw)])[0]
         np.testing.assert_array_equal(ref, out)
@@ -300,7 +269,7 @@ class TestServerIntegration:
 
     def test_server_knob_validation(self):
         model, _ = tiny_model(layers=1)
-        eng = ContinuousBatchingEngine(model, max_batch=1, max_len=64)
+        eng = paged_engine(model, max_batch=1, max_len=64)
         with pytest.raises(ValueError, match="draft_k"):
             Server(eng, start=False, draft_k=-2)
         with pytest.raises(ValueError, match="speculative"):
@@ -313,8 +282,7 @@ class TestServerIntegration:
         """paddle_tpu_spec_draft_tokens_total{engine,outcome} counts
         proposed/accepted per engine and retires in engine.close()."""
         model, _ = tiny_model()
-        eng = ContinuousBatchingEngine(model, max_batch=1, max_len=128,
-                                       draft_k=6)
+        eng = paged_engine(model, max_batch=1, max_len=128, draft_k=6)
         _run(eng, [REP], [_spec(16)])
         snap = monitor.snapshot()["metrics"]
         by = {s["labels"]["outcome"]: s["value"]
@@ -447,8 +415,7 @@ class TestPrefixCacheInteraction:
 class TestSpecStatsSurface:
     def test_spec_stats_identity_and_reset(self):
         model, _ = tiny_model()
-        eng = ContinuousBatchingEngine(model, max_batch=2, max_len=128,
-                                       draft_k=4)
+        eng = paged_engine(model, max_batch=2, max_len=128, draft_k=4)
         _run(eng, [REP, RND], [_spec(12), _spec(12)])
         st = eng.spec_stats()
         assert st["emitted"] == st["slot_steps"] + st["accepted"]

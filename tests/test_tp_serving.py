@@ -21,8 +21,7 @@ import jax
 
 import paddle_tpu as paddle
 from paddle_tpu import monitor
-from paddle_tpu.inference.generation import (ContinuousBatchingEngine,
-                                             GenerationConfig,
+from paddle_tpu.inference.generation import (GenerationConfig,
                                              PagedContinuousBatchingEngine)
 from paddle_tpu.models import LlamaForCausalLM, llama_config
 
@@ -151,24 +150,6 @@ class TestParity:
         assert out[0] == a[0]
         assert len(out[1]) == 6
         _assert_no_leaks(eng)
-        eng.close()
-
-    def test_dense_engine_parity_tp2(self):
-        """The dense continuous-batching engine shards its [B, max_len]
-        slabs the same way (ISSUE: 'and the dense engine')."""
-        def dense(tp):
-            paddle.seed(0)
-            return ContinuousBatchingEngine(
-                LlamaForCausalLM(CFG), max_batch=2, max_len=64,
-                tp_degree=tp)
-
-        ref = dense(1)
-        a = drain(ref, [PROMPT, SHORT], [greedy(8), greedy(8)])
-        eng = dense(2)
-        b = drain(eng, [PROMPT, SHORT], [greedy(8), greedy(8)])
-        assert a == b
-        assert eng.caches[0][0].sharding.spec[2] is not None
-        ref.close()
         eng.close()
 
 
