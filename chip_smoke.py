@@ -262,8 +262,6 @@ def _check_serving_kernels(eng, widths, segment_steps, kernels, tag=""):
     """Lower (trace only, no compile) the engine's prefill programs at the
     bucket widths in use and its decode segment, with the arguments the
     engine itself passes, and look for the kernels."""
-    import jax
-
     tp = "tp_" if eng.tp_degree > 1 else ""
     pools, pt = eng.caches
     for w in widths:
@@ -277,8 +275,8 @@ def _check_serving_kernels(eng, widths, segment_steps, kernels, tag=""):
             tp + ("prefill_wide" if w % 128 == 0 else "prefill_narrow"),
             lowered, kernels)
     lowered = eng._segment_fn(segment_steps)._jitted.lower(
-        eng.params, eng.last, eng.lens, eng.done_dev, eng.active_dev,
-        eng.samp, eng._bank(), eng.caches, jax.random.PRNGKey(0))
+        eng.params, eng.last, eng.lens, eng.done_dev, eng._active_mask(),
+        eng.samp, eng._bank(), eng.caches, np.uint32(0), np.uint32(0))
     check_kernels(f"{tag}cb_segment[{segment_steps}]",
                   tp + "decode_segment", lowered, kernels)
 

@@ -1,23 +1,26 @@
 """sha256 of the lowered text of the serving engine's programs, for one fixed
 tiny dense model and one tiny sparse-expert, window-attention model.
 
-A refactor of the engine that must not change what the chip runs is held to
-this: run it at the parent commit and at the change and compare the columns
-(`PERF.md` section 6, PR 30). Lowering traces and never compiles, so it
-runs on the CPU in seconds:
+A change to the engine is held to this: a refactor must leave every column
+as it was, and a change of a program's text must move the programs it names
+and no other (`PERF.md` section 6, PRs 30 and 31). Lowering traces and
+never compiles, so it runs on the CPU in seconds:
 
-    JAX_PLATFORMS=cpu python experiments/exp_program_hashes.py [REPO_ROOT]
+    JAX_PLATFORMS=cpu python experiments/exp_program_hashes.py
+    JAX_PLATFORMS=cpu python experiments/exp_program_hashes.py --against PARENT
+
+With ``--against`` the checkout at PARENT runs ITS OWN copy of this script
+(the programs' arguments belong to the commit) and both columns are printed
+side by side.
 """
 import hashlib
 import os
+import subprocess
 import sys
 
-ROOT = os.path.abspath(sys.argv[1] if len(sys.argv) > 1 else
-                       os.path.join(os.path.dirname(__file__), ".."))
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, ROOT)
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 import paddle_tpu as paddle  # noqa: E402
@@ -60,41 +63,43 @@ def programs(eng):
     """(name, lowered) of each program the engine can run, with the
     arguments the engine itself passes."""
     mb = eng.max_batch
-    key = jax.random.PRNGKey(0)
+    idle = np.zeros((mb,), bool)
+    key = (np.uint32(0), np.uint32(0))
     pools, pt = eng.caches
-    yield "jit_prefill_one", eng._prefill_paged._jitted.lower(
+    prefill = eng._prefill_paged._jitted.lower(
         eng.params, np.zeros((1, WIDTH), np.int32), pools, pt, np.int32(0),
         np.int32(WIDTH), eng._bank(), np.int32(0))
+    yield "jit_prefill_one", prefill
     yield "jit_segment", eng._segment_fn(STEPS)._jitted.lower(
-        eng.params, eng.last, eng.lens, eng.done_dev, eng.active_dev,
-        eng.samp, eng._bank(), eng.caches, key)
+        eng.params, eng.last, eng.lens, eng.done_dev, idle, eng.samp,
+        eng._bank(), eng.caches, *key)
     yield "cb_admit_state", eng._admit_state._jitted.lower(
-        eng.lens, eng.last, eng.done_dev, eng.active_dev, eng.samp,
-        eng.hist, eng.hist_len, np.int32(0), np.int32(5), jnp.int32(1),
-        jnp.asarray(False), np.float32(1.0), np.int32(0), np.float32(1.0),
-        np.bool_(False), np.int32(-1), np.int32(0), np.int32(0),
-        np.int32(0), np.zeros((eng.spec_history,), np.int32), np.int32(0))
+        eng.lens, eng.last, eng.done_dev, eng.samp, eng.hist, eng.hist_len,
+        np.int32(0), np.int32(5), prefill.out_info[0], np.uint32(0),
+        np.float32(1.0), np.int32(0), np.float32(1.0), np.bool_(False),
+        np.int32(-1), np.int32(0), np.int32(0), np.int32(0),
+        np.zeros((eng.spec_history,), np.int32), np.int32(0))
     if eng.prefill_chunk is not None:
         yield "cb_prefill_chunk", eng._prefill_chunk._jitted.lower(
             eng.params, np.zeros((1, eng.prefill_chunk), np.int32),
-            eng._mini_cache(eng.max_len), jnp.int32(0), jnp.int32(0),
-            eng._bank(), jnp.int32(0))
+            eng._mini_cache(eng.max_len), np.int32(0), np.int32(0),
+            eng._bank(), np.int32(0))
     if eng.draft_k and eng.spec_mode == "host":
         yield "cb_spec_step", eng._spec_step_fn()._jitted.lower(
-            eng.params, eng.last, eng.lens, eng.active_dev, eng.samp,
-            eng._bank(), eng.caches, key,
-            jnp.zeros((mb, eng.draft_k), jnp.int32), jnp.zeros((mb,), bool),
-            jnp.zeros((mb,), jnp.int32))
+            eng.params, eng.last, eng.lens, idle, eng.samp, eng._bank(),
+            eng.caches, *key, np.zeros((mb, eng.draft_k), np.int32), idle,
+            np.zeros((mb,), np.int32))
     if eng.draft_k and eng.spec_mode == "device":
         yield ("cb_spec_device_segment",
                eng._spec_segment_device_fn(STEPS)._jitted.lower(
-                   eng.params, eng.last, eng.lens, eng.done_dev,
-                   eng.active_dev, eng.samp, eng._bank(), eng.caches,
-                   eng.hist, eng.hist_len, jnp.zeros((mb,), jnp.int32),
-                   jnp.zeros((mb,), jnp.int32), key))
+                   eng.params, eng.last, eng.lens, eng.done_dev, idle,
+                   eng.samp, eng._bank(), eng.caches, eng.hist,
+                   eng.hist_len, np.zeros((mb,), np.int32),
+                   np.zeros((mb,), np.int32), *key))
 
 
-def main():
+def rows():
+    """(engine tag, program name, hash) of every program of every engine."""
     geometry = dict(max_batch=2, num_pages=16, page_size=8, max_pages=8,
                     prefill_buckets=[WIDTH, 64])
     engines = [
@@ -110,8 +115,35 @@ def main():
     for tag, make, kw in engines:
         eng = PagedContinuousBatchingEngine(make(), **{**geometry, **kw})
         for name, lowered in programs(eng):
-            print(f"{tag:18s} {name:24s} {sha(lowered)}")
+            yield tag, name, sha(lowered)
         eng.close()
+
+
+def parent_rows(root):
+    """The same table from the checkout at ``root``, by its own script."""
+    out = subprocess.run(
+        [sys.executable, os.path.join(root, "experiments",
+                                      "exp_program_hashes.py")],
+        check=True, capture_output=True, text=True).stdout
+    table = {}
+    for line in out.splitlines():
+        # "<tag padded to 18> <name> <hash>": the tag may hold spaces
+        tag, (name, digest) = line[:18].strip(), line[18:].split()
+        table[tag, name] = digest
+    return table
+
+
+def main():
+    against = None
+    if "--against" in sys.argv:
+        against = parent_rows(sys.argv[sys.argv.index("--against") + 1])
+    for tag, name, digest in rows():
+        line = f"{tag:18s} {name:24s} {digest}"
+        if against is not None:
+            was = against.get((tag, name), "-")
+            line = (f"{tag:18s} {name:24s} {was:16s} {digest} "
+                    f"{'same' if was == digest else 'CHANGED'}")
+        print(line)
 
 
 if __name__ == "__main__":

@@ -315,6 +315,32 @@ def _sample(logits, key, cfg: GenerationConfig):
     return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
 
 
+def _filter_rows(logits, temp, top_k, top_p):
+    """`_sample`'s temperature, top-k and top-p with TRACED per-row
+    parameters ([B] vectors over [B, V] logits): the float32 logits a
+    categorical draw takes. A row with top_k == 0 / top_p == 1.0 skips
+    that filter (`_sample`'s `if` branches, expressed as masks)."""
+    vocab = logits.shape[-1]
+    scaled = logits.astype(jnp.float32) / jnp.maximum(temp, 1e-6)[:, None]
+    # values alone are sorted, so an unstable sort gives the stable one's
+    # result; the chip's compiler takes 8 s for it and 24 s for the
+    # stable one, in every program that holds a sampling branch
+    desc = jnp.sort(scaled, axis=-1, stable=False)[:, ::-1]
+    k_eff = jnp.clip(top_k, 1, vocab)
+    kth = jnp.take_along_axis(desc, (k_eff - 1)[:, None], axis=-1)
+    scaled = jnp.where((top_k > 0)[:, None] & (scaled < kth),
+                       -jnp.inf, scaled)
+    # top-p runs over the top-k-FILTERED logits (_sample's order)
+    desc2 = jnp.sort(scaled, axis=-1, stable=False)[:, ::-1]
+    probs = jax.nn.softmax(desc2, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep = cum - probs < top_p[:, None]
+    cutoff = jnp.min(jnp.where(keep, desc2, jnp.inf), axis=-1,
+                     keepdims=True)
+    return jnp.where((top_p < 1.0)[:, None] & (scaled < cutoff),
+                     -jnp.inf, scaled)
+
+
 def _sample_rows(logits, key, samp):
     """Per-ROW next-token choice from [B, V] logits: every sampling
     parameter (greedy-vs-sample, temperature, top-k, top-p, eos) is a
@@ -332,27 +358,11 @@ def _sample_rows(logits, key, samp):
     per-slot vector) is folded into the shared per-step key, so a
     request's sampled trajectory depends on ITS GenerationConfig.seed,
     not on which other requests share the batch."""
-    vocab = logits.shape[-1]
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     def drawn(_):
-        scaled = (logits.astype(jnp.float32)
-                  / jnp.maximum(samp["temp"], 1e-6)[:, None])
-        desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-        k_eff = jnp.clip(samp["top_k"], 1, vocab)
-        kth = jnp.take_along_axis(desc, (k_eff - 1)[:, None], axis=-1)
-        scaled = jnp.where((samp["top_k"] > 0)[:, None] & (scaled < kth),
-                           -jnp.inf, scaled)
-        # top-p runs over the top-k-FILTERED logits (_sample's order)
-        desc2 = jnp.sort(scaled, axis=-1)[:, ::-1]
-        probs = jax.nn.softmax(desc2, axis=-1)
-        cum = jnp.cumsum(probs, axis=-1)
-        keep = cum - probs < samp["top_p"][:, None]
-        cutoff = jnp.min(jnp.where(keep, desc2, jnp.inf), axis=-1,
-                         keepdims=True)
-        scaled = jnp.where(
-            (samp["top_p"] < 1.0)[:, None] & (scaled < cutoff),
-            -jnp.inf, scaled)
+        scaled = _filter_rows(logits, samp["temp"], samp["top_k"],
+                              samp["top_p"])
         keys = jax.vmap(lambda s: jax.random.fold_in(key, s))(
             samp["seed"])
         return jax.vmap(jax.random.categorical)(keys, scaled) \
@@ -367,11 +377,55 @@ def _sample_rows(logits, key, samp):
     return jnp.where(samp["sample"], sampled, greedy)
 
 
+def _sample_one(logits, seed, temp, top_k, top_p, do_sample):
+    """`_sample` of ONE request's [1, V] logits with every parameter a
+    traced scalar, so that one compiled program samples any request's
+    first token: greedy is `_sample`'s argmax; a sampled request draws
+    from ``PRNGKey(seed)`` over the logits `_filter_rows` gives, in
+    `_sample`'s order of operations. ``lax.cond`` runs one branch: a
+    greedy admission pays the argmax alone."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def drawn(_):
+        scaled = _filter_rows(logits, temp[None], top_k[None],
+                              top_p[None])
+        return jax.random.categorical(
+            jax.random.PRNGKey(seed), scaled, axis=-1).astype(jnp.int32)
+
+    return jax.lax.cond(do_sample, drawn, lambda _: greedy, None)[0]
+
+
+def _segment_key(seed, counter):
+    """A segment's sampling key, computed INSIDE the segment programs
+    from two uint32 scalars that ride as arguments (`_u32`): bit for
+    bit ``jax.random.fold_in(jax.random.PRNGKey(seed), counter)`` of the
+    host integers. Made eagerly, the seed and the fold were two device
+    programs of their own before every segment."""
+    return jax.random.fold_in(jax.random.PRNGKey(seed), counter)
+
+
+def _u32(n: int):
+    """The low 32 bits of a host integer as a numpy scalar: what
+    ``PRNGKey`` and ``fold_in`` keep of a Python int without x64."""
+    return np.uint32(int(n) & 0xFFFFFFFF)
+
+
+def _live_samp(samp, active):
+    """The per-slot sampling vectors as a segment program reads them:
+    a slot's sampled flag counts only while the slot holds a request
+    (``active``, the host's live mask), so retirement writes nothing on
+    the device and an all-greedy batch still skips `_sample_rows`'
+    sort, softmax and cumsum."""
+    return dict(samp, sample=samp["sample"] & active)
+
+
 def _prompt_ids(prompt):
     """Normalize a prompt (Tensor / ndarray / list) to int32 [1, plen].
     serve()'s capacity probe and add_request MUST agree on this — a
     Tensor probed with a bare np.asarray becomes a size-1 object array
     and defeats the paged defer logic."""
+    # lint: allow-host-sync(a prompt arrives from the host; a Tensor's
+    # ids are read once, at admission, before any device work of it)
     return np.asarray(prompt.value if isinstance(prompt, Tensor)
                       else prompt).astype(np.int32).reshape(1, -1)
 
@@ -1055,15 +1109,20 @@ class PagedContinuousBatchingEngine:
 
         H = self.spec_history
 
-        def admit_state(lens, last, done, active, samp, hist, hl, slot,
-                        plen, first, tok_done, temp, top_k, top_p,
-                        do_samp, eos, seed, spec_k, adapter, hrow,
-                        hlen):
-            # one program for the per-slot scalars AND the request's
-            # sampling parameters — admission sits in the
-            # latency-critical gap between decode segments, and separate
-            # .at[].set dispatches would each cost a host→device
-            # round-trip where this costs one
+        def admit_state(lens, last, done, samp, hist, hl, slot, plen,
+                        logits, key_seed, temp, top_k, top_p, do_samp,
+                        eos, seed, spec_k, adapter, hrow, hlen):
+            # the tail of every admission in ONE program: the first
+            # token sampled from the prompt's last-position logits
+            # (greedy: the argmax; sampled: from PRNGKey(key_seed)),
+            # its eos verdict, the per-slot scalars AND the request's
+            # sampling parameters. Admission sits in the
+            # latency-critical gap between decode segments: sampled
+            # eagerly the token was three to eight dispatches, and each
+            # .at[].set apart a round-trip, where this costs one
+            first = _sample_one(logits, key_seed, temp, top_k, top_p,
+                                do_samp)
+            tok_done = (eos >= 0) & (first == eos)
             samp = {
                 "temp": samp["temp"].at[slot].set(temp),
                 "top_k": samp["top_k"].at[slot].set(top_k),
@@ -1076,22 +1135,22 @@ class PagedContinuousBatchingEngine:
             }
             # history-ring seed: hrow is the prompt's last H-1 tokens
             # (host-padded to the fixed [H] shape — never a recompile);
-            # the admission's FIRST token is a device scalar, so it
-            # lands in its slot here rather than forcing a host sync
+            # the first token lands in its slot here
             hrow = jnp.where(
                 hlen > 0,
                 hrow.at[jnp.clip(hlen - 1, 0, H - 1)].set(first),
                 hrow)
-            return (lens.at[slot].set(plen),
+            # (first, tok_done) packed: the host reads both in ONE pull
+            return (jnp.stack([first, tok_done.astype(jnp.int32)]),
+                    lens.at[slot].set(plen),
                     last.at[slot].set(first),
-                    done.at[slot].set(tok_done),
-                    active.at[slot].set(True), samp,
+                    done.at[slot].set(tok_done), samp,
                     hist.at[slot].set(hrow), hl.at[slot].set(hlen))
 
         self._admit_state = monitor.monitored_jit(
             admit_state, name="cb_admit_state",
             owner=self._monitor_engine,
-            donate_argnums=(0, 1, 2, 3, 4, 5, 6))
+            donate_argnums=(0, 1, 2, 3, 4, 5))
         self._segment_cache = {}
         self._measure_quant_savings()
 
@@ -1128,7 +1187,6 @@ class PagedContinuousBatchingEngine:
         self.lens = jnp.zeros((mb,), jnp.int32)
         self.last = jnp.zeros((mb,), jnp.int32)
         self.done_dev = jnp.zeros((mb,), bool)
-        self.active_dev = jnp.zeros((mb,), bool)
         self.samp = {
             "temp": jnp.ones((mb,), jnp.float32),
             "top_k": jnp.zeros((mb,), jnp.int32),
@@ -1168,12 +1226,20 @@ class PagedContinuousBatchingEngine:
             self.lens = self._tp_rep(self.lens)
             self.last = self._tp_rep(self.last)
             self.done_dev = self._tp_rep(self.done_dev)
-            self.active_dev = self._tp_rep(self.active_dev)
             self.samp = {k: self._tp_rep(v)
                          for k, v in self.samp.items()}
             self.hist = self._tp_rep(self.hist)
             self.hist_len = self._tp_rep(self.hist_len)
         self._free = list(range(mb))
+
+    def _active_mask(self) -> np.ndarray:
+        """The live mask the segment programs take, [max_batch] bool:
+        the slots that hold a request, from host bookkeeping alone. It
+        rides into each program as a numpy argument, so admission and
+        retirement keep no copy of it on the device."""
+        active = np.zeros((self.max_batch,), bool)
+        active[list(self._slot_req)] = True
+        return active
 
     # -- tensor-parallel placement helpers -----------------------------------
     def _tp_rep(self, x):
@@ -1446,6 +1512,7 @@ class PagedContinuousBatchingEngine:
                 and prompt_len + cfg.max_new_tokens <= self.max_len
                 and self._can_admit(prompt_len, cfg))
 
+    # lint: hot-path
     def add_request(self, prompt_ids, cfg: GenerationConfig) -> int:
         """Prefill one request into a free slot; returns the request id.
         Raises if no slot is free (call decode_segment / collect first)
@@ -1482,18 +1549,19 @@ class PagedContinuousBatchingEngine:
     def _first_token(self, slot: int, rid: int, ids, plen: int,
                      last_logits, cfg, aidx: int, t0: float) -> int:
         """The tail every admission shares (one-shot, warm, chunked):
-        sample the first token from the prompt's last logits, install
-        the slot's state, register the request. The device calls sit
-        inside the abort guard; the bookkeeping after them does not
-        (no device call left to fail). Traced as ``engine.first_token``:
-        ``_register`` reads the token (``int(first)``), which is where
-        the host waits for the prefill it dispatched earlier."""
+        ONE program (``cb_admit_state``) samples the first token from
+        the prompt's last logits and installs the slot's state, ONE
+        pull brings the token and its eos verdict to the host, and the
+        request is registered. The device call and the pull sit inside
+        the abort guard; the bookkeeping after them does not (no device
+        call left to fail). Traced as ``engine.first_token``: the pull
+        is where the host waits for the prefill it dispatched
+        earlier."""
         with trace.span("engine.first_token"):
             try:
-                first, tok_done = self._sample_first(rid, last_logits,
-                                                     cfg)
-                self._install_state(slot, plen, first, tok_done, cfg,
-                                    aidx=aidx, ids=ids)
+                first, tok_done = self._install_state(
+                    slot, plen, last_logits, cfg, rid=rid, aidx=aidx,
+                    ids=ids)
             except BaseException:
                 self._abort_admit(slot)
                 raise
@@ -1537,17 +1605,7 @@ class PagedContinuousBatchingEngine:
         k = self._spec_k_for(cfg)
         if k > 0:
             self._spec[rid] = NgramProposer(
-                [int(t) for t in ids[0]] + [int(first)], k,
-                self.ngram_max)
-
-    def _sample_first(self, rid: int, last_logits, cfg):
-        """Sample the admission's first token from the prompt's
-        last-position logits."""
-        key = jax.random.PRNGKey(cfg.seed + rid)
-        first = _sample(last_logits, key, cfg)[0]
-        tok_done = (jnp.asarray(False) if cfg.eos_token_id is None
-                    else first == cfg.eos_token_id)
-        return first, tok_done
+                [int(t) for t in ids[0]] + [first], k, self.ngram_max)
 
     def _spec_k_for(self, cfg) -> int:
         """Draft window for a request under ``cfg`` (0 = plain decode):
@@ -1567,45 +1625,53 @@ class PagedContinuousBatchingEngine:
         prop = self._spec.get(rid)
         return 0 if prop is None else prop.k
 
-    def _install_state(self, slot: int, plen: int, first, tok_done,
-                       cfg, aidx: int = 0, ids=None) -> None:
-        """Install the request's per-slot scalars AND sampling parameters
-        (the LoRA adapter index included) in ONE jitted program instead
-        of separate dispatches. ``ids`` (the host-side prompt, when the caller has
-        one) seeds the slot's history ring with the prompt's trailing
-        window — the device-mode draft source; a replayed request
-        re-admits prompt+generated, so the ring rebuilds exactly like
-        the host proposer's context."""
+    def _install_state(self, slot: int, plen: int, last_logits, cfg,
+                       rid: int = 0, aidx: int = 0, ids=None):
+        """Sample the request's first token from ``last_logits`` (the
+        prompt's last position, [1, V]: greedy the argmax, sampled from
+        ``PRNGKey(cfg.seed + rid)``) and install its per-slot scalars
+        AND sampling parameters (the LoRA adapter index included), all
+        in ONE jitted program; returns ``(first, tok_done)`` as host
+        values from ONE pull. ``ids`` (the host-side prompt, when the
+        caller has one) seeds the slot's history ring with the prompt's
+        trailing window — the device-mode draft source; a replayed
+        request re-admits prompt+generated, so the ring rebuilds exactly
+        like the host proposer's context."""
         eos = -1 if cfg.eos_token_id is None else cfg.eos_token_id
         H = self.spec_history
         hrow = np.zeros((H,), np.int32)
         hlen = 0
         if ids is not None:
+            # lint: allow-host-sync(the prompt is a host array)
             tail = np.asarray(ids, np.int32).reshape(-1)[-(H - 1):]
             hrow[:len(tail)] = tail
             hlen = len(tail) + 1     # + the first token (set in-program)
-        # a token sampled from a TP program carries the mesh in its
-        # type; a host-made one (warmup, eos=None) does not — commit
-        # both to the mesh so the program has one signature. The
-        # scalars are numpy: they ride as arguments, where a
-        # jnp.int32() each is a device program of its own
-        (self.lens, self.last, self.done_dev, self.active_dev,
-         self.samp, self.hist, self.hist_len) = self._admit_state(
-            self.lens, self.last, self.done_dev, self.active_dev,
-            self.samp, self.hist, self.hist_len, np.int32(slot),
-            np.int32(plen), self._tp_rep(first),
-            self._tp_rep(tok_done), np.float32(cfg.temperature),
-            np.int32(cfg.top_k), np.float32(cfg.top_p),
-            np.bool_(cfg.do_sample), np.int32(eos),
-            np.int32(cfg.seed % (2 ** 31)),
-            np.int32(self._spec_k_for(cfg)), np.int32(aidx),
-            hrow, np.int32(hlen))
+        # the logits come out of whichever prefill program ran; under TP
+        # they are committed replicated, so the program has one
+        # signature. The scalars are numpy: they ride as arguments,
+        # where a jnp.int32() each is a device program of its own
+        (out, self.lens, self.last, self.done_dev, self.samp, self.hist,
+         self.hist_len) = self._admit_state(
+            self.lens, self.last, self.done_dev, self.samp, self.hist,
+            self.hist_len, np.int32(slot), np.int32(plen),
+            self._tp_rep(last_logits), _u32(cfg.seed + rid),
+            np.float32(cfg.temperature), np.int32(cfg.top_k),
+            np.float32(cfg.top_p), np.bool_(cfg.do_sample),
+            np.int32(eos), np.int32(cfg.seed % (2 ** 31)),
+            np.int32(self._spec_k_for(cfg)), np.int32(aidx), hrow,
+            np.int32(hlen))
+        # lint: allow-host-sync(the admission's ONE pull: the first
+        # token and its eos verdict, packed; the host waits here for
+        # the prefill it dispatched)
+        first, tok_done = np.asarray(out).tolist()
+        return first, bool(tok_done)
 
-    def _register(self, slot: int, rid: int, first, tok_done, cfg,
-                  t0: float) -> int:
+    def _register(self, slot: int, rid: int, first: int, tok_done: bool,
+                  cfg, t0: float) -> int:
         """Host-side bookkeeping tail of a completed admission (one-shot
-        or chunked): record the request, retire degenerate ones, count
-        metrics. Runs OUTSIDE the abort guard — no device call left."""
+        or chunked): record the request (``first`` and ``tok_done`` are
+        host values already), retire degenerate ones, count metrics.
+        Runs OUTSIDE the abort guard — no device call left."""
         # a new live slot may be under-covered for the next segment
         # (optimistic claims stop at prompt + one page) — any growth
         # stamp predating it is stale, as is the gap's (lens, done)
@@ -1618,10 +1684,10 @@ class PagedContinuousBatchingEngine:
         # stash to the live request; _retire releases it
         self._rid_aidx[rid] = self._aidx_stash.pop(slot, 0)
         self._slot_req[slot] = rid
-        self._tokens[rid] = [int(first)]
+        self._tokens[rid] = [first]
         self._budget[rid] = cfg.max_new_tokens - 1
         self._cfg[rid] = cfg
-        if bool(tok_done) or self._budget[rid] <= 0:
+        if tok_done or self._budget[rid] <= 0:
             self._retire(slot)
         if monitor.enabled():
             monitor.histogram(
@@ -1801,9 +1867,9 @@ class PagedContinuousBatchingEngine:
         # bucket: the tail program's width
         with self._prefill_span(plen, wt, cached=c_cmp):
             last_logits, mini = self._prefill_chunk(
-                self.params, tail_ids, mini, jnp.int32(c_cmp),
-                jnp.int32(tail - 1), self._bank(),
-                jnp.int32(self._aidx_stash.get(slot, 0)))
+                self.params, tail_ids, mini, np.int32(c_cmp),
+                np.int32(tail - 1), self._bank(),
+                np.int32(self._aidx_stash.get(slot, 0)))
         with trace.span("engine.reserve"):
             self.alloc.map_shared(slot, pids)
             self._reserve_admit(slot, plen, cfg)
@@ -1856,14 +1922,14 @@ class PagedContinuousBatchingEngine:
             # happened — forgetting either fails check() loudly
             for kp, vp, ks, vs in pools:
                 kp, vp, ks, vs = copy_page_q(kp, vp, ks, vs,
-                                             jnp.int32(old),
-                                             jnp.int32(new))
+                                             np.int32(old),
+                                             np.int32(new))
                 new_pools.append((kp, vp, ks, vs))
             self.caches = (new_pools, pt)
             self.alloc.note_scale_copied(new)
             return
         for kp, vp in pools:
-            kp, vp = copy_page(kp, vp, jnp.int32(old), jnp.int32(new))
+            kp, vp = copy_page(kp, vp, np.int32(old), np.int32(new))
             new_pools.append((kp, vp))
         self.caches = (new_pools, pt)
 
@@ -1951,15 +2017,15 @@ class PagedContinuousBatchingEngine:
                 # absmax for the suffix rows landing in it
                 for (kp, vp, ks, vs), (mk, mv) in zip(pools, mini):
                     kp, vp, ks, vs = scatter_rows_q(
-                        kp, vp, ks, vs, pt, jnp.int32(slot),
-                        jnp.int32(c_map), jnp.int32(plen), mk, mv,
+                        kp, vp, ks, vs, pt, np.int32(slot),
+                        np.int32(c_map), np.int32(plen), mk, mv,
                         width=width)
                     new_pools.append((kp, vp, ks, vs))
             else:
                 for (kp, vp), (mk, mv) in zip(pools, mini):
                     kp, vp = scatter_rows(
-                        kp, vp, pt, jnp.int32(slot), jnp.int32(c_map),
-                        jnp.int32(plen), mk, mv, width=width)
+                        kp, vp, pt, np.int32(slot), np.int32(c_map),
+                        np.int32(plen), mk, mv, width=width)
                     new_pools.append((kp, vp))
             self.caches = (new_pools, pt)
         else:
@@ -1993,10 +2059,10 @@ class PagedContinuousBatchingEngine:
             # harmless (dead rows are masked, and the index is only
             # rewritten when a future load recycles it)
             self.adapters.release(aidx)
-        self.active_dev = self.active_dev.at[slot].set(False)
-        # drop the slot's sampled flag so an all-greedy batch regains
-        # the _sample_rows fast path once sampled requests retire
-        self.samp["sample"] = self.samp["sample"].at[slot].set(False)
+        # no device write: the segment programs take the live mask from
+        # ``_slot_req`` (`_active_mask`) and mask the sampled flags by
+        # it, so an all-greedy batch regains `_sample_rows`' fast path
+        # once sampled requests retire
         # heap, not append+sort: retire/abort run in the latency-critical
         # inter-segment gap, and admission must stay deterministic
         # (lowest free slot first) without an O(n log n) sort per event
@@ -2269,7 +2335,7 @@ class PagedContinuousBatchingEngine:
             if self.kv_dtype == "int8":
                 for (kp, vp, ks, vs), lay in zip(pools, layers):
                     kp, vp, ks, vs = install_page_q(
-                        kp, vp, ks, vs, jnp.int32(pid),
+                        kp, vp, ks, vs, np.int32(pid),
                         lay["k"][b], lay["v"][b],
                         lay["k_scale"][b], lay["v_scale"][b])
                     new_pools.append((kp, vp, ks, vs))
@@ -2277,7 +2343,7 @@ class PagedContinuousBatchingEngine:
                 self.alloc.note_scale_copied(pid)
             else:
                 for (kp, vp), lay in zip(pools, layers):
-                    kp, vp = install_page(kp, vp, jnp.int32(pid),
+                    kp, vp = install_page(kp, vp, np.int32(pid),
                                           lay["k"][b], lay["v"][b])
                     new_pools.append((kp, vp))
                 self.caches = (new_pools, pt)
@@ -2286,6 +2352,7 @@ class PagedContinuousBatchingEngine:
                 "coverage": len(blocks) * self.page_size}
 
     # -- chunked admission (host-driven, one chunk per inter-segment gap) ----
+    # lint: hot-path
     def begin_admit(self, prompt_ids, cfg: GenerationConfig):
         """Start a CHUNKED admission: claim the slot AND the request's
         pages up front — the existing
@@ -2374,6 +2441,7 @@ class PagedContinuousBatchingEngine:
                 mini = self._gather_mini(mini, pids)
         return mini, start
 
+    # lint: hot-path
     def admit_chunk(self, adm: _ChunkedAdmission) -> bool:
         """Run ONE fixed-shape prefill chunk of an admission started
         with :meth:`begin_admit`. Returns True when the admission
@@ -2393,8 +2461,8 @@ class PagedContinuousBatchingEngine:
             # ``cached``: the prompt tokens already in the mini
             with self._prefill_span(adm.off + r, C, cached=adm.off):
                 adm.last_logits, adm.mini = self._prefill_chunk(
-                    self.params, chunk, adm.mini, jnp.int32(adm.off),
-                    jnp.int32(r - 1), self._bank(), jnp.int32(aidx))
+                    self.params, chunk, adm.mini, np.int32(adm.off),
+                    np.int32(r - 1), self._bank(), np.int32(aidx))
             adm.off += C
             adm.chunks_done += 1
             if monitor.enabled():
@@ -2445,50 +2513,53 @@ class PagedContinuousBatchingEngine:
         # programs) are unbounded — warmup cannot cover them, so it
         # warms only the length-independent programs
         widths = self.prefill_buckets or ()
+        logits = None
         for w in widths:
             t0 = time.perf_counter()
-            self._warmup_prefill(w)
+            logits = self._warmup_prefill(w)
             out[f"prefill_{w}"] = time.perf_counter() - t0
         if self.prefill_chunk is not None:
             t0 = time.perf_counter()
-            _, mini = self._prefill_chunk(
+            logits, mini = self._prefill_chunk(
                 self.params, np.zeros((1, self.prefill_chunk), np.int32),
-                self._mini_cache(self.max_len), jnp.int32(0),
-                jnp.int32(0), self._bank(), jnp.int32(0))
+                self._mini_cache(self.max_len), np.int32(0),
+                np.int32(0), self._bank(), np.int32(0))
             # and the install of a max_len mini (into the free slot 0)
             self._install_mini(0, mini, self.prefill_chunk)
             out["prefill_chunk"] = time.perf_counter() - t0
-        # slot-state install program (values match the initial state,
-        # except the active flag — reset below)
-        t0 = time.perf_counter()
-        self._install_state(0, 0, jnp.int32(0), jnp.asarray(False),
-                            GenerationConfig(max_new_tokens=1))
-        self.active_dev = self.active_dev.at[0].set(False)
-        out["admit_state"] = time.perf_counter() - t0
+        if logits is not None:
+            # the admission tail's program (first token + slot state),
+            # fed a warmed prefill's own logits so that it compiles for
+            # the type and placement a real admission hands it. Slot 0
+            # stays free: no live mask names it, and its next admission
+            # rewrites every value this installs
+            t0 = time.perf_counter()
+            self._install_state(0, 0, logits,
+                                GenerationConfig(max_new_tokens=1))
+            out["admit_state"] = time.perf_counter() - t0
+        mb = self.max_batch
+        idle = np.zeros((mb,), bool)
         if segment_steps is not None:
             # with every slot inactive the segment is a semantic no-op
             # (live rows mask to nothing), so running it only compiles
             t0 = time.perf_counter()
-            key = jax.random.PRNGKey(0)
             (_, self.last, self.lens, self.done_dev, self.caches, _) = \
                 self._segment_fn(segment_steps)(
                     self.params, self.last, self.lens, self.done_dev,
-                    self.active_dev, self.samp, self._bank(),
-                    self.caches, key)
+                    idle, self.samp, self._bank(), self.caches,
+                    _u32(0), _u32(0))
             out[f"segment_{segment_steps}"] = time.perf_counter() - t0
         if self.draft_k and self.spec_mode == "host":
             # the widened speculative verify step: with every slot
             # inactive (live mask all-False) acceptance is 0 and every
             # KV write drops, so running it only compiles
             t0 = time.perf_counter()
-            mb = self.max_batch
             (_, _, self.last, self.lens, self.caches) = \
                 self._spec_step_fn()(
-                    self.params, self.last, self.lens, self.active_dev,
-                    self.samp, self._bank(), self.caches,
-                    jax.random.PRNGKey(0),
-                    jnp.zeros((mb, self.draft_k), jnp.int32),
-                    jnp.zeros((mb,), bool), jnp.zeros((mb,), jnp.int32))
+                    self.params, self.last, self.lens, idle,
+                    self.samp, self._bank(), self.caches, _u32(0),
+                    _u32(0), np.zeros((mb, self.draft_k), np.int32),
+                    idle, np.zeros((mb,), np.int32))
             out[f"spec_step_{self.draft_k}"] = time.perf_counter() - t0
         if (self.draft_k and self.spec_mode == "device"
                 and segment_steps is not None):
@@ -2498,15 +2569,14 @@ class PagedContinuousBatchingEngine:
             # a speculating request hits is hot before the first
             # admission
             t0 = time.perf_counter()
-            mb = self.max_batch
             (_, self.last, self.lens, self.done_dev, self.hist,
              self.hist_len, self.caches) = \
                 self._spec_segment_device_fn(segment_steps)(
                     self.params, self.last, self.lens, self.done_dev,
-                    self.active_dev, self.samp, self._bank(),
-                    self.caches, self.hist, self.hist_len,
-                    jnp.zeros((mb,), jnp.int32),
-                    jnp.zeros((mb,), jnp.int32), jax.random.PRNGKey(0))
+                    idle, self.samp, self._bank(), self.caches,
+                    self.hist, self.hist_len,
+                    np.zeros((mb,), np.int32),
+                    np.zeros((mb,), np.int32), _u32(0), _u32(0))
             out[f"spec_segment_{segment_steps}"] = \
                 time.perf_counter() - t0
         if self.adapters is not None:
@@ -2525,11 +2595,13 @@ class PagedContinuousBatchingEngine:
                 engine=self._monitor_engine).set(out["total"])
         return out
 
-    def _warmup_prefill(self, width: int) -> None:
+    def _warmup_prefill(self, width: int):
         """Run what a cold admission of a ``width``-token prompt runs
-        (the jitted program directly, not the dispatch helpers). Slot 0
-        is free and maps no page, so every row it scatters drops."""
-        self._prefill_install(0, np.zeros((1, width), np.int32), width, 0)
+        (the jitted program directly, not the dispatch helpers); returns
+        its logits. Slot 0 is free and maps no page, so every row it
+        scatters drops."""
+        return self._prefill_install(0, np.zeros((1, width), np.int32),
+                                     width, 0)
 
     def _warmup_prefix(self) -> dict:
         """Pre-compile every program a WARM admission can hit — the
@@ -2559,11 +2631,11 @@ class PagedContinuousBatchingEngine:
         new_pools = []
         for entry in pools:
             if quant:
-                new_pools.append(copy_page_q(*entry, jnp.int32(0),
-                                             jnp.int32(0)))
+                new_pools.append(copy_page_q(*entry, np.int32(0),
+                                             np.int32(0)))
             else:
-                new_pools.append(copy_page(*entry, jnp.int32(0),
-                                           jnp.int32(0)))
+                new_pools.append(copy_page(*entry, np.int32(0),
+                                           np.int32(0)))
         self.caches = (new_pools, pt)
         out["prefix_gather_copy"] = time.perf_counter() - t0
         pt_dev = self._device_tables()
@@ -2571,22 +2643,33 @@ class PagedContinuousBatchingEngine:
             t0 = time.perf_counter()
             _, mini = self._prefill_chunk(
                 self.params, np.zeros((1, w), np.int32), mini,
-                jnp.int32(0), jnp.int32(0), self._bank(),
-                jnp.int32(0))
+                np.int32(0), np.int32(0), self._bank(),
+                np.int32(0))
             pools, _ = self.caches
             new_pools = []
             for entry, (mk, mv) in zip(pools, mini):
                 if quant:
                     new_pools.append(scatter_rows_q(
-                        *entry, pt_dev, jnp.int32(0), jnp.int32(0),
-                        jnp.int32(0), mk, mv, width=w))
+                        *entry, pt_dev, np.int32(0), np.int32(0),
+                        np.int32(0), mk, mv, width=w))
                 else:
                     new_pools.append(scatter_rows(
-                        *entry, pt_dev, jnp.int32(0), jnp.int32(0),
-                        jnp.int32(0), mk, mv, width=w))
+                        *entry, pt_dev, np.int32(0), np.int32(0),
+                        np.int32(0), mk, mv, width=w))
             self.caches = (new_pools, pt)
             out[f"prefix_warm_{w}"] = time.perf_counter() - t0
         return out
+
+    def _next_key_args(self, cfg):
+        """Advance the engine's PRNG stream position and return the
+        ``(seed, counter)`` scalars a segment program makes its key
+        from (`_segment_key`): every segment, and every verify step of
+        a host-mode speculative one, draws fresh sampling noise even
+        when no request was admitted in between. ``cfg`` seeds the
+        shared base stream (None: 0)."""
+        self._segments_run += 1
+        return (_u32(cfg.seed if cfg is not None else 0),
+                _u32(self._segments_run))
 
     def _segment_fn(self, n_steps: int):
         # keyed on n_steps ALONE: sampling parameters AND the LoRA
@@ -2598,8 +2681,10 @@ class PagedContinuousBatchingEngine:
             max_len = self.max_len
 
             def segment(params, last, lens, done, active, samp, bank,
-                        caches, key):
+                        caches, seed, counter):
                 lora = (bank, samp["adapter"]) if bank else None
+                samp = _live_samp(samp, active)
+                key = _segment_key(seed, counter)
 
                 def step(carry, _):
                     last, lens, done, caches, key = carry
@@ -2724,9 +2809,11 @@ class PagedContinuousBatchingEngine:
             k = self.draft_k
 
             def spec_step(params, last, lens, active, samp, bank,
-                          caches, key, drafts, live_in, lim):
+                          caches, seed, counter, drafts, live_in, lim):
                 b = last.shape[0]
                 lora = (bank, samp["adapter"]) if bank else None
+                samp = _live_samp(samp, active)
+                key = _segment_key(seed, counter)
                 live = live_in & active & (lens < self.max_len)
                 inp = jnp.concatenate([last[:, None], drafts], axis=1)
                 logits, caches, aux = self._fwd_spec(
@@ -2800,9 +2887,12 @@ class PagedContinuousBatchingEngine:
             self_draft = self.spec_draft == "self"
 
             def spec_segment(params, last, lens, done, active, samp,
-                             bank, caches, hist, hl, bud, cov, key):
+                             bank, caches, hist, hl, bud, cov, seed,
+                             counter):
                 b = last.shape[0]
                 lora = (bank, samp["adapter"]) if bank else None
+                samp = _live_samp(samp, active)
+                key = _segment_key(seed, counter)
                 rows = jnp.arange(b)
                 iw = jnp.arange(k, dtype=jnp.int32)[None]
 
@@ -2938,17 +3028,13 @@ class PagedContinuousBatchingEngine:
             cov[slot] = min(self._coverage_limit(slot), self.max_len)
         # fresh noise per segment, like the plain scan (the program
         # splits per step; sampled rows fold their own seed in)
-        self._segments_run += 1
-        key = jax.random.fold_in(
-            jax.random.PRNGKey(cfg.seed if cfg is not None else 0),
-            self._segments_run)
         (seg, self.last, self.lens, self.done_dev, self.hist,
          self.hist_len, self.caches) = self._spec_segment_device_fn(
             n_steps)(
             self.params, self.last, self.lens, self.done_dev,
-            self.active_dev, self.samp, self._bank(), self.caches,
-            self.hist, self.hist_len, jnp.asarray(bud),
-            jnp.asarray(cov), key)
+            self._active_mask(), self.samp, self._bank(), self.caches,
+            self.hist, self.hist_len, bud, cov,
+            *self._next_key_args(cfg))
         # lint: allow-host-sync(collection itself: ONE readback per
         # FUSED segment — n_steps x (tokens, acceptance, liveness)
         # plus the final done flags ride one packed tensor; this is
@@ -3068,13 +3154,11 @@ class PagedContinuousBatchingEngine:
         # pull per SEGMENT: the host proposers need real lengths to
         # place drafts; tracked incrementally below, not re-pulled per
         # step. Device mode ships no per-row pulls at all.)
-        lens_h = np.asarray(self.lens).copy()
-        # lint: allow-host-sync(same once-per-segment spec_mode="host"
-        # pull as lens_h)
-        done_h = np.asarray(self.done_dev)
+        lens_h, done_h = jax.device_get((self.lens, self.done_dev))
+        lens_h = lens_h.copy()
+        active = self._active_mask()
         emitted = {rid: [] for rid in self._slot_req.values()}
         finished = set()
-        base = jax.random.PRNGKey(cfg.seed if cfg is not None else 0)
         forwards = 0
         proposed = accepted = slot_steps = 0
         for _ in range(n_steps):
@@ -3101,23 +3185,17 @@ class PagedContinuousBatchingEngine:
             slot_steps += int(live.sum())
             # fresh noise per verify step, like the plain scan's
             # per-step key split (sampled rows fold their own seed in)
-            self._segments_run += 1
-            key = jax.random.fold_in(base, self._segments_run)
             toks, n_acc, self.last, self.lens, self.caches = fn(
-                self.params, self.last, self.lens, self.active_dev,
-                self.samp, self._bank(), self.caches, key,
-                jnp.asarray(drafts), jnp.asarray(live),
-                jnp.asarray(lim))
+                self.params, self.last, self.lens, active,
+                self.samp, self._bank(), self.caches,
+                *self._next_key_args(cfg), drafts, live, lim)
             forwards += 1
             # lint: allow-host-sync(the spec_mode="host" branch's
             # per-verify-step readback — host n-gram proposers must
             # see acceptance before drafting again. This is exactly
             # the sync spec_mode="device" eliminates; spec_stats'
             # host_syncs counts it, and it reads 0 in device mode.)
-            toks_h = np.asarray(toks)
-            # lint: allow-host-sync(same spec_mode="host"
-            # per-verify-step readback)
-            acc_h = np.asarray(n_acc)
+            toks_h, acc_h = jax.device_get((toks, n_acc))
             for slot, rid in self._slot_req.items():
                 if not live[slot]:
                     continue
@@ -3305,26 +3383,19 @@ class PagedContinuousBatchingEngine:
                        for rid in self._slot_req.values()),
                    pages_table=self.alloc.page_table.size)
         t0 = time.perf_counter()
-        # every segment must draw fresh sampling noise even when no
-        # request was admitted in between — fold in a segment counter
-        self._segments_run += 1
-        key = jax.random.fold_in(
-            jax.random.PRNGKey(cfg.seed if cfg is not None else 0),
-            self._segments_run)
         toks, self.last, self.lens, self.done_dev, self.caches, aux = \
             self._segment_fn(n_steps)(
                 self.params, self.last, self.lens, self.done_dev,
-                self.active_dev, self.samp, self._bank(), self.caches,
-                key)
+                self._active_mask(), self.samp, self._bank(),
+                self.caches, *self._next_key_args(cfg))
         # lint: allow-host-sync(collection itself: ONE readback per
-        # n_steps-step segment — tokens must reach handles/streams)
-        toks = np.asarray(toks)
-        if aux is not None and trace.enabled():
-            # the step's own counters (routing), out of the same program
-            # lint: allow-host-sync(a few scalars beside the tokens)
+        # n_steps-step segment — tokens must reach handles/streams; the
+        # done flags and, traced, the step's own counters (routing) come
+        # in the same transfer; lens stays on the device)
+        toks, done, aux = jax.device_get(
+            (toks, self.done_dev, aux if trace.enabled() else None))
+        if aux is not None:
             sp.set(**{k: int(v) for k, v in aux.items()})
-        # lint: allow-host-sync(same once-per-segment collection pull)
-        done = np.asarray(self.done_dev)
         emitted = 0
         for slot, rid in list(self._slot_req.items()):
             rcfg = self._cfg[rid]

@@ -179,12 +179,11 @@ def max_logit_divergence(eng_a, eng_b, prompts, cfg=None,
             break
         la = eng_a._fwd_ragged(eng_a.params, eng_a.last[:, None],
                                eng_a.caches, eng_a.lens,
-                               eng_a.active_dev)[0]
+                               eng_a._active_mask())[0]
         lb = eng_b._fwd_ragged(eng_b.params, eng_b.last[:, None],
                                eng_b.caches, eng_b.lens,
-                               eng_b.active_dev)[0]
-        live = np.asarray(eng_a.active_dev) & np.asarray(
-            eng_b.active_dev)
+                               eng_b._active_mask())[0]
+        live = eng_a._active_mask() & eng_b._active_mask()
         la = np.asarray(la[:, 0], np.float32)
         lb = np.asarray(lb[:, 0], np.float32)
         for s in np.nonzero(live)[0]:

@@ -2,7 +2,7 @@
 
 Three layers:
 
-- per-checker FIXTURE tests: each of PT001-PT006 fires on a seeded
+- per-checker FIXTURE tests: each of PT001-PT007 fires on a seeded
   violation and stays quiet on the blessed idiom (the checker's
   contract, independent of the live tree);
 - engine tests: fingerprint stability under line drift, annotation
@@ -466,6 +466,94 @@ class TestPT006:
 # ---------------------------------------------------------------------------
 # engine: annotations, fingerprints, baseline
 # ---------------------------------------------------------------------------
+# ---------------------------------------------------------------------------
+# PT007 — eager device computation in hot path
+# ---------------------------------------------------------------------------
+class TestPT007:
+    HOT = (
+        "import jax\n"
+        "import jax.numpy as jnp\n"
+        "import numpy as np\n"
+        "class E:\n"
+        "    def segment(self, seed):  # lint: hot-path\n"
+        "        key = jax.random.fold_in(jax.random.PRNGKey(seed), 1)\n"
+        "        pos = jnp.int32(3)\n"
+        "        table = jnp.asarray(self.table)\n"
+        "        self._retire(0)\n"
+        "        return self._fn()(key, pos, table, np.int32(4))\n"
+        "    def _retire(self, slot):\n"
+        "        self.active = self.active.at[slot].set(False)\n"
+        "    def _fn(self):\n"
+        "        def program(key, pos, table, n):\n"
+        "            key, sub = jax.random.split(key)\n"
+        "            return jnp.where(table > pos, n, 0).at[0].set(1)\n"
+        "        return jax.jit(program)\n"
+        "    def cold(self):\n"
+        "        return jnp.zeros((4,)).at[0].set(1)\n")
+
+    def test_fires_in_hot_and_transitively_not_in_cold(self):
+        f = only(lint_source(self.HOT), "PT007")
+        assert sorted(x.detail for x in f) == [
+            ".at[]", "jax.random.PRNGKey", "jax.random.fold_in",
+            "jnp.int32"]
+        retire = [x for x in f if x.context == "E._retire"][0]
+        assert "reached from E.segment" in retire.message
+        assert all(x.context != "E.cold" for x in f)
+
+    def test_quiet_without_annotation(self):
+        src = self.HOT.replace("  # lint: hot-path", "")
+        assert only(lint_source(src), "PT007") == []
+
+    @pytest.mark.parametrize("jit", [
+        "return jax.jit(program)",
+        "return monitor.monitored_jit(program, name='p')",
+        "return functools.partial(jax.jit, donate_argnums=(0,))(program)",
+    ])
+    def test_quiet_inside_a_jitted_nested_def(self, jit):
+        """The jitted def's body, and what it calls while tracing, is
+        where eager code ends; the same def NOT jitted is a closure of
+        the hot function and fires."""
+        src = (
+            "import jax\n"
+            "import jax.numpy as jnp\n"
+            "def _key(seed, n):\n"
+            "    return jax.random.fold_in(jax.random.PRNGKey(seed), n)\n"
+            "class E:\n"
+            "    def segment(self, seed):  # lint: hot-path\n"
+            "        def program(seed, n):\n"
+            "            return jnp.argmax(jax.random.bits(_key(seed, n)))\n"
+            "        " + jit + "\n")
+        assert only(lint_source(src), "PT007") == []
+        eager = src.replace(jit, "return program(seed, 1)")
+        assert sorted(x.detail for x in only(lint_source(eager),
+                                             "PT007")) == [
+            "jax.random.PRNGKey", "jax.random.bits",
+            "jax.random.fold_in", "jnp.argmax"]
+
+    def test_quiet_on_a_decorated_module_function(self):
+        src = (
+            "import functools\n"
+            "import jax\n"
+            "import jax.numpy as jnp\n"
+            "@functools.partial(jax.jit, donate_argnums=(0,))\n"
+            "def copy_page(pool, src, dst):\n"
+            "    return pool.at[dst].set(pool[src])\n"
+            "def gap(pool):  # lint: hot-path\n"
+            "    return copy_page(pool, 0, 1)\n")
+        assert only(lint_source(src), "PT007") == []
+
+    def test_escape_hatch_requires_reason(self):
+        src = (
+            "import jax.numpy as jnp\n"
+            "class E:\n"
+            "    def _gap(self):  # lint: hot-path\n"
+            "        # lint: allow-eager-dispatch(debug-only zeros)\n"
+            "        a = jnp.zeros((4,))\n"
+            "        b = jnp.ones((4,))  # lint: allow-eager-dispatch\n")
+        f = only(lint_source(src), "PT007")
+        assert len(f) == 1 and "REASON is required" in f[0].message
+
+
 class TestEngine:
     def test_unknown_directive_is_config_error(self):
         f = lint_source("x = 1  # lint: allow-hostsync(typo)\n")
@@ -626,9 +714,16 @@ class TestRepoGate:
                 "_RemoteAdapters.__contains__"},
             "paddle_tpu/inference/generation.py": {
                 "PagedContinuousBatchingEngine.decode_segment",
+                "PagedContinuousBatchingEngine._decode_segment_plain",
                 "PagedContinuousBatchingEngine._decode_segment_spec",
+                "PagedContinuousBatchingEngine"
+                "._decode_segment_spec_device",
                 "PagedContinuousBatchingEngine.load",
-                "PagedContinuousBatchingEngine.grow_for_segment"},
+                "PagedContinuousBatchingEngine.grow_for_segment",
+                # the admissions sit in the same gap (PR 31)
+                "PagedContinuousBatchingEngine.add_request",
+                "PagedContinuousBatchingEngine.begin_admit",
+                "PagedContinuousBatchingEngine.admit_chunk"},
         }
         for rel, want in expected.items():
             with open(os.path.join(REPO, rel)) as f:

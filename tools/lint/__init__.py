@@ -29,6 +29,11 @@ PT006     blocking socket I/O in a hot path: ``urlopen`` / connection
           ``.recv``/``.accept``/``.getresponse`` reads reached from a
           ``# lint: hot-path`` function — the cached-snapshot-only bar
           the cross-process fleet's routing seam rides on (PR 17).
+PT007     eager device computation in a hot path: ``jax.random.*``,
+          ``x.at[...]`` or a ``jnp.*`` call other than ``jnp.asarray``
+          reached from a ``# lint: hot-path`` function and outside a
+          jitted def — each is a compiled program of its own between
+          two segments; the one-dispatch-a-segment bar (PR 31).
 ========  ==================================================================
 
 Run ``python -m tools.lint paddle_tpu/``; see ``tools/lint/baseline.json``

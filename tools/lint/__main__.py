@@ -53,7 +53,7 @@ def main(argv=None) -> int:
         description="invariant-aware static analysis for paddle_tpu "
                     "(PT001 recompile / PT002 host-sync / PT003 series "
                     "lifecycle / PT004 lock discipline / PT005 flag "
-                    "gating)")
+                    "gating / PT006 socket I/O / PT007 eager dispatch)")
     ap.add_argument("paths", nargs="+", help="files/dirs to lint")
     ap.add_argument("--baseline", default=None,
                     help="baseline JSON (default: tools/lint/"

@@ -9,6 +9,7 @@ from .series import check_series_lifecycle
 from .locks import check_lock_discipline
 from .gating import check_flag_gating
 from .socket_io import check_socket_io
+from .eager_dispatch import check_eager_dispatch
 
 CHECKERS = {
     "PT001": check_recompile_hazard,
@@ -17,6 +18,7 @@ CHECKERS = {
     "PT004": check_lock_discipline,
     "PT005": check_flag_gating,
     "PT006": check_socket_io,
+    "PT007": check_eager_dispatch,
 }
 
 __all__ = ["CHECKERS"]

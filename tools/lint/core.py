@@ -38,9 +38,10 @@ _GUARDED_RE = re.compile(r"#\s*guarded-by:\s*([^#]+?)\s*$")
 _COMMENT_ONLY_RE = re.compile(r"^\s*#")
 
 KNOWN_DIRECTIVES = frozenset({
-    "hot-path",            # PT002 root: scan this function (transitively)
+    "hot-path",            # PT002/6/7 root: scan this function (transitively)
     "allow-host-sync",     # PT002 escape; reason required
     "allow-blocking-io",   # PT006 escape; reason required
+    "allow-eager-dispatch",  # PT007 escape; reason required
     "allow-recompile",     # PT001 escape; reason required
     "allow-unlocked",      # PT004 escape; reason required
     "allow-ungated",       # PT005 escape; reason required
