@@ -1,5 +1,6 @@
 """sha256 of the lowered text of the serving engine's programs, for one fixed
-tiny dense model and one tiny sparse-expert, window-attention model.
+tiny dense model, one tiny sparse-expert, window-attention model and one tiny
+latent-attention model with the sparse-attention indexer.
 
 A change to the engine is held to this: a refactor must leave every column
 as it was, and a change of a program's text must move the programs it names
@@ -29,6 +30,8 @@ from paddle_tpu.inference.generation import \
 from paddle_tpu.models import LlamaForCausalLM, llama_config  # noqa: E402
 from paddle_tpu.models.afmoe import (AfmoeConfig,  # noqa: E402
                                      AfmoeForCausalLM)
+from paddle_tpu.models.deepseek_v32 import (  # noqa: E402
+    DeepseekV32Config, DeepseekV32ForCausalLM)
 
 STEPS, WIDTH, CHUNK, DRAFT_K = 4, 16, 8, 3
 
@@ -55,6 +58,23 @@ def sparse_model():
         num_experts_per_tok=2,
         layer_types=["sliding_attention", "full_attention",
                      "sliding_attention", "full_attention"]))
+    model.eval()
+    return model
+
+
+def latent_model():
+    paddle.seed(3)
+    model = DeepseekV32ForCausalLM(DeepseekV32Config(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        moe_intermediate_size=32, num_hidden_layers=3,
+        num_attention_heads=4, num_key_value_heads=4, q_lora_rank=32,
+        kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, index_n_heads=16, index_head_dim=16, index_topk=8,
+        first_k_dense_replace=1, n_routed_experts=16, ep_size=4, ep_rank=1,
+        num_experts_per_tok=4, n_group=4, topk_group=2,
+        rope_scaling={"type": "yarn", "factor": 40, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                      "original_max_position_embeddings": 16}))
     model.eval()
     return model
 
@@ -111,6 +131,8 @@ def rows():
          dict(draft_k=DRAFT_K, spec_mode="device")),
         ("afmoe", sparse_model, dict(num_pages=64, page_size=4,
                                      max_pages=16)),
+        ("deepseek_v32", latent_model, dict(num_pages=64, page_size=4,
+                                            max_pages=16)),
     ]
     for tag, make, kw in engines:
         eng = PagedContinuousBatchingEngine(make(), **{**geometry, **kw})

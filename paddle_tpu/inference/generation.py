@@ -786,10 +786,15 @@ class PagedContinuousBatchingEngine:
     too), ``init_paged_cache`` + ``forward_decode_paged`` (a decode step
     through the page table) and, where it speculates,
     ``forward_decode_spec_paged`` (the W-position verify step). A model
-    whose layers keep KV in two geometries (full and sliding-window
-    attention) also gives ``paged_layout``: the engine then keeps two
-    page tables (``paged_cache.WindowedPageAllocator``) and refuses, by
-    name, the features whose programs do not read a ring of pages.
+    whose pages hold anything but per-head K and V in one table also
+    gives ``paged_layout``, a dict the constructor reads in ONE place:
+    ``ring`` (window layers keep a ring of pages: the engine then keeps
+    two page tables, ``paged_cache.WindowedPageAllocator``), ``last_idx``
+    (prefill is told the prompt's last position), ``counters`` (a decode
+    step returns counters, which ``jit_segment`` sums and hands back) and
+    ``rows`` (what its pages hold: latent rows, a ring), by which the
+    engine refuses, by name, the features whose programs do not read
+    such pages.
 
     ``admission_mode``: ``"reserved"`` (default) claims a request's
     worst case (prompt + max_new_tokens) at admission, so a running
@@ -889,12 +894,22 @@ class PagedContinuousBatchingEngine:
         self.num_pages = num_pages
         self.page_size = page_size
         max_len = max_pages * page_size
-        # a model whose layers keep their KV in TWO geometries (full and
-        # sliding-window attention side by side) says so; None = one
-        # table serves every layer
+        # THE place the engine reads what a model says of its cache
+        # (``paged_layout``; a model without it keeps per-head K and V in
+        # one table and gives no answer): ``ring`` — its geometries: None
+        # = one table serves every layer, else the window layers' ring
+        # (``window``, ``ring_pages``, ``window_layers``); ``last_idx`` —
+        # its prefill takes the prompt's last position (that position's
+        # logits alone, no padding routed); ``counters`` — a decode step
+        # returns counters beside its pools; ``rows`` — what its pages
+        # hold where that is not per-head K and V in one table, which the
+        # features below neither read nor write
         layout = getattr(model, "paged_layout", None)
-        self._layout = layout(page_size) if layout is not None else None
-        if self._layout is not None:
+        layout = layout(page_size) if layout is not None else {}
+        self._ring = layout.get("ring")
+        self._prefill_last_idx = bool(layout.get("last_idx"))
+        self._step_counters = bool(layout.get("counters"))
+        if layout.get("rows"):
             refused = {"tp_degree": tp_degree != 1,
                        "kv_dtype='int8'": kv_dtype != "bf16",
                        "draft_k (speculative decoding)": draft_k != 0,
@@ -904,16 +919,16 @@ class PagedContinuousBatchingEngine:
             for feature, asked in refused.items():
                 if asked:
                     raise ValueError(
-                        f"{feature} is not implemented for a model with "
-                        f"sliding-window layers or routed experts "
-                        f"({type(model).__name__}): its window layers "
-                        f"keep a ring of pages that this feature's "
+                        f"{feature} is not implemented for "
+                        f"{type(model).__name__}: its pages hold "
+                        f"{layout['rows']}, which this feature's "
                         f"programs do not read or write")
+        if self._ring is not None:
             from .paged_cache import WindowedPageAllocator
 
             self.alloc = WindowedPageAllocator(
                 num_pages, page_size, max_batch, max_pages,
-                self._layout["ring_pages"], debug=debug_pages)
+                self._ring["ring_pages"], debug=debug_pages)
         else:
             self.alloc = PageAllocator(num_pages, page_size, max_batch,
                                        max_pages, debug=debug_pages,
@@ -1061,19 +1076,21 @@ class PagedContinuousBatchingEngine:
             from .paged_cache import write_prompt
 
             mini = self._tp_kv(self.model.init_cache(1, ids.shape[1]))
-            if self._layout is not None:
+            # a ring model's window layers' rows go into their rings
+            rings = ({} if self._ring is None else
+                     {"window_layers": self._ring["window_layers"]})
+            if self._prefill_last_idx:
                 # the model is told the last position: it computes that
-                # position's logits alone and routes no padding, and its
-                # window layers' rows go into their rings
+                # position's logits alone and routes no padding
                 logits, mini = self._fwd_prefill(params, ids, mini,
                                                  last_idx=plen - 1)
-                return (logits[:, 0], write_prompt(
-                    pools, page_table, slot, plen, mini,
-                    window_layers=self._layout["window_layers"]))
-            logits, mini = self._fwd_prefill(
-                params, ids, mini, lora=_lora_rows(bank, aidx, ids))
-            return (logits[:, plen - 1],
-                    write_prompt(pools, page_table, slot, plen, mini))
+                last = logits[:, 0]
+            else:
+                logits, mini = self._fwd_prefill(
+                    params, ids, mini, lora=_lora_rows(bank, aidx, ids))
+                last = logits[:, plen - 1]
+            return last, write_prompt(pools, page_table, slot, plen, mini,
+                                      **rings)
 
         # monitor "cb_prefill", XLA module jit_prefill_one: the miss
         # counters and the benchmark's readers find a prompt's prefill
@@ -1276,7 +1293,7 @@ class PagedContinuousBatchingEngine:
                     f"init_paged_cache accepts kv_dtype (llama does); "
                     f"{type(self.model).__name__} does not") from e
             return self._tp_kv(pools), self._device_tables()
-        if self._layout is not None:
+        if self._ring is not None:
             return (self.model.init_paged_cache(
                         self.num_pages, self.page_size,
                         window_pages=self.alloc.window.num_pages),
@@ -1288,7 +1305,7 @@ class PagedContinuousBatchingEngine:
     def _device_tables(self):
         """The host page table(s) as the device programs take them: one
         array, or ``(full, ring)`` for a model with window layers."""
-        if self._layout is not None:
+        if self._ring is not None:
             return tuple(jnp.asarray(t) for t in self.alloc.tables())
         return self._tp_rep(jnp.asarray(self.alloc.page_table))
 
@@ -1408,11 +1425,12 @@ class PagedContinuousBatchingEngine:
 
         pools, pt = caches
         with substituted_state(self.model, params), no_grad():
-            logits, pools, *aux = self.model.forward_decode_paged(
+            out = self.model.forward_decode_paged(
                 Tensor(tok), pools, pt, lens, live,
                 **self._fwd_kwargs(lora))
+        logits, pools, aux = out if self._step_counters else (*out, None)
         return (logits.value if isinstance(logits, Tensor) else logits,
-                (pools, pt), aux[0] if aux else None)
+                (pools, pt), aux)
 
     def _reserved(self, plen: int, cfg) -> int:
         return min(plen + cfg.max_new_tokens, self.max_len)
@@ -1794,9 +1812,9 @@ class PagedContinuousBatchingEngine:
         # runs its running absmax against them
         self._flush_fresh_scales()
         with self._prefill_span(plen, width, fused=1) as sp:
-            if self._layout is not None and trace.enabled():
+            if self._ring is not None and trace.enabled():
                 # rows of the prompt that go into the window layers' rings
-                ps, ring = self.page_size, self._layout["ring_pages"]
+                ps, ring = self.page_size, self._ring["ring_pages"]
                 first_page = max((plen - 1) // ps - ring + 1, 0)
                 sp.set(window_rows=plen - first_page * ps)
             last_logits = self._prefill_install(
@@ -3363,11 +3381,11 @@ class PagedContinuousBatchingEngine:
 
     # lint: hot-path
     def _decode_segment_plain(self, n_steps: int, cfg, sp):
-        if self._layout is not None and trace.enabled():
+        if self._ring is not None and trace.enabled():
             # what the two geometries hold at the segment's start, and
             # the tokens a window layer's attention reads (a full
             # layer's: ctx_tokens)
-            w = self._layout["window"]
+            w = self._ring["window"]
             held = [self.alloc.held_pages(slot) for slot in self._slot_req]
             sp.set(ctx_tokens_window=sum(
                        min(self._plen[rid] + len(self._tokens[rid]), w)

@@ -426,14 +426,20 @@ class AfmoeForCausalLM(Layer):
         return self._logits(hidden), caches
 
     def paged_layout(self, page_size: int) -> dict:
-        """The KV geometry of each layer, for the paged engine: which
+        """What the paged engine has to know of this model's cache: which
         layers keep a ring of the last ``window`` positions, and the
-        ring's pages."""
+        ring's pages; that prefill takes ``last_idx``; that a decode step
+        hands out counters."""
         cfg = self.config
-        return {"window": cfg.sliding_window,
-                "ring_pages": ring_pages(cfg.sliding_window, page_size),
-                "window_layers": tuple(
-                    cfg.is_sliding(i) for i in range(cfg.num_hidden_layers))}
+        return {"ring": {"window": cfg.sliding_window,
+                         "ring_pages": ring_pages(cfg.sliding_window,
+                                                  page_size),
+                         "window_layers": tuple(
+                             cfg.is_sliding(i)
+                             for i in range(cfg.num_hidden_layers))},
+                "last_idx": True, "counters": True,
+                "rows": "per-head K and V in two geometries (its window "
+                        "layers keep a ring of pages)"}
 
     def init_paged_cache(self, num_pages: int, page_size: int,
                          window_pages: int = 0):

@@ -29,6 +29,9 @@ __all__ = ["RoutedExperts", "route_top_k", "routed_experts_ffn"]
 # How far an expert's initial weights lie from the other experts', as a
 # share of their norm (see :class:`_Upcycled`).
 EXPERT_SPREAD = 0.01
+# The most the float32 result rows [tokens x choices, hidden] of one pass
+# through the experts may take (see :class:`RoutedExperts`).
+ROWS_BYTES = 1 << 29
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "dtype", "limit"))
@@ -66,36 +69,62 @@ class _Upcycled(Initializer):
 
 
 def route_top_k(x, router, bias, top_k: int, route_scale: float = 1.0,
-                route_norm: bool = True):
+                route_norm: bool = True, n_group: int = 1,
+                topk_group: int = 1):
     """x [T, h] -> (experts [T, k] int32, weights [T, k] float32).
 
     The product and the scores are float32 at the highest matmul
     precision whatever ``x``'s dtype: near-ties between the k-th and the
     (k+1)-th score decide which expert runs, and bf16 cannot tell them
-    apart."""
+    apart.
+
+    ``n_group`` > 1 limits the choice to groups (``noaux_tc``): the
+    experts lie in ``n_group`` groups of equal size, a group's score is
+    the sum of its 2 largest ``score + bias``, and the ``top_k`` are
+    chosen inside the ``topk_group`` best groups. 1 group = no limit."""
     scores = jax.nn.sigmoid(jnp.matmul(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, sel = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    biased = scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        t, e = biased.shape
+        grouped = biased.reshape(t, n_group, e // n_group)
+        best2, _ = jax.lax.top_k(grouped, 2)
+        _, keep = jax.lax.top_k(best2.sum(axis=-1), topk_group)
+        kept = jnp.zeros((t, n_group), bool).at[
+            jnp.arange(t)[:, None], keep].set(True)
+        biased = jnp.where(kept[:, :, None], grouped,
+                           -jnp.inf).reshape(t, e)
+    _, sel = jax.lax.top_k(biased, top_k)
     w = jnp.take_along_axis(scores, sel, axis=-1)
     if route_norm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return sel.astype(jnp.int32), w * route_scale
 
 
-def routed_experts_ffn(x, sel, w, gate, up, down, valid=None):
+def routed_experts_ffn(x, sel, w, gate, up, down, valid=None,
+                       first_expert=None):
     """sum_k w[t, k] * expert_{sel[t, k]}(x[t]) for x [T, h].
 
     gate/up [E, h, m], down [E, m, h]. ``valid`` [T] bool: rows that are
     padding or dead take no expert (their output is 0, and they make no
-    expert's weights be read). Returns (out [T, h] in x's dtype, stats)
-    with ``stats`` = {"experts_hit": experts with at least one row,
-    "expert_rows_max": rows of the busiest expert}, int32 scalars."""
+    expert's weights be read). ``first_expert`` (an int): the layer is one
+    chip's SHARE of an expert-parallel layer, ``sel`` names experts of
+    the whole layer and the ``E`` held here are ``[first_expert,
+    first_expert + E)``; a choice of an expert held elsewhere is dropped
+    before the products (its term is that chip's to add). Returns (out
+    [T, h] in x's dtype, stats) with ``stats`` = {"experts_hit": held
+    experts with at least one row, "expert_rows_max": rows of the busiest
+    held expert}, int32 scalars, and with a share also
+    "expert_rows_here": the (row, choice) pairs that landed here."""
     from ...ops.pallas import grouped_matmul
 
     t, k = sel.shape
     n_exp = gate.shape[0]
     ids = sel.reshape(-1)
+    if first_expert is not None:
+        ids = ids - first_expert
+        ids = jnp.where((ids >= 0) & (ids < n_exp), ids, n_exp)
     if valid is not None:
         # past every group: sorted last, visited by no product
         ids = jnp.where(jnp.repeat(valid, k), ids, n_exp)
@@ -116,6 +145,8 @@ def routed_experts_ffn(x, sel, w, gate, up, down, valid=None):
     out = jnp.take(ys, back, axis=0).reshape(t, k, -1).sum(axis=1)
     stats = {"experts_hit": jnp.sum((sizes > 0).astype(jnp.int32)),
              "expert_rows_max": jnp.max(sizes)}
+    if first_expert is not None:
+        stats["expert_rows_here"] = jnp.sum(sizes)
     return out.astype(x.dtype), stats
 
 
@@ -123,36 +154,70 @@ class RoutedExperts(Layer):
     """Router + stacked SwiGLU experts. ``forward(x, valid=None)`` takes
     x [..., h] and returns (out, stats) — see :func:`routed_experts_ffn`.
     ``expert_bias`` is a float32 parameter, zero at initialisation, that
-    enters the choice of experts only."""
+    enters the choice of experts only.
+
+    ``n_group`` / ``topk_group``: group-limited routing
+    (:func:`route_top_k`). ``held=(first, count)``: this layer is one
+    chip's share of ``num_experts``: the router scores all of them, the
+    weights of ``count`` experts from ``first`` on are held, and the
+    output is their part of the sum. Where the sorted (token, choice) rows
+    of a call would pass ``ROWS_BYTES`` as float32 (a 16,384-token prefill
+    at hidden 7168: 3.8 GB), the tokens are computed in blocks, one after
+    another; the counters of the blocks are added, ``expert_rows_max`` is
+    the largest."""
 
     def __init__(self, hidden_size: int, expert_width: int,
                  num_experts: int, top_k: int, route_scale: float = 1.0,
-                 route_norm: bool = True):
+                 route_norm: bool = True, n_group: int = 1,
+                 topk_group: int = 1, held=None):
         super().__init__()
         self.top_k = top_k
         self.route_scale = route_scale
         self.route_norm = route_norm
+        self.n_group, self.topk_group = n_group, topk_group
+        self.first_expert = None if held is None else int(held[0])
+        # tokens a block: the largest power of two whose rows fit
+        self.token_block = 1 << int(math.log2(
+            ROWS_BYTES // (4 * top_k * hidden_size)))
         h, m, e = hidden_size, expert_width, num_experts
+        n_held = e if held is None else int(held[1])
         self.router = self.create_parameter(
             [h, e], default_initializer=XavierUniform(h, e))
         self.expert_bias = self.create_parameter(
             [e], dtype="float32", default_initializer=Constant(0.0))
         self.gate_proj = self.create_parameter(
-            [e, h, m], default_initializer=_Upcycled())
+            [n_held, h, m], default_initializer=_Upcycled())
         self.up_proj = self.create_parameter(
-            [e, h, m], default_initializer=_Upcycled())
+            [n_held, h, m], default_initializer=_Upcycled())
         self.down_proj = self.create_parameter(
-            [e, m, h], default_initializer=_Upcycled())
+            [n_held, m, h], default_initializer=_Upcycled())
 
     def forward(self, x, valid=None):
         def f(xv, router, bias, gate, up, down):
+            def block(flat, ok):
+                sel, w = route_top_k(flat, router, bias, self.top_k,
+                                     self.route_scale, self.route_norm,
+                                     self.n_group, self.topk_group)
+                return routed_experts_ffn(
+                    flat, sel, w, gate, up, down,
+                    valid=None if ok is None else ok.reshape(-1),
+                    first_expert=self.first_expert)
+
             flat = xv.reshape(-1, xv.shape[-1])
-            sel, w = route_top_k(flat, router, bias, self.top_k,
-                                 self.route_scale, self.route_norm)
-            out, stats = routed_experts_ffn(
-                flat, sel, w, gate, up, down,
-                valid=None if valid is None else valid.reshape(-1))
-            return out.reshape(xv.shape), stats
+            tb, t = self.token_block, flat.shape[0]
+            if t <= tb:
+                out, stats = block(flat, valid)
+                return out.reshape(xv.shape), stats
+            ok = (jnp.ones((t,), bool) if valid is None
+                  else valid.reshape(-1))
+            nb = -(-t // tb)           # the last block's tail: no token
+            out, stats = jax.lax.map(lambda a: block(*a), (
+                jnp.pad(flat, ((0, nb * tb - t), (0, 0))).reshape(
+                    nb, tb, -1),
+                jnp.pad(ok, (0, nb * tb - t)).reshape(nb, tb)))
+            stats = {k: (v.max() if k == "expert_rows_max" else v.sum())
+                     for k, v in stats.items()}
+            return out.reshape(nb * tb, -1)[:t].reshape(xv.shape), stats
 
         return apply_op(f, x, self.router, self.expert_bias,
                         self.gate_proj, self.up_proj, self.down_proj,

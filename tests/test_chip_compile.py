@@ -194,3 +194,56 @@ def test_grouped_matmul_expert_products(chip, rows, monkeypatch):
                     ((128, 2048, 1024), BF16), ((128,), I32)) == 1
     assert _compile(chip, down, ((rows, 1024), BF16),
                     ((128, 1024, 2048), BF16), ((128,), I32)) == 1
+
+
+# -- the latent-attention, sparse-selection decoder's kernels at its widths -------
+def test_dsa_index_scores(chip):
+    """16 rows, 64 indexer heads, a table of 1088 pages of 16 keys of
+    128."""
+    import functools
+
+    from paddle_tpu.ops import sparse_latent_attention as sla
+
+    fn = functools.partial(sla.dsa_index_scores, interpret=False)
+    assert _compile(chip, fn, ((16, 64, 128), BF16), ((16, 64), F32),
+                    ((17408, 16, 128), BF16), ((16, 1088), I32),
+                    ((16,), I32)) == 1
+
+
+@pytest.mark.parametrize("masked", [True, False], ids=["selected", "causal"])
+def test_selected_attention_prefill(chip, masked):
+    """A group of 16 heads of a 16,384-wide bucket: keys 192 wide, values
+    128, under the selection's [S, S] mask or causal alone."""
+    import functools
+
+    from paddle_tpu.ops import sparse_latent_attention as sla
+
+    s = 16384
+    fn = functools.partial(sla.selected_attention, scale=0.135,
+                           interpret=False)
+    qk, v = ((16, s, 192), BF16), ((16, s, 128), BF16)
+    if masked:
+        assert _compile(chip, fn, qk, qk, v, ((s, s), I8), ((), I32)) == 1
+    else:
+        assert _compile(
+            chip, lambda q, k, v, last: fn(q, k, v, None, last),
+            qk, qk, v, ((), I32)) == 1
+
+
+def test_grouped_matmul_held_experts(chip, monkeypatch):
+    """The held experts' products (16 experts, 7168 -> 2048 -> 7168) of a
+    decode step (16 rows x 8 choices) and of a prefill's token block."""
+    from paddle_tpu.ops import pallas as ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    for rows in (128, 16384):
+        assert _compile(
+            chip, lambda x, w, n: ops.grouped_matmul(
+                x, w, n, preferred_element_type=BF16),
+            ((rows, 7168), BF16), ((16, 7168, 2048), BF16),
+            ((16,), I32)) == 1
+        assert _compile(
+            chip, lambda x, w, n: ops.grouped_matmul(
+                x, w, n, preferred_element_type=F32),
+            ((rows, 2048), BF16), ((16, 2048, 7168), BF16),
+            ((16,), I32)) == 1
