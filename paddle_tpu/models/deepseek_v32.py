@@ -324,12 +324,15 @@ class DeepseekV32Attention(Layer):
             * (hi ** -0.5 * di ** -0.5)
         return qi, w
 
-    def _query(self, cq, cos, sin, wqb):
+    def _query(self, cq, cos, sin, wqb, scale=None):
         """(q_nope [..., H, nope], q_rope [..., H, rope]) in cq's dtype,
-        the rope in float32."""
+        the rope in float32; ``scale`` multiplies both before they are
+        rounded."""
         cfg = self.config
         n, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
         q = jnp.matmul(cq, wqb, preferred_element_type=jnp.float32)
+        if scale is not None:
+            q = q * scale
         q = q.reshape(q.shape[:-1] + (-1, n + r))
         qr = rope_pairs(q[..., n:], cos[..., None, :], sin[..., None, :])
         return q[..., :n].astype(cq.dtype), qr.astype(cq.dtype)
@@ -402,13 +405,14 @@ class DeepseekV32Attention(Layer):
             k = jnp.concatenate(
                 [kv[..., :n], jnp.broadcast_to(kr[:, None, :], (s, hg, r))],
                 axis=-1)
+            # the softmax's scale rides in q, folded in before q is rounded
             q = jnp.concatenate(self._query(
                 cq, cos, sin, jax.lax.dynamic_slice_in_dim(
-                    wqb, g * hg * (n + r), hg * (n + r), axis=1)), axis=-1)
+                    wqb, g * hg * (n + r), hg * (n + r), axis=1),
+                scale=cfg.softmax_scale), axis=-1)
             out = selected_attention(
                 jnp.swapaxes(q, 0, 1), jnp.swapaxes(k, 0, 1),
-                jnp.swapaxes(kv[..., n:], 0, 1), mask, last,
-                scale=cfg.softmax_scale)
+                jnp.swapaxes(kv[..., n:], 0, 1), mask, last)
             return jax.lax.dynamic_update_slice_in_dim(
                 ctx, jnp.swapaxes(out, 0, 1).reshape(s, hg * v), g * hg * v,
                 axis=1)

@@ -26,8 +26,13 @@ is largest. Five pieces, each pure and jittable:
   sort of a [queries, keys] block).
 - :func:`selected_attention` (prefill): a Pallas kernel, online softmax
   over blocks of keys under the selection's mask, for heads whose keys are
-  wider than their values; blocks above the diagonal and blocks of queries
-  past the prompt's last position are neither computed nor fetched.
+  wider than their values. Its grid walks the block pairs at or under the
+  diagonal and no other; a block of queries past the prompt's last position
+  is neither computed nor fetched. A grid step turns its block of the mask
+  into an additive bias once for all its heads (the causal compare in the
+  diagonal block alone) and walks each head's queries in sub-tiles, whose
+  softmax statistics lie along the lanes (one reduction across lanes a
+  sub-tile); the scale rides in the queries.
 - :func:`sparse_latent_decode` (decode): XLA's gather of the chosen rows by
   their flat index in the pool, and the attention over them in latent
   space (the up-projection absorbed into the query and the output).
@@ -38,6 +43,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -212,19 +218,43 @@ def sparse_latent_decode(q_lat, q_rope, lat_pool, chosen, ok, scale):
                       preferred_element_type=jnp.float32)
 
 
-def _selected_attention_kernel(last_ref, q_ref, k_ref, v_ref, *rest, scale,
-                               masked):
-    """One (head block, query block, key block) grid step of the online
-    softmax; key blocks innermost. ``m``/``l``/``acc`` live in scratch
-    across the key blocks of a query block."""
+def _tiling(s, h):
+    """(positions a block, queries a sub-tile, heads a grid step) of
+    :func:`selected_attention` for ``h`` heads of ``s`` positions. The
+    pipeline fetches square blocks of queries by keys; the body walks a
+    block's queries in sub-tiles, so that a sub-tile of a diagonal block
+    stops at its own last key."""
+    blk = next(b for b in (1024, 512, s) if s % b == 0)
+    sub = 256 if blk % 256 == 0 else blk
+    hb = 4 if h % 4 == 0 else 1
+    return blk, sub, hb
+
+
+def _selected_attention_kernel(last_ref, qb_ref, kb_ref, q_ref, k_ref, v_ref,
+                               *rest, masked, sub):
+    """One (head block, block pair) grid step of the online softmax. The
+    grid's second axis walks the (query block, key block) pairs at or under
+    the diagonal, key blocks innermost; ``qb_ref`` / ``kb_ref`` say which
+    pair a step is. ``m``/``l``/``acc`` live in scratch across the key
+    blocks of a query block.
+
+    The step's mask becomes an additive float32 ``bias`` (0 / NEG) once,
+    for all its heads: the selection's int8 entries, and the causal compare
+    in the diagonal block only. A head's queries are then walked in
+    sub-tiles of ``sub`` rows, each one chain: product, bias, max, exp,
+    sum, cast, product; a sub-tile of the diagonal block takes the keys up
+    to its own last row and no more. ``m`` and ``l`` are ``w`` lanes wide:
+    a row's maximum repeated over the lanes, and its sum as ``w`` partial
+    sums (keys ``c, c + w, ...`` in lane ``c``) that meet in ``_finalize``:
+    a sub-tile costs one reduction across lanes, the maximum's."""
     if masked:
-        mask_ref, o_ref, m_sc, l_sc, acc_sc = rest
+        mask_ref, o_ref, m_sc, l_sc, acc_sc, bias_sc = rest
     else:
-        o_ref, m_sc, l_sc, acc_sc = rest
-    hb, bq, _ = q_ref.shape
-    bk = k_ref.shape[1]
-    i, j = pl.program_id(1), pl.program_id(2)
-    q0, k0 = i * bq, j * bk
+        o_ref, m_sc, l_sc, acc_sc, bias_sc = rest
+    hb, blk, _ = q_ref.shape
+    dv, w = v_ref.shape[2], m_sc.shape[2]
+    t = pl.program_id(1)
+    i, j = qb_ref[t], kb_ref[t]
 
     @pl.when(j == 0)
     def _init():
@@ -232,80 +262,130 @@ def _selected_attention_kernel(last_ref, q_ref, k_ref, v_ref, *rest, scale,
         l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
         acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
 
-    @pl.when((k0 <= q0 + bq - 1) & (q0 <= last_ref[0]))
-    def _compute():
-        rows = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        cols = k0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        keep = cols <= rows
+    def causal():
+        return (jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
+                <= jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0))
+
+    def over(x, n):                 # [rows, w] -> [rows, n]
+        return x if w == 1 or n == w else pltpu.repeat(x, n // w, axis=1)
+
+    def attend(biased, diagonal):
+        def head(h, _):
+            for r0 in range(0, blk, sub):
+                rows = pl.ds(r0, sub)
+                n = r0 + sub if diagonal else blk       # keys it can see
+                sc = _dot(q_ref[h, rows, :], k_ref[h, :n, :], ((1,), (1,)))
+                if biased:
+                    sc = sc + bias_sc[rows, :n]
+                m_prev = m_sc[h, rows, :]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(sc, axis=1, keepdims=True))
+                # a row that has attended nothing yet: exp(NEG - 0) = 0
+                m_use = jnp.where(m_new > 0.5 * NEG, m_new, 0.0)
+                p = jnp.exp(sc - over(m_use, n))
+                alpha = jnp.exp(m_prev - m_use)
+                if w == 1:
+                    folded = jnp.sum(p, axis=1, keepdims=True)
+                else:
+                    folded = p[:, :w]
+                    for c in range(w, n, w):
+                        folded = folded + p[:, c:c + w]
+                l_sc[h, rows, :] = l_sc[h, rows, :] * alpha + folded
+                acc_sc[h, rows, :] = (
+                    acc_sc[h, rows, :] * (over(alpha, dv) if dv % w == 0
+                                          else alpha[:, :1])
+                    + _dot(p.astype(v_ref.dtype), v_ref[h, :n, :],
+                           ((1,), (0,))))
+                m_sc[h, rows, :] = m_new
+            return None
+
+        jax.lax.fori_loop(0, hb, head, None)
+
+    live = i * blk <= last_ref[0]
+
+    @pl.when(live & (i == j))
+    def _diagonal():
+        keep = causal()
         if masked:
             keep = keep & (mask_ref[...].astype(jnp.int32) != 0)
-        for h in range(hb):
-            sc = _dot(q_ref[h], k_ref[h], ((1,), (1,))) * scale
-            sc = jnp.where(keep, sc, NEG)
-            m_prev = m_sc[h]
-            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-            p = jnp.where(keep, jnp.exp(sc - m_new), 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            l_sc[h] = l_sc[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc_sc[h] = acc_sc[h] * alpha + _dot(
-                p.astype(v_ref.dtype), v_ref[h], ((1,), (0,)))
-            m_sc[h] = m_new
+        bias_sc[...] = jnp.where(keep, 0.0, NEG)
+        attend(True, True)
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(live & (i != j))
+    def _under():
+        if masked:
+            bias_sc[...] = jnp.where(mask_ref[...].astype(jnp.int32) != 0,
+                                     0.0, NEG)
+        attend(masked, False)
+
+    @pl.when(i == j)
     def _finalize():
-        o_ref[...] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(
-            o_ref.dtype)
+        def head(h, _):
+            l = jnp.sum(l_sc[h], axis=1, keepdims=True)
+            o_ref[h] = (acc_sc[h] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+            return None
+
+        jax.lax.fori_loop(0, hb, head, None)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def selected_attention(q, k, v, mask, last_idx, scale, interpret=None):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def selected_attention(q, k, v, mask, last_idx, interpret=None):
     """Causal attention under a selection's mask, keys wider than values.
 
-    q/k: [H, S, Dk], v: [H, S, Dv], mask: [S, S] int8 (0 = not attended;
-    the causal mask is applied besides) or None (causal alone), last_idx:
-    int32 scalar, the last query whose output matters (queries of later
-    blocks give zeros). Returns [H, S, Dv] in v's dtype. ``Dk`` is padded
-    to whole lanes with zeros here."""
+    q/k: [H, S, Dk], the softmax's scale already in ``q``; v: [H, S, Dv];
+    mask: [S, S] int8 (0 = not attended; the causal mask is applied
+    besides) or None (causal alone); last_idx: int32 scalar, the last query
+    whose output matters: the blocks of queries after its own give zeros,
+    and nothing is computed or fetched for them. Returns [H, S, Dv] in v's
+    dtype. A query that attends nothing gives zeros. The products take the
+    operands as they are (``Dk`` need not fill whole lanes: nothing is
+    padded or copied on the way in) and accumulate in float32; the softmax
+    is float32, ``p`` rounded to v's dtype before ``p x v``."""
     h, s, dk = q.shape
     dv = v.shape[-1]
-    pad = -dk % 128
-    if pad:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad)))
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad)))
-    blk = 512 if s % 512 == 0 else s
-    hb = 4 if h % 4 == 0 else 1
+    blk, sub, hb = _tiling(s, h)
+    w = 128 if sub % 128 == 0 else 1    # lanes of the softmax's statistics
     masked = mask is not None
     it = _interpret() if interpret is None else interpret
+    # the block pairs at or under the diagonal, key blocks innermost
+    qb, kb = np.tril_indices(s // blk)
 
-    def last_key_block(i):
-        return (i * blk + blk - 1) // blk
+    # a block of queries past ``last`` repeats the blocks fetched last, the
+    # diagonal pair of the last live block, so the pipeline copies nothing
+    def qi(t, last, qb):
+        return jnp.minimum(qb[t], last[0] // blk)
 
-    qo = pl.BlockSpec((hb, blk, dk + pad), lambda g, i, j, last: (g, i, 0))
-    kk = pl.BlockSpec((hb, blk, dk + pad), lambda g, i, j, last: (
-        g, jnp.minimum(j, last_key_block(i)), 0))
-    vv = pl.BlockSpec((hb, blk, dv), lambda g, i, j, last: (
-        g, jnp.minimum(j, last_key_block(i)), 0))
-    in_specs, operands = [qo, kk, vv], [q, k, v]
+    def ki(t, last, qb, kb):
+        return jnp.where(qb[t] * blk <= last[0], kb[t], last[0] // blk)
+
+    def spec(shape, index):
+        return pl.BlockSpec(shape, lambda g, t, last, qb, kb: index(
+            g, qi(t, last, qb), ki(t, last, qb, kb)))
+
+    qq = spec((hb, blk, dk), lambda g, i, j: (g, i, 0))
+    kk = spec((hb, blk, dk), lambda g, i, j: (g, j, 0))
+    vv = spec((hb, blk, dv), lambda g, i, j: (g, j, 0))
+    in_specs, operands = [qq, kk, vv], [q, k, v]
     if masked:
-        in_specs.append(pl.BlockSpec((blk, blk), lambda g, i, j, last: (
-            i, jnp.minimum(j, last_key_block(i)))))
+        in_specs.append(spec((blk, blk), lambda g, i, j: (i, j)))
         operands.append(mask)
     return pl.pallas_call(
-        functools.partial(_selected_attention_kernel, scale=scale,
-                          masked=masked),
+        functools.partial(_selected_attention_kernel, masked=masked, sub=sub),
         out_shape=jax.ShapeDtypeStruct((h, s, dv), v.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(h // hb, s // blk, s // blk),
+            num_scalar_prefetch=3,
+            grid=(h // hb, len(qb)),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((hb, blk, dv),
-                                   lambda g, i, j, last: (g, i, 0)),
-            scratch_shapes=[pltpu.VMEM((hb, blk, 1), jnp.float32),
-                            pltpu.VMEM((hb, blk, 1), jnp.float32),
-                            pltpu.VMEM((hb, blk, dv), jnp.float32)]),
+                                   lambda g, t, last, qb, kb: (g, qb[t], 0)),
+            scratch_shapes=[pltpu.VMEM((hb, blk, w), jnp.float32),
+                            pltpu.VMEM((hb, blk, w), jnp.float32),
+                            pltpu.VMEM((hb, blk, dv), jnp.float32),
+                            pltpu.VMEM((blk, blk), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=it,
         name="mla_selected_prefill",
-    )(jnp.asarray(last_idx, jnp.int32).reshape(1), *operands)
+    )(jnp.asarray(last_idx, jnp.int32).reshape(1), qb.astype(np.int32),
+      kb.astype(np.int32), *operands)

@@ -210,17 +210,19 @@ def test_dsa_index_scores(chip):
                     ((16,), I32)) == 1
 
 
-@pytest.mark.parametrize("masked", [True, False], ids=["selected", "causal"])
-def test_selected_attention_prefill(chip, masked):
-    """A group of 16 heads of a 16,384-wide bucket: keys 192 wide, values
-    128, under the selection's [S, S] mask or causal alone."""
+@pytest.mark.parametrize("masked, s", [
+    (True, 4096), (True, 8192), (True, 16384), (True, 17408),
+    (False, 2048), (False, 16384)],
+    ids=lambda v: {True: "selected", False: "causal"}.get(v, str(v)))
+def test_selected_attention_prefill(chip, masked, s):
+    """A group of 16 heads of each bucket the latent cell runs (the
+    selection's [S, S] mask past ``index_topk``, causal alone in the 2,048
+    bucket), keys 192 wide, values 128: every tiling compiles."""
     import functools
 
     from paddle_tpu.ops import sparse_latent_attention as sla
 
-    s = 16384
-    fn = functools.partial(sla.selected_attention, scale=0.135,
-                           interpret=False)
+    fn = functools.partial(sla.selected_attention, interpret=False)
     qk, v = ((16, s, 192), BF16), ((16, s, 128), BF16)
     if masked:
         assert _compile(chip, fn, qk, qk, v, ((s, s), I8), ((), I32)) == 1

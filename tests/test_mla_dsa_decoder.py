@@ -23,6 +23,7 @@ from paddle_tpu.models.deepseek_v32 import (DeepseekV32Config,
                                             DeepseekV32ForCausalLM,
                                             yarn_inv_freq, yarn_mscale)
 from paddle_tpu.nn.layer.routed_experts import RoutedExperts, route_top_k
+from paddle_tpu.ops import sparse_latent_attention as sla
 from paddle_tpu.ops.sparse_latent_attention import (dsa_index_scores,
                                                     index_scores,
                                                     selected_attention,
@@ -332,30 +333,87 @@ def test_top_k_mask_is_the_sorts(k):
         np.testing.assert_array_equal(got[r], want)
 
 
-@pytest.mark.parametrize("masked, last", [(True, 1023), (True, 300),
-                                          (False, 700)])
-def test_selected_attention_kernel_against_masked_softmax(masked, last):
-    """Two blocks of queries by two of keys, keys wider than values: the
+@pytest.mark.parametrize("masked, last, h, s, dtype, selection", [
+    (True, 1535, 4, 1536, "float32", "random"),
+    (True, 300, 4, 1536, "float32", "random"),
+    (False, 700, 4, 1536, "float32", "random"),
+    # queries of the later blocks attend nothing of the first key block
+    # and something later: nothing attended yet must stay nothing
+    (True, 1535, 4, 1536, "float32", "first_block_empty"),
+    # a mask that is not causal: the diagonal's block still applies it
+    (True, 1535, 4, 1536, "float32", "everything"),
+    # ``last`` on a sub-tile's edge and one short of it
+    (True, 768, 4, 1536, "float32", "random"),
+    (True, 767, 4, 1536, "float32", "random"),
+    (False, 256, 4, 1536, "float32", "random"),
+    (False, 255, 4, 1536, "float32", "random"),
+    # heads that are no multiple of the heads a grid step
+    (True, 1535, 6, 1536, "float32", "random"),
+    (False, 1535, 3, 1536, "float32", "random"),
+    # one block: of four sub-tiles, of two, of one, of less than a lane tile
+    (True, 1023, 4, 1024, "float32", "random"),
+    (True, 511, 4, 512, "float32", "random"),
+    (False, 383, 4, 384, "float32", "random"),
+    (True, 71, 2, 72, "float32", "random"),
+    # two blocks of 1024
+    (True, 2047, 2, 2048, "float32", "random"),
+    (False, 1100, 2, 2048, "float32", "random"),
+    # bf16 operands against the float32 reference
+    (True, 1535, 4, 1536, "bfloat16", "random"),
+    (False, 700, 4, 1536, "bfloat16", "random"),
+])
+def test_selected_attention_kernel_against_masked_softmax(masked, last, h, s,
+                                                          dtype, selection):
+    """Blocks of queries by blocks of keys, keys wider than values: the
     online softmax over key blocks under a random selection (a query may
     attend nothing of a whole block), the block above the diagonal skipped,
     and a block of queries past ``last`` left at zero."""
-    h, s, dk, dv = 4, 1024, 24, 16
+    dk, dv, dtype = 24, 16, jnp.dtype(dtype)
     rs = np.random.RandomState(last)
-    q, k = (jnp.asarray(rs.randn(h, s, dk), jnp.float32) for _ in range(2))
-    v = jnp.asarray(rs.randn(h, s, dv), jnp.float32)
+    q, k = (jnp.asarray(rs.randn(h, s, dk), dtype) for _ in range(2))
+    v = jnp.asarray(rs.randn(h, s, dv), dtype)
     causal = np.tril(np.ones((s, s), bool))
     chosen = rs.rand(s, s) < 0.3
     chosen[np.arange(s), np.arange(s)] = True      # a query attends itself
-    chosen[600:, :512] &= rs.rand(s - 600, 1) < 0.5
+    chosen[600:, :512] &= rs.rand(max(s - 600, 0), 1) < 0.5
+    if selection == "first_block_empty":
+        chosen[512:, :512] = False
+    elif selection == "everything":
+        chosen[:] = True
     mask = jnp.asarray(chosen, jnp.int8) if masked else None
-    got = selected_attention(q, k, v, mask, jnp.int32(last), scale=0.2)
+    qs = (q.astype(jnp.float32) * 0.2).astype(dtype)   # the scale rides in q
+    got = selected_attention(qs, k, v, mask, jnp.int32(last))
+    assert got.dtype == dtype
     keep = causal & chosen if masked else causal
-    sc = jnp.einsum("hqd,hkd->hqk", q, k, precision="highest") * 0.2
+    sc = jnp.einsum("hqd,hkd->hqk", qs.astype(jnp.float32),
+                    k.astype(jnp.float32), precision="highest")
     p = jax.nn.softmax(jnp.where(keep[None], sc, -jnp.inf), axis=-1)
-    want = jnp.einsum("hqk,hkv->hqv", p, v, precision="highest")
-    done = 512 * (last // 512 + 1)      # whole blocks of queries computed
-    np.testing.assert_allclose(got[:, :done], want[:, :done], atol=2e-5)
+    want = jnp.einsum("hqk,hkv->hqv", p, v.astype(jnp.float32),
+                      precision="highest")
+    blk = sla._tiling(s, h)[0]
+    done = blk * (last // blk + 1)      # whole blocks of queries computed
+    # bf16: p and the output are rounded to 8 bits of mantissa
+    atol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(got[:, :done].astype(jnp.float32),
+                               want[:, :done], atol=atol)
     assert float(jnp.abs(got[:, done:]).max(initial=0.0)) == 0.0
+
+
+def test_selected_attention_of_a_query_that_attends_nothing_is_zero():
+    """Rows of the mask that are all False (a bucket's padding inside a
+    computed block) give zeros, not the mean of the values."""
+    h, s = 2, 1536
+    rs = np.random.RandomState(0)
+    q, k = (jnp.asarray(rs.randn(h, s, 24), jnp.float32) for _ in range(2))
+    v = jnp.asarray(rs.randn(h, s, 16), jnp.float32)
+    chosen = np.tril(rs.rand(s, s) < 0.3)
+    chosen[np.arange(s), np.arange(s)] = True
+    chosen[700:] = False
+    got = selected_attention(q, k, v, jnp.asarray(chosen, jnp.int8),
+                             jnp.int32(699))
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.abs(got[:, 700:]).max()) == 0.0
+    assert float(jnp.abs(got[:, :700]).max()) > 0.0
 
 
 def test_absorbed_decode_equals_expanded_attention():
