@@ -1,6 +1,7 @@
 """sha256 of the lowered text of the serving engine's programs, for one fixed
-tiny dense model, one tiny sparse-expert, window-attention model and one tiny
-latent-attention model with the sparse-attention indexer.
+tiny dense model, one tiny sparse-expert, window-attention model, one tiny
+latent-attention model with the sparse-attention indexer and one tiny model of
+linear-attention layers that keep a state a row.
 
 A change to the engine is held to this: a refactor must leave every column
 as it was, and a change of a program's text must move the programs it names
@@ -32,6 +33,8 @@ from paddle_tpu.models.afmoe import (AfmoeConfig,  # noqa: E402
                                      AfmoeForCausalLM)
 from paddle_tpu.models.deepseek_v32 import (  # noqa: E402
     DeepseekV32Config, DeepseekV32ForCausalLM)
+from paddle_tpu.models.olmo_hybrid import (  # noqa: E402
+    OlmoHybridConfig, OlmoHybridForCausalLM)
 
 STEPS, WIDTH, CHUNK, DRAFT_K = 4, 16, 8, 3
 
@@ -75,6 +78,17 @@ def latent_model():
         rope_scaling={"type": "yarn", "factor": 40, "beta_fast": 32,
                       "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
                       "original_max_position_embeddings": 16}))
+    model.eval()
+    return model
+
+
+def hybrid_model():
+    paddle.seed(3)
+    model = OlmoHybridForCausalLM(OlmoHybridConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2,
+        linear_num_key_heads=3, linear_num_value_heads=3,
+        linear_key_head_dim=8, linear_value_head_dim=16))
     model.eval()
     return model
 
@@ -133,6 +147,8 @@ def rows():
                                      max_pages=16)),
         ("deepseek_v32", latent_model, dict(num_pages=64, page_size=4,
                                             max_pages=16)),
+        ("olmo_hybrid", hybrid_model, dict(num_pages=64, page_size=4,
+                                           max_pages=16)),
     ]
     for tag, make, kw in engines:
         eng = PagedContinuousBatchingEngine(make(), **{**geometry, **kw})
@@ -163,8 +179,9 @@ def main():
         line = f"{tag:18s} {name:24s} {digest}"
         if against is not None:
             was = against.get((tag, name), "-")
-            line = (f"{tag:18s} {name:24s} {was:16s} {digest} "
-                    f"{'same' if was == digest else 'CHANGED'}")
+            verdict = ("same" if was == digest else
+                       "new" if was == "-" else "CHANGED")
+            line = f"{tag:18s} {name:24s} {was:16s} {digest} {verdict}"
         print(line)
 
 

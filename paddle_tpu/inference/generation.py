@@ -794,7 +794,11 @@ class PagedContinuousBatchingEngine:
     step returns counters, which ``jit_segment`` sums and hands back) and
     ``rows`` (what its pages hold: latent rows, a ring), by which the
     engine refuses, by name, the features whose programs do not read
-    such pages.
+    such pages, and ``state_layers`` (the layers that keep a fixed-size
+    state a ROW and no pages, a recurrent state: their entries of the
+    pools are ``[max_batch, ...]`` arrays indexed by slot, which an
+    admission overwrites and a dead row never reads, so retirement,
+    preemption and replay copy nothing).
 
     ``admission_mode``: ``"reserved"`` (default) claims a request's
     worst case (prompt + max_new_tokens) at admission, so a running
@@ -903,12 +907,16 @@ class PagedContinuousBatchingEngine:
         # logits alone, no padding routed); ``counters`` — a decode step
         # returns counters beside its pools; ``rows`` — what its pages
         # hold where that is not per-head K and V in one table, which the
-        # features below neither read nor write
+        # features below neither read nor write; ``state_layers`` — one
+        # bool a layer, True where the layer keeps a state a row (its
+        # entry of the pools is [max_batch, ...], indexed by slot) and no
+        # pages: the page budget counts the other layers alone
         layout = getattr(model, "paged_layout", None)
         layout = layout(page_size) if layout is not None else {}
         self._ring = layout.get("ring")
         self._prefill_last_idx = bool(layout.get("last_idx"))
         self._step_counters = bool(layout.get("counters"))
+        self._state_layers = layout.get("state_layers")
         if layout.get("rows"):
             refused = {"tp_degree": tp_degree != 1,
                        "kv_dtype='int8'": kv_dtype != "bf16",
@@ -934,6 +942,18 @@ class PagedContinuousBatchingEngine:
                                        max_pages, debug=debug_pages,
                                        prefix_cache=prefix_cache,
                                        kv_dtype=kv_dtype)
+        # what the cache's kind adds to the two calls that build the pools
+        # (``model.init_paged_cache``) and fill them at admission
+        # (``paged_cache.write_prompt``): nothing, the window layers'
+        # ring, or the layers that keep a state a row
+        self._pool_kwargs, self._write_kwargs = {}, {}
+        if self._ring is not None:
+            self._pool_kwargs = {"window_pages": self.alloc.window.num_pages}
+            self._write_kwargs = {
+                "window_layers": self._ring["window_layers"]}
+        elif self._state_layers is not None:
+            self._pool_kwargs = {"state_rows": max_batch}
+            self._write_kwargs = {"state_layers": self._state_layers}
         if (isinstance(draft_k, bool)
                 or not isinstance(draft_k, (int, np.integer))
                 or not 0 <= draft_k <= 256):
@@ -1076,9 +1096,6 @@ class PagedContinuousBatchingEngine:
             from .paged_cache import write_prompt
 
             mini = self._tp_kv(self.model.init_cache(1, ids.shape[1]))
-            # a ring model's window layers' rows go into their rings
-            rings = ({} if self._ring is None else
-                     {"window_layers": self._ring["window_layers"]})
             if self._prefill_last_idx:
                 # the model is told the last position: it computes that
                 # position's logits alone and routes no padding
@@ -1089,8 +1106,10 @@ class PagedContinuousBatchingEngine:
                 logits, mini = self._fwd_prefill(
                     params, ids, mini, lora=_lora_rows(bank, aidx, ids))
                 last = logits[:, plen - 1]
+            # a window layer's rows go into its ring, a state layer's
+            # final state into the slot's row
             return last, write_prompt(pools, page_table, slot, plen, mini,
-                                      **rings)
+                                      **self._write_kwargs)
 
         # monitor "cb_prefill", XLA module jit_prefill_one: the miss
         # counters and the benchmark's readers find a prompt's prefill
@@ -1293,13 +1312,8 @@ class PagedContinuousBatchingEngine:
                     f"init_paged_cache accepts kv_dtype (llama does); "
                     f"{type(self.model).__name__} does not") from e
             return self._tp_kv(pools), self._device_tables()
-        if self._ring is not None:
-            return (self.model.init_paged_cache(
-                        self.num_pages, self.page_size,
-                        window_pages=self.alloc.window.num_pages),
-                    self._device_tables())
         return (self._tp_kv(self.model.init_paged_cache(
-                    self.num_pages, self.page_size)),
+                    self.num_pages, self.page_size, **self._pool_kwargs)),
                 self._device_tables())
 
     def _device_tables(self):
@@ -1334,7 +1348,10 @@ class PagedContinuousBatchingEngine:
         CPU test model's f32 cache dtype."""
         pools, _ = self.caches
         total = elems = 0
-        for entry in pools:
+        for entry, state in zip(pools, self._state_layers
+                                or (False,) * len(pools)):
+            if state:       # a state a row holds no page
+                continue
             total += sum(a.nbytes for a in entry)
             elems += entry[0].size + entry[1].size
         return {"bytes_per_page": total // self.num_pages,

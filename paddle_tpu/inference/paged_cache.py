@@ -358,7 +358,18 @@ def write_ring_tokens(k_pool, v_pool, ring_table, slots, positions, limit,
     return k_pool, v_pool
 
 
-def write_prompt(pools, page_table, slot, limit, mini, window_layers=None):
+def write_row_state(pool, slot, mini):
+    """A layer whose cache is a fixed-size state a ROW (a recurrent state,
+    the last inputs of a convolution), not pages: ``pool``'s arrays are
+    ``[rows, ...]`` and row ``slot`` takes the B=1 ``mini``'s, whole. The
+    state a prefill hands out is the state after the prompt's last
+    position already, so there is no ``limit`` to apply."""
+    return tuple(p.at[slot].set(m[0].astype(p.dtype))
+                 for p, m in zip(pool, mini))
+
+
+def write_prompt(pools, page_table, slot, limit, mini, window_layers=None,
+                 state_layers=None):
     """Scatter every row of a B=1 dense mini cache into ``slot``'s
     pages, all layers (pure: the body the paged engine's fused prefill
     program ends with, and of :func:`install_prompt`). Row ``i`` of the
@@ -372,11 +383,21 @@ def write_prompt(pools, page_table, slot, limit, mini, window_layers=None):
 
     ``window_layers`` (one bool a layer; static): ``page_table`` is then
     the pair ``(full, ring)`` of a :class:`WindowedPageAllocator`, and a
-    layer marked True goes into its ring (:func:`write_ring_tokens`)."""
-    width = mini[0][0].shape[1]
+    layer marked True goes into its ring (:func:`write_ring_tokens`).
+    ``state_layers`` (one bool a layer; static): a layer marked True keeps
+    a state a row and no pages (:func:`write_row_state`)."""
+    out = []
+    # the bucket's width: of the first layer whose mini holds positions
+    paged = [m for m, state in zip(mini, state_layers or ()) if not state]
+    width = (paged or mini)[0][0].shape[1]
     slots = jnp.full((width,), slot, jnp.int32)
     pos = jnp.arange(width, dtype=jnp.int32)
-    out = []
+    if state_layers is not None:
+        for pool, entry, state in zip(pools, mini, state_layers):
+            out.append(write_row_state(pool, slot, entry) if state
+                       else write_tokens(*pool, page_table, slots, pos,
+                                         entry[0][0], entry[1][0]))
+        return out
     if window_layers is not None:
         full, ring = page_table
         for pool, (mk, mv), win in zip(pools, mini, window_layers):

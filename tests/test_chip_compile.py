@@ -19,6 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops import flash_attention_kernel as fk
+from paddle_tpu.ops import gated_delta_rule as gdn
 from paddle_tpu.ops import paged_attention as pa
 from paddle_tpu.ops import pallas_kernels as pk
 
@@ -47,7 +48,7 @@ def chip(topo):
 def _compiled_not_interpreted(monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache
 
-    for mod in (fk, pa, pk):
+    for mod in (fk, pa, pk, gdn):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     cache = jax.config.jax_enable_compilation_cache
     precision = jax.config.jax_default_matmul_precision
@@ -249,3 +250,44 @@ def test_grouped_matmul_held_experts(chip, monkeypatch):
                 x, w, n, preferred_element_type=F32),
             ((rows, 2048), BF16), ((16, 2048, 7168), BF16),
             ((16,), I32)) == 1
+
+
+@pytest.mark.parametrize("s", [256, 4096])
+def test_gdn_chunk_prefill(chip, s):
+    """The chunked scan of one linear layer's admission at the hybrid
+    cell's widths (30 heads x 96 x 192), the cell's smallest and widest
+    buckets."""
+    qk, v = ((1, s, 30, 96), F32), ((1, s, 30, 192), BF16)
+    gate = ((1, s, 30), F32)
+    assert _compile(chip, gdn.gdn_chunk_prefill, qk, qk, v, gate, gate,
+                    ((), I32)) == 1
+
+
+def test_gdn_decode_step(chip):
+    """The one-token update of 48 rows' states in place."""
+    state, qk = ((48, 30, 96, 192), F32), ((48, 30, 96), F32)
+    assert _compile(chip, gdn.gdn_decode_step, state, qk, qk,
+                    ((48, 30, 192), F32), ((48, 30), F32), ((48, 30), F32),
+                    ((48,), jnp.bool_)) == 1
+
+
+def test_attention_kernels_at_thirty_heads(chip):
+    """As many KV heads as query heads, a count that fills no tile: the
+    flash forward takes 30; ``paged_decode`` copies a page only whole tiles
+    of the head axis wide, so the hybrid model stores 32 (its
+    ``cache_kv_heads``) and the kernel is asked for that."""
+    qkv = ((1, 30, 4096, 128), BF16)
+    assert _compile(
+        chip, lambda q, k, v: fk.flash_attention_bhsd(q, k, v, causal=True),
+        qkv, qkv, qkv) == 1
+    pool = ((4096, 16, 32, 128), BF16)
+    assert _compile(
+        chip, lambda q, k, v, t, n: pa.paged_decode_mha(q, k, v, t, n),
+        ((48, 32, 128), BF16), pool, pool, ((48, 320), I32),
+        ((48,), I32)) == 1
+    with pytest.raises(Exception, match="aligned to tiling"):
+        pool = ((4096, 16, 30, 128), BF16)
+        _compile(
+            chip, lambda q, k, v, t, n: pa.paged_decode_mha(q, k, v, t, n),
+            ((48, 30, 128), BF16), pool, pool, ((48, 320), I32),
+            ((48,), I32))
