@@ -904,7 +904,10 @@ class PagedContinuousBatchingEngine:
         # = one table serves every layer, else the window layers' ring
         # (``window``, ``ring_pages``, ``window_layers``); ``last_idx`` —
         # its prefill takes the prompt's last position (that position's
-        # logits alone, no padding routed); ``counters`` — a decode step
+        # logits alone, no padding routed); ``prefill_row_block`` — that
+        # prefill runs its row-wise work in blocks of so many rows, up to
+        # the prompt's last (``models/_live_rows``; the span's
+        # ``rows_run``); ``counters`` — a decode step
         # returns counters beside its pools; ``rows`` — what its pages
         # hold where that is not per-head K and V in one table, which the
         # features below neither read nor write; ``state_layers`` — one
@@ -915,6 +918,7 @@ class PagedContinuousBatchingEngine:
         layout = layout(page_size) if layout is not None else {}
         self._ring = layout.get("ring")
         self._prefill_last_idx = bool(layout.get("last_idx"))
+        self._prefill_row_block = layout.get("prefill_row_block")
         self._step_counters = bool(layout.get("counters"))
         self._state_layers = layout.get("state_layers")
         if layout.get("rows"):
@@ -1765,12 +1769,19 @@ class PagedContinuousBatchingEngine:
         program's width) is the observable that explains its latency
         class, ``plen - cached`` is what it computes of the prompt,
         ``fused`` = 1 when the mini cache and the install ride inside
-        the program."""
+        the program, ``rows_run`` the rows its row-wise work covers: the
+        bucket's, or the prompt's in whole blocks with a model that
+        names a ``prefill_row_block``."""
         if not trace.enabled():
             return trace.NULL_SPAN
+        rows = bucket
+        if self._prefill_row_block is not None:
+            from ..models._live_rows import rows_run
+
+            rows = rows_run(bucket, plen, self._prefill_row_block)
         return trace.span("engine.prefill", engine=self._monitor_engine,
                           plen=plen, bucket=bucket, cached=cached,
-                          fused=fused)
+                          fused=fused, rows_run=rows)
 
     def _cold_width(self, plen: int) -> int:
         """Program width of a cold one-shot prefill, counted."""

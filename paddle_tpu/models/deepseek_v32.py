@@ -29,7 +29,10 @@ head group by head group and attends under the selection's mask
 (``ops/sparse_latent_attention.selected_attention``); decode scores the
 row's pages with ``dsa_index_scores`` of that module, takes the top
 ``index_topk`` and attends over them in latent space, ``Wkvb`` absorbed
-into the query and the output (the same mathematics).
+into the query and the output (the same mathematics). A prefill is told
+where its prompt ends in the bucket (``last_idx``) and runs what is a
+function of one position alone (projections, norms, ropes, the FFN) over the
+row blocks up to there and over no other (``_live_rows``).
 
 The share: ``ep_size`` chips hold one layer's ``n_routed_experts`` between
 them. The router scores all of them; this chip (rank ``ep_rank``) holds
@@ -66,6 +69,7 @@ from ..nn.layer.routed_experts import RoutedExperts
 from ..ops.sparse_latent_attention import (dsa_index_scores, index_scores,
                                            selected_attention,
                                            sparse_latent_decode, top_k_mask)
+from ._live_rows import live_rows, row_block
 from .llama import LlamaMLP
 
 __all__ = ["DeepseekV32Config", "DeepseekV32Model", "DeepseekV32ForCausalLM",
@@ -74,6 +78,11 @@ __all__ = ["DeepseekV32Config", "DeepseekV32Model", "DeepseekV32ForCausalLM",
 INDEX_NORM_EPS = 1e-6       # the indexer's LayerNorm (assumed)
 INDEX_QUERY_BLOCK = 32      # queries of one block of the indexer's scores
 HEAD_GROUP = 16             # heads whose k_nope | v are expanded at a time
+# Rows of one block of a prefill's row-wise work (``_live_rows``): what is a
+# function of one position alone runs over the blocks at or before
+# ``last_idx`` and over no other. One value for every bucket, chosen on the
+# chip (PERF.md, PR 35).
+PREFILL_ROW_BLOCK = 512
 
 
 @dataclass
@@ -221,6 +230,26 @@ def _rms(x, w, eps):
     x = x.astype(jnp.float32)
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)
+
+
+def _rows(fn, last_idx, *args, block=None):
+    """``fn(*args)`` for a row-wise ``fn`` of Tensors or arrays [B, S, ...]
+    that gives one Tensor: over the whole bucket with ``last_idx`` None,
+    else over the row blocks at or before it (``_live_rows``; a later
+    row is zero), blocks of ``block`` rows (None: ``PREFILL_ROW_BLOCK``)."""
+    if last_idx is None:
+        return fn(*args)
+    tensors = [isinstance(a, Tensor) for a in args]
+
+    def on_slabs(*slabs):
+        return _val(fn(*(Tensor(v) if t else v
+                         for t, v in zip(tensors, slabs))))
+
+    vals = [_val(a) for a in args]
+    return Tensor(live_rows(
+        on_slabs, vals, last_idx + 1,
+        row_block(vals[0].shape[1], block or PREFILL_ROW_BLOCK),
+        in_axes=1, out_axes=1))
 
 
 def _block(n: int, want: int) -> int:
@@ -381,61 +410,84 @@ class DeepseekV32Attention(Layer):
                                ((0, 0), (0, s - stop))))
         return jnp.concatenate(out, axis=0)
 
-    def _attend_expanded(self, cq, row, mask, cos, sin, wqb, wkvb,
-                         last_idx):
+    def _attend_expanded(self, cq, row, mask, pos, wqb, wkvb, last_idx):
         """Attention with expanded heads: cq [S, q_lora_rank], row [S, 640]
-        (the cache's rows), mask [S, S] or None (causal). Returns [S, H * v]
-        in row's dtype. A group of heads at a time has its queries made and
-        its ``k_nope | v`` expanded from the rows, and goes through
-        ``selected_attention``."""
+        (the cache's rows), mask [S, S] or None (causal), pos [S]. Returns
+        [H, S, v] in row's dtype, heads first as the kernel gives them. A
+        group of heads at a time has its queries made and its
+        ``k_nope | v`` expanded from the live rows, in the kernel's layout,
+        and goes through ``selected_attention``."""
         cfg = self.config
         s, heads = cq.shape[0], cfg.num_attention_heads
         n, r, v, c = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim, cfg.kv_lora_rank)
         hg = _block(heads, HEAD_GROUP)
-        lat, kr = row[:, :c], row[:, c:c + r]
         last = s - 1 if last_idx is None else last_idx
+        live = None if last_idx is None else last_idx + 1
         if mask is not None:
             mask = mask.astype(jnp.int8)
 
         def group(g, ctx):
             w = jax.lax.dynamic_slice_in_dim(wkvb, g * hg * (n + v),
                                              hg * (n + v), axis=1)
-            kv = jnp.matmul(lat, w).reshape(s, hg, n + v)
-            k = jnp.concatenate(
-                [kv[..., :n], jnp.broadcast_to(kr[:, None, :], (s, hg, r))],
-                axis=-1)
-            # the softmax's scale rides in q, folded in before q is rounded
-            q = jnp.concatenate(self._query(
-                cq, cos, sin, jax.lax.dynamic_slice_in_dim(
-                    wqb, g * hg * (n + r), hg * (n + r), axis=1),
-                scale=cfg.softmax_scale), axis=-1)
-            out = selected_attention(
-                jnp.swapaxes(q, 0, 1), jnp.swapaxes(k, 0, 1),
-                jnp.swapaxes(kv[..., n:], 0, 1), mask, last)
+            wq = jax.lax.dynamic_slice_in_dim(wqb, g * hg * (n + r),
+                                              hg * (n + r), axis=1)
+
+            def operands(cq, row, pos):
+                cos, sin = _angles(pos, self.inv_freq)
+                kv = jnp.matmul(row[:, :c], w).reshape(-1, hg, n + v)
+                k = jnp.concatenate(
+                    [kv[..., :n], jnp.broadcast_to(
+                        row[:, None, c:c + r], kv.shape[:2] + (r,))],
+                    axis=-1)
+                # the softmax's scale rides in q, folded in before q is
+                # rounded
+                q = jnp.concatenate(self._query(
+                    cq, cos, sin, wq, scale=cfg.softmax_scale), axis=-1)
+                return tuple(jnp.swapaxes(t, 0, 1)
+                             for t in (q, k, kv[..., n:]))
+
+            q, k, val = live_rows(operands, (cq, row, pos), live,
+                                  row_block(s, PREFILL_ROW_BLOCK),
+                                  out_axes=1)
             return jax.lax.dynamic_update_slice_in_dim(
-                ctx, jnp.swapaxes(out, 0, 1).reshape(s, hg * v), g * hg * v,
-                axis=1)
+                ctx, selected_attention(q, k, val, mask, last), g * hg,
+                axis=0)
 
         return jax.lax.fori_loop(0, heads // hg, group,
-                                 jnp.zeros((s, heads * v), row.dtype))
+                                 jnp.zeros((heads, s, v), row.dtype))
 
     def forward_with_cache(self, x, cache, last_idx=None):
         """Prefill from position 0: x [B, S, h]; ``cache`` (rows [B, S_max,
         640], index keys [B, S_max, 128]) takes the prompt's at [0, S).
         Returns (out, new_cache)."""
+        live = None if last_idx is None else last_idx + 1
+
         def one(a, *w):
             (wqa, nq, wqb, wkva, nkv, wkvb, wo, wqbi, wki, lnw, lnb,
              ww) = w
-            cos, sin = _angles(jnp.arange(a.shape[0]), self.inv_freq)
-            cq = _rms(jnp.matmul(a, wqa,
-                                 preferred_element_type=jnp.float32),
-                      nq, self.config.rms_norm_eps).astype(a.dtype)
-            row, ki = self._cached(a, cos, sin, wkva, nkv, wki, lnw, lnb)
-            mask = self._selection(a, cq, ki, cos, sin, wqbi, ww, last_idx)
-            ctx = self._attend_expanded(cq, row, mask, cos, sin, wqb, wkvb,
+            s = a.shape[0]
+            block = row_block(s, PREFILL_ROW_BLOCK)
+            pos = jnp.arange(s)
+
+            def before(a, pos):
+                cos, sin = _angles(pos, self.inv_freq)
+                cq = _rms(jnp.matmul(a, wqa,
+                                     preferred_element_type=jnp.float32),
+                          nq, self.config.rms_norm_eps).astype(a.dtype)
+                return (cq, *self._cached(a, cos, sin, wkva, nkv, wki, lnw,
+                                          lnb))
+
+            def after(ctx):
+                return jnp.matmul(
+                    jnp.swapaxes(ctx, 0, 1).reshape(ctx.shape[1], -1), wo)
+
+            cq, row, ki = live_rows(before, (a, pos), live, block)
+            mask = self._selection(a, cq, ki, *_angles(pos, self.inv_freq),
+                                   wqbi, ww, last_idx)
+            ctx = self._attend_expanded(cq, row, mask, pos, wqb, wkvb,
                                         last_idx)
-            return jnp.matmul(ctx, wo), row, ki
+            return live_rows(after, (ctx,), live, block, in_axes=1), row, ki
 
         def attend(xv, rows, keys, *w):
             out, row, ki = (jnp.stack(v) for v in zip(
@@ -552,10 +604,22 @@ class DeepseekV32DecoderLayer(Layer):
         return x + f, stats
 
     def forward_with_cache(self, x, cache, valid=None, last_idx=None):
+        """(x, cache) of a prefill; what is row-wise here (the norms, the
+        residuals, the FFN) runs over the prompt's row blocks alone."""
         attn, cache = self.self_attn.forward_with_cache(
-            self.input_layernorm(x), cache, last_idx=last_idx)
-        x, stats = self._ffn(x + attn, valid)
-        return x, cache, stats
+            _rows(self.input_layernorm, last_idx, x), cache,
+            last_idx=last_idx)
+
+        def after(x, attn, valid=None):
+            return self._ffn(x + attn, valid)[0]
+
+        # the routed experts read every held expert's weights once a call,
+        # whatever its rows: their layers' blocks are as wide as the
+        # experts take whole (what else runs there is a norm and the
+        # shared expert, a tenth of a dense layer's row)
+        block = self.mlp.experts.token_block if self.sparse else None
+        rest = () if valid is None else (valid,)
+        return _rows(after, last_idx, x, attn, *rest, block=block), cache
 
     def forward_decode_paged(self, x, cache, page_table, lens, live):
         attn, cache = self.self_attn.forward_decode_paged(
@@ -588,8 +652,8 @@ class DeepseekV32Model(Layer):
                  else (jnp.arange(s) <= last_idx)[None, :])
         new_caches = []
         for layer, cache in zip(self.layers, caches):
-            x, cache, _ = layer.forward_with_cache(x, cache, valid=valid,
-                                                   last_idx=last_idx)
+            x, cache = layer.forward_with_cache(x, cache, valid=valid,
+                                                last_idx=last_idx)
             new_caches.append(cache)
         if last_idx is not None:
             # only the position that is sampled goes through the head
@@ -677,6 +741,7 @@ class DeepseekV32ForCausalLM(Layer):
         table and no ring; prefill takes ``last_idx``; a decode step hands
         out counters; and its pages hold no per-head K and V."""
         return {"ring": None, "last_idx": True, "counters": True,
+                "prefill_row_block": PREFILL_ROW_BLOCK,
                 "rows": "latent rows (one compressed KV row and one "
                         "indexer key a token, no heads)"}
 

@@ -233,6 +233,46 @@ def test_selected_attention_prefill(chip, masked, s):
             qk, qk, v, ((), I32)) == 1
 
 
+@pytest.mark.parametrize("s", [16384, 17408])
+def test_latent_prefill_head_groups_under_the_row_loop(chip, s, monkeypatch):
+    """A layer's eight head groups of the latent model's prefill at the
+    published widths, in the widest bucket of the cell and the engine's
+    last width (17 blocks of 1,024): a group's q, k and v are made for the
+    live row blocks (``models/_live_rows``: a ``while`` whose outputs go
+    into ``[16, S, 192]`` buffers along their second axis), then the
+    kernel takes them as they lie."""
+    from paddle_tpu.models import deepseek_v32 as dsv
+    from paddle_tpu.nn import initializer
+    from paddle_tpu.ops import sparse_latent_attention as sla
+
+    # the module holds its own name for paged_attention's helper
+    monkeypatch.setattr(sla, "_interpret", lambda: False)
+
+    def shape_only(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
+
+    initializer.set_global_initializer(shape_only, shape_only)
+    try:
+        attn = dsv.DeepseekV32Attention(dsv.DeepseekV32Config(
+            dtype="bfloat16"))
+    finally:
+        initializer.set_global_initializer(None, None)
+    assert s % dsv.row_block(s, dsv.PREFILL_ROW_BLOCK) == 0 \
+        and dsv.row_block(s, dsv.PREFILL_ROW_BLOCK) >= 512
+
+    def groups(cq, row, mask, wqb, wkvb, last):
+        return attn._attend_expanded(cq, row, mask, jnp.arange(s), wqb,
+                                     wkvb, last)
+
+    args = [jax.ShapeDtypeStruct(sh, d, sharding=chip) for sh, d in (
+        ((s, 1536), BF16), ((s, 640), BF16), ((s, s), jnp.bool_),
+        ((1536, 128 * 192), BF16), ((512, 128 * 256), BF16), ((), I32))]
+    compiled = jax.jit(groups).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and " while(" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
 def test_grouped_matmul_held_experts(chip, monkeypatch):
     """The held experts' products (16 experts, 7168 -> 2048 -> 7168) of a
     decode step (16 rows x 8 choices) and of a prefill's token block."""

@@ -565,6 +565,97 @@ def test_a_prefill_routes_in_token_blocks():
     assert int(sb["expert_rows_max"]) <= int(sa["expert_rows_max"])
 
 
+# -- the prefill's row-wise work under the prompt's extent -------------------------
+ROW_BLOCK, WIDTH = 8, 32
+
+
+def _prefill(model, ids, last, embed=None):
+    """The engine's call: logits of ``last`` and every layer's cache."""
+    from paddle_tpu.core.autograd import no_grad
+
+    emb = model.model.embed_tokens
+    if embed is not None:
+        model.model.embed_tokens = lambda i: embed(emb(i))
+    try:
+        with no_grad():
+            logits, caches = model.forward_with_cache(
+                paddle.to_tensor(ids), model.init_cache(1, ids.shape[1]), 0,
+                last_idx=jnp.int32(last))
+    finally:
+        model.model.embed_tokens = emb
+    return logits.value[0, 0], caches
+
+
+@pytest.mark.parametrize("last", [2 * ROW_BLOCK - 1, 2 * ROW_BLOCK,
+                                  WIDTH - 1, ROW_BLOCK - 5])
+def test_prefill_runs_the_prompts_row_blocks_and_no_other(last, monkeypatch):
+    """``last_idx`` at a block's last row, its first, the bucket's last and
+    inside the first block of a bucket four blocks wide: the logits of that
+    position are the reference's of the prompt alone and the bucket-wide
+    run's, the cache rows of the prompt are the bucket-wide run's; and the
+    blocks past the prompt were not run: NaN in their embeddings reaches
+    nothing, and their cache rows are zero."""
+    from paddle_tpu.models import deepseek_v32 as dsv
+
+    cfg, model, params = tiny_model()
+    for layer in model.model.layers[cfg.first_k_dense_replace:]:
+        # an expert layer's FFN goes in blocks as wide as the experts take
+        layer.mlp.experts.token_block = 2 * ROW_BLOCK
+    ids = _ids(WIDTH, seed=last)
+    want = ref.forward(params.__getitem__, cfg, ids[:, :last + 1])[0, last]
+    monkeypatch.setattr(dsv, "PREFILL_ROW_BLOCK", WIDTH)
+    whole, whole_caches = _prefill(model, ids, last)
+    monkeypatch.setattr(dsv, "PREFILL_ROW_BLOCK", ROW_BLOCK)
+    ran = (last // ROW_BLOCK + 1) * ROW_BLOCK
+
+    def nan_past_the_blocks(x):
+        return paddle.to_tensor(x.value.at[:, ran:].set(jnp.nan))
+
+    for embed in (None, nan_past_the_blocks):
+        got, caches = _prefill(model, ids, last, embed)
+        np.testing.assert_allclose(got, want, atol=5e-5)
+        np.testing.assert_allclose(got, whole, atol=5e-5)
+        for (rows, keys), (wrows, wkeys) in zip(caches, whole_caches):
+            np.testing.assert_allclose(rows[:, :last + 1],
+                                       wrows[:, :last + 1], atol=5e-5)
+            np.testing.assert_allclose(keys[:, :last + 1],
+                                       wkeys[:, :last + 1], atol=5e-5)
+            assert bool(jnp.isfinite(rows).all() & jnp.isfinite(keys).all())
+            assert not np.asarray(rows[:, ran:]).any()
+            assert not np.asarray(keys[:, ran:]).any()
+
+
+def test_engine_admits_under_the_row_loop_and_counts_its_rows(monkeypatch):
+    """Through the engine with a block a quarter of the bucket: a 9-token
+    prompt in the 16 bucket runs 12 rows (``engine.prefill{rows_run}``),
+    and the served tokens are the reference's."""
+    from paddle_tpu import tracing
+    from paddle_tpu.models import deepseek_v32 as dsv
+
+    monkeypatch.setattr(dsv, "PREFILL_ROW_BLOCK", 4)
+    cfg, model, params = tiny_model()
+    assert model.paged_layout(PAGE)["prefill_row_block"] == 4
+    eng = tiny_engine(model)
+    prompt = _ids(TOPK + 1, seed=5)
+    tracing.enable()
+    tracing.clear()
+    try:
+        rid = eng.add_request(prompt, GenerationConfig(max_new_tokens=6,
+                                                       do_sample=False))
+        while eng.decode_segment(4):
+            pass
+        events = tracing.events()
+    finally:
+        tracing.disable()
+    toks = eng.collect_finished()[rid]
+    full = np.concatenate([prompt[0], toks[:-1]])[None]
+    logits = ref.forward(params.__getitem__, cfg, full, last=6)[0]
+    assert float((logits.max(-1) - logits[np.arange(6), toks]).max()) <= 1e-4
+    pre, = [e for e in events if e["phase"] == "engine.prefill"]
+    assert (pre["plen"], pre["bucket"], pre["rows_run"]) == (9, 16, 12)
+    eng.close()
+
+
 # -- YaRN --------------------------------------------------------------------------
 def test_yarn_inv_freq_and_mscale_against_hand_computed_values():
     """rope dim 64, theta 10000, factor 40 over 4096: the dimension that

@@ -650,6 +650,9 @@ class TestServingTree:
                       if e["phase"] == "engine.prefill"]
         assert (cold["plen"], cold["bucket"], cold["cached"],
                 cold["fused"]) == (20, 32, 0, 1)
+        # a model whose layout names no row block runs the bucket's rows
+        assert cold["rows_run"] == 32 and warm["rows_run"] == warm["bucket"]
+        assert all(e["rows_run"] == e["bucket"] for e in pres)
         assert warm["plen"] == 20 and warm["cached"] > 0
         assert warm["fused"] == 0
         # the warm hit keeps its separate mini cache and install
