@@ -1322,10 +1322,23 @@ class PagedContinuousBatchingEngine:
 
     def _device_tables(self):
         """The host page table(s) as the device programs take them: one
-        array, or ``(full, ring)`` for a model with window layers."""
+        array, or ``(full, ring)`` for a model with window layers. Every
+        upload passes here, a segment's and an admission's alike, so
+        here a traced run keeps what was sent (``_tables_sent``, for the
+        ``changed`` of ``engine.tables``) and any other run drops it."""
+        self._tables_sent = self._tables_bytes() if trace.enabled() else None
         if self._ring is not None:
             return tuple(jnp.asarray(t) for t in self.alloc.tables())
         return self._tp_rep(jnp.asarray(self.alloc.page_table))
+
+    def _tables_bytes(self) -> list:
+        """The host page table(s) as bytes, to compare with what the
+        last upload sent: a copy and a ``!=`` under the interpreter
+        lock, where numpy's comparison lets go of it and the threads the
+        last collection woke would run inside the comparison."""
+        tabs = (self.alloc.tables() if self._ring is not None
+                else (self.alloc.page_table,))
+        return [t.tobytes() for t in tabs]
 
     def _measure_quant_savings(self) -> None:
         """Price the int8 layout from the REAL pool arrays: HBM bytes
@@ -1689,20 +1702,25 @@ class PagedContinuousBatchingEngine:
         # they are committed replicated, so the program has one
         # signature. The scalars are numpy: they ride as arguments,
         # where a jnp.int32() each is a device program of its own
-        (out, self.lens, self.last, self.done_dev, self.samp, self.hist,
-         self.hist_len) = self._admit_state(
-            self.lens, self.last, self.done_dev, self.samp, self.hist,
-            self.hist_len, np.int32(slot), np.int32(plen),
-            self._tp_rep(last_logits), _u32(cfg.seed + rid),
-            np.float32(cfg.temperature), np.int32(cfg.top_k),
-            np.float32(cfg.top_p), np.bool_(cfg.do_sample),
-            np.int32(eos), np.int32(cfg.seed % (2 ** 31)),
-            np.int32(self._spec_k_for(cfg)), np.int32(aidx), hrow,
-            np.int32(hlen))
-        # lint: allow-host-sync(the admission's ONE pull: the first
-        # token and its eos verdict, packed; the host waits here for
-        # the prefill it dispatched)
-        first, tok_done = np.asarray(out).tolist()
+        # where it dispatches and where it waits are spans of their own
+        # (children of ``engine.first_token`` in an admission): a host
+        # stall with the chip idle then reads as one or the other
+        with trace.span("engine.dispatch"):
+            (out, self.lens, self.last, self.done_dev, self.samp,
+             self.hist, self.hist_len) = self._admit_state(
+                self.lens, self.last, self.done_dev, self.samp, self.hist,
+                self.hist_len, np.int32(slot), np.int32(plen),
+                self._tp_rep(last_logits), _u32(cfg.seed + rid),
+                np.float32(cfg.temperature), np.int32(cfg.top_k),
+                np.float32(cfg.top_p), np.bool_(cfg.do_sample),
+                np.int32(eos), np.int32(cfg.seed % (2 ** 31)),
+                np.int32(self._spec_k_for(cfg)), np.int32(aidx), hrow,
+                np.int32(hlen))
+        with trace.span("engine.wait"):
+            # lint: allow-host-sync(the admission's ONE pull: the first
+            # token and its eos verdict, packed; the host waits here for
+            # the prefill it dispatched)
+            first, tok_done = np.asarray(out).tolist()
         return first, bool(tok_done)
 
     def _register(self, slot: int, rid: int, first: int, tok_done: bool,
@@ -3064,78 +3082,85 @@ class PagedContinuousBatchingEngine:
         the ``emitted == slot_steps + accepted`` identity across both
         modes."""
         t0 = time.perf_counter()
-        mb = self.max_batch
         k = self.draft_k
         W = k + 1
-        bud = np.zeros((mb,), np.int32)
-        cov = np.zeros((mb,), np.int32)
-        for slot, rid in self._slot_req.items():
-            bud[slot] = max(self._budget[rid], 0)
-            cov[slot] = min(self._coverage_limit(slot), self.max_len)
-        # fresh noise per segment, like the plain scan (the program
-        # splits per step; sampled rows fold their own seed in)
-        (seg, self.last, self.lens, self.done_dev, self.hist,
-         self.hist_len, self.caches) = self._spec_segment_device_fn(
-            n_steps)(
-            self.params, self.last, self.lens, self.done_dev,
-            self._active_mask(), self.samp, self._bank(), self.caches,
-            self.hist, self.hist_len, bud, cov,
-            *self._next_key_args(cfg))
-        # lint: allow-host-sync(collection itself: ONE readback per
-        # FUSED segment — n_steps x (tokens, acceptance, liveness)
-        # plus the final done flags ride one packed tensor; this is
-        # the plain path's once-per-segment collect pull, not the
-        # host-mode per-verify-step sync)
-        seg = np.asarray(seg)
-        done_h = seg[-1, :, 0].astype(bool)
-        total = proposed = accepted = slot_steps = 0
-        steps_live = np.zeros((n_steps,), bool)
-        for slot, rid in list(self._slot_req.items()):
-            live_s = seg[:n_steps, slot, W + 1].astype(bool)
-            acc_s = seg[:n_steps, slot, W]
-            sk = self._spec_k_of(rid)
-            seq = []
-            for s in range(n_steps):
-                if not live_s[s]:
-                    continue
-                steps_live[s] = True
-                slot_steps += 1
-                proposed += sk
-                na = int(acc_s[s])
-                seq.extend(int(t) for t in seg[s, slot, :na])
-                accepted += max(na - 1, 0)
-            self._tokens[rid].extend(seq)
-            self._budget[rid] -= len(seq)
-            total += len(seq)
-            if self._budget[rid] <= 0 or bool(done_h[slot]):
-                self._retire(slot)
-        # forwards counts verify steps that served at least one live
-        # row — the host loop's early-exit semantics; the fused
-        # program's trailing all-dead steps are masked no-ops
-        forwards = int(steps_live.sum())
-        self._spec_totals["proposed"] += proposed
-        self._spec_totals["accepted"] += accepted
-        self._spec_totals["forwards"] += forwards
-        self._spec_totals["slot_steps"] += slot_steps
-        self._spec_totals["emitted"] += total
-        if monitor.enabled():
-            dt = time.perf_counter() - t0
-            monitor.counter(
-                "paddle_tpu_generated_tokens_total",
-                "tokens generated by the continuous-batching engines "
-                "(admission first-token + decode segments)").inc(total)
-            self._tokens_per_sec_gauge().labels(
-                engine=self._monitor_engine).set(
-                total / dt if dt > 0 else 0.0)
-            if proposed:
-                c = self._spec_tokens_counter()
-                c.labels(engine=self._monitor_engine,
-                         outcome="proposed").inc(proposed)
-                c.labels(engine=self._monitor_engine,
-                         outcome="accepted").inc(accepted)
-        if trace.enabled():
-            sp.set(mode="device", forwards=forwards, proposed=proposed,
-                   accepted=accepted, emitted=total, host_syncs=0)
+        fn = self._spec_segment_device_fn(n_steps)
+        # the plain segment's three phases, a span each
+        with trace.span("engine.dispatch") as dsp:
+            mb = self.max_batch
+            bud = np.zeros((mb,), np.int32)
+            cov = np.zeros((mb,), np.int32)
+            for slot, rid in self._slot_req.items():
+                bud[slot] = max(self._budget[rid], 0)
+                cov[slot] = min(self._coverage_limit(slot), self.max_len)
+            # fresh noise per segment, like the plain scan (the program
+            # splits per step; sampled rows fold their own seed in)
+            args = (self.params, self.last, self.lens, self.done_dev,
+                    self._active_mask(), self.samp, self._bank(),
+                    self.caches, self.hist, self.hist_len, bud, cov,
+                    *self._next_key_args(cfg))
+            (seg, self.last, self.lens, self.done_dev, self.hist,
+             self.hist_len, self.caches) = fn(*args)
+            if trace.enabled():
+                dsp.set(args=len(jax.tree_util.tree_leaves(args)))
+        with trace.span("engine.wait"):
+            # lint: allow-host-sync(collection itself: ONE readback per
+            # FUSED segment — n_steps x (tokens, acceptance, liveness)
+            # plus the final done flags ride one packed tensor; this is
+            # the plain path's once-per-segment collect pull, not the
+            # host-mode per-verify-step sync)
+            seg = np.asarray(seg)
+        with trace.span("engine.collect"):
+            done_h = seg[-1, :, 0].astype(bool)
+            total = proposed = accepted = slot_steps = 0
+            steps_live = np.zeros((n_steps,), bool)
+            for slot, rid in list(self._slot_req.items()):
+                live_s = seg[:n_steps, slot, W + 1].astype(bool)
+                acc_s = seg[:n_steps, slot, W]
+                sk = self._spec_k_of(rid)
+                seq = []
+                for s in range(n_steps):
+                    if not live_s[s]:
+                        continue
+                    steps_live[s] = True
+                    slot_steps += 1
+                    proposed += sk
+                    na = int(acc_s[s])
+                    seq.extend(int(t) for t in seg[s, slot, :na])
+                    accepted += max(na - 1, 0)
+                self._tokens[rid].extend(seq)
+                self._budget[rid] -= len(seq)
+                total += len(seq)
+                if self._budget[rid] <= 0 or bool(done_h[slot]):
+                    self._retire(slot)
+            # forwards counts verify steps that served at least one live
+            # row — the host loop's early-exit semantics; the fused
+            # program's trailing all-dead steps are masked no-ops
+            forwards = int(steps_live.sum())
+            self._spec_totals["proposed"] += proposed
+            self._spec_totals["accepted"] += accepted
+            self._spec_totals["forwards"] += forwards
+            self._spec_totals["slot_steps"] += slot_steps
+            self._spec_totals["emitted"] += total
+            if monitor.enabled():
+                dt = time.perf_counter() - t0
+                monitor.counter(
+                    "paddle_tpu_generated_tokens_total",
+                    "tokens generated by the continuous-batching engines "
+                    "(admission first-token + decode segments)").inc(total)
+                self._tokens_per_sec_gauge().labels(
+                    engine=self._monitor_engine).set(
+                    total / dt if dt > 0 else 0.0)
+                if proposed:
+                    c = self._spec_tokens_counter()
+                    c.labels(engine=self._monitor_engine,
+                             outcome="proposed").inc(proposed)
+                    c.labels(engine=self._monitor_engine,
+                             outcome="accepted").inc(accepted)
+            if trace.enabled():
+                sp.set(mode="device", forwards=forwards,
+                       proposed=proposed, accepted=accepted,
+                       emitted=total, host_syncs=0)
         return len(self._slot_req)
 
     @staticmethod
@@ -3382,7 +3407,15 @@ class PagedContinuousBatchingEngine:
                     slot, int(lens[slot]),
                     write_ahead=1 + self._spec_k_of(rid))
         pools, _ = self.caches
-        self.caches = (pools, self._device_tables())
+        # ``changed``: the device does not hold this table yet (the last
+        # upload, a segment's or an admission's, sent another); reckoned
+        # before the span opens, which times the upload alone
+        changed = (trace.enabled()
+                   and self._tables_bytes() != self._tables_sent)
+        with trace.span("engine.tables") as tsp:
+            self.caches = (pools, self._device_tables())
+            if trace.enabled():
+                tsp.set(changed=int(changed))
         run, phase = self._decode_segment_plain, "engine.segment"
         if self._spec:
             # at least one live slot is speculating: the whole batch
@@ -3429,44 +3462,54 @@ class PagedContinuousBatchingEngine:
                        for rid in self._slot_req.values()),
                    pages_table=self.alloc.page_table.size)
         t0 = time.perf_counter()
-        toks, self.last, self.lens, self.done_dev, self.caches, aux = \
-            self._segment_fn(n_steps)(
-                self.params, self.last, self.lens, self.done_dev,
-                self._active_mask(), self.samp, self._bank(),
-                self.caches, *self._next_key_args(cfg))
-        # lint: allow-host-sync(collection itself: ONE readback per
-        # n_steps-step segment — tokens must reach handles/streams; the
-        # done flags and, traced, the step's own counters (routing) come
-        # in the same transfer; lens stays on the device)
-        toks, done, aux = jax.device_get(
-            (toks, self.done_dev, aux if trace.enabled() else None))
+        fn = self._segment_fn(n_steps)
+        # the host's three phases of a segment, a span each: handing
+        # the program over, waiting for it, the bookkeeping after it
+        with trace.span("engine.dispatch") as dsp:
+            args = (self.params, self.last, self.lens, self.done_dev,
+                    self._active_mask(), self.samp, self._bank(),
+                    self.caches, *self._next_key_args(cfg))
+            toks, self.last, self.lens, self.done_dev, self.caches, aux = \
+                fn(*args)
+            if trace.enabled():
+                dsp.set(args=len(jax.tree_util.tree_leaves(args)))
+        with trace.span("engine.wait"):
+            # lint: allow-host-sync(collection itself: ONE readback per
+            # n_steps-step segment — tokens must reach handles/streams;
+            # the done flags and, traced, the step's own counters
+            # (routing) come in the same transfer; lens stays on the
+            # device)
+            toks, done, aux = jax.device_get(
+                (toks, self.done_dev, aux if trace.enabled() else None))
         if aux is not None:
             sp.set(**{k: int(v) for k, v in aux.items()})
-        emitted = 0
-        for slot, rid in list(self._slot_req.items()):
-            rcfg = self._cfg[rid]
-            take = min(self._budget[rid], n_steps)
-            seq = toks[slot, :take].tolist()
-            if (rcfg.eos_token_id is not None
-                    and rcfg.eos_token_id in seq):
-                seq = seq[:seq.index(rcfg.eos_token_id) + 1]
-            self._tokens[rid].extend(int(t) for t in seq)
-            self._budget[rid] -= len(seq)
-            emitted += len(seq)
-            if (self._budget[rid] <= 0 or bool(done[slot])
-                    or len(seq) < take):
-                self._retire(slot)
-        if monitor.enabled():
-            dt = time.perf_counter() - t0
-            monitor.counter(
-                "paddle_tpu_generated_tokens_total",
-                "tokens generated by the continuous-batching engines "
-                "(admission first-token + decode segments)").inc(emitted)
-            self._tokens_per_sec_gauge().labels(
-                engine=self._monitor_engine).set(
-                emitted / dt if dt > 0 else 0.0)
-        if trace.enabled():
-            sp.set(emitted=emitted)
+        with trace.span("engine.collect"):
+            emitted = 0
+            for slot, rid in list(self._slot_req.items()):
+                rcfg = self._cfg[rid]
+                take = min(self._budget[rid], n_steps)
+                seq = toks[slot, :take].tolist()
+                if (rcfg.eos_token_id is not None
+                        and rcfg.eos_token_id in seq):
+                    seq = seq[:seq.index(rcfg.eos_token_id) + 1]
+                self._tokens[rid].extend(int(t) for t in seq)
+                self._budget[rid] -= len(seq)
+                emitted += len(seq)
+                if (self._budget[rid] <= 0 or bool(done[slot])
+                        or len(seq) < take):
+                    self._retire(slot)
+            if monitor.enabled():
+                dt = time.perf_counter() - t0
+                monitor.counter(
+                    "paddle_tpu_generated_tokens_total",
+                    "tokens generated by the continuous-batching engines "
+                    "(admission first-token + decode segments)").inc(
+                    emitted)
+                self._tokens_per_sec_gauge().labels(
+                    engine=self._monitor_engine).set(
+                    emitted / dt if dt > 0 else 0.0)
+            if trace.enabled():
+                sp.set(emitted=emitted)
         return len(self._slot_req)
 
     @staticmethod

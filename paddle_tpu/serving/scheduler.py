@@ -1916,8 +1916,10 @@ class Server:
             self._guard(
                 "decode",
                 lambda: self.engine.decode_segment(self.segment_steps))
-        with trace.span("collect"):
-            self._guard("collect", self._collect)
+        with trace.span("collect") as csp:
+            pushed = self._guard("collect", self._collect)
+            if trace.enabled():
+                csp.set(pushed=pushed)
         return True
 
     def _gap(self, busy: bool) -> None:  # lint: hot-path
@@ -2397,11 +2399,15 @@ class Server:
             # scores the SLO verdict from the same stamps later)
             self.slo.observe("ttft", h.tenant, ttft)
 
-    def _collect(self) -> None:
+    def _collect(self) -> int:
         """Post-segment: finish retired requests, stream deltas for the
         still-running ones. Engine-side token indices are offset by a
         replayed handle's ``_engine_base`` (tokens emitted before the
-        last restart live only handle-side)."""
+        last restart live only handle-side). Returns ``pushed``, the
+        ``collect`` span's counter: the handles given a delta here, each
+        of which wakes an HTTP thread that needs the interpreter lock
+        while this thread runs its gap and dispatch."""
+        pushed = 0
         for rid, seq in self.engine.collect_finished().items():
             h = self._active.pop(rid, None)
             if h is None:      # foreign request (user drove the engine)
@@ -2409,6 +2415,7 @@ class Server:
             self._push_delta(
                 h, list(seq[h._n_pushed - h._engine_base:]))
             h._finish(FINISHED)
+            pushed += 1
             self._count("completed")
             if monitor.enabled():
                 n = len(seq) + h._engine_base
@@ -2422,4 +2429,6 @@ class Server:
                 rid, h._n_pushed - h._engine_base)
             if delta:
                 self._push_delta(h, delta)
+                pushed += 1
         self._depth_gauge()
+        return pushed

@@ -38,7 +38,12 @@ and per decode segment — never per token and never per op — so tracing
 ON stays cheap enough for production serving (PERF.md §6, PR 25: six
 alternating pairs of the chat cell on one TPU v5e, tracing on against
 off, read TPOT p50 38.35 against 38.14 ms and 309.06 against 309.12
-tokens/s, inside either side's own spread).
+tokens/s, inside either side's own spread; PR 36, with a decode
+segment's dispatch, wait, collection and page-table upload as spans of
+their own, six alternating pairs each: the chat cell TPOT p50 15.441
+against 15.413 ms, ratio 1.0018, and 326.16 against 326.11 tokens/s;
+the hybrid cell, 20 rows live, 20.289 against 20.210 ms, ratio 1.0039,
+and 734.97 against 735.30 tokens/s, ratio 0.9996).
 
 Event shape (dict form, what every surface returns)::
 

@@ -26,7 +26,13 @@ Covers ``paddle_tpu.tracing`` end to end on CPU:
   ``jax.profiler`` session every ring span is in ``/host:CPU`` by id;
   behind ``Server`` the loop's ``step`` > ``gap`` > ``admit`` > the five
   ``engine.*`` children nest by parent id, and ``engine.segment``
-  carries the context lengths the test knows.
+  carries the context lengths the test knows;
+- the segment's host phases (ISSUE 36): ``engine.dispatch``,
+  ``engine.wait``, ``engine.collect`` as the three children of every
+  ``engine.segment`` (and of the device-mode ``engine.spec_segment``),
+  ``engine.tables`` before it with ``changed`` over known sequences,
+  the two children of ``engine.first_token``, ``collect``'s ``pushed``,
+  and nothing recorded or built with tracing off.
 
 The flight-recorder triggers (engine fault / stall / preemption storm)
 are exercised where the faults are injected — the chaos suite
@@ -658,12 +664,283 @@ class TestServingTree:
         # the warm hit keeps its separate mini cache and install
         phases = [e["phase"] for e in trace.events()
                   if e["phase"].startswith("engine.")]
+        # (the ring keeps a span when it ends: children before parent)
         assert phases == [
-            "engine.reserve", "engine.prefill", "engine.first_token",
+            "engine.reserve", "engine.prefill",
+            "engine.dispatch", "engine.wait", "engine.first_token",
             "engine.mini_cache", "engine.prefill", "engine.reserve",
-            "engine.install", "engine.first_token"]
+            "engine.install",
+            "engine.dispatch", "engine.wait", "engine.first_token"]
         assert isinstance(warm["bucket"], int)
         assert warm["plen"] - warm["cached"] <= warm["bucket"]
+
+
+# the host's three phases of a decode segment (ISSUE 36); an
+# admission's ``engine.first_token`` holds the first two
+SEGMENT_CHILDREN = ("engine.dispatch", "engine.wait", "engine.collect")
+NEW_SITES = SEGMENT_CHILDREN + ("engine.tables",)
+
+
+def _children(parent, spans):
+    return sorted((e for e in spans.values()
+                   if e["span.parent"] == parent["span.id"]),
+                  key=lambda e: e["ts_ns"])
+
+
+def _assert_three_children(seg, spans):
+    """Exactly dispatch, wait, collect: in that order, disjoint, inside
+    the parent."""
+    kids = _children(seg, spans)
+    assert tuple(e["phase"] for e in kids) == SEGMENT_CHILDREN
+    t = seg["ts_ns"]
+    for e in kids:
+        assert e["ts_ns"] >= t
+        t = e["ts_ns"] + e["dur_ns"]
+    assert t <= seg["ts_ns"] + seg["dur_ns"]
+
+
+def _tables_of(evs):
+    return [e for e in sorted(evs, key=lambda e: e["ts_ns"])
+            if e["phase"] == "engine.tables"]
+
+
+class TestSegmentCycle:
+    """ISSUE 36: the host's cycle between two decode segments, a span a
+    piece, and the counters that size ROADMAP S12's leads."""
+
+    @pytest.mark.parametrize("behind_server", [True, False])
+    def test_segment_children_and_tables(self, tr, behind_server):
+        model, mcfg = tiny_model()
+        if behind_server:
+            srv = Server(paged_engine(model), segment_steps=4)
+            try:
+                srv.submit(_prompts(mcfg, 1)[0], _greedy(9)).result(
+                    timeout=120)
+            finally:
+                srv.shutdown()
+        else:
+            eng = paged_engine(model)
+            try:
+                eng.add_request(_prompts(mcfg, 1)[0], _greedy(9))
+                eng.decode_segment(4)
+                eng.decode_segment(4)
+            finally:
+                eng.close()
+        evs = trace.events()
+        spans = _by_id(evs)
+        segs = [e for e in _by_start(spans)
+                if e["phase"] == "engine.segment"]
+        tabs = _tables_of(evs)
+        assert len(segs) == 2 and len(tabs) == 2
+        for seg, tab in zip(segs, tabs):
+            _assert_three_children(seg, spans)
+            # the upload is a sibling that ends before the segment opens
+            assert tab["span.parent"] == seg["span.parent"]
+            assert tab["ts_ns"] + tab["dur_ns"] <= seg["ts_ns"]
+            parent = spans.get(tab["span.parent"])
+            assert (parent["phase"] == "segment" if behind_server
+                    else parent is None)
+            assert _children(tab, spans) == []
+            assert tab["changed"] in (0, 1)
+        # the parent keeps what its readers read; of the children only
+        # the dispatch carries a counter
+        for seg in segs:
+            d, w, c = _children(seg, spans)
+            assert seg["emitted"] == 4 and "args" not in seg
+            assert {"steps", "rows", "ctx_tokens", "pages_live"} <= set(seg)
+            assert d["args"] > 0
+            assert set(w) - {"phase"} == set(c) - {"phase"}
+            assert set(d) - set(w) == {"args"}
+
+    def test_first_token_children(self, tr):
+        model, mcfg = tiny_model()
+        eng = paged_engine(model)
+        try:
+            eng.add_request(_prompts(mcfg, 1)[0], _greedy(2))
+        finally:
+            eng.close()
+        spans = _by_id(trace.events())
+        first = next(e for e in spans.values()
+                     if e["phase"] == "engine.first_token")
+        kids = _children(first, spans)
+        assert [e["phase"] for e in kids] == ["engine.dispatch",
+                                              "engine.wait"]
+        assert kids[0]["ts_ns"] + kids[0]["dur_ns"] <= kids[1]["ts_ns"]
+        assert (kids[1]["ts_ns"] + kids[1]["dur_ns"]
+                <= first["ts_ns"] + first["dur_ns"])
+        # ``args`` belongs to the segment's dispatch alone
+        assert all("args" not in e for e in kids)
+
+    def test_changed_reserved_mode(self, tr):
+        """``changed``: the device does not hold this table. An
+        admission uploads its own, so the segment after one reads 0; a
+        retirement takes the row's pages out of the host's table alone,
+        so the segment after one reads 1; then nothing."""
+        model, mcfg = tiny_model()
+        eng = paged_engine(model)
+        try:
+            eng.add_request(_prompts(mcfg, 1)[0], _greedy(24))
+            eng.decode_segment(4)
+            eng.decode_segment(4)
+            eng.add_request(_prompts(mcfg, 1, plen=9, seed=1)[0],
+                            _greedy(3))
+            eng.decode_segment(4)                  # b retires inside it
+            eng.decode_segment(4)
+            eng.decode_segment(4)
+        finally:
+            eng.close()
+        assert [e["changed"] for e in _tables_of(trace.events())] == [
+            0, 0, 0, 1, 0]
+
+    def test_changed_optimistic_mode(self, tr):
+        """Pages of 4: a prompt of 6 claims 3 pages (6 + one page), so
+        segments of 2 steps stay inside them up to position 12 and the
+        next crosses a page edge: the growth re-check claims a page the
+        device has not seen."""
+        model, mcfg = tiny_model()
+        eng = paged_engine(model, admission_mode="optimistic")
+        try:
+            eng.add_request(_prompts(mcfg, 1)[0], _greedy(14))
+            for _ in range(4):
+                eng.decode_segment(2)
+        finally:
+            eng.close()
+        assert [e["changed"] for e in _tables_of(trace.events())] == [
+            0, 0, 0, 1]
+
+    def test_changed_is_unknown_after_tracing_was_off(self, tr):
+        """What was sent is kept only while tracing is on: an upload
+        with tracing off drops it, so the first traced segment reads 1
+        (the tracer cannot say the device holds this table) and the
+        next 0; a fresh state is an upload like any other."""
+        trace.disable()
+        model, mcfg = tiny_model()
+        eng = paged_engine(model)
+        try:
+            eng.add_request(_prompts(mcfg, 1)[0], _greedy(24))
+            eng.decode_segment(4)
+            assert eng._tables_sent is None
+            trace.enable()
+            eng.decode_segment(4)
+            eng.decode_segment(4)
+            eng.reset_state()
+            eng.add_request(_prompts(mcfg, 1)[0], _greedy(24))
+            eng.decode_segment(4)
+        finally:
+            eng.close()
+        assert [e["changed"] for e in _tables_of(trace.events())] == [
+            1, 0, 0]
+
+    def test_args_is_the_leaf_count_of_the_call(self, tr):
+        import jax
+
+        model, mcfg = tiny_model()
+        eng = paged_engine(model)
+        seen = []
+        try:
+            fn = eng._segment_fn(4)
+
+            def counting(*args):
+                seen.append(len(jax.tree_util.tree_leaves(args)))
+                return fn(*args)
+
+            eng._segment_cache[4] = counting
+            eng.add_request(_prompts(mcfg, 1)[0], _greedy(9))
+            eng.decode_segment(4)
+            eng.decode_segment(4)
+        finally:
+            eng.close()
+        spans = _by_id(trace.events())
+        got = [e["args"] for e in _by_start(spans)
+               if e["phase"] == "engine.dispatch" and "args" in e]
+        assert got == seen and len(seen) == 2
+        # parameters, pools and page table, the per-slot vectors, the
+        # live mask, seed and counter: every one an array leaf
+        n_params = len(jax.tree_util.tree_leaves(eng.params))
+        assert seen[0] > n_params + 2 + len(eng.samp)
+
+    @pytest.mark.parametrize("budgets, want", [
+        ((9,), [1, 1]), ((9, 5), [2, 1])])
+    def test_collect_counts_pushed(self, tr, budgets, want):
+        """Requests queued before the loop starts are admitted in one
+        gap (each admission pushes its first token itself); segments of
+        4: every live handle gets a delta after a segment, its last
+        one included."""
+        model, mcfg = tiny_model()
+        srv = Server(paged_engine(model), segment_steps=4, start=False)
+        try:
+            hs = [srv.submit(p, _greedy(n)) for p, n in
+                  zip(_prompts(mcfg, len(budgets)), budgets)]
+            srv._thread.start()
+            for h in hs:
+                h.result(timeout=120)
+        finally:
+            srv.shutdown()
+        got = [e["pushed"] for e in _by_start(_by_id(trace.events()))
+               if e["phase"] == "collect"]
+        assert got == want
+
+    def test_off_records_nothing_and_builds_no_span(self, monkeypatch):
+        """With tracing off every new site gets the shared null span and
+        the ring stays empty: through an admission, two segments (plain
+        and device-speculative) and a server's collection."""
+        trace.disable()
+        trace.clear()
+        sites = []
+        real = trace.span
+
+        def spying(phase, *a, **kw):
+            out = real(phase, *a, **kw)
+            sites.append((phase, out))
+            return out
+
+        monkeypatch.setattr(trace, "span", spying)
+        model, mcfg = tiny_model()
+        eng = paged_engine(model)
+        try:
+            eng.add_request(_prompts(mcfg, 1)[0], _greedy(6))
+            eng.decode_segment(4)
+            assert eng._tables_sent is None
+        finally:
+            eng.close()
+        spec = paged_engine(model, draft_k=2, spec_mode="device")
+        try:
+            spec.add_request(_prompts(mcfg, 1)[0], GenerationConfig(
+                max_new_tokens=6, eos_token_id=None, speculative=True))
+            spec.decode_segment(4)
+        finally:
+            spec.close()
+        srv = Server(paged_engine(model), segment_steps=4)
+        try:
+            srv.submit(_prompts(mcfg, 1)[0], _greedy(6)).result(
+                timeout=120)
+        finally:
+            srv.shutdown()
+        assert trace.events() == []
+        assert {p for p, _ in sites} >= set(NEW_SITES) | {"collect"}
+        assert all(out is trace.NULL_SPAN for _, out in sites)
+
+    def test_spec_device_segment_has_the_three_children(self, tr):
+        model, mcfg = tiny_model()
+        eng = paged_engine(model, draft_k=2, spec_mode="device")
+        try:
+            eng.add_request(_prompts(mcfg, 1)[0], GenerationConfig(
+                max_new_tokens=9, eos_token_id=None, speculative=True))
+            eng.decode_segment(4)
+        finally:
+            eng.close()
+        spans = _by_id(trace.events())
+        seg = next(e for e in spans.values()
+                   if e["phase"] == "engine.spec_segment")
+        assert seg["mode"] == "device"
+        _assert_three_children(seg, spans)
+        d, w, c = _children(seg, spans)
+        assert d["args"] > 0 and "args" not in seg
+        assert len(_tables_of(spans.values())) == 1
+
+
+def _by_start(spans):
+    return sorted(spans.values(), key=lambda e: e["ts_ns"])
 
 
 def _tools():
