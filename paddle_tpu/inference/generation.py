@@ -13,7 +13,7 @@ from __future__ import annotations
 import functools
 import heapq
 import time
-from typing import Optional
+from typing import Callable, Optional
 
 import numpy as np
 
@@ -1565,10 +1565,15 @@ class PagedContinuousBatchingEngine:
                 and self._can_admit(prompt_len, cfg))
 
     # lint: hot-path
-    def add_request(self, prompt_ids, cfg: GenerationConfig) -> int:
+    def add_request(self, prompt_ids, cfg: GenerationConfig, *,
+                    on_dispatch: Optional[Callable[[], None]] = None
+                    ) -> int:
         """Prefill one request into a free slot; returns the request id.
         Raises if no slot is free (call decode_segment / collect first)
-        — probe :meth:`can_admit` to defer instead of catching."""
+        — probe :meth:`can_admit` to defer instead of catching.
+        ``on_dispatch`` runs once the admission's programs are on the
+        device and before the host waits for its first token, as in
+        :meth:`decode_segment`."""
         if not self._free:
             raise RuntimeError("no free slot; drain with decode_segment()")
         t0 = time.perf_counter()
@@ -1596,10 +1601,11 @@ class PagedContinuousBatchingEngine:
             self._abort_admit(slot)
             raise
         return self._first_token(slot, rid, ids, plen, last_logits, cfg,
-                                 aidx, t0)
+                                 aidx, t0, on_dispatch)
 
     def _first_token(self, slot: int, rid: int, ids, plen: int,
-                     last_logits, cfg, aidx: int, t0: float) -> int:
+                     last_logits, cfg, aidx: int, t0: float,
+                     on_dispatch=None) -> int:
         """The tail every admission shares (one-shot, warm, chunked):
         ONE program (``cb_admit_state``) samples the first token from
         the prompt's last logits and installs the slot's state, ONE
@@ -1613,7 +1619,7 @@ class PagedContinuousBatchingEngine:
             try:
                 first, tok_done = self._install_state(
                     slot, plen, last_logits, cfg, rid=rid, aidx=aidx,
-                    ids=ids)
+                    ids=ids, on_dispatch=on_dispatch)
             except BaseException:
                 self._abort_admit(slot)
                 raise
@@ -1678,7 +1684,8 @@ class PagedContinuousBatchingEngine:
         return 0 if prop is None else prop.k
 
     def _install_state(self, slot: int, plen: int, last_logits, cfg,
-                       rid: int = 0, aidx: int = 0, ids=None):
+                       rid: int = 0, aidx: int = 0, ids=None,
+                       on_dispatch=None):
         """Sample the request's first token from ``last_logits`` (the
         prompt's last position, [1, V]: greedy the argmax, sampled from
         ``PRNGKey(cfg.seed + rid)``) and install its per-slot scalars
@@ -1716,6 +1723,8 @@ class PagedContinuousBatchingEngine:
                 np.int32(eos), np.int32(cfg.seed % (2 ** 31)),
                 np.int32(self._spec_k_for(cfg)), np.int32(aidx), hrow,
                 np.int32(hlen))
+        if on_dispatch is not None:
+            on_dispatch()
         with trace.span("engine.wait"):
             # lint: allow-host-sync(the admission's ONE pull: the first
             # token and its eos verdict, packed; the host waits here for
@@ -2506,12 +2515,15 @@ class PagedContinuousBatchingEngine:
         return mini, start
 
     # lint: hot-path
-    def admit_chunk(self, adm: _ChunkedAdmission) -> bool:
+    def admit_chunk(self, adm: _ChunkedAdmission, *,
+                    on_dispatch: Optional[Callable[[], None]] = None
+                    ) -> bool:
         """Run ONE fixed-shape prefill chunk of an admission started
         with :meth:`begin_admit`. Returns True when the admission
         completed — the request is live in its slot under ``adm.rid``
         (its first token is in ``partial_tokens``). On ANY failure the
-        claimed capacity is reclaimed and the admission is closed."""
+        claimed capacity is reclaimed and the admission is closed.
+        ``on_dispatch``: as :meth:`add_request`'s, on the final chunk."""
         if adm.closed:
             raise RuntimeError("admission already completed or aborted")
         C = self.prefill_chunk
@@ -2545,7 +2557,8 @@ class PagedContinuousBatchingEngine:
             raise
         adm.closed = True       # _first_token reclaims on ITS failures
         self._first_token(adm.slot, adm.rid, adm.ids, adm.plen,
-                          adm.last_logits, adm.cfg, aidx, adm.t0)
+                          adm.last_logits, adm.cfg, aidx, adm.t0,
+                          on_dispatch)
         return True
 
     def abort_admit(self, adm: _ChunkedAdmission) -> None:
@@ -3067,7 +3080,8 @@ class PagedContinuousBatchingEngine:
         return self._segment_cache[key_]
 
     # lint: hot-path
-    def _decode_segment_spec_device(self, n_steps: int, cfg, sp):
+    def _decode_segment_spec_device(self, n_steps: int, cfg, sp,
+                                    on_dispatch):
         """Device-resident speculative decode segment: ONE dispatch of
         the fused :meth:`_spec_segment_device_fn` program, then ONE
         readback for collection — no per-verify-step host round-trip
@@ -3103,6 +3117,8 @@ class PagedContinuousBatchingEngine:
              self.hist_len, self.caches) = fn(*args)
             if trace.enabled():
                 dsp.set(args=len(jax.tree_util.tree_leaves(args)))
+        if on_dispatch is not None:
+            on_dispatch()
         with trace.span("engine.wait"):
             # lint: allow-host-sync(collection itself: ONE readback per
             # FUSED segment — n_steps x (tokens, acceptance, liveness)
@@ -3201,7 +3217,7 @@ class PagedContinuousBatchingEngine:
         return t
 
     # lint: hot-path
-    def _decode_segment_spec(self, n_steps: int, cfg, sp):
+    def _decode_segment_spec(self, n_steps: int, cfg, sp, on_dispatch):
         """Speculative decode segment: ``n_steps`` verify steps of the
         ONE compiled ``_spec_step_fn`` program, with the host loop in
         between — propose fresh drafts from each slot's proposer,
@@ -3261,6 +3277,8 @@ class PagedContinuousBatchingEngine:
                 self.samp, self._bank(), self.caches,
                 *self._next_key_args(cfg), drafts, live, lim)
             forwards += 1
+            if forwards == 1 and on_dispatch is not None:
+                on_dispatch()
             # lint: allow-host-sync(the spec_mode="host" branch's
             # per-verify-step readback — host n-gram proposers must
             # see acceptance before drafting again. This is exactly
@@ -3335,10 +3353,17 @@ class PagedContinuousBatchingEngine:
 
     # lint: hot-path
     def decode_segment(self, n_steps: int,
-                       cfg: Optional[GenerationConfig] = None):
+                       cfg: Optional[GenerationConfig] = None, *,
+                       on_dispatch: Optional[Callable[[], None]] = None):
         """Run ``n_steps`` ragged decode steps over the current slots;
         collect per-request tokens and retire finished requests. Returns
         the number of still-active requests.
+
+        ``on_dispatch``, if given, runs once the segment's program has
+        been handed to the device and before the host waits for it (in
+        host-mode speculation, after the first verify step's call): the
+        caller's own host work then overlaps the device's. Not called
+        when no program runs (no live slot).
 
         Each request decodes under ITS OWN GenerationConfig (installed
         at ``add_request``) — including its seed, which every sampling
@@ -3438,10 +3463,10 @@ class PagedContinuousBatchingEngine:
                 ctx_tokens=sum(self._plen[rid] + len(self._tokens[rid])
                                for rid in self._slot_req.values()))
         with sp:
-            return run(n_steps, cfg, sp)
+            return run(n_steps, cfg, sp, on_dispatch)
 
     # lint: hot-path
-    def _decode_segment_plain(self, n_steps: int, cfg, sp):
+    def _decode_segment_plain(self, n_steps: int, cfg, sp, on_dispatch):
         if self._ring is not None and trace.enabled():
             # what the two geometries hold at the segment's start, and
             # the tokens a window layer's attention reads (a full
@@ -3473,6 +3498,8 @@ class PagedContinuousBatchingEngine:
                 fn(*args)
             if trace.enabled():
                 dsp.set(args=len(jax.tree_util.tree_leaves(args)))
+        if on_dispatch is not None:
+            on_dispatch()
         with trace.span("engine.wait"):
             # lint: allow-host-sync(collection itself: ONE readback per
             # n_steps-step segment — tokens must reach handles/streams;

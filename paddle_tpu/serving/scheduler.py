@@ -15,8 +15,13 @@ AnalysisPredictor; here a dedicated scheduler THREAD owns a
            ``prefill_chunk`` admit chunk-by-chunk across gaps, so a
            long prompt never monopolizes the gap and running requests'
            TPOT stays flat
-    step:  one jitted decode segment over every occupied slot
-    drain: stream new tokens to handles, finish retired requests
+    step:  one jitted decode segment over every occupied slot; once it
+           (or an admission in the gap) is dispatched, hand the handles
+           what the last collection and the gap owe them (tokens,
+           terminal states), so the client threads those pushes wake
+           run while the device computes, not while this thread
+           launches the segment
+    drain: owe new tokens to handles, retire finished requests
 
 Admission happens only in the inter-segment gap, so a transiently full
 pool defers work instead of failing it; cancellation retires the slot in
@@ -65,6 +70,7 @@ event.
 """
 from __future__ import annotations
 
+import collections
 import math
 import threading
 import time
@@ -461,6 +467,12 @@ class Server:
         self._replay = []                 # handles surviving an engine
         #                                   restart, awaiting
         #                                   re-admission (replay)
+        self._pending = collections.deque()   # what the handles are
+        #                                   owed, in order: (handle,
+        #                                   tokens, status, error),
+        #                                   handed over by _flush once
+        #                                   the next program is
+        #                                   dispatched
         self._faulted = False             # True while a handle rides an
         #                                   in-flight fault signal
         #                                   (between its seam and
@@ -664,7 +676,8 @@ class Server:
             return self._idle_cv.wait_for(
                 lambda: (self.queue.depth == 0 and not self._active
                          and not self._admitting and self._adm is None
-                         and not self._replay and not self._faulted)
+                         and not self._replay and not self._faulted
+                         and not self._pending)
                 or self._stopped.is_set(), timeout)
 
     def shutdown(self, drain: bool = True,
@@ -1482,6 +1495,15 @@ class Server:
             self._fatal = err
         wrapped = (RuntimeError(f"serving scheduler died: {err!r}")
                    if fail else None)
+        # what the handles were owed reaches them first; an entry whose
+        # hand-over itself raised gets the terminal state below instead
+        try:
+            self._flush()
+        except Exception:
+            pass
+        for h, _toks, _status, _err in self._pending:
+            h._finish(FAILED if fail else CANCELLED, wrapped)
+        self._pending.clear()
         # pending adapter admin ops must not strand their callers in
         # load_adapter()'s wait — report the terminal state as an error
         with self._lock:
@@ -1552,7 +1574,7 @@ class Server:
             raise exc
         self._count_fault(kind, site)
         if kind == "request":
-            h._finish(FAILED, exc)
+            self._finish_later(h, FAILED, exc)
             self._count("failed")
             self._slo_fail(h)
             return
@@ -1579,6 +1601,9 @@ class Server:
         # the restart budget is already exhausted (the seam's
         # _count_fault event naming the site is already in the ring)
         self._flight_dump(f"engine_fault_{sig.site}")
+        # no dispatch follows the fault: hand over what is owed before
+        # the handles are parked for replay
+        self._flush()
         try:
             return self._recover_inner(sig)
         finally:
@@ -1685,12 +1710,12 @@ class Server:
                     f"{sig.cause!r}: {rebuild_err!r}") from rebuild_err
             for h in inflight:
                 if h._cancel_requested:
-                    h._finish(CANCELLED)
+                    self._finish_later(h, CANCELLED)
                     self._count("cancelled")
                     continue
                 h._replays += 1
                 if h._replays > self.max_replays:
-                    h._finish(FAILED, RuntimeError(
+                    self._finish_later(h, FAILED, RuntimeError(
                         f"request {h.id} exceeded its replay budget "
                         f"(max_replays={self.max_replays}) across "
                         f"engine restarts; last fault at {sig.site}: "
@@ -1757,7 +1782,8 @@ class Server:
                             replay=h._engine_base > 0, **t_attrs)
         with sp:
             try:
-                rid = self.engine.add_request(ids, cfg)
+                rid = self.engine.add_request(
+                    ids, cfg, on_dispatch=self._flush_dispatched)
             except Exception as e:
                 self._contain(h, e, "admit")
                 return False
@@ -1805,24 +1831,24 @@ class Server:
             while pending:
                 h = pending.pop(0)
                 if h._cancel_requested:
-                    h._finish(CANCELLED)
+                    self._finish_later(h, CANCELLED)
                     self._count("cancelled")
                     continue
                 if (h.engine_rid is None and h.deadline is not None
                         and time.monotonic() >= h.deadline):
-                    h._finish(EXPIRED)
+                    self._finish_later(h, EXPIRED)
                     self._count("expired")
                     continue
-                n_toks = h._n_pushed    # == len(h._tokens): scheduler-
-                #                         thread bookkeeping, O(1)
+                n_toks = h._n_pushed    # scheduler-thread bookkeeping,
+                #                         O(1); the handle's own list
+                #                         may still be owed tokens
                 remaining = h.cfg.max_new_tokens - n_toks
                 if remaining < 1:
                     # fully emitted before the fault (retirement raced
-                    # the crash) — it is simply finished
-                    h._finish(FINISHED)
+                    # the crash) — it is simply finished (scored at the
+                    # flush, where its finish is stamped)
+                    self._finish_later(h, FINISHED)
                     self._count("completed")
-                    if monitor.enabled():
-                        self._slo_finish(h, n_toks)
                     continue
                 plen = h.prompt_len + n_toks
                 if (chunk is not None and plen > chunk
@@ -1847,7 +1873,7 @@ class Server:
                         # every emitted token) — fail loudly with the
                         # typed cause instead of deferring forever
                         # against an empty engine
-                        h._finish(FAILED, PagePoolExhausted(
+                        self._finish_later(h, FAILED, PagePoolExhausted(
                             [h.id],
                             f"replay of request {h.id} "
                             f"(prompt+generated={plen} tokens) can "
@@ -1859,6 +1885,9 @@ class Server:
                         continue
                     still.append(h)
                     continue
+                if n_toks:
+                    # the handle's list must hold every token owed it
+                    self._flush()
                 # lint: allow-host-sync(host-list copy, no device
                 # read: tokens_so_far() is the handle's python list)
                 ids = np.concatenate(
@@ -1885,7 +1914,7 @@ class Server:
 
     def _has_work(self) -> bool:
         return bool(self._active or self._adm is not None
-                    or self._replay or self.queue.depth)
+                    or self._replay or self.queue.depth or self._pending)
 
     def _step(self, traced: bool) -> bool:  # lint: hot-path
         """One loop iteration: gap, then (with anything live) a decode
@@ -1894,6 +1923,7 @@ class Server:
         False when no segment ran: the caller waits."""
         self._gap(traced)
         if not self._active and self._adm is None:
+            self._flush()
             return False
         # with only a chunked admission in flight the segment is a
         # fast no-op and the loop spins straight back into _gap for
@@ -1915,7 +1945,10 @@ class Server:
         with seg:
             self._guard(
                 "decode",
-                lambda: self.engine.decode_segment(self.segment_steps))
+                lambda: self.engine.decode_segment(
+                    self.segment_steps, on_dispatch=self._flush_dispatched))
+        # a segment with no live slot dispatched nothing
+        self._flush()
         with trace.span("collect") as csp:
             pushed = self._guard("collect", self._collect)
             if trace.enabled():
@@ -1976,7 +2009,7 @@ class Server:
                 if toks is not None:
                     self._push_delta(
                         h, list(toks[h._n_pushed - h._engine_base:]))
-                h._finish(CANCELLED)
+                self._finish_later(h, CANCELLED)
                 self._count("cancelled")
         # 1b. advance the in-flight chunked admission by ONE fixed-shape
         #     chunk (or abandon it if its client cancelled / its
@@ -1994,12 +2027,14 @@ class Server:
                        and time.monotonic() >= h.deadline)
             if h._cancel_requested or expired:
                 self._adm = None
-                h._finish(CANCELLED if h._cancel_requested else EXPIRED)
+                self._finish_later(h, CANCELLED if h._cancel_requested
+                                   else EXPIRED)
                 self._count("cancelled" if h._cancel_requested
                             else "expired")
-                # the handle is terminal first: if the abort itself
-                # faults, recovery reclaims capacity wholesale and the
-                # client is not stranded behind the engine's health
+                # the handle is owed its terminal first: if the abort
+                # itself faults, recovery hands it over and reclaims
+                # capacity wholesale, so the client is not stranded
+                # behind the engine's health
                 self._guard("cancel",
                             lambda: self.engine.abort_admit(adm))
             else:
@@ -2009,7 +2044,8 @@ class Server:
                                     off=getattr(adm, "off", None))
                 try:
                     with sp:
-                        finished = self.engine.admit_chunk(adm)
+                        finished = self.engine.admit_chunk(
+                            adm, on_dispatch=self._flush_dispatched)
                 except Exception as e:
                     self._adm = None
                     # admit_chunk aborts itself on ITS failures, but a
@@ -2038,10 +2074,10 @@ class Server:
                 trace.event("queue.expire", rid=h._trace_rid,
                             cancelled=h._cancel_requested)
             if h._cancel_requested:
-                h._finish(CANCELLED)
+                self._finish_later(h, CANCELLED)
                 self._count("cancelled")
             else:
-                h._finish(EXPIRED)
+                self._finish_later(h, EXPIRED)
                 self._count("expired")
         # 2b. replays surviving an engine restart re-admit before new
         #     queue work (their capacity claim predates the fault)
@@ -2097,7 +2133,7 @@ class Server:
                         lambda h: not self.engine.can_admit(
                             h.prompt_len, h.cfg))
                     if bad is not None:
-                        bad._finish(FAILED, RuntimeError(
+                        self._finish_later(bad, FAILED, RuntimeError(
                             f"request {bad.id} (prompt_len="
                             f"{bad.prompt_len}, max_new_tokens="
                             f"{bad.cfg.max_new_tokens}) can never "
@@ -2302,7 +2338,7 @@ class Server:
                 if toks is not None:
                     self._push_delta(
                         h, list(toks[h._n_pushed - h._engine_base:]))
-                h._finish(FAILED, PagePoolExhausted(
+                self._finish_later(h, FAILED, PagePoolExhausted(
                     [rid],
                     f"request {h.id} cannot grow its KV mapping even "
                     f"with the pool to itself (prompt+generated="
@@ -2346,7 +2382,7 @@ class Server:
         the pool forever. A cancel-requested handle finishes CANCELLED
         (``_finish`` is idempotent — terminal exactly once)."""
         if h._cancel_requested:
-            h._finish(CANCELLED)
+            self._finish_later(h, CANCELLED)
             self._count("cancelled")
             return
         h._preempts += 1
@@ -2374,7 +2410,7 @@ class Server:
             if self._flight_dump("preemption_storm") is not None:
                 self._last_storm_dump = now
         if h._preempts > self.max_preemptions:
-            h._finish(FAILED, PreemptionBudgetExceeded(
+            self._finish_later(h, FAILED, PreemptionBudgetExceeded(
                 f"request {h.id} preempted {h._preempts} times under "
                 f"KV memory pressure (max_preemptions="
                 f"{self.max_preemptions}): the pool is too small for "
@@ -2386,44 +2422,91 @@ class Server:
         self._replay.append(h)
 
     def _push_delta(self, h: RequestHandle, toks) -> None:
-        """Push newly generated tokens (scheduler thread only);
-        ``_n_pushed`` keeps each gap's copy O(delta), and the first
-        push is the TTFT observation."""
+        """Owe the handle newly generated tokens (scheduler thread
+        only): ``_n_pushed`` counts them now, which keeps each gap's copy
+        O(delta); the handle gets them at the next :meth:`_flush`."""
         h._n_pushed += len(toks)
-        if h._push(toks) and monitor.enabled():
-            ttft = h.first_token_ts - h.submit_ts
-            self._ttft_hist().labels(server=self.monitor_server).observe(
-                ttft)
-            # per-tenant TTFT digest (observed at the edge so /stats
-            # reflects it while the request still streams; record_finish
-            # scores the SLO verdict from the same stamps later)
-            self.slo.observe("ttft", h.tenant, ttft)
+        if len(toks):
+            self._pending.append((h, toks, None, None))
+
+    def _finish_later(self, h: RequestHandle, status: str,
+                      error: Optional[BaseException] = None) -> None:
+        """Owe the handle its terminal state, after every token owed it
+        before (scheduler thread only)."""
+        self._pending.append((h, None, status, error))
+
+    def _flush_dispatched(self) -> None:
+        self._flush(after_dispatch=True)
+
+    def _flush(self, after_dispatch: bool = False) -> None:  # lint: hot-path
+        """Hand every handle what it is owed, in the order it was owed:
+        tokens (``_push``: a ``notify_all`` that wakes the client's
+        thread; the first push is the TTFT edge) and terminal states.
+        The engine calls it once the next segment's program, or an
+        admission's, is on the device (``after_dispatch``), so the woken
+        threads run while the device computes, not while this thread
+        builds the launch, and a first token never waits for another
+        request's prefill. Where no dispatch follows it runs at once:
+        before the idle wait, after a segment with no live slot, before
+        a recovery or a replay's read of the handle's tokens, and on
+        the loop's way out."""
+        pending = self._pending
+        if not pending:
+            return
+        sp = trace.NULL_SPAN
+        if trace.enabled():
+            sp = trace.span("push",
+                            handles=len({id(e[0]) for e in pending}),
+                            after_dispatch=int(after_dispatch))
+        with sp:
+            while pending:
+                h, toks, status, error = pending[0]
+                if toks is not None:
+                    if h._push(toks) and monitor.enabled():
+                        ttft = h.first_token_ts - h.submit_ts
+                        self._ttft_hist().labels(
+                            server=self.monitor_server).observe(ttft)
+                        # per-tenant TTFT digest (observed at the edge
+                        # so /stats reflects it while the request still
+                        # streams; record_finish scores the SLO verdict
+                        # from the same stamps later)
+                        self.slo.observe("ttft", h.tenant, ttft)
+                elif (status == FINISHED and monitor.enabled()
+                      and not h.done):
+                    h._finish(FINISHED)
+                    n = h._n_pushed
+                    if h.first_token_ts is not None and n > 1:
+                        self._tpot_hist().labels(
+                            server=self.monitor_server).observe(
+                            (h.finish_ts - h.first_token_ts) / (n - 1))
+                    self._slo_finish(h, n)
+                else:
+                    h._finish(status, error)
+                # off the list only once handed over: a timed drain()
+                # never sees "nothing left" while a finish is owed
+                pending.popleft()
 
     def _collect(self) -> int:
-        """Post-segment: finish retired requests, stream deltas for the
-        still-running ones. Engine-side token indices are offset by a
-        replayed handle's ``_engine_base`` (tokens emitted before the
-        last restart live only handle-side). Returns ``pushed``, the
-        ``collect`` span's counter: the handles given a delta here, each
-        of which wakes an HTTP thread that needs the interpreter lock
-        while this thread runs its gap and dispatch."""
+        """Post-segment: owe retired requests their last tokens and
+        their finish, and the still-running ones their deltas (handed
+        over after the next dispatch, :meth:`_flush`). Engine-side token
+        indices are offset by a replayed handle's ``_engine_base``
+        (tokens emitted before the last restart live only handle-side).
+        Returns ``pushed``, the ``collect`` span's counter: the handles
+        given a delta here, each of which wakes an HTTP thread at the
+        flush."""
         pushed = 0
         for rid, seq in self.engine.collect_finished().items():
-            h = self._active.pop(rid, None)
+            h = self._active.get(rid)
             if h is None:      # foreign request (user drove the engine)
                 continue
             self._push_delta(
                 h, list(seq[h._n_pushed - h._engine_base:]))
-            h._finish(FINISHED)
+            self._finish_later(h, FINISHED)
+            # off _active only once owed its finish (drain's view)
+            del self._active[rid]
             pushed += 1
             self._count("completed")
-            if monitor.enabled():
-                n = len(seq) + h._engine_base
-                if h.first_token_ts is not None and n > 1:
-                    self._tpot_hist().labels(
-                        server=self.monitor_server).observe(
-                        (h.finish_ts - h.first_token_ts) / (n - 1))
-                self._slo_finish(h, n)
         for rid, h in list(self._active.items()):
             delta = self.engine.partial_tokens(
                 rid, h._n_pushed - h._engine_base)

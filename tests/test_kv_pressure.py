@@ -520,6 +520,7 @@ class TestServerPreemption:
         met.engine_rid = 12345      # admitted once, then preempted
         srv._replay.extend([dead, met])
         srv._admit_replays()
+        srv._flush()         # the loop hands the finish over after it
         assert dead.status == "expired"
         with pytest.raises(DeadlineExpired):
             dead.result(timeout=1)

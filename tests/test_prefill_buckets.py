@@ -238,9 +238,10 @@ class TestChunkedAdmission:
         events = []
         ds, ac = eng.decode_segment, eng.admit_chunk
         eng.decode_segment = \
-            lambda n, cfg=None: (events.append("seg"), ds(n, cfg))[1]
+            lambda n, cfg=None, **kw: (events.append("seg"),
+                                       ds(n, cfg, **kw))[1]
         eng.admit_chunk = \
-            lambda adm: (events.append("chunk"), ac(adm))[1]
+            lambda adm, **kw: (events.append("chunk"), ac(adm, **kw))[1]
         srv = Server(eng, max_queue=8, segment_steps=2)
         try:
             h_short = srv.submit(
@@ -276,7 +277,7 @@ class TestChunkedAdmission:
             prefill_chunk=8)
         real = eng.admit_chunk
         eng.admit_chunk = \
-            lambda adm: (_time.sleep(0.05), real(adm))[1]
+            lambda adm, **kw: (_time.sleep(0.05), real(adm, **kw))[1]
         srv = Server(eng, segment_steps=2)
         try:
             h = srv.submit(np.arange(30, dtype=np.int32)

@@ -319,6 +319,20 @@ def _server(model_layers=1, **kw):
     return Server(eng, **kw), eng, cfg
 
 
+def _slow_segments(eng, s=0.01):
+    """Pace the engine's segments so a 48-token request holds its slot
+    for half a second: the client sees a first token only once the first
+    segment is dispatched (its compile done), after which a tiny model on
+    the CPU would finish the rest inside a 50 ms deadline."""
+    ds = eng.decode_segment
+
+    def slow(*a, **kw):
+        time.sleep(s)
+        return ds(*a, **kw)
+
+    eng.decode_segment = slow
+
+
 class TestServerOnline:
     def test_acceptance_demo_end_to_end(self, mon):
         """ISSUE-2 acceptance: >= 8 concurrent requests, mixed prompt
@@ -436,6 +450,7 @@ class TestServerOnline:
     def test_deadline_expired_never_admits(self, mon):
         srv, eng, cfg = _server(max_batch=1, num_pages=24,
                                 segment_steps=2)
+        _slow_segments(eng)
         try:
             rng = np.random.RandomState(3)
             h1 = srv.submit(rng.randint(0, cfg.vocab_size, (4,))
@@ -524,6 +539,158 @@ class TestServerOnline:
             srv.shutdown(drain=False)
 
 
+class _PushAtOnce(Server):
+    """The order before ISSUE 37: every token and terminal state handed
+    to its handle the moment the scheduler owes it."""
+
+    def _push_delta(self, h, toks):
+        super()._push_delta(h, toks)
+        self._flush()
+
+    def _finish_later(self, h, status, error=None):
+        super()._finish_later(h, status, error)
+        self._flush()
+
+
+class TestPushAfterDispatch:
+    """ISSUE 37: what a segment's collection owes the handles (and what
+    the gap owes them: first tokens, cancellations) is handed over after
+    the next segment's dispatch, or at once where none follows."""
+
+    def _streamed(self, server_cls):
+        model, cfg = tiny_model()
+        srv = server_cls(paged_engine(model, max_batch=2), max_queue=16,
+                         segment_steps=3, start=False)
+        rng = np.random.RandomState(11)
+        spec = [(5, 7), (9, 4), (3, 11), (7, 1), (4, 6)]
+        hs = [srv.submit(p, GenerationConfig(max_new_tokens=n,
+                                             eos_token_id=None))
+              for p, (_, n) in zip(_prompts(rng, cfg.vocab_size,
+                                            [n for n, _ in spec]), spec)]
+        got = [[] for _ in hs]
+        readers = [threading.Thread(target=lambda h=h, out=out:
+                                    out.extend(h.stream(timeout=120)))
+                   for h, out in zip(hs, got)]
+        for t in readers:
+            t.start()
+        srv._thread.start()
+        try:
+            for t in readers:
+                t.join(timeout=180)
+            assert srv.drain(timeout=60)
+        finally:
+            srv.shutdown(drain=False)
+        return got, [h.status for h in hs], [len(h.tokens_so_far())
+                                             for h in hs]
+
+    def test_streams_equal_the_order_of_pushes_at_once(self):
+        """Greedy: every handle streams the same tokens, to the same
+        status, whether the pushes wait for the next dispatch or not;
+        one request of budget 1 retires at its admission."""
+        got, status, n = self._streamed(Server)
+        want, want_status, want_n = self._streamed(_PushAtOnce)
+        assert got == want
+        assert status == want_status == ["finished"] * 5
+        assert n == want_n == [7, 4, 11, 1, 6]
+
+    def test_streams_whole_under_thread_switches(self):
+        """More client threads than cores, the interpreter switching
+        threads every 10 us, and a third of the requests cancelled
+        mid-stream: every stream ends, holds exactly the handle's
+        tokens, and drain() returns only once all are terminal."""
+        import os
+        import sys
+
+        model, cfg = tiny_model()
+        srv = Server(paged_engine(model, max_batch=3), max_queue=64,
+                     segment_steps=2)
+        n = max(12, 2 * (os.cpu_count() or 1))
+        rng = np.random.RandomState(17)
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            hs = [srv.submit(p, GenerationConfig(
+                      max_new_tokens=int(rng.randint(1, 12)),
+                      eos_token_id=None))
+                  for p in _prompts(rng, cfg.vocab_size,
+                                    rng.randint(2, 9, n))]
+            got = [[] for _ in hs]
+
+            def read(i):
+                for t in hs[i].stream(timeout=120):
+                    got[i].append(t)
+                    if i % 3 == 0:
+                        hs[i].cancel()
+
+            readers = [threading.Thread(target=read, args=(i,))
+                       for i in range(n)]
+            for t in readers:
+                t.start()
+            for t in readers:
+                t.join(timeout=180)
+            assert not any(t.is_alive() for t in readers)
+            assert srv.drain(timeout=60)
+            for h, g in zip(hs, got):
+                assert h.done and g == h.tokens_so_far()
+                if h.status == "finished":
+                    assert len(g) == h.cfg.max_new_tokens
+                else:
+                    assert h.status == "cancelled"
+        finally:
+            sys.setswitchinterval(old)
+            srv.shutdown(drain=False)
+
+    def test_cancelled_running_request_finishes_once(self):
+        model, cfg = tiny_model()
+        srv, eng, _ = _server(segment_steps=2)
+        _slow_segments(eng)
+        p = np.arange(5, dtype=np.int32)
+        try:
+            h = srv.submit(p, GenerationConfig(max_new_tokens=40,
+                                               eos_token_id=None))
+            next(iter(h.stream(timeout=60)))
+            calls = []
+            real = h._finish
+            h._finish = lambda *a: (calls.append(a[0]), real(*a))
+            h.cancel()
+            with pytest.raises(RequestCancelled):
+                h.result(timeout=60)
+            assert srv.drain(timeout=60)
+            assert calls == ["cancelled"]
+            # what it streamed is a prefix of the uncancelled run
+            dense = CausalLMEngine(model, max_batch=1, max_len=64)
+            full = dense.generate(p[None], GenerationConfig(
+                max_new_tokens=40, eos_token_id=None))[0, len(p):]
+            toks = h.tokens_so_far()
+            assert 1 <= len(toks) < 40
+            assert toks == [int(t) for t in full[:len(toks)]]
+            assert eng.free_slots() == eng.max_batch
+        finally:
+            srv.shutdown(drain=False)
+
+    def test_cancel_while_its_finish_is_owed(self):
+        """A client cancels a request the engine has already retired
+        but whose finish the scheduler still owes: the handle is
+        finished once, as finished, with every token."""
+        srv, eng, cfg = _server(segment_steps=4, start=False)
+        try:
+            h = srv.submit(np.arange(4, dtype=np.int32),
+                           GenerationConfig(max_new_tokens=3,
+                                            eos_token_id=None))
+            assert srv._step(False)     # admit, segment, collect: retired
+            assert h.status == "running" and srv._pending
+            calls = []
+            real = h._finish
+            h._finish = lambda *a: (calls.append(a[0]), real(*a))
+            h.cancel()                  # not terminal yet: flagged
+            assert not srv._step(False)  # nothing live: the idle flush
+            assert calls == ["finished"] and not srv._pending
+            assert len(h.result(timeout=1)) == 3
+            assert eng.free_slots() == eng.max_batch
+        finally:
+            srv.shutdown(drain=False)
+
+
 class TestHTTPFrontend:
     def test_roundtrip_health_metrics_and_streaming(self, mon):
         srv, eng, cfg = _server(max_queue=8, segment_steps=2)
@@ -583,6 +750,7 @@ class TestHTTPFrontend:
         from urllib.request import Request, urlopen
 
         srv, eng, cfg = _server()
+        _slow_segments(eng)
         httpd = serve_http(srv)
         port = httpd.server_address[1]
         url = f"http://127.0.0.1:{port}/generate"

@@ -399,6 +399,50 @@ class TestEngineRecovery:
         finally:
             srv.shutdown(drain=False)
 
+    def test_fault_while_tokens_are_owed_replays_identically(self):
+        """ISSUE 37: the second decode call faults before its dispatch,
+        so the first segment's tokens are still owed to the handles.
+        Recovery hands them over first (a ``push`` with
+        ``after_dispatch`` 0 before the ``recover`` span), and the
+        replays, which re-prefill from the handles' tokens, finish
+        identical to a fault-free run."""
+        from paddle_tpu import tracing
+
+        model, _ = tiny_model()
+        rng = np.random.RandomState(5)
+        ps = [rng.randint(0, 100, (n,)).astype(np.int32)
+              for n in (6, 9, 4)]
+        maxes = [10, 7, 12]
+        want = _oracle(model, ps, maxes)
+        plan = FaultPlan().raise_at(
+            "decode", nth=2, exc=EngineFault("injected device loss"))
+        srv, eng, cfg = faulty_server(plan, max_batch=3,
+                                      segment_steps=2,
+                                      restart_backoff_s=0.01, start=False)
+        tracing.clear()
+        tracing.enable()
+        try:
+            hs = [srv.submit(p, _greedy(m)) for p, m in zip(ps, maxes)]
+            srv._thread.start()
+            for h, w in zip(hs, want):
+                np.testing.assert_array_equal(h.result(timeout=120), w)
+            assert srv.restarts == 1
+            assert [h._replays for h in hs] == [1, 1, 1]
+            assert srv.drain(timeout=60)
+            evs = tracing.events()
+        finally:
+            tracing.disable()
+            tracing.clear()
+            srv.shutdown(drain=False)
+        _assert_no_leaks(eng)
+        rec = next(e for e in evs if e["phase"] == "recover")
+        owed = [e for e in evs if e["phase"] == "push"
+                and e["ts_ns"] + e["dur_ns"] <= rec["ts_ns"]]
+        # after a dispatch up to the fault (two admissions' and the
+        # first segment's); then the three deltas no dispatch followed
+        assert [e["after_dispatch"] for e in owed] == [1, 1, 1, 0]
+        assert owed[-1]["handles"] == 3
+
     def test_engine_fault_during_admission_replays_request(self):
         """An EngineFault raised at the ADMISSION seam escalates to
         recovery with the triggering request riding along — it replays

@@ -679,6 +679,10 @@ class TestServingTree:
 # admission's ``engine.first_token`` holds the first two
 SEGMENT_CHILDREN = ("engine.dispatch", "engine.wait", "engine.collect")
 NEW_SITES = SEGMENT_CHILDREN + ("engine.tables",)
+# behind a server (ISSUE 37) what the handles are owed is handed over
+# between the dispatch and the wait
+SERVED_SEGMENT_CHILDREN = ("engine.dispatch", "push", "engine.wait",
+                           "engine.collect")
 
 
 def _children(parent, spans):
@@ -687,11 +691,12 @@ def _children(parent, spans):
                   key=lambda e: e["ts_ns"])
 
 
-def _assert_three_children(seg, spans):
-    """Exactly dispatch, wait, collect: in that order, disjoint, inside
-    the parent."""
+def _assert_three_children(seg, spans, want=SEGMENT_CHILDREN):
+    """Exactly dispatch, wait, collect (behind a server, the push
+    between the first two): in that order, disjoint, inside the
+    parent."""
     kids = _children(seg, spans)
-    assert tuple(e["phase"] for e in kids) == SEGMENT_CHILDREN
+    assert tuple(e["phase"] for e in kids) == want
     t = seg["ts_ns"]
     for e in kids:
         assert e["ts_ns"] >= t
@@ -733,7 +738,8 @@ class TestSegmentCycle:
         tabs = _tables_of(evs)
         assert len(segs) == 2 and len(tabs) == 2
         for seg, tab in zip(segs, tabs):
-            _assert_three_children(seg, spans)
+            _assert_three_children(seg, spans, SERVED_SEGMENT_CHILDREN
+                                   if behind_server else SEGMENT_CHILDREN)
             # the upload is a sibling that ends before the segment opens
             assert tab["span.parent"] == seg["span.parent"]
             assert tab["ts_ns"] + tab["dur_ns"] <= seg["ts_ns"]
@@ -745,7 +751,8 @@ class TestSegmentCycle:
         # the parent keeps what its readers read; of the children only
         # the dispatch carries a counter
         for seg in segs:
-            d, w, c = _children(seg, spans)
+            d, w, c = (e for e in _children(seg, spans)
+                       if e["phase"] != "push")
             assert seg["emitted"] == 4 and "args" not in seg
             assert {"steps", "rows", "ctx_tokens", "pages_live"} <= set(seg)
             assert d["args"] > 0
@@ -937,6 +944,136 @@ class TestSegmentCycle:
         d, w, c = _children(seg, spans)
         assert d["args"] > 0 and "args" not in seg
         assert len(_tables_of(spans.values())) == 1
+
+
+class TestPushSpan:
+    """ISSUE 37: the scheduler's hand-over of what the handles are owed
+    is one ``push`` span (``handles``, ``after_dispatch``), after the
+    next segment's dispatch where one follows."""
+
+    def test_first_token_owed_across_an_admission(self, tr):
+        """Two requests admitted in one gap: the first one's first token
+        is handed over once the second admission's programs are on the
+        device, not after that admission's wait."""
+        spans, _ = self._served((5, 5))
+        firsts = [e for e in _by_start(spans)
+                  if e["phase"] == "engine.first_token"]
+        assert len(firsts) == 2
+        push = [e for e in _children(firsts[1], spans)
+                if e["phase"] == "push"]
+        assert [(e["after_dispatch"], e["handles"]) for e in push] == [
+            (1, 1)]
+        assert self._instants_under(push[0]) == ["first_token"]
+        assert not [e for e in _children(firsts[0], spans)
+                    if e["phase"] == "push"]
+
+    def _served(self, budgets, steps=4, max_batch=4):
+        model, mcfg = tiny_model()
+        srv = Server(paged_engine(model, max_batch=max_batch),
+                     segment_steps=steps, start=False)
+        calls = []
+        ds = srv.engine.decode_segment
+        srv.engine.decode_segment = \
+            lambda *a, **kw: (calls.append(1), ds(*a, **kw))[1]
+        try:
+            hs = [srv.submit(p, _greedy(n)) for p, n in
+                  zip(_prompts(mcfg, len(budgets)), budgets)]
+            srv._thread.start()
+            for h in hs:
+                h.result(timeout=120)
+            n_seg = len(calls)
+            assert srv.drain(timeout=60)
+            assert len(calls) == n_seg
+        finally:
+            srv.shutdown()
+        return _by_id(trace.events()), n_seg
+
+    @staticmethod
+    def _instants_under(span):
+        return [e["phase"] for e in trace.events()
+                if e["span.parent"] == span["span.id"]
+                and not e["span.id"]]
+
+    def test_push_lies_between_dispatch_and_wait(self, tr):
+        spans, _ = self._served((9, 5, 13, 2))
+        pushes = [e for e in _by_start(spans) if e["phase"] == "push"]
+        after = [e for e in pushes if e["after_dispatch"] == 1]
+        assert after and all(e["handles"] >= 1 for e in pushes)
+        for p in after:
+            # a segment's dispatch, or an admission's
+            seg = spans[p["span.parent"]]
+            assert seg["phase"] in ("engine.segment", "engine.first_token")
+            d, w = (next(e for e in _children(seg, spans)
+                         if e["phase"] == name)
+                    for name in ("engine.dispatch", "engine.wait"))
+            assert d["ts_ns"] + d["dur_ns"] <= p["ts_ns"]
+            assert p["ts_ns"] + p["dur_ns"] <= w["ts_ns"] + w["dur_ns"]
+            # the handles' own events hang under the push
+            assert set(self._instants_under(p)) <= {"first_token",
+                                                    "finish"}
+        # a segment of live rows always hands something over
+        for seg in (e for e in spans.values()
+                    if e["phase"] == "engine.segment"):
+            assert [e["phase"] for e in _children(seg, spans)].count(
+                "push") == 1
+
+    def test_last_finish_at_the_idle_flush(self, tr):
+        """One request of 1 + 2 x 4 tokens: two segments; the first
+        token and the first segment's tokens go after a dispatch, the
+        last segment's tokens and the finish at the idle flush, which
+        needs no segment after it."""
+        spans, n_seg = self._served((9,))
+        assert n_seg == 2
+        pushes = [e for e in _by_start(spans) if e["phase"] == "push"]
+        assert [(e["after_dispatch"], e["handles"]) for e in pushes] == [
+            (1, 1), (1, 1), (0, 1)]
+        last = pushes[-1]
+        assert spans[last["span.parent"]]["phase"] == "step"
+        assert self._instants_under(last) == ["finish"]
+        assert self._instants_under(pushes[0]) == ["first_token"]
+
+    @pytest.mark.parametrize("mode", ["plain", "device", "host"])
+    def test_engine_calls_on_dispatch_once(self, tr, mode):
+        """``decode_segment(on_dispatch=)``: called once a segment,
+        after the program was handed over and before the wait; never
+        without a live slot."""
+        model, mcfg = tiny_model()
+        kw = {} if mode == "plain" else {"draft_k": 2, "spec_mode": mode}
+        eng = paged_engine(model, **kw)
+        seen = []
+        try:
+            eng.add_request(_prompts(mcfg, 1)[0], GenerationConfig(
+                max_new_tokens=6, eos_token_id=None,
+                speculative=mode != "plain"))
+            while eng.decode_segment(
+                    4, on_dispatch=lambda: (seen.append(1),
+                                            trace.event("cb"))):
+                pass
+            n = len(seen)
+            assert n >= 1
+            assert eng.decode_segment(4, on_dispatch=seen.append) == 0
+            assert len(seen) == n
+            # an admission calls it once, before its one pull
+            eng.add_request(_prompts(mcfg, 1)[0], _greedy(2),
+                            on_dispatch=lambda: seen.append(trace.event(
+                                "cb.admit")))
+            assert len(seen) == n + 1
+        finally:
+            eng.close()
+        spans = _by_id(trace.events())
+        cbs = [e for e in trace.events() if e["phase"] == "cb"]
+        assert len(cbs) == n
+        adm = next(e for e in trace.events() if e["phase"] == "cb.admit")
+        first = spans[adm["span.parent"]]
+        assert first["phase"] == "engine.first_token"
+        d, w = _children(first, spans)
+        assert d["ts_ns"] + d["dur_ns"] <= adm["ts_ns"] <= w["ts_ns"]
+        if mode == "host":
+            return              # host speculation keeps its span alone
+        for cb in cbs:
+            seg = spans[cb["span.parent"]]
+            d, w, _c = _children(seg, spans)
+            assert d["ts_ns"] + d["dur_ns"] <= cb["ts_ns"] <= w["ts_ns"]
 
 
 def _by_start(spans):
