@@ -136,10 +136,10 @@ def variants(model):
                                  * cfg.index_head_dim ** -0.5)
 
     def bf16_router(x, router, bias, top_k, route_scale=1.0, route_norm=True,
-                    n_group=1, topk_group=1):
+                    n_group=1, topk_group=1, **kw):
         with patched(jnp, "float32", jnp.bfloat16):
             return ROUTE(x, router, bias, top_k, route_scale, route_norm,
-                         n_group, topk_group)
+                         n_group, topk_group, **kw)
 
     def fp8_norm(self, x):
         out = NORM(self, x)

@@ -1,7 +1,8 @@
 """sha256 of the lowered text of the serving engine's programs, for one fixed
 tiny dense model, one tiny sparse-expert, window-attention model, one tiny
-latent-attention model with the sparse-attention indexer and one tiny model of
-linear-attention layers that keep a state a row.
+latent-attention model with the sparse-attention indexer, one tiny model of
+linear-attention layers that keep a state a row and one tiny sparse-expert,
+window-attention model whose router reads the attention's input.
 
 A change to the engine is held to this: a refactor must leave every column
 as it was, and a change of a program's text must move the programs it names
@@ -35,6 +36,8 @@ from paddle_tpu.models.deepseek_v32 import (  # noqa: E402
     DeepseekV32Config, DeepseekV32ForCausalLM)
 from paddle_tpu.models.olmo_hybrid import (  # noqa: E402
     OlmoHybridConfig, OlmoHybridForCausalLM)
+from paddle_tpu.models.smallthinker import (  # noqa: E402
+    SmallThinkerConfig, SmallThinkerForCausalLM)
 
 STEPS, WIDTH, CHUNK, DRAFT_K = 4, 16, 8, 3
 
@@ -89,6 +92,17 @@ def hybrid_model():
         num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2,
         linear_num_key_heads=3, linear_num_value_heads=3,
         linear_key_head_dim=8, linear_value_head_dim=16))
+    model.eval()
+    return model
+
+
+def smallthinker_model():
+    paddle.seed(3)
+    model = SmallThinkerForCausalLM(SmallThinkerConfig(
+        vocab_size=256, hidden_size=64, moe_ffn_hidden_size=32,
+        num_hidden_layers=4, num_attention_heads=7, num_key_value_heads=1,
+        head_dim=16, sliding_window_size=16, moe_num_primary_experts=8,
+        moe_num_active_primary_experts=3))
     model.eval()
     return model
 
@@ -149,6 +163,8 @@ def rows():
                                             max_pages=16)),
         ("olmo_hybrid", hybrid_model, dict(num_pages=64, page_size=4,
                                            max_pages=16)),
+        ("smallthinker", smallthinker_model, dict(num_pages=64, page_size=4,
+                                                  max_pages=16)),
     ]
     for tag, make, kw in engines:
         eng = PagedContinuousBatchingEngine(make(), **{**geometry, **kw})

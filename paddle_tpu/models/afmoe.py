@@ -17,14 +17,12 @@ dense SwiGLU, the rest a router, routed experts and one shared expert):
     x   = x + N4(f)
 
 It implements the paged engine's model contract and nothing of the dense
-engines': ``init_cache`` / ``forward_with_cache`` (one-shot prefill from
-position 0 into a bucket-wide cache), ``init_paged_cache`` /
-``forward_decode_paged``, and ``paged_layout``, which tells the engine
-that its layers keep their KV in TWO geometries: a full layer holds every
-position of a row, a window layer the last ``sliding_window`` in a ring of
-pages (``inference/paged_cache.WindowedPageAllocator``). What the engine
-cannot do with such a model (tensor parallelism, int8 pools, speculation,
-the prefix cache, chunked prefill, LoRA) it refuses at construction.
+engines', through ``models/_windowed.py`` (shared with
+``models/smallthinker.py``): its layers keep their KV in TWO geometries, a
+full layer every position of a row, a window layer the last
+``sliding_window`` in a ring of pages. What the engine cannot do with such
+a model (tensor parallelism, int8 pools, speculation, the prefix cache,
+chunked prefill, LoRA) it refuses at construction.
 ``tests/reference_moe_window_decoder.py`` is the plain reference.
 """
 from __future__ import annotations
@@ -38,16 +36,17 @@ import jax
 import jax.numpy as jnp
 
 from ..core.autograd import apply_op
-from ..core.tensor import Tensor
 from ..distributed.fleet.layers.mpu import (ColumnParallelLinear,
                                             RowParallelLinear,
                                             VocabParallelEmbedding)
 from ..nn.layer.layers import Layer
 from ..nn.layer.norm import RMSNorm
 from ..nn.layer.routed_experts import RoutedExperts
-from .llama import LlamaMLP, _rope_cos_sin, apply_rotary_emb
+from ._windowed import (WindowedAttention, WindowedForCausalLM,
+                        WindowedModel, ring_pages)
+from .llama import LlamaMLP, apply_rotary_emb
 
-__all__ = ["AfmoeConfig", "AfmoeModel", "AfmoeForCausalLM"]
+__all__ = ["AfmoeConfig", "AfmoeModel", "AfmoeForCausalLM", "ring_pages"]
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -114,12 +113,12 @@ class AfmoeConfig:
                     f"{key}={getattr(self, key)!r} is not implemented "
                     f"(only {want!r})")
 
+    @property
+    def window(self) -> int:
+        return self.sliding_window
+
     def is_sliding(self, layer: int) -> bool:
         return self.layer_types[layer] == SLIDING
-
-
-def _val(t):
-    return t.value if isinstance(t, Tensor) else t
 
 
 def _head_norm(x, w, eps):
@@ -128,14 +127,7 @@ def _head_norm(x, w, eps):
     return x * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)
 
 
-def ring_pages(window: int, page_size: int) -> int:
-    """Pages a window layer holds for one row at the most: the window's
-    own, and one more so that the page being written never shares a ring
-    slot with a page the window still reaches."""
-    return -(-window // page_size) + 1
-
-
-class AfmoeAttention(Layer):
+class AfmoeAttention(WindowedAttention):
     """Gated attention with QK-norm; ``window`` None = a full layer (no
     position encoding), else a sliding layer (rope, last ``window`` keys)."""
 
@@ -157,10 +149,14 @@ class AfmoeAttention(Layer):
         self.q_norm = RMSNorm(hd, epsilon=config.rms_norm_eps)
         self.k_norm = RMSNorm(hd, epsilon=config.rms_norm_eps)
 
-    def _heads(self, qv, kv, vv, qw, kw, cos, sin):
+    def _head_weights(self):
+        return self.q_norm.weight, self.k_norm.weight
+
+    def _heads(self, qv, kv, vv, weights, cos, sin):
         """Projections [B, S, H*D] -> normed (and, sliding, rotated) heads
         in the cache dtype. ``cos``/``sin``: float32, broadcastable to
         [B, S, 1, D/2] (per-position or per-row angles)."""
+        qw, kw = weights
         b, s = qv.shape[0], qv.shape[1]
         hd, eps = self.config.head_dim, self.config.rms_norm_eps
         qh = _head_norm(qv.reshape(b, s, self.num_heads, hd)
@@ -181,76 +177,6 @@ class AfmoeAttention(Layer):
                           ).astype(c.dtype),
             ctx, self.gate_proj(x), op_name="attention_gate")
         return self.o_proj(gated)
-
-    def forward_with_cache(self, x, cos, sin, cache):
-        """Prefill from position 0: x [B, S, h]; ``cache`` (k, v)
-        [B, S_max, Hkv, D] takes the prompt's keys and values at [0, S).
-        Returns (out, new_cache)."""
-        from ..ops.pallas import flash_attention
-
-        b, s = x.shape[0], x.shape[1]
-
-        def attend(qv, kv, vv, qw, kw, kc, vc):
-            qh, kh, vh = self._heads(qv, kv, vv, qw, kw,
-                                     cos[None, :s, None, :],
-                                     sin[None, :s, None, :])
-            ctx = flash_attention(qh, kh, vh, causal=True,
-                                  window=self.window)
-            kc = jax.lax.dynamic_update_slice_in_dim(
-                kc, kh.astype(kc.dtype), 0, axis=1)
-            vc = jax.lax.dynamic_update_slice_in_dim(
-                vc, vh.astype(vc.dtype), 0, axis=1)
-            return ctx.reshape(b, s, -1), kc, vc
-
-        ctx, kc, vc = apply_op(
-            attend, self.q_proj(x), self.k_proj(x), self.v_proj(x),
-            self.q_norm.weight, self.k_norm.weight, *cache,
-            op_name="cached_attention")
-        return self._out(ctx, x), (_val(kc), _val(vc))
-
-    def forward_decode_paged(self, x, cos, sin, cache, page_table, lens,
-                             live):
-        """One token per row at per-row position ``lens``. A full layer's
-        ``page_table`` row lists the row's pages in order; a sliding
-        layer's is a RING of ``ring_pages`` slots in which position p
-        lives at slot (p // page_size) % ring: the kernel is handed the
-        ring turned so that the window's first page comes first, and the
-        lengths counted from that page."""
-        from ..ops.paged_attention import paged_decode_mha
-
-        b = x.shape[0]
-
-        def attend(qv, kv, vv, qw, kw, kp, vp):
-            ps, cols = kp.shape[1], page_table.shape[1]
-            c = cos[lens][:, None, None, :]
-            sn = sin[lens][:, None, None, :]
-            qh, kh, vh = self._heads(qv, kv, vv, qw, kw, c, sn)
-            # a dead row attends nothing: length 0 costs the kernel no page
-            new_len = jnp.where(live, lens + 1, 0)
-            col = lens // ps
-            table = page_table
-            if self.window is not None:
-                col = col % cols
-                first = jnp.maximum(new_len - self.window, 0) // ps
-                turn = (first[:, None] + jnp.arange(cols)[None, :]) % cols
-                table = jnp.take_along_axis(page_table, turn, axis=1)
-                new_len = new_len - first * ps
-            page = page_table[jnp.arange(b), jnp.minimum(col, cols - 1)]
-            # dead rows / unmapped pages -> sentinel, dropped by scatter
-            page = jnp.where(live & (page >= 0), page, kp.shape[0])
-            kp = kp.at[page, lens % ps].set(kh[:, 0].astype(kp.dtype),
-                                            mode="drop")
-            vp = vp.at[page, lens % ps].set(vh[:, 0].astype(vp.dtype),
-                                            mode="drop")
-            ctx = paged_decode_mha(qh[:, 0], kp, vp, table, new_len,
-                                   window=self.window)
-            return ctx.reshape(b, 1, -1), kp, vp
-
-        ctx, kp, vp = apply_op(
-            attend, self.q_proj(x), self.k_proj(x), self.v_proj(x),
-            self.q_norm.weight, self.k_norm.weight, *cache,
-            op_name="paged_attention")
-        return self._out(ctx, x), (_val(kp), _val(vp))
 
 
 class AfmoeSparseMLP(Layer):
@@ -311,7 +237,7 @@ class AfmoeDecoderLayer(Layer):
         return x, cache, stats
 
 
-class AfmoeModel(Layer):
+class AfmoeModel(WindowedModel):
     def __init__(self, config: AfmoeConfig):
         super().__init__(dtype=config.dtype)
         from ..nn.layer.container import LayerList
@@ -329,56 +255,8 @@ class AfmoeModel(Layer):
             x = x * math.sqrt(self.config.hidden_size)
         return x
 
-    def _rope(self, positions: int):
-        cfg = self.config
-        return _rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
-                             jnp.float32)
 
-    def forward_with_cache(self, input_ids, caches, pos=0, last_idx=None):
-        if not (isinstance(pos, int) and pos == 0):
-            raise NotImplementedError(
-                "prefill at an offset (chunked prefill, a warm prefix hit) "
-                "is not implemented for window layers")
-        x = self._embed(input_ids)
-        s = x.shape[1]
-        cos, sin = self._rope(s)
-        # bucket padding past the prompt's last token takes no expert
-        valid = (None if last_idx is None
-                 else (jnp.arange(s) <= last_idx)[None, :])
-        new_caches = []
-        for layer, cache in zip(self.layers, caches):
-            x, cache, _ = layer.forward_with_cache(x, cos, sin, cache,
-                                                   valid=valid)
-            new_caches.append(cache)
-        if last_idx is not None:
-            # the head is 200k wide: only the position that is sampled
-            x = apply_op(lambda v: jax.lax.dynamic_slice_in_dim(
-                v, last_idx, 1, axis=1), x, op_name="last_position")
-        return self.norm(x), new_caches
-
-    def forward_decode_paged(self, input_ids, caches, page_table, lens,
-                             live):
-        cfg = self.config
-        full_table, ring_table = page_table
-        x = self._embed(input_ids)
-        ps = caches[0][0].shape[1]
-        cos, sin = self._rope(full_table.shape[1] * ps)
-        lens = jnp.minimum(lens, full_table.shape[1] * ps - 1)
-        new_caches = []
-        hit = rows_max = jnp.int32(0)
-        for i, (layer, cache) in enumerate(zip(self.layers, caches)):
-            x, cache, stats = layer.forward_decode_paged(
-                x, cos, sin, cache,
-                ring_table if cfg.is_sliding(i) else full_table, lens, live)
-            new_caches.append(cache)
-            if stats is not None:
-                hit = hit + _val(stats["experts_hit"])
-                rows_max = rows_max + _val(stats["expert_rows_max"])
-        return (self.norm(x), new_caches,
-                {"experts_hit": hit, "expert_rows_max": rows_max})
-
-
-class AfmoeForCausalLM(Layer):
+class AfmoeForCausalLM(WindowedForCausalLM):
     def __init__(self, config: AfmoeConfig):
         super().__init__(dtype=config.dtype)
         self.config = config
@@ -393,71 +271,3 @@ class AfmoeForCausalLM(Layer):
                 gather_output=False)
         finally:
             set_default_dtype(prev)
-
-    def _logits(self, hidden):
-        """The head's product with a float32 result, whatever the weights'
-        dtype: the top of 200k bf16 logits would be rounded to steps as
-        large as the differences between them."""
-        return apply_op(
-            lambda h, w: jnp.matmul(h, w,
-                                    preferred_element_type=jnp.float32),
-            hidden, self.lm_head.weight, op_name="lm_head")
-
-    def forward(self, input_ids):
-        """Logits [B, S, V] of a whole sequence, no cache kept."""
-        ids = _val(input_ids)
-        logits, _ = self.forward_with_cache(
-            input_ids, self.init_cache(ids.shape[0], ids.shape[1]), 0)
-        return logits
-
-    def init_cache(self, batch_size: int, max_len: int):
-        cfg = self.config
-        shape = (batch_size, max_len, cfg.num_key_value_heads, cfg.head_dim)
-        dt = jnp.dtype(cfg.dtype)
-        return [(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
-                for _ in range(cfg.num_hidden_layers)]
-
-    def forward_with_cache(self, input_ids, caches, pos=0, last_idx=None):
-        """(logits, new_caches) of a one-shot prefill from position 0.
-        ``last_idx`` (a traced position): logits [B, 1, V] of that
-        position only, and the padding after it is routed nowhere."""
-        hidden, caches = self.model.forward_with_cache(
-            input_ids, caches, pos, last_idx=last_idx)
-        return self._logits(hidden), caches
-
-    def paged_layout(self, page_size: int) -> dict:
-        """What the paged engine has to know of this model's cache: which
-        layers keep a ring of the last ``window`` positions, and the
-        ring's pages; that prefill takes ``last_idx``; that a decode step
-        hands out counters."""
-        cfg = self.config
-        return {"ring": {"window": cfg.sliding_window,
-                         "ring_pages": ring_pages(cfg.sliding_window,
-                                                  page_size),
-                         "window_layers": tuple(
-                             cfg.is_sliding(i)
-                             for i in range(cfg.num_hidden_layers))},
-                "last_idx": True, "counters": True,
-                "rows": "per-head K and V in two geometries (its window "
-                        "layers keep a ring of pages)"}
-
-    def init_paged_cache(self, num_pages: int, page_size: int,
-                         window_pages: int = 0):
-        """Per-layer page pools: ``num_pages`` for a full layer,
-        ``window_pages`` for a sliding layer."""
-        cfg = self.config
-        dt = jnp.dtype(cfg.dtype)
-        out = []
-        for i in range(cfg.num_hidden_layers):
-            shape = (window_pages if cfg.is_sliding(i) else num_pages,
-                     page_size, cfg.num_key_value_heads, cfg.head_dim)
-            out.append((jnp.zeros(shape, dt), jnp.zeros(shape, dt)))
-        return out
-
-    def forward_decode_paged(self, input_ids, caches, page_table, lens,
-                             live):
-        """(logits [B, 1, V], new_caches, routing counts) — one decode
-        step over the two page tables ``(full, ring)``."""
-        hidden, caches, stats = self.model.forward_decode_paged(
-            input_ids, caches, page_table, lens, live)
-        return self._logits(hidden), caches, stats

@@ -1,14 +1,17 @@
 """Routed experts: a token-choice sparse FFN with no capacity and no
 dropped token, every shape static.
 
-Each token scores every expert (sigmoid of a float32 product), the
-``top_k`` largest of ``score + bias`` are chosen (the bias steers the
-choice only), their scores are renormalised and scaled, and the token's
-output is the weighted sum of the chosen experts' SwiGLU. The products
-run as three grouped matrix products over the (token, choice) rows sorted
-by expert (:func:`paddle_tpu.ops.pallas.grouped_matmul`), so an expert's
-weights are read once for all its rows and an expert no row chose is not
-read at all. Reference analog: the reference's capacity-dispatch MoE
+Each token scores every expert from a float32 product: either the
+sigmoid of the product, the ``top_k`` largest of ``score + bias`` chosen
+(the bias steers the choice only), their scores renormalised and scaled;
+or (``score="softmax"``) the ``top_k`` largest products chosen and a
+softmax over them. The token's output is the weighted sum of the chosen
+experts' gated linear units (SwiGLU, or ReLU-GLU with ``act="relu"``).
+The router may read another tensor than the experts compute on. The
+products run as three grouped matrix products over the (token, choice)
+rows sorted by expert (:func:`paddle_tpu.ops.pallas.grouped_matmul`), so
+an expert's weights are read once for all its rows and an expert no row
+chose is not read at all. Reference analog: the reference's capacity-dispatch MoE
 (incubate/distributed/models/moe) pads every expert to a capacity and
 drops what overflows; this layer is the serving form, exact for any skew.
 """
@@ -68,9 +71,13 @@ class _Upcycled(Initializer):
         return _upcycled_uniform(key, shape, jnp.dtype(dtype), limit)
 
 
+# the gated linear unit's activation, by name
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def route_top_k(x, router, bias, top_k: int, route_scale: float = 1.0,
                 route_norm: bool = True, n_group: int = 1,
-                topk_group: int = 1):
+                topk_group: int = 1, score: str = "sigmoid"):
     """x [T, h] -> (experts [T, k] int32, weights [T, k] float32).
 
     The product and the scores are float32 at the highest matmul
@@ -78,13 +85,23 @@ def route_top_k(x, router, bias, top_k: int, route_scale: float = 1.0,
     (k+1)-th score decide which expert runs, and bf16 cannot tell them
     apart.
 
+    ``score="sigmoid"``: the scores are the product's sigmoid, chosen by
+    ``score + bias``, weighted by the chosen scores (renormalised where
+    ``route_norm``). ``score="softmax"``: the ``top_k`` largest products
+    are chosen and weighted by a softmax over them alone (a softmax over
+    every expert renormalised over the chosen gives the same weights);
+    ``bias`` is None and ``route_norm`` has nothing left to do.
+
     ``n_group`` > 1 limits the choice to groups (``noaux_tc``): the
     experts lie in ``n_group`` groups of equal size, a group's score is
     the sum of its 2 largest ``score + bias``, and the ``top_k`` are
     chosen inside the ``topk_group`` best groups. 1 group = no limit."""
-    scores = jax.nn.sigmoid(jnp.matmul(
-        x.astype(jnp.float32), router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
+    logits = jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    if score == "softmax":
+        top, sel = jax.lax.top_k(logits, top_k)
+        return sel.astype(jnp.int32), jax.nn.softmax(top, -1) * route_scale
+    scores = jax.nn.sigmoid(logits)
     biased = scores + bias.astype(jnp.float32)
     if n_group > 1:
         t, e = biased.shape
@@ -103,10 +120,12 @@ def route_top_k(x, router, bias, top_k: int, route_scale: float = 1.0,
 
 
 def routed_experts_ffn(x, sel, w, gate, up, down, valid=None,
-                       first_expert=None):
-    """sum_k w[t, k] * expert_{sel[t, k]}(x[t]) for x [T, h].
+                       first_expert=None, act: str = "silu"):
+    """sum_k w[t, k] * expert_{sel[t, k]}(x[t]) for x [T, h], an expert
+    being ``(act(x Wg) * x Wu) Wd``.
 
-    gate/up [E, h, m], down [E, m, h]. ``valid`` [T] bool: rows that are
+    gate/up [E, h, m], down [E, m, h]; ``act`` names an entry of
+    ``ACTIVATIONS``. ``valid`` [T] bool: rows that are
     padding or dead take no expert (their output is 0, and they make no
     expert's weights be read). ``first_expert`` (an int): the layer is one
     chip's SHARE of an expert-parallel layer, ``sel`` names experts of
@@ -133,7 +152,7 @@ def routed_experts_ffn(x, sel, w, gate, up, down, valid=None,
     xs = jnp.take(x, order // k, axis=0)          # [T*k, h]
     g = grouped_matmul(xs, gate, sizes, preferred_element_type=x.dtype)
     u = grouped_matmul(xs, up, sizes, preferred_element_type=x.dtype)
-    mid = (jax.nn.silu(g.astype(jnp.float32))
+    mid = (ACTIVATIONS[act](g.astype(jnp.float32))
            * u.astype(jnp.float32)).astype(x.dtype)
     ys = grouped_matmul(mid, down, sizes,
                         preferred_element_type=jnp.float32)
@@ -151,10 +170,14 @@ def routed_experts_ffn(x, sel, w, gate, up, down, valid=None,
 
 
 class RoutedExperts(Layer):
-    """Router + stacked SwiGLU experts. ``forward(x, valid=None)`` takes
-    x [..., h] and returns (out, stats) — see :func:`routed_experts_ffn`.
-    ``expert_bias`` is a float32 parameter, zero at initialisation, that
-    enters the choice of experts only.
+    """Router + stacked gated experts. ``forward(x, valid=None,
+    router_input=None)`` takes x [..., h] and returns (out, stats) — see
+    :func:`routed_experts_ffn`; ``router_input`` [..., h], where given, is
+    what the router reads in place of ``x``. ``score`` and ``act``: the
+    router's scores and the experts' activation (:func:`route_top_k`,
+    ``ACTIVATIONS``). With sigmoid scores ``expert_bias`` is a float32
+    parameter, zero at initialisation, that enters the choice of experts
+    only; a softmax router has none.
 
     ``n_group`` / ``topk_group``: group-limited routing
     (:func:`route_top_k`). ``held=(first, count)``: this layer is one
@@ -169,12 +192,18 @@ class RoutedExperts(Layer):
     def __init__(self, hidden_size: int, expert_width: int,
                  num_experts: int, top_k: int, route_scale: float = 1.0,
                  route_norm: bool = True, n_group: int = 1,
-                 topk_group: int = 1, held=None):
+                 topk_group: int = 1, held=None, score: str = "sigmoid",
+                 act: str = "silu"):
         super().__init__()
+        if score not in ("sigmoid", "softmax") or act not in ACTIVATIONS:
+            raise ValueError(f"score={score!r}, act={act!r}: the router "
+                             f"scores by 'sigmoid' or 'softmax', the "
+                             f"experts take one of {sorted(ACTIVATIONS)}")
         self.top_k = top_k
         self.route_scale = route_scale
         self.route_norm = route_norm
         self.n_group, self.topk_group = n_group, topk_group
+        self.score, self.act = score, act
         self.first_expert = None if held is None else int(held[0])
         # tokens a block: the largest power of two whose rows fit
         self.token_block = 1 << int(math.log2(
@@ -183,8 +212,9 @@ class RoutedExperts(Layer):
         n_held = e if held is None else int(held[1])
         self.router = self.create_parameter(
             [h, e], default_initializer=XavierUniform(h, e))
-        self.expert_bias = self.create_parameter(
-            [e], dtype="float32", default_initializer=Constant(0.0))
+        self.expert_bias = None if score == "softmax" else \
+            self.create_parameter([e], dtype="float32",
+                                  default_initializer=Constant(0.0))
         self.gate_proj = self.create_parameter(
             [n_held, h, m], default_initializer=_Upcycled())
         self.up_proj = self.create_parameter(
@@ -192,33 +222,40 @@ class RoutedExperts(Layer):
         self.down_proj = self.create_parameter(
             [n_held, m, h], default_initializer=_Upcycled())
 
-    def forward(self, x, valid=None):
-        def f(xv, router, bias, gate, up, down):
-            def block(flat, ok):
-                sel, w = route_top_k(flat, router, bias, self.top_k,
+    def forward(self, x, valid=None, router_input=None):
+        def f(xv, rv, router, bias, gate, up, down):
+            def block(flat, ok, rflat=None):
+                sel, w = route_top_k(flat if rflat is None else rflat,
+                                     router, bias, self.top_k,
                                      self.route_scale, self.route_norm,
-                                     self.n_group, self.topk_group)
+                                     self.n_group, self.topk_group,
+                                     score=self.score)
                 return routed_experts_ffn(
                     flat, sel, w, gate, up, down,
                     valid=None if ok is None else ok.reshape(-1),
-                    first_expert=self.first_expert)
+                    first_expert=self.first_expert, act=self.act)
 
             flat = xv.reshape(-1, xv.shape[-1])
+            rflat = None if rv is None else rv.reshape(-1, rv.shape[-1])
             tb, t = self.token_block, flat.shape[0]
             if t <= tb:
-                out, stats = block(flat, valid)
+                out, stats = block(flat, valid, rflat)
                 return out.reshape(xv.shape), stats
             ok = (jnp.ones((t,), bool) if valid is None
                   else valid.reshape(-1))
             nb = -(-t // tb)           # the last block's tail: no token
+
+            def blocks(a):
+                return jnp.pad(a, ((0, nb * tb - t),) + ((0, 0),) * (
+                    a.ndim - 1)).reshape(nb, tb, *a.shape[1:])
+
             out, stats = jax.lax.map(lambda a: block(*a), (
-                jnp.pad(flat, ((0, nb * tb - t), (0, 0))).reshape(
-                    nb, tb, -1),
-                jnp.pad(ok, (0, nb * tb - t)).reshape(nb, tb)))
+                blocks(flat), blocks(ok)) + (
+                () if rflat is None else (blocks(rflat),)))
             stats = {k: (v.max() if k == "expert_rows_max" else v.sum())
                      for k, v in stats.items()}
             return out.reshape(nb * tb, -1)[:t].reshape(xv.shape), stats
 
-        return apply_op(f, x, self.router, self.expert_bias,
+        return apply_op(f, x, router_input, self.router, self.expert_bias,
                         self.gate_proj, self.up_proj, self.down_proj,
                         op_name="routed_experts")

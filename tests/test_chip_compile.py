@@ -331,3 +331,47 @@ def test_attention_kernels_at_thirty_heads(chip):
             chip, lambda q, k, v, t, n: pa.paged_decode_mha(q, k, v, t, n),
             ((48, 30, 128), BF16), pool, pool, ((48, 320), I32),
             ((48,), I32))
+
+
+# -- the decoder whose router reads the attention's input, at its widths ----------
+@pytest.mark.parametrize("window, cols", [(None, 512), (4096, 257)],
+                         ids=["full", "ring"])
+def test_paged_decode_seven_query_heads_a_kv_head(chip, window, cols):
+    """24 rows of 28 query heads over 4 KV heads x 128: a full layer's
+    table of 512 pages, a window layer's ring of 257 (4,096 / 16 + 1)."""
+    import functools
+
+    b, h, hkv, d, ps = 24, 28, 4, 128, 16
+    pool = ((b * cols, ps, hkv, d), BF16)
+    fn = functools.partial(pa.paged_decode_mha, window=window)
+    assert _compile(chip, fn, ((b, h, d), BF16), pool, pool,
+                    ((b, cols), I32), ((b,), I32)) == 1
+
+
+def test_window_flash_fwd_at_seven_query_heads_a_kv_head(chip):
+    """The widest prefill bucket of a window layer: 28 query / 4 KV heads
+    x 128, 6,144 positions, window 4,096."""
+    def fwd(q, k, v):
+        return fk.flash_attention_bhsd(q, k, v, causal=True, window=4096)
+
+    assert _compile(chip, fwd, ((1, 28, 6144, 128), BF16),
+                    ((1, 4, 6144, 128), BF16),
+                    ((1, 4, 6144, 128), BF16)) == 1
+
+
+@pytest.mark.parametrize("rows", [144, 36864], ids=["decode", "bucket6144"])
+def test_grouped_matmul_sixty_four_experts(chip, rows, monkeypatch):
+    """The three expert products (64 experts, 2560 -> 768 -> 2560) of a
+    decode step (24 rows x 6 choices) and of the widest prefill bucket
+    (6,144 tokens x 6)."""
+    from paddle_tpu.ops import pallas as ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    assert _compile(
+        chip, lambda x, w, n: ops.grouped_matmul(
+            x, w, n, preferred_element_type=BF16),
+        ((rows, 2560), BF16), ((64, 2560, 768), BF16), ((64,), I32)) == 1
+    assert _compile(
+        chip, lambda x, w, n: ops.grouped_matmul(
+            x, w, n, preferred_element_type=F32),
+        ((rows, 768), BF16), ((64, 768, 2560), BF16), ((64,), I32)) == 1
