@@ -255,15 +255,64 @@ def paged_attention(q, k_pages, v_pages, lengths, page_indices, **kw):
     return _pa(q, k_pages, v_pages, lengths, page_indices, **kw)
 
 
+# The scoped VMEM a pallas_call gets on v5e when it sets no
+# vmem_limit_bytes, as the megablox kernel's does not.
+GMM_VMEM_BYTES = 16 * 2**20
+
+
+def _gmm_vmem_bytes(tm: int, tk: int, tn: int) -> int:
+    """VMEM the megablox kernel's blocks take at tiles (tm, tk, tn): the
+    double-buffered bf16 [tm, tk] rows and [tk, tn] weights, the
+    double-buffered [tm, tn] output (counted as float32, the widest a
+    caller asks for) and the float32 [tm, tn] accumulator."""
+    return 2 * (tm * tk + tk * tn) * 2 + 2 * tm * tn * 4 + tm * tn * 4
+
+
+def _widest_tile(dim: int, fits) -> int | None:
+    """The widest multiple of 128 that divides ``dim`` and ``fits``."""
+    for t in range(dim - dim % 128, 0, -128):
+        if dim % t == 0 and fits(t):
+            return t
+    return None
+
+
 def _gmm_tiling(m: int, k: int, n: int):
     """Tile sizes for the megablox kernel at (m, k, n): few, wide steps,
-    so that one step streams one group's [tk, tn] slab of weights. By the
-    chip's clock at the expert widths (experiments/exp_grouped_matmul.py,
-    v5e, 128 groups, k 2048, n 1024): a decode batch of 256 rows (a couple
-    a group) streams 690 GB/s at (128, 2048, 1024); a prefill of 4,096 to
-    65,536 rows is fastest at (256, 2048, 1024) (114 TFLOP/s at 65,536);
-    512 rows a tile with a 1024-wide slab runs out of VMEM."""
-    return 256 if m >= 4096 else 128, min(k, 2048), min(n, 1024)
+    so that one step streams one group's [tk, tn] slab of weights, and
+    tiles that divide k and n. A tk that leaves a remainder makes the
+    kernel's last k-tile of every group mask its whole [tm, tk] rows and
+    [tk, tn] weights on the vector unit before a full-depth product; a tn
+    that leaves one adds a partial output tile.
+
+    tm is 128, and 256 from 4,096 rows; 512 rows a tile with a 1024-wide
+    slab runs out of VMEM. tk is min(k, 2048) and tn min(n, 1024) where
+    they divide (128 groups, k 2048, n 1024: a decode batch of 256 rows
+    streams 690 GB/s at (128, 2048, 1024), a prefill of 4,096 to 65,536
+    rows is fastest at (256, 2048, 1024)); where one does not, it is the
+    widest multiple of 128 that divides its dimension and keeps
+    :func:`_gmm_vmem_bytes` under ``GMM_VMEM_BYTES``. By the chip's clock
+    (experiments/exp_grouped_matmul.py, v5e, ms a call, uniform routing):
+
+        rows x k x n, groups    remainder tile     divisors (* = picked)
+        144 x 2560 x 768, 64    tk 2048: 0.506     1280: 0.408  *2560: 0.353
+        12288 x 2560 x 768      tk 2048: 1.227     1280: 0.835  *2560: 0.682
+        144 x 768 x 2560        tn 1024: 0.380     1280: 0.357  *2560: 0.356
+        12288 x 768 x 2560      tn 1024: 1.011     1280: 0.904  *2560: 0.866
+        128 x 7168 x 2048, 16   tk 2048: 0.342     1024: 0.335  *1792: 0.336
+        16384 x 7168 x 2048     tk 2048: 1.136     1024: 1.034  *1792: 1.024
+
+    (at k 7168, 16 of 256 experts are held: a sixteenth of the rows is in
+    a group). Shapes that divide keep their tiles: at 256 x 1024 x 2048 a
+    tn of 2048 reads the same as 1024 (0.670 ms)."""
+    tm = 256 if m >= 4096 else 128
+    tk, tn = min(k, 2048), min(n, 1024)
+    if k % tk:
+        tk = _widest_tile(
+            k, lambda t: _gmm_vmem_bytes(tm, t, tn) <= GMM_VMEM_BYTES) or tk
+    if n % tn:
+        tn = _widest_tile(
+            n, lambda t: _gmm_vmem_bytes(tm, tk, t) <= GMM_VMEM_BYTES) or tn
+    return tm, tk, tn
 
 
 def grouped_matmul(lhs, rhs, group_sizes, preferred_element_type=jnp.float32):

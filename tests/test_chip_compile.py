@@ -273,6 +273,15 @@ def test_latent_prefill_head_groups_under_the_row_loop(chip, s, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
 
 
+def _assert_no_remainder_tile(m, k, n):
+    """The tiles ``grouped_matmul`` takes at (m, k, n) divide k and n: the
+    kernel then masks no ragged last tile."""
+    from paddle_tpu.ops.pallas import _gmm_tiling
+
+    _, tk, tn = _gmm_tiling(m, k, n)
+    assert k % tk == 0 and n % tn == 0, (m, k, n, tk, tn)
+
+
 def test_grouped_matmul_held_experts(chip, monkeypatch):
     """The held experts' products (16 experts, 7168 -> 2048 -> 7168) of a
     decode step (16 rows x 8 choices) and of a prefill's token block."""
@@ -280,6 +289,8 @@ def test_grouped_matmul_held_experts(chip, monkeypatch):
 
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     for rows in (128, 16384):
+        _assert_no_remainder_tile(rows, 7168, 2048)
+        _assert_no_remainder_tile(rows, 2048, 7168)
         assert _compile(
             chip, lambda x, w, n: ops.grouped_matmul(
                 x, w, n, preferred_element_type=BF16),
@@ -367,6 +378,8 @@ def test_grouped_matmul_sixty_four_experts(chip, rows, monkeypatch):
     from paddle_tpu.ops import pallas as ops
 
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    _assert_no_remainder_tile(rows, 2560, 768)
+    _assert_no_remainder_tile(rows, 768, 2560)
     assert _compile(
         chip, lambda x, w, n: ops.grouped_matmul(
             x, w, n, preferred_element_type=BF16),
