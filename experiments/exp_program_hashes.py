@@ -1,8 +1,9 @@
 """sha256 of the lowered text of the serving engine's programs, for one fixed
 tiny dense model, one tiny sparse-expert, window-attention model, one tiny
 latent-attention model with the sparse-attention indexer, one tiny model of
-linear-attention layers that keep a state a row and one tiny sparse-expert,
-window-attention model whose router reads the attention's input.
+linear-attention layers that keep a state a row, one tiny sparse-expert,
+window-attention model whose router reads the attention's input and one
+tiny model of latent attention beside grouped linear-attention layers.
 
 A change to the engine is held to this: a refactor must leave every column
 as it was, and a change of a program's text must move the programs it names
@@ -34,6 +35,8 @@ from paddle_tpu.models.afmoe import (AfmoeConfig,  # noqa: E402
                                      AfmoeForCausalLM)
 from paddle_tpu.models.deepseek_v32 import (  # noqa: E402
     DeepseekV32Config, DeepseekV32ForCausalLM)
+from paddle_tpu.models.gigachat35 import (  # noqa: E402
+    GigaChat35Config, GigaChat35ForCausalLM)
 from paddle_tpu.models.olmo_hybrid import (  # noqa: E402
     OlmoHybridConfig, OlmoHybridForCausalLM)
 from paddle_tpu.models.smallthinker import (  # noqa: E402
@@ -107,6 +110,22 @@ def smallthinker_model():
     return model
 
 
+def latent_hybrid_model():
+    paddle.seed(3)
+    model = GigaChat35ForCausalLM(GigaChat35Config(
+        vocab_size=256, hidden_size=64, intermediate_size=96,
+        moe_intermediate_size=32, num_hidden_layers=4,
+        num_attention_heads=4, num_key_value_heads=4, q_lora_rank=32,
+        kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        qk_head_dim=24, v_head_dim=16, n_routed_experts=16, ep_size=4,
+        num_experts_per_tok=4, first_k_dense_replace=1,
+        full_attention_layers=[3], linear_key_head_dim=16,
+        linear_value_head_dim=8, linear_num_key_heads=2,
+        linear_num_value_heads=4))
+    model.eval()
+    return model
+
+
 def programs(eng):
     """(name, lowered) of each program the engine can run, with the
     arguments the engine itself passes."""
@@ -165,6 +184,8 @@ def rows():
                                            max_pages=16)),
         ("smallthinker", smallthinker_model, dict(num_pages=64, page_size=4,
                                                   max_pages=16)),
+        ("gigachat35", latent_hybrid_model, dict(num_pages=64, page_size=4,
+                                                 max_pages=16)),
     ]
     for tag, make, kw in engines:
         eng = PagedContinuousBatchingEngine(make(), **{**geometry, **kw})

@@ -1370,7 +1370,7 @@ class PagedContinuousBatchingEngine:
             if state:       # a state a row holds no page
                 continue
             total += sum(a.nbytes for a in entry)
-            elems += entry[0].size + entry[1].size
+            elems += sum(a.size for a in entry)
         return {"bytes_per_page": total // self.num_pages,
                 "bf16_equiv_bytes_per_page":
                     2 * elems // self.num_pages}

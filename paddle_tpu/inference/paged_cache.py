@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["PageAllocator", "PagedKVCache", "write_tokens",
+__all__ = ["PageAllocator", "PagedKVCache", "write_tokens", "write_rows",
            "gather_dense", "scatter_rows", "copy_page", "gather_pages",
            "install_page", "write_tokens_q", "scatter_rows_q",
            "copy_page_q", "gather_pages_q", "gather_dense_q",
@@ -105,16 +105,22 @@ def write_tokens(k_pool, v_pool, page_table, slots, positions, k_new,
     loudly instead of as wrong tokens far downstream
     (:meth:`PageAllocator.check_coverage`).
     """
-    ps = k_pool.shape[1]
+    return write_rows((k_pool, v_pool), page_table, slots, positions,
+                      (k_new, v_new))
+
+
+def write_rows(pools, page_table, slots, positions, news):
+    """:func:`write_tokens` for any number of pools that share the table
+    (a latent layer's one pool of rows, a K and a V pool): ``news[i]``
+    [N, ...] goes into ``pools[i]`` [pages, page_size, ...] at the same
+    (page, offset) of each. Returns the pools, a tuple."""
+    ps = pools[0].shape[1]
     pages = page_table[slots, positions // ps]        # [N]
     # unmapped -> out-of-range sentinel; mode="drop" discards those rows
-    pages = jnp.where(pages >= 0, pages, k_pool.shape[0])
+    pages = jnp.where(pages >= 0, pages, pools[0].shape[0])
     offs = positions % ps
-    k_pool = k_pool.at[pages, offs].set(k_new.astype(k_pool.dtype),
-                                        mode="drop")
-    v_pool = v_pool.at[pages, offs].set(v_new.astype(v_pool.dtype),
-                                        mode="drop")
-    return k_pool, v_pool
+    return tuple(p.at[pages, offs].set(n.astype(p.dtype), mode="drop")
+                 for p, n in zip(pools, news))
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1),
@@ -385,7 +391,8 @@ def write_prompt(pools, page_table, slot, limit, mini, window_layers=None,
     the pair ``(full, ring)`` of a :class:`WindowedPageAllocator`, and a
     layer marked True goes into its ring (:func:`write_ring_tokens`).
     ``state_layers`` (one bool a layer; static): a layer marked True keeps
-    a state a row and no pages (:func:`write_row_state`)."""
+    a state a row and no pages (:func:`write_row_state`); the others'
+    entries are any number of pools (:func:`write_rows`)."""
     out = []
     # the bucket's width: of the first layer whose mini holds positions
     paged = [m for m, state in zip(mini, state_layers or ()) if not state]
@@ -395,8 +402,8 @@ def write_prompt(pools, page_table, slot, limit, mini, window_layers=None,
     if state_layers is not None:
         for pool, entry, state in zip(pools, mini, state_layers):
             out.append(write_row_state(pool, slot, entry) if state
-                       else write_tokens(*pool, page_table, slots, pos,
-                                         entry[0][0], entry[1][0]))
+                       else write_rows(pool, page_table, slots, pos,
+                                       tuple(m[0] for m in entry)))
         return out
     if window_layers is not None:
         full, ring = page_table

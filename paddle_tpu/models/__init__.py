@@ -18,6 +18,8 @@ _LAZY = {
     "gpt": ("GPTConfig", "GPTModel", "GPTForCausalLM", "gpt_config"),
     "ernie": ("ErnieMoEConfig", "ErnieMoEModel", "ErnieMoEForMaskedLM",
               "ernie_moe_config"),
+    "gigachat35": ("GigaChat35Config", "GigaChat35Model",
+                   "GigaChat35ForCausalLM"),
 }
 
 

@@ -73,7 +73,7 @@ from ._live_rows import live_rows, row_block
 from .llama import LlamaMLP
 
 __all__ = ["DeepseekV32Config", "DeepseekV32Model", "DeepseekV32ForCausalLM",
-           "yarn_inv_freq", "yarn_mscale"]
+           "LatentAttention", "yarn_inv_freq", "yarn_mscale"]
 
 INDEX_NORM_EPS = 1e-6       # the indexer's LayerNorm (assumed)
 INDEX_QUERY_BLOCK = 32      # queries of one block of the indexer's scores
@@ -281,77 +281,61 @@ class DeepseekV32Indexer(Layer):
             config.hidden_size, config.index_n_heads, **lin)
 
 
-class DeepseekV32Attention(Layer):
-    def __init__(self, config: DeepseekV32Config):
+def row_page(page_table, lens, live, num_pages, page_size):
+    """The pool page each row's token at ``lens`` goes to; a dead row or
+    an unmapped page gives ``num_pages``, a sentinel that a scatter with
+    ``mode="drop"`` drops."""
+    page = page_table[jnp.arange(lens.shape[0]),
+                      jnp.minimum(lens // page_size, page_table.shape[1] - 1)]
+    return jnp.where(live & (page >= 0), page, num_pages)
+
+
+class LatentAttention(Layer):
+    """The latent attention's projections (``q_a | q_b``, ``kv_a | kv_b``,
+    ``o``, the two latent norms) and what is computed with them alike
+    whatever chooses the positions a query attends: a token's cache row,
+    the query's heads, the expanded prefill attention, the query absorbed
+    into the latent space and the context out of it. ``norm`` is the
+    latent norms' class (its ``weight`` is what ``_rms`` is handed, as the
+    caller makes it)."""
+
+    def __init__(self, config, norm=RMSNorm):
         super().__init__(dtype=config.dtype)
         self.config = config
         cfg, h = config, config.hidden_size
         heads = cfg.num_attention_heads
         lin = dict(has_bias=False, gather_output=False)
         self.q_a_proj = ColumnParallelLinear(h, cfg.q_lora_rank, **lin)
-        self.q_a_layernorm = RMSNorm(cfg.q_lora_rank,
-                                     epsilon=cfg.rms_norm_eps)
+        self.q_a_layernorm = norm(cfg.q_lora_rank, epsilon=cfg.rms_norm_eps)
         self.q_b_proj = ColumnParallelLinear(
             cfg.q_lora_rank,
             heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim), **lin)
         self.kv_a_proj_with_mqa = ColumnParallelLinear(
             h, cfg.kv_lora_rank + cfg.qk_rope_head_dim, **lin)
-        self.kv_a_layernorm = RMSNorm(cfg.kv_lora_rank,
-                                      epsilon=cfg.rms_norm_eps)
+        self.kv_a_layernorm = norm(cfg.kv_lora_rank,
+                                   epsilon=cfg.rms_norm_eps)
         self.kv_b_proj = ColumnParallelLinear(
             cfg.kv_lora_rank,
             heads * (cfg.qk_nope_head_dim + cfg.v_head_dim), **lin)
         self.o_proj = RowParallelLinear(heads * cfg.v_head_dim, h,
                                         has_bias=False,
                                         input_is_parallel=True)
-        self.indexer = DeepseekV32Indexer(config)
-        self.inv_freq = yarn_inv_freq(cfg.qk_rope_head_dim, cfg.rope_theta,
-                                      cfg.rope_scaling)
 
-    def _weights(self):
-        ix = self.indexer
-        return (self.q_a_proj.weight, self.q_a_layernorm.weight,
-                self.q_b_proj.weight, self.kv_a_proj_with_mqa.weight,
-                self.kv_a_layernorm.weight, self.kv_b_proj.weight,
-                self.o_proj.weight, ix.wq_b.weight, ix.wk.weight,
-                ix.k_norm.weight, ix.k_norm.bias, ix.weights_proj.weight)
-
-    # -- what a token leaves in the cache, and what asks for it -------------
-    def _cached(self, a, cos, sin, wkva, nkv, wki, lnw, lnb):
-        """a [..., h] -> the cache's row (c | k_rope | zeros) [..., 640] and
-        the indexer's key [..., 128], normed and rotated in float32 and
-        rounded once, to ``a``'s dtype."""
+    def _latent(self, a, cos, sin, wkva, nkv):
+        """a [..., h] -> (c [..., kv_lora_rank], k_rope [..., rope]),
+        normed and rotated in float32."""
         cfg = self.config
         kv = jnp.matmul(a, wkva, preferred_element_type=jnp.float32)
         c = _rms(kv[..., :cfg.kv_lora_rank], nkv, cfg.rms_norm_eps)
         kr = rope_pairs(kv[..., cfg.kv_lora_rank:], cos, sin)
-        ki = jnp.matmul(a, wki, preferred_element_type=jnp.float32)
-        mu = jnp.mean(ki, axis=-1, keepdims=True)
-        var = jnp.mean((ki - mu) ** 2, axis=-1, keepdims=True)
-        ki = (ki - mu) * jax.lax.rsqrt(var + INDEX_NORM_EPS) \
-            * lnw.astype(jnp.float32) + lnb.astype(jnp.float32)
-        r = cfg.qk_rope_head_dim
-        ki = jnp.concatenate([rope_halves(ki[..., :r], cos, sin),
-                              ki[..., r:]], axis=-1)
-        pad = jnp.zeros(c.shape[:-1] + (cfg.cache_row - c.shape[-1]
-                                        - kr.shape[-1],), jnp.float32)
-        return (jnp.concatenate([c, kr, pad], axis=-1).astype(a.dtype),
-                ki.astype(a.dtype))
+        return c, kr
 
-    def _index_query(self, a, cq, cos, sin, wqbi, ww):
-        """The indexer's queries [..., Hi, Di] float32 (rope on the first
-        rope dims of each) and head weights [..., Hi] float32."""
-        cfg = self.config
-        hi, di, r = cfg.index_n_heads, cfg.index_head_dim, \
-            cfg.qk_rope_head_dim
-        qi = jnp.matmul(cq, wqbi, preferred_element_type=jnp.float32)
-        qi = qi.reshape(qi.shape[:-1] + (hi, di))
-        qi = jnp.concatenate(
-            [rope_halves(qi[..., :r], cos[..., None, :], sin[..., None, :]),
-             qi[..., r:]], axis=-1)
-        w = jnp.matmul(a, ww, preferred_element_type=jnp.float32) \
-            * (hi ** -0.5 * di ** -0.5)
-        return qi, w
+    def _as_row(self, c, kr, dtype):
+        """The cache's row ``c | k_rope | zeros`` [..., cache_row], rounded
+        once to ``dtype``."""
+        pad = jnp.zeros(c.shape[:-1] + (self.config.cache_row - c.shape[-1]
+                                        - kr.shape[-1],), jnp.float32)
+        return jnp.concatenate([c, kr, pad], axis=-1).astype(dtype)
 
     def _query(self, cq, cos, sin, wqb, scale=None):
         """(q_nope [..., H, nope], q_rope [..., H, rope]) in cq's dtype,
@@ -366,49 +350,24 @@ class DeepseekV32Attention(Layer):
         qr = rope_pairs(q[..., n:], cos[..., None, :], sin[..., None, :])
         return q[..., :n].astype(cq.dtype), qr.astype(cq.dtype)
 
-    # -- prefill ---------------------------------------------------------------
-    def _selection(self, a, cq, ki, cos, sin, wqbi, ww, last_idx):
-        """The mask [S, S] of the positions each query attends: causal, and
-        where a query sees more than ``index_topk`` positions the
-        ``index_topk`` of largest indexer score. None = causal alone (no
-        query of the bucket sees more). A block of queries at a time has
-        its indexer queries made and scored against the keys its run of
-        the sequence can see. Queries past ``last_idx`` (bucket padding)
-        score nothing; their rows of the mask are False."""
+    def _absorbed_query(self, cq, cos, sin, wqb, wkvb):
+        """Decode's query in latent space: (q_lat [B, H, kv_lora_rank]
+        = q_nope Wkvb_k^T, float32 and rounded once to cq's dtype, q_rope
+        [B, H, rope], Wkvb as [kv_lora_rank, H, nope + v])."""
         cfg = self.config
-        s = a.shape[0]
-        if s <= cfg.index_topk:
-            return None
-        bq = _block(s, INDEX_QUERY_BLOCK)
-        out = []
-        for start, stop in _segments(s, bq):
-            keys = ki[:stop]
-            upos = jnp.arange(stop)[None, :]
+        n, v, c = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+        qn, qr = self._query(cq, cos, sin, wqb)
+        up = wkvb.reshape(c, -1, n + v)
+        q_lat = jnp.einsum("bhn,chn->bhc", qn, up[..., :n],
+                           preferred_element_type=jnp.float32)
+        return q_lat.astype(cq.dtype), qr, up
 
-            def block(args, keys=keys, upos=upos, stop=stop):
-                q0, ab, cqb, cb, sb = args
-
-                def scored():
-                    qi, w = self._index_query(ab, cqb, cb, sb, wqbi, ww)
-                    sc = index_scores(qi.astype(keys.dtype), w, keys)
-                    tpos = q0 + jnp.arange(bq)[:, None]
-                    causal = upos <= tpos
-                    sc = jnp.where(causal, sc, -jnp.inf)
-                    k = jnp.minimum(tpos[:, 0] + 1, cfg.index_topk)
-                    return top_k_mask(sc, k) & causal
-
-                if last_idx is None:
-                    return scored()
-                return jax.lax.cond(q0 <= last_idx, scored,
-                                    lambda: jnp.zeros((bq, stop), bool))
-
-            nb = (stop - start) // bq
-            m = jax.lax.map(block, (start + bq * jnp.arange(nb), *(
-                v[start:stop].reshape((nb, bq) + v.shape[1:])
-                for v in (a, cq, cos, sin))))
-            out.append(jnp.pad(m.reshape(stop - start, stop),
-                               ((0, 0), (0, s - stop))))
-        return jnp.concatenate(out, axis=0)
+    def _latent_out(self, ctx, up, dtype):
+        """A context in latent space [B, H, kv_lora_rank] -> the heads'
+        values [B, H, v] float32 (Wkvb_v, ``ctx`` rounded to ``dtype``)."""
+        return jnp.einsum("bhc,chv->bhv", ctx.astype(dtype),
+                          up[..., self.config.qk_nope_head_dim:],
+                          preferred_element_type=jnp.float32)
 
     def _attend_expanded(self, cq, row, mask, pos, wqb, wkvb, last_idx):
         """Attention with expanded heads: cq [S, q_lora_rank], row [S, 640]
@@ -456,6 +415,99 @@ class DeepseekV32Attention(Layer):
 
         return jax.lax.fori_loop(0, heads // hg, group,
                                  jnp.zeros((heads, s, v), row.dtype))
+
+
+class DeepseekV32Attention(LatentAttention):
+    def __init__(self, config: DeepseekV32Config):
+        super().__init__(config)
+        cfg = config
+        self.indexer = DeepseekV32Indexer(config)
+        self.inv_freq = yarn_inv_freq(cfg.qk_rope_head_dim, cfg.rope_theta,
+                                      cfg.rope_scaling)
+
+    def _weights(self):
+        ix = self.indexer
+        return (self.q_a_proj.weight, self.q_a_layernorm.weight,
+                self.q_b_proj.weight, self.kv_a_proj_with_mqa.weight,
+                self.kv_a_layernorm.weight, self.kv_b_proj.weight,
+                self.o_proj.weight, ix.wq_b.weight, ix.wk.weight,
+                ix.k_norm.weight, ix.k_norm.bias, ix.weights_proj.weight)
+
+    # -- what a token leaves in the cache, and what asks for it -------------
+    def _cached(self, a, cos, sin, wkva, nkv, wki, lnw, lnb):
+        """a [..., h] -> the cache's row (c | k_rope | zeros) [..., 640] and
+        the indexer's key [..., 128], normed and rotated in float32 and
+        rounded once, to ``a``'s dtype."""
+        cfg = self.config
+        c, kr = self._latent(a, cos, sin, wkva, nkv)
+        ki = jnp.matmul(a, wki, preferred_element_type=jnp.float32)
+        mu = jnp.mean(ki, axis=-1, keepdims=True)
+        var = jnp.mean((ki - mu) ** 2, axis=-1, keepdims=True)
+        ki = (ki - mu) * jax.lax.rsqrt(var + INDEX_NORM_EPS) \
+            * lnw.astype(jnp.float32) + lnb.astype(jnp.float32)
+        r = cfg.qk_rope_head_dim
+        ki = jnp.concatenate([rope_halves(ki[..., :r], cos, sin),
+                              ki[..., r:]], axis=-1)
+        return self._as_row(c, kr, a.dtype), ki.astype(a.dtype)
+
+    def _index_query(self, a, cq, cos, sin, wqbi, ww):
+        """The indexer's queries [..., Hi, Di] float32 (rope on the first
+        rope dims of each) and head weights [..., Hi] float32."""
+        cfg = self.config
+        hi, di, r = cfg.index_n_heads, cfg.index_head_dim, \
+            cfg.qk_rope_head_dim
+        qi = jnp.matmul(cq, wqbi, preferred_element_type=jnp.float32)
+        qi = qi.reshape(qi.shape[:-1] + (hi, di))
+        qi = jnp.concatenate(
+            [rope_halves(qi[..., :r], cos[..., None, :], sin[..., None, :]),
+             qi[..., r:]], axis=-1)
+        w = jnp.matmul(a, ww, preferred_element_type=jnp.float32) \
+            * (hi ** -0.5 * di ** -0.5)
+        return qi, w
+
+    # -- prefill ---------------------------------------------------------------
+    def _selection(self, a, cq, ki, cos, sin, wqbi, ww, last_idx):
+        """The mask [S, S] of the positions each query attends: causal, and
+        where a query sees more than ``index_topk`` positions the
+        ``index_topk`` of largest indexer score. None = causal alone (no
+        query of the bucket sees more). A block of queries at a time has
+        its indexer queries made and scored against the keys its run of
+        the sequence can see. Queries past ``last_idx`` (bucket padding)
+        score nothing; their rows of the mask are False."""
+        cfg = self.config
+        s = a.shape[0]
+        if s <= cfg.index_topk:
+            return None
+        bq = _block(s, INDEX_QUERY_BLOCK)
+        out = []
+        for start, stop in _segments(s, bq):
+            keys = ki[:stop]
+            upos = jnp.arange(stop)[None, :]
+
+            def block(args, keys=keys, upos=upos, stop=stop):
+                q0, ab, cqb, cb, sb = args
+
+                def scored():
+                    qi, w = self._index_query(ab, cqb, cb, sb, wqbi, ww)
+                    sc = index_scores(qi.astype(keys.dtype), w, keys)
+                    tpos = q0 + jnp.arange(bq)[:, None]
+                    causal = upos <= tpos
+                    sc = jnp.where(causal, sc, -jnp.inf)
+                    k = jnp.minimum(tpos[:, 0] + 1, cfg.index_topk)
+                    return top_k_mask(sc, k) & causal
+
+                if last_idx is None:
+                    return scored()
+                return jax.lax.cond(q0 <= last_idx, scored,
+                                    lambda: jnp.zeros((bq, stop), bool))
+
+            nb = (stop - start) // bq
+            m = jax.lax.map(block, (start + bq * jnp.arange(nb), *(
+                v[start:stop].reshape((nb, bq) + v.shape[1:])
+                for v in (a, cq, cos, sin))))
+            out.append(jnp.pad(m.reshape(stop - start, stop),
+                               ((0, 0), (0, s - stop))))
+        return jnp.concatenate(out, axis=0)
 
     def forward_with_cache(self, x, cache, last_idx=None):
         """Prefill from position 0: x [B, S, h]; ``cache`` (rows [B, S_max,
@@ -512,17 +564,13 @@ class DeepseekV32Attention(Layer):
         def attend(xv, lat_pool, key_pool, wqa, nq, wqb, wkva, nkv, wkvb,
                    wo, wqbi, wki, lnw, lnb, ww):
             a = xv[:, 0]
-            b, ps, cols = a.shape[0], lat_pool.shape[1], page_table.shape[1]
-            n, v, c = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+            b, ps = a.shape[0], lat_pool.shape[1]
             cos, sin = _angles(lens, self.inv_freq)
             cq = _rms(jnp.matmul(a, wqa,
                                  preferred_element_type=jnp.float32),
                       nq, cfg.rms_norm_eps).astype(a.dtype)
             row, ki = self._cached(a, cos, sin, wkva, nkv, wki, lnw, lnb)
-            page = page_table[jnp.arange(b),
-                              jnp.minimum(lens // ps, cols - 1)]
-            # dead rows / unmapped pages -> sentinel, dropped by scatter
-            page = jnp.where(live & (page >= 0), page, lat_pool.shape[0])
+            page = row_page(page_table, lens, live, lat_pool.shape[0], ps)
             lat_pool = lat_pool.at[page, lens % ps].set(
                 row.astype(lat_pool.dtype), mode="drop")
             key_pool = key_pool.at[page, lens % ps].set(
@@ -544,15 +592,11 @@ class DeepseekV32Attention(Layer):
             worst_first, where = jax.lax.sort((worst_first, where),
                                               dimension=1, num_keys=1)
             k = min(cfg.index_topk, width)
-            qn, qr = self._query(cq, cos, sin, wqb)
-            up = wkvb.reshape(c, -1, n + v)
-            q_lat = jnp.einsum("bhn,chn->bhc", qn, up[..., :n],
-                               preferred_element_type=jnp.float32)
+            q_lat, qr, up = self._absorbed_query(cq, cos, sin, wqb, wkvb)
             ctx = sparse_latent_decode(
-                q_lat.astype(a.dtype), qr, lat_pool, where[:, :k],
+                q_lat, qr, lat_pool, where[:, :k],
                 worst_first[:, :k] < jnp.inf, cfg.softmax_scale)
-            o = jnp.einsum("bhc,chv->bhv", ctx.astype(a.dtype), up[..., n:],
-                           preferred_element_type=jnp.float32)
+            o = self._latent_out(ctx, up, a.dtype)
             out = jnp.matmul(o.astype(a.dtype).reshape(b, -1), wo)
             return out[:, None], lat_pool, key_pool
 

@@ -47,11 +47,13 @@ from ..distributed.fleet.layers.mpu import (ColumnParallelLinear,
                                             VocabParallelEmbedding)
 from ..nn.initializer import Constant, Initializer
 from ..nn.layer.layers import Layer
-from ..nn.layer.norm import RMSNorm
+from ..nn.layer.norm import (RMSNorm, ZeroCenteredGatedNorm,
+                             zero_centered_scale)
 from ..ops import gated_delta_rule as gdn
 from .llama import LlamaMLP
 
-__all__ = ["OlmoHybridConfig", "OlmoHybridModel", "OlmoHybridForCausalLM"]
+__all__ = ["OlmoHybridConfig", "OlmoHybridModel", "OlmoHybridForCausalLM",
+           "GatedDeltaNet", "gdn_conv_dim", "gdn_state_entry"]
 
 LINEAR, FULL = "linear_attention", "full_attention"
 F32 = jnp.float32
@@ -129,8 +131,25 @@ class OlmoHybridConfig:
     @property
     def conv_dim(self) -> int:
         """The width of ``u``: q | k | v of every head."""
-        return self.linear_num_key_heads * (2 * self.linear_key_head_dim
-                                            + self.linear_value_head_dim)
+        return gdn_conv_dim(self)
+
+
+def gdn_conv_dim(cfg) -> int:
+    """The width of a linear layer's ``u``: q and k of every key head, v of
+    every value head."""
+    return (cfg.linear_num_key_heads * 2 * cfg.linear_key_head_dim
+            + cfg.linear_num_value_heads * cfg.linear_value_head_dim)
+
+
+def gdn_state_entry(cfg, rows: int):
+    """A linear layer's cache for ``rows`` rows, zeros: (the float32 state
+    [rows, value heads, dk, dv], the last K - 1 inputs of the convolution
+    [rows, K - 1, conv width] in the configuration's dtype)."""
+    return (jnp.zeros((rows, cfg.linear_num_value_heads,
+                       cfg.linear_key_head_dim,
+                       cfg.linear_value_head_dim), F32),
+            jnp.zeros((rows, cfg.linear_conv_kernel_dim - 1,
+                       gdn_conv_dim(cfg)), jnp.dtype(cfg.dtype)))
 
 
 class _LogUniform(Initializer):
@@ -161,27 +180,46 @@ def _f32_product(x, w):
 
 
 class GatedDeltaNet(Layer):
-    """The linear-attention mixer. Its cache is ``(state [B, H, dk, dv]
-    float32, rows [B, K-1, conv_dim])``."""
+    """The linear-attention mixer. Its cache is ``(state [B, Hv, dk, dv]
+    float32, rows [B, K-1, conv_dim])``.
 
-    def __init__(self, config: OlmoHybridConfig):
+    ``linear_num_value_heads`` may be a multiple of ``linear_num_key_heads``
+    (grouped heads: value head j reads key head j // (Hv / Hk); the gates,
+    the decay rates and the state are a value head's). ``gating`` (static)
+    is the output's: ``"silu"`` (this module's block: ``N_o(o) * silu(z)``,
+    ``N_o`` an RMSNorm with a weight) or ``"sigmoid_zero_centered"``
+    (``N_o(o) * 2 sigmoid(z)``, ``N_o`` a zero-centred gated norm:
+    ``nn.layer.norm.ZeroCenteredGatedNorm``), with ``norm_eps`` (None =
+    ``rms_norm_eps``)."""
+
+    GATINGS = ("silu", "sigmoid_zero_centered")
+
+    def __init__(self, config, gating: str = "silu", norm_eps=None):
         super().__init__(dtype=config.dtype)
+        if gating not in self.GATINGS:
+            raise ValueError(f"gating={gating!r} is not implemented (only "
+                             f"{self.GATINGS})")
         self.config = config
-        h, heads = config.hidden_size, config.linear_num_key_heads
+        self.gating = gating
+        self.norm_eps = config.rms_norm_eps if norm_eps is None else norm_eps
+        h, heads = config.hidden_size, config.linear_num_value_heads
         v_width = heads * config.linear_value_head_dim
+        conv_dim = gdn_conv_dim(config)
         lin = dict(has_bias=False, gather_output=False)
-        self.in_proj_qkv = ColumnParallelLinear(h, config.conv_dim, **lin)
+        self.in_proj_qkv = ColumnParallelLinear(h, conv_dim, **lin)
         self.in_proj_z = ColumnParallelLinear(h, v_width, **lin)
         self.in_proj_b = ColumnParallelLinear(h, heads, **lin)
         self.in_proj_a = ColumnParallelLinear(h, heads, **lin)
         self.conv1d = self.create_parameter(
-            [config.conv_dim, config.linear_conv_kernel_dim])
+            [conv_dim, config.linear_conv_kernel_dim])
         self.A_log = self.create_parameter(
             [heads], dtype="float32", default_initializer=_LogUniform(16.0))
         self.dt_bias = self.create_parameter(
             [heads], dtype="float32", default_initializer=Constant(1.0))
-        self.norm = RMSNorm(config.linear_value_head_dim,
-                            epsilon=config.rms_norm_eps)
+        self.norm = (RMSNorm(config.linear_value_head_dim,
+                             epsilon=self.norm_eps) if gating == "silu"
+                     else ZeroCenteredGatedNorm(config.linear_value_head_dim,
+                                                epsilon=self.norm_eps))
         self.out_proj = RowParallelLinear(v_width, h, has_bias=False,
                                           input_is_parallel=True)
 
@@ -196,21 +234,27 @@ class GatedDeltaNet(Layer):
         return g, beta
 
     def _split(self, c):
-        """The convolution's output [..., conv_dim] -> q, k (L2-normed,
-        q scaled; float32) and v, each [..., H, d]."""
+        """The convolution's output [..., conv_dim] -> q, k [..., Hk, dk]
+        (L2-normed, q scaled; float32) and v [..., Hv, dv]."""
         cfg = self.config
         heads, dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
         lead = c.shape[:-1]
         q, k, v = jnp.split(c, [heads * dk, 2 * heads * dk], axis=-1)
         q = gdn.l2norm(q.reshape(lead + (heads, dk)).astype(F32))
         k = gdn.l2norm(k.reshape(lead + (heads, dk)).astype(F32))
-        return q * dk ** -0.5, k, v.reshape(lead + (heads, -1))
+        return q * dk ** -0.5, k, v.reshape(
+            lead + (cfg.linear_num_value_heads, -1))
 
     def _out(self, o, z, norm_w):
-        """(N_o(o) * silu(z)) as [..., H*dv] in z's dtype; o [..., H, dv]
+        """(N_o(o) * gate(z)) as [..., Hv*dv] in z's dtype; o [..., Hv, dv]
         float32."""
-        o = _rms(o.astype(F32), norm_w, self.config.rms_norm_eps)
-        gate = jax.nn.silu(z.astype(F32)).reshape(o.shape)
+        if self.gating == "silu":
+            o = _rms(o.astype(F32), norm_w, self.norm_eps)
+            gate = jax.nn.silu(z.astype(F32)).reshape(o.shape)
+        else:
+            o = _rms(o.astype(F32), zero_centered_scale(norm_w),
+                     self.norm_eps)
+            gate = 2.0 * jax.nn.sigmoid(z.astype(F32)).reshape(o.shape)
         return (o * gate).reshape(z.shape).astype(z.dtype)
 
     def _weights(self):
@@ -466,12 +510,7 @@ class OlmoHybridForCausalLM(Layer):
 
     def _state_entry(self, rows: int):
         """A linear layer's cache for ``rows`` rows, zeros."""
-        cfg = self.config
-        return (jnp.zeros((rows, cfg.linear_num_key_heads,
-                           cfg.linear_key_head_dim,
-                           cfg.linear_value_head_dim), F32),
-                jnp.zeros((rows, cfg.linear_conv_kernel_dim - 1,
-                           cfg.conv_dim), jnp.dtype(cfg.dtype)))
+        return gdn_state_entry(self.config, rows)
 
     def _kv_entry(self, *lead):
         cfg = self.config

@@ -18,6 +18,7 @@ __all__ = [
     "BatchNorm", "BatchNorm1D", "BatchNorm2D", "BatchNorm3D", "SyncBatchNorm",
     "LayerNorm", "GroupNorm", "InstanceNorm1D", "InstanceNorm2D",
     "InstanceNorm3D", "LocalResponseNorm", "SpectralNorm", "RMSNorm",
+    "ZeroCenteredGatedNorm", "zero_centered_scale",
 ]
 
 
@@ -300,3 +301,37 @@ class SpectralNorm(Layer):
 
         out = apply_op(f, weight, op_name="spectral_norm")
         return out
+
+
+def zero_centered_scale(w, gating: float = 2.0):
+    """The multiplier [D] float32 of :class:`ZeroCenteredGatedNorm`'s
+    weight ``w``: ``gating * sigmoid(w)``."""
+    import jax
+
+    return gating * jax.nn.sigmoid(w.astype(jnp.float32))
+
+
+class ZeroCenteredGatedNorm(Layer):
+    """RMS norm with a zero-centred, gated weight: ``x * rsqrt(mean(x^2) +
+    eps) * gating * sigmoid(w)``, ``w`` zero at initialisation (at
+    ``gating`` 2 a plain RMS norm then). float32 inside, the result in
+    x's dtype."""
+
+    def __init__(self, hidden_size, epsilon=1e-6, gating=2.0):
+        super().__init__()
+        self._epsilon, self._gating = epsilon, gating
+        self.weight = self.create_parameter(
+            [hidden_size], default_initializer=Constant(0.0))
+
+    def forward(self, x):
+        import jax
+
+        from ...core.autograd import apply_op
+
+        def f(v, w):
+            x32 = v.astype(jnp.float32)
+            var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+            return (x32 * jax.lax.rsqrt(var + self._epsilon)
+                    * zero_centered_scale(w, self._gating)).astype(v.dtype)
+
+        return apply_op(f, x, self.weight, op_name="zero_centered_gated_norm")

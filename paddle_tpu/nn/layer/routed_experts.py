@@ -27,7 +27,7 @@ from ...core.autograd import apply_op
 from ..initializer import Constant, Initializer, XavierUniform
 from .layers import Layer
 
-__all__ = ["RoutedExperts", "route_top_k", "routed_experts_ffn"]
+__all__ = ["RoutedExperts", "glu", "route_top_k", "routed_experts_ffn"]
 
 # How far an expert's initial weights lie from the other experts', as a
 # share of their norm (see :class:`_Upcycled`).
@@ -119,10 +119,23 @@ def route_top_k(x, router, bias, top_k: int, route_scale: float = 1.0,
     return sel.astype(jnp.int32), w * route_scale
 
 
+def glu(g, u, act: str = "silu", limit=None):
+    """The gated linear unit's middle in float32 from the two products:
+    ``act(g) * u``, or with ``limit`` ``act(min(g, limit)) * clip(u,
+    -limit, limit)`` (a SwiGLU limit: the gate clamped from above, the
+    linear half on both sides)."""
+    if limit is None:
+        return ACTIVATIONS[act](g.astype(jnp.float32)) * u.astype(jnp.float32)
+    g = jnp.minimum(g.astype(jnp.float32), limit)
+    return ACTIVATIONS[act](g) * jnp.clip(u.astype(jnp.float32), -limit,
+                                          limit)
+
+
 def routed_experts_ffn(x, sel, w, gate, up, down, valid=None,
-                       first_expert=None, act: str = "silu"):
+                       first_expert=None, act: str = "silu", limit=None):
     """sum_k w[t, k] * expert_{sel[t, k]}(x[t]) for x [T, h], an expert
-    being ``(act(x Wg) * x Wu) Wd``.
+    being ``(act(x Wg) * x Wu) Wd``, or with ``limit`` (static)
+    ``(act(min(x Wg, limit)) * clip(x Wu, -limit, limit)) Wd``.
 
     gate/up [E, h, m], down [E, m, h]; ``act`` names an entry of
     ``ACTIVATIONS``. ``valid`` [T] bool: rows that are
@@ -152,8 +165,7 @@ def routed_experts_ffn(x, sel, w, gate, up, down, valid=None,
     xs = jnp.take(x, order // k, axis=0)          # [T*k, h]
     g = grouped_matmul(xs, gate, sizes, preferred_element_type=x.dtype)
     u = grouped_matmul(xs, up, sizes, preferred_element_type=x.dtype)
-    mid = (ACTIVATIONS[act](g.astype(jnp.float32))
-           * u.astype(jnp.float32)).astype(x.dtype)
+    mid = glu(g, u, act, limit).astype(x.dtype)
     ys = grouped_matmul(mid, down, sizes,
                         preferred_element_type=jnp.float32)
     in_group = jnp.arange(t * k) < jnp.sum(sizes)
@@ -180,7 +192,8 @@ class RoutedExperts(Layer):
     only; a softmax router has none.
 
     ``n_group`` / ``topk_group``: group-limited routing
-    (:func:`route_top_k`). ``held=(first, count)``: this layer is one
+    (:func:`route_top_k`). ``limit``: the experts' SwiGLU limit
+    (:func:`glu`; None = none). ``held=(first, count)``: this layer is one
     chip's share of ``num_experts``: the router scores all of them, the
     weights of ``count`` experts from ``first`` on are held, and the
     output is their part of the sum. Where the sorted (token, choice) rows
@@ -193,7 +206,7 @@ class RoutedExperts(Layer):
                  num_experts: int, top_k: int, route_scale: float = 1.0,
                  route_norm: bool = True, n_group: int = 1,
                  topk_group: int = 1, held=None, score: str = "sigmoid",
-                 act: str = "silu"):
+                 act: str = "silu", limit=None):
         super().__init__()
         if score not in ("sigmoid", "softmax") or act not in ACTIVATIONS:
             raise ValueError(f"score={score!r}, act={act!r}: the router "
@@ -203,7 +216,7 @@ class RoutedExperts(Layer):
         self.route_scale = route_scale
         self.route_norm = route_norm
         self.n_group, self.topk_group = n_group, topk_group
-        self.score, self.act = score, act
+        self.score, self.act, self.limit = score, act, limit
         self.first_expert = None if held is None else int(held[0])
         # tokens a block: the largest power of two whose rows fit
         self.token_block = 1 << int(math.log2(
@@ -233,7 +246,8 @@ class RoutedExperts(Layer):
                 return routed_experts_ffn(
                     flat, sel, w, gate, up, down,
                     valid=None if ok is None else ok.reshape(-1),
-                    first_expert=self.first_expert, act=self.act)
+                    first_expert=self.first_expert, act=self.act,
+                    limit=self.limit)
 
             flat = xv.reshape(-1, xv.shape[-1])
             rflat = None if rv is None else rv.reshape(-1, rv.shape[-1])

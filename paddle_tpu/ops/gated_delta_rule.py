@@ -11,6 +11,12 @@ L2-normed, ``q`` scaled by ``dk^-0.5``, ``g <= 0`` the log decay and
     S_t = S + k_t (x) d_t
     o_t = q_t . S_t
 
+Grouped heads: with ``Hv`` value heads over ``Hk`` key heads (``Hv`` a
+multiple of ``Hk``), value head ``j`` reads key head ``j // (Hv / Hk)``:
+q and k come with ``Hk`` heads, v, g, beta and the state with ``Hv``. Both
+kernels take the key heads as they are and read each once for its group
+(nothing is repeated in HBM); equal counts are the ungrouped form.
+
 ``gdn_chunk_prefill`` (a Pallas kernel) runs it over a prompt in chunks of
 ``CHUNK`` positions: inside a chunk the WY representation turns the
 recurrence into products on the matrix unit, the float32 state is carried
@@ -41,6 +47,8 @@ XLA composition, and ``recurrence`` the equations above as a ``lax.scan``
 (what the chunked form is tested against).
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -93,11 +101,21 @@ def l2norm(x, eps=1e-6):
 
 
 # -- the recurrence as written ---------------------------------------------------
+def _grouped(q, k, heads):
+    """q, k [..., Hk, dk] -> each key head repeated for the value heads
+    of its group, [..., heads, dk] (the plain forms' reading)."""
+    group = heads // q.shape[-2]
+    if group == 1:
+        return q, k
+    return (jnp.repeat(q, group, axis=-2), jnp.repeat(k, group, axis=-2))
+
+
 def recurrence(q, k, v, g, beta, state=None):
     """The equations of the module's docstring, one position at a time.
-    q/k [B, S, H, dk], v [B, S, H, dv], g/beta [B, S, H], state
+    q/k [B, S, Hk, dk], v [B, S, H, dv], g/beta [B, S, H], state
     [B, H, dk, dv] or None (zeros); float32. Returns (o [B, S, H, dv],
     final state)."""
+    q, k = _grouped(q, k, v.shape[2])
     if state is None:
         b, _, h, dk = q.shape
         state = jnp.zeros((b, h, dk, v.shape[-1]), F32)
@@ -117,9 +135,23 @@ def recurrence(q, k, v, g, beta, state=None):
 
 
 # -- prefill: the chunked scan ---------------------------------------------------
-def _chunk_kernel(last_ref, q_ref, k_ref, v_ref, gb_ref, o_ref, s_ref):
+def _chunk_kernel(last_ref, q_ref, k_ref, v_ref, gb_ref, o_ref, s_ref,
+                  group=1):
+    """One (key head, chunk) grid step: the ``group`` value heads that read
+    the key head, one after another; ``k k^T`` and ``q k^T`` are made once
+    for all of them. With ``group`` 1 the value blocks carry no head
+    axis."""
     c = pl.program_id(1)
     n = q_ref.shape[0]
+
+    def get(ref, t):
+        return ref[...] if group == 1 else ref[t]
+
+    def put(ref, t, value):
+        if group == 1:
+            ref[...] = value
+        else:
+            ref[t] = value
 
     @pl.when(c == 0)
     def _():
@@ -127,63 +159,82 @@ def _chunk_kernel(last_ref, q_ref, k_ref, v_ref, gb_ref, o_ref, s_ref):
 
     @pl.when(c * n <= last_ref[0])
     def _():
-        q, k, v = q_ref[...], k_ref[...], v_ref[...].astype(F32)
-        g_row, beta_row = gb_ref[0:1, :], gb_ref[1:2, :]      # [1, n]
-        row = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-        eye = row == col
+        q, k = q_ref[...], k_ref[...]
+        shared = {}         # the group's products of q and k, made once
+        for t in range(group):
+            _chunk_head(q, k, get(v_ref, t).astype(F32),
+                        gb_ref[2 * t:2 * t + 1, :],
+                        gb_ref[2 * t + 1:2 * t + 2, :],
+                        lambda t=t: get(s_ref, t), shared, lambda o, t=t: put(o_ref, t, o),
+                        lambda st, t=t: put(s_ref, t, st), o_ref.dtype)
 
-        def column(x):          # [1, n] along lanes -> [n, 1] along sublanes
-            return jnp.sum(jnp.where(eye, x, 0.0), axis=1, keepdims=True)
 
-        g_col, beta_col = column(g_row), column(beta_row)
-        decay = jnp.exp(jnp.where(row >= col, g_col - g_row, -jnp.inf))
-        nt = ((1,), (1,))
-        m = jnp.where(row > col, beta_col * _dot(k, k, nt) * decay, 0.0)
-        # T = (I + M)^-1, M nilpotent: diagonal blocks first, then the
-        # blocks' own (block-)nilpotent remainder
-        mm = ((1,), (0,))
-        ident = eye.astype(F32)
-        own = (row // _BLOCK) == (col // _BLOCK)
-        nd = jnp.where(own, -m, 0.0)
-        t_d, power = ident + nd, nd
-        for _ in range(3):                    # N^2, N^4, N^8: N^16 = 0
-            power = _dot(power, power, mm)
-            t_d = t_d + _dot(t_d, power, mm)
-        x = _dot(t_d, jnp.where(own, 0.0, -m), mm)
-        join = ident + x
-        join = join + _dot(join, _dot(x, x, mm), mm)          # X^4 = 0
-        t = _dot(join, t_d, mm)
+def _chunk_head(q, k, v, g_row, beta_row, get_s, shared, put_o, put_s,
+                o_dtype):
+    """One value head's chunk: the module docstring's WY form."""
+    n = q.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    eye = row == col
 
-        e_col = jnp.exp(g_col)
-        w = _dot(t, k * (beta_col * e_col), mm)
-        u = _dot(t, v * beta_col, mm)
-        s = s_ref[...]
-        v_new = u - _dot(w, s, mm)
-        qk = jnp.where(row >= col, _dot(q, k, nt) * decay, 0.0)
-        o_ref[...] = (_dot(q * e_col, s, mm)
-                      + _dot(qk, v_new, mm)).astype(o_ref.dtype)
-        # G at the chunk's end, as a column (G never rises, so its least):
-        # Mosaic broadcasts along one axis at a time, a [1, 1] not at all
-        def last(rows):
-            return jnp.min(jnp.broadcast_to(g_row, (rows, n)), axis=1,
-                           keepdims=True)
+    def column(x):          # [1, n] along lanes -> [n, 1] along sublanes
+        return jnp.sum(jnp.where(eye, x, 0.0), axis=1, keepdims=True)
 
-        s_ref[...] = (s * jnp.exp(last(s.shape[0]))
-                      + _dot(k * jnp.exp(last(n) - g_col), v_new,
-                             ((0,), (0,))))
+    g_col, beta_col = column(g_row), column(beta_row)
+    decay = jnp.exp(jnp.where(row >= col, g_col - g_row, -jnp.inf))
+    nt = ((1,), (1,))
+    below = row > col
+    if "kk" not in shared:
+        shared["kk"] = _dot(k, k, nt)
+    m = jnp.where(below, beta_col * shared["kk"] * decay, 0.0)
+    # T = (I + M)^-1, M nilpotent: diagonal blocks first, then the
+    # blocks' own (block-)nilpotent remainder
+    mm = ((1,), (0,))
+    ident = eye.astype(F32)
+    own = (row // _BLOCK) == (col // _BLOCK)
+    nd = jnp.where(own, -m, 0.0)
+    t_d, power = ident + nd, nd
+    for _ in range(3):                    # N^2, N^4, N^8: N^16 = 0
+        power = _dot(power, power, mm)
+        t_d = t_d + _dot(t_d, power, mm)
+    x = _dot(t_d, jnp.where(own, 0.0, -m), mm)
+    join = ident + x
+    join = join + _dot(join, _dot(x, x, mm), mm)          # X^4 = 0
+    t = _dot(join, t_d, mm)
+
+    e_col = jnp.exp(g_col)
+    w = _dot(t, k * (beta_col * e_col), mm)
+    u = _dot(t, v * beta_col, mm)
+    s = get_s()
+    v_new = u - _dot(w, s, mm)
+    causal = row >= col
+    if "qk" not in shared:
+        shared["qk"] = _dot(q, k, nt)
+    qk = jnp.where(causal, shared["qk"] * decay, 0.0)
+    put_o((_dot(q * e_col, s, mm) + _dot(qk, v_new, mm)).astype(o_dtype))
+    # G at the chunk's end, as a column (G never rises, so its least):
+    # Mosaic broadcasts along one axis at a time, a [1, 1] not at all
+    def last(rows):
+        return jnp.min(jnp.broadcast_to(g_row, (rows, n)), axis=1,
+                       keepdims=True)
+
+    put_s(s * jnp.exp(last(s.shape[0]))
+          + _dot(k * jnp.exp(last(n) - g_col), v_new, ((0,), (0,))))
 
 
 def gdn_chunk_prefill(q, k, v, g, beta, last_idx, interpret=None):
     """The gated delta rule over whole sequences, state zero at the start.
 
-    q/k [B, S, H, dk] float32 (L2-normed, ``q`` scaled), v [B, S, H, dv],
-    g/beta [B, S, H] float32; ``last_idx``: int32 scalar, the last position
-    that counts (every row's). Returns (o [B, S, H, dv] in v's dtype, zeros
-    past ``last_idx``'s chunk; the state after ``last_idx`` [B, H, dk, dv]
-    float32). ``S`` is padded to whole chunks inside."""
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
+    q/k [B, S, Hk, dk] float32 (L2-normed, ``q`` scaled), v [B, S, H, dv],
+    g/beta [B, S, H] float32 (``H`` a multiple of ``Hk``: grouped heads,
+    see the module's docstring); ``last_idx``: int32 scalar, the last
+    position that counts (every row's). Returns (o [B, S, H, dv] in v's
+    dtype, zeros past ``last_idx``'s chunk; the state after ``last_idx``
+    [B, H, dk, dv] float32). ``S`` is padded to whole chunks inside. A grid
+    step is a key head's chunk, for the value heads of its group."""
+    b, s, hk, dk = q.shape
+    h, dv = v.shape[2], v.shape[-1]
+    group = h // hk
     n = CHUNK
     pad = -s % n
     last = jnp.asarray(last_idx, jnp.int32)
@@ -192,7 +243,8 @@ def gdn_chunk_prefill(q, k, v, g, beta, last_idx, interpret=None):
     def heads_major(a, fill=0.0):
         a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2),
                     constant_values=fill)
-        return jnp.moveaxis(a, 2, 1).reshape((b * h, s + pad) + a.shape[3:])
+        return jnp.moveaxis(a, 2, 1).reshape(
+            (b * a.shape[2], s + pad) + a.shape[3:])
 
     nc = (s + pad) // n
     g = jnp.where(live, jnp.pad(g.astype(F32), ((0, 0), (0, pad), (0, 0))),
@@ -202,7 +254,12 @@ def gdn_chunk_prefill(q, k, v, g, beta, last_idx, interpret=None):
     g_sum = jnp.cumsum(g.reshape(b, nc, n, h), axis=2)
     gb = jnp.stack([jnp.moveaxis(g_sum, 3, 1),
                     jnp.moveaxis(beta.reshape(b, nc, n, h), 3, 1)],
-                   axis=3).reshape(b * h, nc, 2, n)
+                   axis=3)
+    if group == 1:
+        gb = gb.reshape(b * h, nc, 2, n)
+    else:                  # a key head's rows: (g, beta) of each value head
+        gb = jnp.moveaxis(gb.reshape(b, hk, group, nc, 2, n), 2, 3).reshape(
+            b * hk, nc, 2 * group, n)
 
     def chunk(c, last):      # past the last live chunk: that chunk, again
         return jnp.minimum(c, last[0] // n)
@@ -211,26 +268,34 @@ def gdn_chunk_prefill(q, k, v, g, beta, last_idx, interpret=None):
         return pl.BlockSpec((None, n, width),
                             lambda i, c, last: (i, chunk(c, last), 0))
 
+    vals, states = at(dv), pl.BlockSpec((None, dk, dv),
+                                        lambda i, c, last: (i, 0, 0))
+    lead, kernel = (b * h,), _chunk_kernel
+    if group > 1:          # a key head's value heads, side by side
+        vals = pl.BlockSpec((None, group, n, dv),
+                            lambda i, c, last: (i, 0, chunk(c, last), 0))
+        states = pl.BlockSpec((None, group, dk, dv),
+                              lambda i, c, last: (i, 0, 0, 0))
+        lead = (b * hk, group)
+        kernel = functools.partial(_chunk_kernel, group=group)
     o, state = pl.pallas_call(
-        _chunk_kernel,
-        out_shape=(jax.ShapeDtypeStruct((b * h, s + pad, dv), v.dtype),
-                   jax.ShapeDtypeStruct((b * h, dk, dv), F32)),
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct(lead + (s + pad, dv), v.dtype),
+                   jax.ShapeDtypeStruct(lead + (dk, dv), F32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b * h, nc),
-            in_specs=[at(dk), at(dk), at(dv),
-                      pl.BlockSpec((None, None, 2, n),
+            grid=(b * hk, nc),
+            in_specs=[at(dk), at(dk), vals,
+                      pl.BlockSpec((None, None, 2 * group, n),
                                    lambda i, c, last: (i, chunk(c, last),
                                                        0, 0))],
-            out_specs=(at(dv),
-                       pl.BlockSpec((None, dk, dv),
-                                    lambda i, c, last: (i, 0, 0)))),
+            out_specs=(vals, states)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret() if interpret is None else interpret,
         name="gdn_chunk_prefill",
     )(last.reshape(1), heads_major(q.astype(F32)), heads_major(k.astype(F32)),
-      heads_major(v), gb)
+      heads_major(v).reshape(lead + (s + pad, dv)), gb)
     # chunks past ``last_idx`` were never written: zeros, not what lay there
     o = jnp.moveaxis(o.reshape(b, h, s + pad, dv), 1, 2)
     chunk_live = (jnp.arange(s + pad) // n <= last // n)[None, :, None, None]
@@ -241,9 +306,10 @@ def gdn_chunk_prefill(q, k, v, g, beta, last_idx, interpret=None):
 # -- decode: one token a row -------------------------------------------------------
 def decode_step_xla(state, q, k, v, g, beta, live):
     """One position of the recurrence for every live row, as an XLA
-    composition. state [R, H, dk, dv] float32; q/k [R, H, dk], v [R, H, dv],
-    g/beta [R, H] float32; live [R] bool. Returns (o [R, H, dv] float32,
-    the states, a dead row's unchanged)."""
+    composition. state [R, H, dk, dv] float32; q/k [R, Hk, dk], v
+    [R, H, dv], g/beta [R, H] float32; live [R] bool. Returns (o [R, H, dv]
+    float32, the states, a dead row's unchanged)."""
+    q, k = _grouped(q, k, v.shape[1])
     hi = jax.lax.Precision.HIGHEST
     decay = jnp.exp(g)[..., None]
     d = beta[..., None] * (v - decay * jnp.einsum(
@@ -257,6 +323,7 @@ def _decode_kernel(rows_ref, n_ref, db_ref, s_ref, qt_ref, kt_ref, v_ref,
                    o_ref, s_out):
     i, j = pl.program_id(0), pl.program_id(1)
     group = s_ref.shape[0]
+    per_key = group // kt_ref.shape[1]     # value heads a key head
 
     # no live row at all: the one block that is fetched goes back as it came
     @pl.when((n_ref[0] == 0) & (i == 0))
@@ -268,7 +335,8 @@ def _decode_kernel(rows_ref, n_ref, db_ref, s_ref, qt_ref, kt_ref, v_ref,
         heads = pl.num_programs(1) * group
         for t in range(group):
             s = s_ref[t]                                   # [dk, dv]
-            k_col, q_col = kt_ref[:, t:t + 1], qt_ref[:, t:t + 1]
+            c = t // per_key
+            k_col, q_col = kt_ref[:, c:c + 1], qt_ref[:, c:c + 1]
             at = 2 * (rows_ref[i] * heads + j * group + t)
             decay, beta = db_ref[at], db_ref[at + 1]       # scalars
             ks = jnp.sum(k_col * s, axis=0, keepdims=True)       # [1, dv]
@@ -278,19 +346,23 @@ def _decode_kernel(rows_ref, n_ref, db_ref, s_ref, qt_ref, kt_ref, v_ref,
             o_ref[t:t + 1, :] = jnp.sum(q_col * s, axis=0, keepdims=True)
 
 
-def _head_group(h):
+def _head_group(h, per_key=1):
     """Heads a grid step of the decode kernel: the largest divisor of
-    ``h`` up to 10, whose states (in and out, double-buffered) take 3 MB
-    at 96 x 192."""
-    return max(d for d in range(1, min(h, 10) + 1) if h % d == 0)
+    ``h`` up to 10 that holds whole groups of ``per_key`` value heads (a
+    key head's), whose states (in and out, double-buffered) take 3 MB at
+    96 x 192."""
+    return max(d for d in range(per_key, max(min(h, 10), per_key) + 1,
+                                per_key) if h % d == 0)
 
 
 def gdn_decode_step(state, q, k, v, g, beta, live, interpret=None):
     """:func:`decode_step_xla` with the states updated IN PLACE (the
     ``state`` argument is aliased to the result: donate it), one read and
-    one write of each live row's state and none of a dead row's."""
+    one write of each live row's state and none of a dead row's. Grouped
+    heads (q/k [R, Hk, dk]): a grid step's block of q and k holds the key
+    heads of its value heads, each once."""
     r, h, dk, dv = state.shape
-    group = _head_group(h)
+    group = _head_group(h, h // q.shape[1])
     hg = h // group
     # live rows first, then the last live row repeated: a repeated block is
     # neither fetched nor written again
@@ -299,10 +371,10 @@ def gdn_decode_step(state, q, k, v, g, beta, live, interpret=None):
     rows = jnp.where(jnp.arange(r) < n_live, order,
                      order[jnp.maximum(n_live - 1, 0)])
 
-    def grouped(a):                  # [R, H, x] -> [R, hg, group, x]
-        return a.astype(F32).reshape(r, hg, group, a.shape[-1])
+    def grouped(a):                  # [R, H, x] -> [R, hg, H / hg, x]
+        return a.astype(F32).reshape(r, hg, a.shape[1] // hg, a.shape[-1])
 
-    def columns(a):                  # [R, H, dk] -> [R, hg, dk, group]
+    def columns(a):                  # [R, Hk, dk] -> [R, hg, dk, Hk / hg]
         return jnp.swapaxes(grouped(a), 2, 3)
 
     # exp(g) and beta of (row, head) as scalars, prefetched beside the rows
@@ -324,8 +396,8 @@ def gdn_decode_step(state, q, k, v, g, beta, live, interpret=None):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(r, hg),
-            in_specs=[spec(group, dk, dv), spec(dk, group), spec(dk, group),
-                      spec(group, dv)],
+            in_specs=[spec(group, dk, dv), spec(dk, q.shape[1] // hg),
+                      spec(dk, q.shape[1] // hg), spec(group, dv)],
             out_specs=(spec(group, dv), spec(group, dk, dv))),
         input_output_aliases={3: 1},
         compiler_params=pltpu.CompilerParams(

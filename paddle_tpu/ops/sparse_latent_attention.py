@@ -36,6 +36,11 @@ is largest. Five pieces, each pure and jittable:
 - :func:`sparse_latent_decode` (decode): XLA's gather of the chosen rows by
   their flat index in the pool, and the attention over them in latent
   space (the up-projection absorbed into the query and the output).
+- :func:`paged_latent_decode` (decode, no selection): a Pallas kernel on
+  ``dsa_index_scores``'s copy pattern that attends one absorbed query a
+  row, all heads against the one shared row of each position, over EVERY
+  position of the row's pages: an online softmax over the compute blocks,
+  the context out in latent space.
 """
 from __future__ import annotations
 
@@ -50,7 +55,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .paged_attention import _dot, _interpret
 
 __all__ = ["dsa_index_scores", "index_scores", "top_k_mask",
-           "sparse_latent_decode", "selected_attention"]
+           "sparse_latent_decode", "paged_latent_decode",
+           "selected_attention"]
 
 BLOCK_POSITIONS = 1024      # positions of one compute block of the kernel
 NEG = -1e30
@@ -66,13 +72,18 @@ def index_scores(q, w, keys):
     return jnp.sum(jnp.maximum(s, 0.0) * w[:, :, None], axis=1)
 
 
-def _index_scores_kernel(pt_ref, len_ref, q_ref, w_ref, k_hbm, o_ref, k_buf,
-                         sem, state):
-    """One batch row a grid step: relu(q k^T) weighted over the heads, for
-    every position of the row's pages, block by block (see the module's
-    docstring; the slot carried across grid steps is ``paged_decode``'s)."""
+def _page_walk(pt_ref, len_ref, k_hbm, k_buf, sem, state):
+    """The copy pattern of this module's paged kernels, for the grid step's
+    row: its pages in compute blocks of ``cpb`` pages, each page one copy
+    into one of two VMEM slots, the next block's copies (or the next live
+    row's first block's) in flight while this one is computed. A table
+    entry past a row's length is never looked at; a row of length 0 costs
+    no copy. ``state[0]`` carries the slot across grid steps
+    (``paged_decode``'s). Returns ``run(compute)``, which walks the row's
+    blocks and calls ``compute(blk, slot)`` once a block's pages are in
+    ``k_buf[slot]``."""
     row, nrows = pl.program_id(0), pl.num_programs(0)
-    _, cpb, ps, d = k_buf.shape
+    _, cpb, ps, _ = k_buf.shape
 
     def pages_of(r):
         ln = jnp.minimum(len_ref[r], pt_ref.shape[1] * ps)
@@ -107,29 +118,81 @@ def _index_scores_kernel(pt_ref, len_ref, q_ref, w_ref, k_hbm, o_ref, k_buf,
     nblk = (pages_of(row) + cpb - 1) // cpb
     slot0 = state[0]
     nxt = next_live(row + 1)
+
+    def run(compute):
+        def block(blk, _):
+            slot = (slot0 + blk) % 2
+            ends = blk + 1 == nblk
+            nr = jnp.where(ends, nxt, row)
+
+            @pl.when(nr < nrows)
+            def _prefetch():
+                block_copies(nr, jnp.where(ends, 0, blk + 1), 1 - slot,
+                             lambda c: c.start())
+
+            block_copies(row, blk, slot, lambda c: c.wait())
+            compute(blk, slot)
+            return None
+
+        jax.lax.fori_loop(0, nblk, block, None)
+        state[0] = (slot0 + nblk) % 2
+
+    return run
+
+
+def _index_scores_kernel(pt_ref, len_ref, q_ref, w_ref, k_hbm, o_ref, k_buf,
+                         sem, state):
+    """One batch row a grid step: relu(q k^T) weighted over the heads, for
+    every position of the row's pages, block by block (see the module's
+    docstring; the slot carried across grid steps is ``paged_decode``'s)."""
+    _, cpb, ps, d = k_buf.shape
+    run = _page_walk(pt_ref, len_ref, k_hbm, k_buf, sem, state)
     q, w = q_ref[0], w_ref[0]                      # [H, D], [H, 1]
     o_ref[0] = jnp.full(o_ref.shape[1:], NEG, jnp.float32)
 
-    def block(blk, _):
-        slot = (slot0 + blk) % 2
-        ends = blk + 1 == nblk
-        nr = jnp.where(ends, nxt, row)
-
-        @pl.when(nr < nrows)
-        def _prefetch():
-            block_copies(nr, jnp.where(ends, 0, blk + 1), 1 - slot,
-                         lambda c: c.start())
-
-        block_copies(row, blk, slot, lambda c: c.wait())
+    def compute(blk, slot):
         k = k_buf[slot].reshape(cpb * ps, d)
         if q.dtype != k.dtype:
             k = k.astype(jnp.float32)
         s = jnp.maximum(_dot(q, k, ((1,), (1,))), 0.0)     # [H, T]
         o_ref[0, pl.ds(blk, 1), :] = jnp.sum(w * s, axis=0, keepdims=True)
-        return None
 
-    jax.lax.fori_loop(0, nblk, block, None)
-    state[0] = (slot0 + nblk) % 2
+    run(compute)
+
+
+def _latent_decode_kernel(pt_ref, len_ref, q_ref, k_hbm, o_ref, k_buf, sem,
+                          state, m_sc, l_sc, acc_sc, *, scale):
+    """One batch row a grid step: the absorbed query's heads [H, W] against
+    every position's row [W] (``c | k_rope | zeros``; the query's zeros
+    meet the row's), an online softmax over the compute blocks, the
+    probabilities times the rows' first ``C`` lanes (``c``) into the
+    context [H, C]."""
+    _, cpb, ps, w = k_buf.shape
+    latent = acc_sc.shape[1]
+    run = _page_walk(pt_ref, len_ref, k_hbm, k_buf, sem, state)
+    n = len_ref[pl.program_id(0)]
+    q = q_ref[0]                                   # [H, W]
+    m_sc[...] = jnp.full(m_sc.shape, NEG, jnp.float32)
+    l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+    acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    def compute(blk, slot):
+        k = k_buf[slot].reshape(cpb * ps, w)
+        s = _dot(q, k, ((1,), (1,))) * scale               # [H, T]
+        pos = blk * (cpb * ps) + jax.lax.broadcasted_iota(
+            jnp.int32, (1, cpb * ps), 1)
+        s = jnp.where(pos < n, s, NEG)
+        m_prev = m_sc[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * alpha + _dot(
+            p.astype(k.dtype), k[:, :latent], ((1,), (0,)))
+        m_sc[...] = m_new
+
+    run(compute)
+    o_ref[0] = acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -216,6 +279,53 @@ def sparse_latent_decode(q_lat, q_rope, lat_pool, chosen, ok, scale):
     p = jax.nn.softmax(jnp.where(ok[:, None, :], s, NEG), axis=-1)
     return jnp.einsum("bhk,bkc->bhc", p.astype(kv.dtype), kv[..., :c],
                       preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_latent_decode(q_lat, q_rope, lat_pool, page_table, seq_lens, scale,
+                        interpret=None):
+    """Attention of one query a row over EVERY position of its paged cache
+    rows, in latent space (a Pallas kernel: see the module's docstring).
+
+    q_lat [B, H, C] (the query's nope part through the absorbed
+    up-projection), q_rope [B, H, R], lat_pool [num_pages, page_size,
+    W >= C + R] (``c | k_rope`` a token, then zeros), page_table
+    [B, max_pages] int32, seq_lens [B] int32 (0 = a dead row: no page is
+    copied and its context is zeros); ``scale`` the softmax's (static). A
+    compute block is ``BLOCK_POSITIONS`` positions. The query is rounded to
+    the pool's dtype, both
+    products accumulate in float32 and the softmax is float32, ``p``
+    rounded to the pool's dtype before ``p x c``. Returns the context in
+    latent space, [B, H, C] float32: the caller applies the value half of
+    the up-projection."""
+    b, h, c = q_lat.shape
+    ps, w = lat_pool.shape[1], lat_pool.shape[2]
+    cpb = max(1, BLOCK_POSITIONS // ps)
+    pad = jnp.zeros((b, h, w - c - q_rope.shape[-1]), lat_pool.dtype)
+    q = jnp.concatenate([q_lat.astype(lat_pool.dtype),
+                         q_rope.astype(lat_pool.dtype), pad], axis=-1)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, h, w), lambda bi, pt, ln: (bi, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, h, c), lambda bi, pt, ln: (bi, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, cpb, ps, w), lat_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((h, c), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_decode_kernel, scale=float(scale)),
+        out_shape=jax.ShapeDtypeStruct((b, h, c), jnp.float32),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret() if interpret is None else interpret,
+        name="paged_latent_decode",
+    )(page_table, seq_lens, q, lat_pool)
 
 
 def _tiling(s, h):

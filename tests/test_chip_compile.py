@@ -388,3 +388,35 @@ def test_grouped_matmul_sixty_four_experts(chip, rows, monkeypatch):
         chip, lambda x, w, n: ops.grouped_matmul(
             x, w, n, preferred_element_type=F32),
         ((rows, 768), BF16), ((64, 768, 2560), BF16), ((64,), I32)) == 1
+
+
+# -- latent attention beside grouped linear-attention layers, at its widths ----
+def test_paged_latent_decode(chip):
+    """The dense latent decode of 32 rows: 64 absorbed heads against one
+    640-wide row a position, a table of 2,176 pages of 16."""
+    import functools
+
+    from paddle_tpu.ops import sparse_latent_attention as sla
+
+    fn = functools.partial(sla.paged_latent_decode, scale=0.105304,
+                           interpret=False)
+    assert _compile(chip, fn, ((32, 64, 512), BF16), ((32, 64, 64), BF16),
+                    ((69632, 16, 640), BF16), ((32, 2176), I32),
+                    ((32,), I32)) == 1
+
+
+@pytest.mark.parametrize("s", [4096, 32768])
+def test_gdn_chunk_prefill_grouped(chip, s):
+    """The chunked scan with 64 value heads over 32 key heads of 128."""
+    qk, v = ((1, s, 32, 128), F32), ((1, s, 64, 128), BF16)
+    gate = ((1, s, 64), F32)
+    assert _compile(chip, gdn.gdn_chunk_prefill, qk, qk, v, gate, gate,
+                    ((), I32)) == 1
+
+
+def test_gdn_decode_step_grouped(chip):
+    """The one-token update of 32 rows' 64 states, 2 a key head."""
+    state, qk = ((32, 64, 128, 128), F32), ((32, 32, 128), F32)
+    assert _compile(chip, gdn.gdn_decode_step, state, qk, qk,
+                    ((32, 64, 128), F32), ((32, 64), F32), ((32, 64), F32),
+                    ((32,), jnp.bool_)) == 1
