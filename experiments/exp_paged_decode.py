@@ -1,24 +1,30 @@
 """``paged_decode`` on the chip at the serving cells' tables and live
 contexts: us a call and us a live page. Prints one JSON line a variant.
 
-Geometries (table rows x pages, heads, window) are the three serving
-cells' (PERF.md section 4); the live rows and their lengths are drawn once
-from a fixed seed at about the share of the table the cells' traced runs
-hold live. Variants:
+Geometries (table rows x pages, heads, window) are the serving cells'
+(PERF.md section 4); the live rows and their lengths are drawn once from a
+fixed seed at about the share of the table the cells' traced runs hold
+live. Variants:
 
 - ``arith``: ``mxu`` is the kernel as it is; ``vpu`` puts the
   repeat-multiply-reduce arithmetic of the old grid kernel, a page at a
   time on the vector unit, in place of ``_block_update`` (what the loop
-  over live pages gives before the products move to the matrix unit).
+  over live pages gives before the products move to the matrix unit);
+  ``copies`` puts a pass-through in its place, so that what is left is
+  the walk over the pages and their copies.
 - ``dead_rows_len``: the length a retired slot hands the kernel: 0 (what
   the models pass now) or ``stale`` (the 300 tokens a retired request left
   in ``lens``, what they passed before).
+
+Each line gives us a call and a live page, and the pages (``cpb``) and
+copies (K and V) of a compute block.
 
 ``--repo DIR`` times the kernel of another checkout (the parent's grid
 kernel) on the same inputs; it has no ``_block_update``, so only ``mxu``
 runs there and means "as it is".
 
-    python experiments/exp_paged_decode.py [--repo DIR] [--calls 20]
+    python experiments/exp_paged_decode.py [--repo DIR] [--calls 20] \
+        [--cells smallthinker_full,...] [--arith mxu,copies]
 
 ``--rehearse`` runs the same control flow at tiny tables on any device; its
 times mean nothing.
@@ -36,6 +42,9 @@ CELLS = {
     "longprompt": (16, 128, 32, 8, None, 3, (600, 1900)),
     "trinity_full": (32, 544, 32, 4, None, 24, (200, 2400)),
     "trinity_ring": (32, 129, 32, 4, 2048, 24, (200, 2400)),
+    "smallthinker_full": (24, 512, 28, 4, None, 13, (2500, 7100)),
+    "smallthinker_ring": (24, 257, 28, 4, 4096, 13, (2500, 7100)),
+    "olmo": (48, 320, 32, 32, None, 22, (300, 4500)),
 }
 D, PS, STALE = 128, 16, 300
 
@@ -65,6 +74,12 @@ def _block_update_vpu(carry, q, k, v, pos0, lo, hi, scale):
         acc = acc * jnp.transpose(alpha) + jnp.sum(p[:, :, None] * vf, axis=0)
         m = m_new
     return jnp.transpose(m), jnp.transpose(l), acc
+
+
+def _block_update_none(carry, q, k, v, pos0, lo, hi, scale):
+    """No arithmetic: the block's copies are waited for and nothing is
+    computed from them."""
+    return carry
 
 
 def inputs(geometry, stale, seed=0):
@@ -102,6 +117,8 @@ def main():
     ap.add_argument("--calls", type=int, default=20,
                     help="kernel calls in one program (a segment's layers)")
     ap.add_argument("--cells", default=",".join(CELLS))
+    ap.add_argument("--arith", default="",
+                    help="variants to run (default: every one there is)")
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny tables, any device: the control flow only")
     args = ap.parse_args()
@@ -122,6 +139,9 @@ def main():
     variants = {"mxu": getattr(pa, "_block_update", None)}
     if variants["mxu"] is not None:
         variants["vpu"] = _block_update_vpu
+        variants["copies"] = _block_update_none
+    if args.arith:
+        variants = {k: variants[k] for k in args.arith.split(",")}
     for cell in args.cells.split(","):
         a = inputs(cells[cell], stale)
         want = {dead: _ref(a, ln) for dead, ln in a["lens"].items()}
@@ -144,20 +164,24 @@ def main():
             for dead, lens in a["lens"].items():
                 ops = (a["q"], a["k"], a["v"], a["table"], jnp.asarray(lens))
                 out = jax.block_until_ready(fn(*ops))
+                cpb = pa._pages_per_block(PS, a["k"].shape[2])
                 n = 2 if args.rehearse else 30
                 t = time.perf_counter()
                 for _ in range(n):
                     out = fn(*ops)
                 jax.block_until_ready(out)
                 us = (time.perf_counter() - t) / (n * args.calls) * 1e6
-                one = pa.paged_decode_mha(*ops, window=a["window"])
-                err = np.abs(np.asarray(one.astype(jnp.float32))
-                             - want[dead]).max()
-                # a float32 query gives a float32 output: rounding p to
-                # bf16 anywhere would show as 1e-3 here, float32 as 1e-6
-                f32 = pa.paged_decode_mha(
-                    a["q"].astype(jnp.float32), *ops[1:], window=a["window"])
-                err32 = np.abs(np.asarray(f32) - want[dead]).max()
+                err = err32 = None
+                if arith != "copies":
+                    one = pa.paged_decode_mha(*ops, window=a["window"])
+                    err = float(np.abs(np.asarray(one.astype(jnp.float32))
+                                       - want[dead]).max())
+                    # a float32 query gives a float32 output: rounding p
+                    # to bf16 anywhere would show as 1e-3 here, float32
+                    # as 1e-6
+                    f32 = pa.paged_decode_mha(a["q"].astype(jnp.float32),
+                                              *ops[1:], window=a["window"])
+                    err32 = float(np.abs(np.asarray(f32) - want[dead]).max())
                 print(json.dumps({
                     "cell": cell, "arith": arith, "dead_rows_len": dead,
                     "rehearsal": args.rehearse,
@@ -165,11 +189,13 @@ def main():
                     "live_pages": a["live_pages"],
                     "table_pages": a["table_pages"],
                     "us_per_live_page": round(us / a["live_pages"], 4),
+                    "pages_per_block": cpb,
+                    "copies_per_block": 2 * cpb,
                     "kv_gb_per_s": round(
                         a["live_pages"] * 2 * PS * a["k"].shape[2] * D * 2
                         / us / 1e3, 1),
-                    "max_abs_err_vs_ref": float(err),
-                    "max_abs_err_f32_query": float(err32),
+                    "max_abs_err_vs_ref": err,
+                    "max_abs_err_f32_query": err32,
                     "finite": bool(jnp.isfinite(out).all())}), flush=True)
 
 

@@ -3465,27 +3465,54 @@ class PagedContinuousBatchingEngine:
         with sp:
             return run(n_steps, cfg, sp, on_dispatch)
 
+    def _kv_pages_copied(self, ctx):
+        """Pages the first step's ``paged_decode`` calls copy, K and V
+        each, summed over layers and the live rows of contexts ``ctx``,
+        by the kernel's own block arithmetic
+        (``paged_attention.pages_copied``). A layer whose first pool is
+        ``[pages, page, Hkv, D]`` calls the kernel; latent rows
+        (``[pages, page, row]``) and a row's state call none. A window
+        layer is handed its lengths counted from the window's first
+        page."""
+        from ..ops.paged_attention import pages_copied
+
+        pools, _ = self.caches
+        win = self._ring["window"] if self._ring is not None else None
+        n = 0
+        for i, entry in enumerate(pools):
+            if ((self._state_layers is not None and self._state_layers[i])
+                    or entry[0].ndim != 4):
+                continue
+            ps, hkv = entry[0].shape[1], entry[0].shape[2] // self.tp_degree
+            if win is not None and self._ring["window_layers"][i]:
+                lens = [c - max(c - win, 0) // ps * ps for c in ctx]
+                n += pages_copied(lens, ps, hkv, window=win)
+            else:
+                n += pages_copied(ctx, ps, hkv)
+        return n
+
     # lint: hot-path
     def _decode_segment_plain(self, n_steps: int, cfg, sp, on_dispatch):
-        if self._ring is not None and trace.enabled():
-            # what the two geometries hold at the segment's start, and
-            # the tokens a window layer's attention reads (a full
-            # layer's: ctx_tokens)
-            w = self._ring["window"]
-            held = [self.alloc.held_pages(slot) for slot in self._slot_req]
-            sp.set(ctx_tokens_window=sum(
-                       min(self._plen[rid] + len(self._tokens[rid]), w)
-                       for rid in self._slot_req.values()),
-                   pages_full=sum(h[0] for h in held),
-                   pages_window=sum(h[1] for h in held))
-        elif trace.enabled():
-            # the pages the live rows' contexts span at the segment's
-            # start (what ``paged_decode`` walks), of the table's
-            ps = self.page_size
-            sp.set(pages_live=sum(
-                       -(-(self._plen[rid] + len(self._tokens[rid])) // ps)
-                       for rid in self._slot_req.values()),
-                   pages_table=self.alloc.page_table.size)
+        if trace.enabled():
+            ctx = [self._plen[rid] + len(self._tokens[rid])
+                   for rid in self._slot_req.values()]
+            sp.set(kv_pages_copied=self._kv_pages_copied(ctx))
+            if self._ring is not None:
+                # what the two geometries hold at the segment's start,
+                # and the tokens a window layer's attention reads (a
+                # full layer's: ctx_tokens)
+                w = self._ring["window"]
+                held = [self.alloc.held_pages(slot)
+                        for slot in self._slot_req]
+                sp.set(ctx_tokens_window=sum(min(c, w) for c in ctx),
+                       pages_full=sum(h[0] for h in held),
+                       pages_window=sum(h[1] for h in held))
+            else:
+                # the pages the live rows' contexts span at the
+                # segment's start (what ``paged_decode`` walks), of the
+                # table's
+                sp.set(pages_live=sum(-(-c // self.page_size) for c in ctx),
+                       pages_table=self.alloc.page_table.size)
         t0 = time.perf_counter()
         fn = self._segment_fn(n_steps)
         # the host's three phases of a segment, a span each: handing

@@ -21,8 +21,11 @@ compute blocks of several pages: each page is one copy the kernel issues
 itself (``pltpu.make_async_copy`` from ``pool.at[table[row, j]]``, a
 ``[page_size, Hkv, D]`` slab that lies contiguous) into one of two VMEM
 slots, the next block's copies, or the next live row's first, in flight
-while this block is computed. Trip counts come from the lengths, so one
-compiled program serves every length; a table entry outside
+while this block is computed. A block's copies are straight-line code
+with no bounds check, and every block copies as many pages (a row's
+last block repeats its last page), so the core waits for a block with
+one wait a stream. Trip counts come from the lengths, so one compiled
+program serves every length; a table entry outside
 ``[first, last)`` is never looked at, a row of length 0 costs one grid
 step and no copy. (The kernel this replaced had a ``(rows, table pages)``
 grid with a block spec a page: it skipped the arithmetic of a page past a
@@ -59,7 +62,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..quantization.kv import KV_QMAX as _KV_QMAX
 
-__all__ = ["paged_decode_mha"]
+__all__ = ["paged_decode_mha", "pages_copied"]
 
 
 def _interpret() -> bool:
@@ -71,6 +74,19 @@ def _pages_per_block(page_size, hkv):
     a block's scores are one ``[Hq, 1024]`` product whatever the head
     geometry."""
     return max(1, 1024 // (page_size * hkv))
+
+
+def pages_copied(lens, page_size, hkv, window=None):
+    """Pages one ``paged_decode_mha`` call copies for rows of lengths
+    ``lens`` (host ints, as the kernel is handed them), each its K and its
+    V: a row copies whole compute blocks of ``_pages_per_block`` pages over
+    the pages it attends, and a row of length 0 copies none."""
+    cpb = _pages_per_block(page_size, hkv)
+    n = 0
+    for ln in lens:
+        first = 0 if window is None else max(ln - window, 0) // page_size
+        n += -(-(-(-ln // page_size) - first) // cpb) * cpb
+    return n
 
 
 def _dot(a, b, contract):
@@ -116,21 +132,24 @@ def _block_update(carry, q, k, v, pos0, lo, hi, scale):
 
 
 def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
-                         scale, window, quant):
+                         scale, window, quant, cols, unroll):
     """One batch row a grid step; the body walks the pages the row
     attends, ``[first, last)`` of its table row, in compute blocks, and
     issues every page copy itself.
 
-    ``pt_ref``/``len_ref`` are scalar-prefetched, the pools stay in HBM
-    (an int8 pool's scales arrive as the row's block, gathered by its
-    table). A block's pages land in one of two VMEM slots; while a block
-    is computed the copies of the row's next block, or of the next live
-    row's first, are in flight (``state`` carries the slot across grid
-    steps, which run in order on one core; the first live row's first
-    block is issued at row 0). A row of length 0 issues no copy and
-    writes zeros. A page past ``last`` in a row's last block is not
-    copied: the slot keeps an older page's finite values there (the slots
-    start zeroed), which the positions' mask leaves out."""
+    ``pt_ref`` (the table flattened, row ``r`` at ``r * cols``, every
+    entry inside the pool) and ``len_ref`` are scalar-prefetched, the
+    pools stay in HBM (an int8 pool's scales arrive as the row's block,
+    gathered by its table). A block's pages land in one of two VMEM
+    slots; while a block is computed the copies of the row's next block,
+    or of the next live row's first, are in flight (``state`` carries the
+    slot across grid steps, which run in order on one core; the first
+    live row's first block is issued at row 0). A row of length 0 issues
+    no copy and writes zeros. Every block copies ``cpb`` pages a stream,
+    issued with no branch on the length and waited for with one wait a
+    stream: a column past ``last`` in a row's last block copies the row's
+    last page again, whose finite values the positions' mask leaves
+    out."""
     if quant:
         ks_ref, vs_ref, o_ref, k_buf, v_buf, sem, state = rest
     else:
@@ -142,7 +161,7 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
 
     def length(r):
         # a length past the table's end attends what the table holds
-        return jnp.minimum(len_ref[r], pt_ref.shape[1] * ps)
+        return jnp.minimum(len_ref[r], cols * ps)
 
     def page_range(r):
         ln = length(r)
@@ -156,32 +175,39 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
             lambda i: (i < nrows) & (len_ref[jnp.minimum(i, nrows - 1)] == 0),
             lambda i: i + 1, r)
 
-    def block_copies(r, blk, slot, do):
-        """start / wait for every page copy of block ``blk`` of row ``r``."""
+    def start_block(r, blk, slot):
+        """Start the copies of block ``blk`` of row ``r``: ``cpb`` pages a
+        stream, with no branch on the row's length (straight-line code
+        where ``unroll``). A column past the row's last page copies that
+        page again: the positions' mask leaves it out, and every block
+        signals its slot's semaphores the bytes of a whole slot."""
         first, last = page_range(r)
         col0 = first + blk * cpb
 
-        def page_copies(i, _):
-            # an unmapped (-1) entry inside the range is the caller's
-            # fault; it reads page 0 and not outside the pool
-            page = jnp.maximum(pt_ref[r, col0 + i], 0)
+        def copy(i, _):
+            page = pt_ref[r * cols + jnp.minimum(col0 + i, last - 1)]
             for src, dst, s in streams:
-                do(pltpu.make_async_copy(src.at[page], dst.at[slot, i],
-                                         sem.at[s, slot]))
+                pltpu.make_async_copy(src.at[page], dst.at[slot, i],
+                                      sem.at[s, slot]).start()
 
-        jax.lax.fori_loop(0, jnp.minimum(last - col0, cpb), page_copies,
-                          None)
+        jax.lax.fori_loop(0, cpb, copy, None, unroll=unroll)
+
+    def wait_block(slot):
+        """One wait a stream for a block's ``cpb`` copies: a descriptor
+        the size of the whole slot (a DMA semaphore counts bytes), so no
+        table read and no descriptor a page."""
+        for _, dst, s in streams:
+            pltpu.make_async_copy(dst.at[slot], dst.at[slot],
+                                  sem.at[s, slot]).wait()
 
     @pl.when(row == 0)
     def _init():
         state[0] = 0           # the slot the next block lands in
-        for _, dst, _ in streams:
-            dst[...] = jnp.zeros_like(dst)
         r0 = next_live(0)
 
         @pl.when(r0 < nrows)
         def _():
-            block_copies(r0, 0, 0, lambda c: c.start())
+            start_block(r0, 0, 0)
 
     ln = length(row)
     first, last = page_range(row)
@@ -201,17 +227,16 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
 
         @pl.when(nr < nrows)
         def _prefetch():
-            block_copies(nr, jnp.where(ends, 0, blk + 1), 1 - slot,
-                         lambda c: c.start())
+            start_block(nr, jnp.where(ends, 0, blk + 1), 1 - slot)
 
-        block_copies(row, blk, slot, lambda c: c.wait())
+        wait_block(slot)
         k, v = k_buf[slot], v_buf[slot]                # [cpb, ps, Hkv, D]
         if quant:
             # fused dequant (quantization.kv conventions), after the copy:
             # the HBM read stays int8
-            cols = pl.ds(first + blk * cpb, cpb)
-            ksc = ks_ref[0, cols, :] * (1.0 / _KV_QMAX)    # [cpb, Hkv]
-            vsc = vs_ref[0, cols, :] * (1.0 / _KV_QMAX)
+            at = pl.ds(first + blk * cpb, cpb)
+            ksc = ks_ref[0, at, :] * (1.0 / _KV_QMAX)      # [cpb, Hkv]
+            vsc = vs_ref[0, at, :] * (1.0 / _KV_QMAX)
             k = k.astype(jnp.float32) * ksc[:, None, :, None]
             v = v.astype(jnp.float32) * vsc[:, None, :, None]
         return _block_update(
@@ -227,13 +252,14 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
 
 
 def _paged_decode_ref(q, k_pool, v_pool, page_table, seq_lens,
-                      k_scale=None, v_scale=None):
+                      k_scale=None, v_scale=None, window=None):
     """Pure-jnp reference the tests hold the kernel to: gather each
     row's pages dense and run a masked softmax — numerically equivalent
     to the kernel (same f32 math, plain instead of online softmax), NOT
     byte-identical, and it materializes [B, max_pages*page_size] KV.
     Quantized pools dequant here with the same ``quantization.kv``
-    conventions the fused kernel uses. No serving path calls it."""
+    conventions the fused kernel uses; ``window`` as the kernel's. No
+    serving path calls it."""
     b, h, d = q.shape
     hkv = k_pool.shape[2]
     ps = k_pool.shape[1]
@@ -252,8 +278,10 @@ def _paged_decode_ref(q, k_pool, v_pool, page_table, seq_lens,
         v = jnp.repeat(v, g, axis=2)
     s = jnp.einsum("bhd,blhd->blh", q.astype(jnp.float32), k)
     s = s * (1.0 / math.sqrt(d))
-    mask = (jnp.arange(L, dtype=jnp.int32)[None, :, None]
-            < seq_lens[:, None, None])
+    pos = jnp.arange(L, dtype=jnp.int32)[None, :, None]
+    mask = pos < seq_lens[:, None, None]
+    if window is not None:
+        mask &= pos >= seq_lens[:, None, None] - window
     s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=1)
     p = jnp.where(mask, p, 0.0)
@@ -331,12 +359,14 @@ def paged_decode_mha(q, k_pool, v_pool, page_table, seq_lens,
     scratch = [pltpu.VMEM((2, cpb) + k_pool.shape[1:], k_pool.dtype),
                pltpu.VMEM((2, cpb) + v_pool.shape[1:], v_pool.dtype)]
     in_specs = [row, hbm, hbm]
+    # every entry inside the pool: an unmapped (-1) entry inside a row's
+    # range is the caller's fault, and reads page 0, not outside the pool
+    table = jnp.clip(page_table, 0, k_pool.shape[0] - 1)
     if quant:
         # a row's scales, gathered by its table outside the kernel (32
         # bytes a page: too narrow a slab for a copy of its own) and
         # padded by a block, so the last block's slice stays inside
-        ids = jnp.pad(jnp.clip(page_table, 0, k_pool.shape[0] - 1),
-                      ((0, 0), (0, cpb)))
+        ids = jnp.pad(table, ((0, 0), (0, cpb)))
         operands += [k_scale[ids], v_scale[ids]]
         in_specs += [pl.BlockSpec((1, ids.shape[1], hkv),
                                   lambda bi, pt, ln: (bi, 0, 0))] * 2
@@ -350,15 +380,25 @@ def paged_decode_mha(q, k_pool, v_pool, page_table, seq_lens,
     )
     return pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=1.0 / math.sqrt(d),
-                          window=window, quant=quant),
+                          window=window, quant=quant,
+                          cols=page_table.shape[1],
+                          # straight-line copies for the chip's compiler;
+                          # the interpreter would compile each copy of an
+                          # unrolled block as code of its own (a minute at
+                          # the 256 pages a block of tiny test models)
+                          unroll=not it),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=grid_spec,
         # rows in order on one core: the slots and ``state`` carry a
-        # prefetch from one row to the next
+        # prefetch from one row to the next. No bounds checks: every
+        # copy's page is an entry of the clipped table, and every table
+        # read is at a column below the row's ``last`` (the length is
+        # clipped to the table), so each address is inside its buffer;
+        # the checks cost two compare chains a copy on the core
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",), disable_bounds_checks=True),
         interpret=it,
         # a stable name: compiled text and profiler traces find the
         # kernel by it
         name="paged_decode",
-    )(page_table, seq_lens, *operands)
+    )(table.reshape(-1), seq_lens, *operands)

@@ -359,6 +359,26 @@ def test_paged_decode_seven_query_heads_a_kv_head(chip, window, cols):
                     ((b, cols), I32), ((b,), I32)) == 1
 
 
+@pytest.mark.parametrize("hq, hkv, window, cols, kv_dtype", [
+    (28, 4, 4096, 257, "int8"), (32, 32, None, 320, "bf16"),
+    (32, 32, None, 320, "int8")],
+    ids=["seven_a_kv_head_ring_int8", "thirty_two_stored", "thirty_two_int8"])
+def test_paged_decode_straight_line_copies(chip, hq, hkv, window, cols,
+                                           kv_dtype):
+    """A block's copies as straight-line code, one wait a stream, no
+    bounds checks: 16 pages a block at 4 KV heads, 2 at 32, int8 pools
+    with their scales."""
+    import functools
+
+    b, d, ps = 24, 128, 16
+    pool = ((b * cols, ps, hkv, d), I8 if kv_dtype == "int8" else BF16)
+    shapes = [((b, hq, d), BF16), pool, pool, ((b, cols), I32), ((b,), I32)]
+    if kv_dtype == "int8":
+        shapes += [((b * cols, hkv), F32)] * 2
+    fn = functools.partial(pa.paged_decode_mha, window=window)
+    assert _compile(chip, fn, *shapes) == 1
+
+
 def test_window_flash_fwd_at_seven_query_heads_a_kv_head(chip):
     """The widest prefill bucket of a window layer: 28 query / 4 KV heads
     x 128, 6,144 positions, window 4,096."""

@@ -722,3 +722,86 @@ def test_p_enters_the_second_product_unrounded():
     assert all(str(e.outvars[0].aval.dtype) == "float32" for e in dots)
     assert all(pr == jax.lax.Precision.HIGHEST
                for pr in dots[1].params["precision"])
+
+
+# -- every block copies the same pages, issued in straight-line code: the
+# -- lengths at a compute block's edges, at the serving cells' geometries
+BLOCK_GEOMETRIES = {
+    # name: Hq, Hkv, D, window, pool dtype
+    "smallthinker_28_4_window": (28, 4, 128, 512, "float32"),
+    "mistral_32_8": (32, 8, 128, None, "float32"),
+    "olmo_32_32": (32, 32, 128, None, "float32"),
+    "mistral_32_8_int8": (32, 8, 128, None, "int8"),
+}
+
+
+def _block_lengths(cpb):
+    """Row lengths at a compute block's edges (``cpb`` pages of ``PS``)."""
+    blk = cpb * PS
+    return {
+        "block_multiples": [blk, 2 * blk],
+        "a_page_more_and_less": [blk + PS, blk - PS],
+        "a_token_more_and_less": [blk + 1, 2 * blk - 1],
+        "one_token": [1, 1],
+        "dead_row": [0, blk + 3],
+        "every_row_dead": [0, 0],
+        # the last block holds one page: a window of 512 rings over 33
+        # pages, 2 blocks of 16 and 1 page, as 4,096 rings over 257
+        "last_block_one_page": [2 * blk + 5, 2 * blk + PS],
+    }
+
+
+@pytest.mark.parametrize("lengths", list(_block_lengths(1)))
+@pytest.mark.parametrize("geometry", list(BLOCK_GEOMETRIES))
+def test_block_edges_match_reference(geometry, lengths):
+    """The kernel against ``_paged_decode_ref`` at lengths on and next to
+    a compute block's edges. A row's last block copies the row's last page
+    again past its length, and that page's positions past the length hold
+    large finite values: the positions' mask leaves both out."""
+    from paddle_tpu.ops.paged_attention import (_pages_per_block,
+                                                _paged_decode_ref)
+    from paddle_tpu.quantization.kv import KV_QMAX
+
+    hq, hkv, d, window, dtype = BLOCK_GEOMETRIES[geometry]
+    lens = _block_lengths(_pages_per_block(PS, hkv))[lengths]
+    maxp = -(-max(max(lens), 1) // PS)
+    q, k, v, table = _pool_and_table(lens, hq, hkv, d, maxp=maxp, seed=4)
+    for r, ln in enumerate(lens):
+        if ln % PS:
+            last = table[r, ln // PS]
+            k[last, ln % PS:], v[last, ln % PS:] = 1e30, 1e30
+    args = [q, k, v, table]
+    if dtype == "int8":
+        def quantize(x):
+            scale = np.abs(x).max(axis=(1, 3))              # [pages, Hkv]
+            scale = np.where(scale > 1e20, 1.0, scale)      # the 1e30 tails
+            xq = np.clip(np.round(x / scale[:, None, :, None] * KV_QMAX),
+                         -KV_QMAX, KV_QMAX)
+            return xq.astype(np.int8), scale.astype(np.float32)
+
+        (kq, ks), (vq, vs) = quantize(k), quantize(v)
+        args = [q, kq, vq, table, ks, vs]
+    args = [jnp.asarray(a) for a in args]
+    lens = jnp.asarray(lens, jnp.int32)
+    got = np.asarray(paged_decode_mha(*args[:4], lens, *args[4:],
+                                      window=window))
+    want = np.asarray(_paged_decode_ref(*args[:4], lens, *args[4:],
+                                        window=window))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert not got[np.asarray(lens) == 0].any()
+
+
+@pytest.mark.parametrize("lens, page_size, hkv, window, copied", [
+    ([0, 1, 128, 129, 127], 16, 8, None, 0 + 8 + 8 + 16 + 8),
+    ([4096 + 15, 4096 - 1], 16, 4, 4096, 17 * 16 + 16 * 16),
+    ([33, 32, 0], 16, 32, None, 4 + 2),
+    ([5000], 16, 4, 4096, 17 * 16),
+], ids=["mistral", "smallthinker_ring", "olmo", "window_from_a_late_page"])
+def test_pages_copied_counts_whole_blocks(lens, page_size, hkv, window,
+                                          copied):
+    """``pages_copied``: whole compute blocks over the pages a row attends
+    (a window's from its first page), none for a row of length 0."""
+    from paddle_tpu.ops.paged_attention import pages_copied
+
+    assert pages_copied(lens, page_size, hkv, window=window) == copied

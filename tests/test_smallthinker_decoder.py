@@ -186,6 +186,8 @@ def test_engine_serves_two_rows_and_counts():
     ring = ring_pages(WINDOW, PAGE)
     assert first["pages_window"] == 2 * ring
     assert first["pages_full"] == -(-(WINDOW + 18) // PAGE) + -(-17 // PAGE)
+    # one compute block a row and a layer: 256 pages of 4 tokens, 1 KV head
+    assert first["kv_pages_copied"] == cfg.num_hidden_layers * 2 * 256
     k, layers = cfg.moe_num_active_primary_experts, cfg.num_hidden_layers
     for e in seg:
         # summed over the segment's steps and the four expert layers

@@ -608,6 +608,29 @@ class TestServingTree:
         assert [(e["pages_live"], e["pages_table"]) for e in segs] == [
             (2, 32), (6, 32), (4, 32)]
 
+    def test_segment_kv_pages_copied(self, tr):
+        """``kv_pages_copied``: the pages the first step's ``paged_decode``
+        copies, whole compute blocks a live row. Pages of 64 tokens over
+        4 KV heads make a block of 4 pages (256 tokens)."""
+        from paddle_tpu.ops.paged_attention import _pages_per_block
+
+        model, mcfg = tiny_model()
+        assert _pages_per_block(64, mcfg.num_key_value_heads) == 4
+        eng = paged_engine(model, num_pages=32, page_size=64, max_pages=8)
+        try:
+            eng.add_request(_prompts(mcfg, 1, plen=300)[0], _greedy(12))
+            eng.decode_segment(4)           # 301: 5 pages, 2 blocks
+            eng.add_request(_prompts(mcfg, 1, plen=9, seed=1)[0],
+                            _greedy(3))
+            eng.decode_segment(4)           # 305 and 10: 2 + 1 blocks
+            eng.decode_segment(4)           # b retired
+        finally:
+            eng.close()
+        segs = [e for e in trace.events()
+                if e["phase"] == "engine.segment"]
+        assert [(e["pages_live"], e["kv_pages_copied"]) for e in segs] == [
+            (5, 8), (6, 12), (5, 8)]
+
     def test_chunked_and_warm_prefill_spans(self, tr):
         """A chunked admission's programs are children of its
         ``prefill_chunk`` spans (the claim and the mini under
